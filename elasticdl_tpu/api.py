@@ -164,11 +164,16 @@ def _run_local_job(args):
 
     if getattr(args, "port", None) is None:
         args.port = 0  # local mode: bind an ephemeral port
-    if getattr(args, "num_ps_pods", 0) > 0:
+    if (
+        getattr(args, "num_ps_pods", 0) > 0
+        and args.distribution_strategy != "AllreduceStrategy"
+    ):
         # local mode never launches PS processes: every worker talks to
         # the master, so the master must hold the optimizer. With the
         # (cluster-oriented) default num_ps_pods=1 left in place the
         # master would hold none and dense gradients would be rejected.
+        # (Under AllreduceStrategy the flag means nothing: parameters
+        # live with the workers and the master holds no model.)
         logger.info(
             "local mode ignores --num_ps_pods=%d (no local PS fleet); "
             "the master holds the model",
@@ -236,7 +241,7 @@ def _run_local_job(args):
                 AllReduceWorker,
             )
 
-            AllReduceWorker(
+            worker = AllReduceWorker(
                 worker_id=0,
                 job_type=master.job_type,
                 minibatch_size=args.minibatch_size,
@@ -259,7 +264,15 @@ def _run_local_job(args):
                 keep_checkpoint_max=getattr(
                     args, "keep_checkpoint_max", 0
                 ),
-            ).run()
+            )
+            try:
+                worker.run()
+            except Exception:
+                # same as the eval-only path above: stop the master
+                # rather than leave it polling requeued tasks forever
+                master.request_stop()
+                master.run(poll_secs=0.2)
+                raise
             return master.run(poll_secs=0.2)
 
         from elasticdl_tpu.worker.worker import Worker
@@ -308,16 +321,6 @@ def _run_local_job(args):
     # local workers all share this host; the allreduce coordinator must
     # advertise an address the sibling processes can dial
     env.setdefault("EDL_COMM_HOST", "localhost")
-    # persistent XLA compilation cache shared by every worker process:
-    # a relaunched (or standby-promoted) worker re-compiles the same
-    # HLO its predecessors already built — with the cache that compile
-    # is a disk hit, cutting world re-formation from ~15 s to ~1 s
-    env.setdefault(
-        "JAX_COMPILATION_CACHE_DIR",
-        os.path.join(
-            os.path.expanduser("~"), ".cache", "elasticdl_tpu", "xla"
-        ),
-    )
 
     def worker_command(worker_id):
         return [
@@ -353,7 +356,12 @@ def _run_local_job(args):
     )
     master.instance_manager = manager
     manager.start_workers()
-    return master.run(poll_secs=1)
+    rc = master.run(poll_secs=1)
+    # the job is over, but a worker may still be landing its last
+    # checkpoint, and it holds the accelerator until it exits: return
+    # only once the processes this command started are gone
+    manager.wait_stopped(grace_secs=120)
+    return rc
 
 
 # -- CLI --------------------------------------------------------------------

@@ -14,14 +14,6 @@ import sys
 def main(argv=None):
     argv = sys.argv[1:] if argv is None else argv
     try:
-        from elasticdl_tpu.common.jax_platform import (
-            honor_jax_platforms_env,
-        )
-
-        honor_jax_platforms_env()
-    except ImportError:
-        pass  # the api import below reports the broken build
-    try:
         from elasticdl_tpu import api
     except ImportError:
         print(

@@ -568,8 +568,8 @@ def add_common_args_between_master_and_worker(parser):
         "Elastic allreduce plane: AOT-compile the train step for likely "
         "next world sizes (current±1 and membership-service hints) on a "
         "background thread during steady-state training, so a resize to "
-        "a pre-compiled size pays state re-placement only; pair with "
-        "EDL_COMPILE_CACHE_DIR so relaunched processes skip XLA "
+        "a pre-compiled size pays state re-placement only; the "
+        "persistent compile cache lets relaunched processes skip XLA "
         "compiles too (docs/compile_plane.md)",
     )
     parser.add_argument(

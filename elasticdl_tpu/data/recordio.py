@@ -163,6 +163,17 @@ def create_recordio(path):
     return RecordIOWriter(path)
 
 
+def reader_kind():
+    """``"native"`` when :func:`open_recordio` serves the C++ reader in
+    this process, ``"python"`` when it serves the portable one."""
+    try:
+        from elasticdl_tpu.native import native_lib
+
+        return "native" if native_lib() is not None else "python"
+    except (ImportError, OSError):
+        return "python"
+
+
 def open_recordio(path):
     """Reader factory: the C++ mmap reader when built, else the Python one.
 

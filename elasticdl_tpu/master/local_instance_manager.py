@@ -68,10 +68,15 @@ class LocalInstanceManager:
 
         self._lock = threading.Lock()
         self._procs = {}  # instance key -> Popen
+        self._stopped_procs = []  # signalled by stop, not yet reaped
         self._rekeyed = {}  # id(proc) -> current key (standby promotions)
         self.exit_codes = {}  # instance key -> last observed returncode
         self._next_worker_id = 0
         self._relaunches = 0
+        # watcher threads between "my process exited" and "its
+        # replacement (if any) is in the proc table": while one is in
+        # there, an empty worker table does not yet mean nobody is left
+        self._deciding = 0
         self._stopping = False
         self._watchers = []
         self.status = InstanceManagerStatus.PENDING
@@ -177,6 +182,16 @@ class LocalInstanceManager:
             if self._procs.get(key) is not proc or self._stopping:
                 return
             del self._procs[key]
+            self._deciding += 1
+        try:
+            self._on_exit(key, returncode)
+        finally:
+            with self._lock:
+                self._deciding -= 1
+
+    def _on_exit(self, key, returncode):
+        """The elasticity decision for one exited instance: requeue its
+        work, tell the membership, relaunch or promote a replacement."""
         kind, instance_id = key
         if kind == "standby":
             # a spare died before promotion: forget its token, refill —
@@ -476,6 +491,23 @@ class LocalInstanceManager:
                 if k[0] == "worker" and p.poll() is None
             ]
 
+    def workers_exhausted(self):
+        """True once workers were started and none is left or coming:
+        no worker or standby process in the table and no watcher still
+        deciding on a replacement (clean exits, a spent relaunch budget
+        and the Never policy all end here). The master fails a job that
+        still has tasks outstanding at that point, because nobody will
+        ever take them."""
+        with self._lock:
+            return (
+                self.status == InstanceManagerStatus.RUNNING
+                and not self._stopping
+                and self._deciding == 0
+                and not any(
+                    k[0] in ("worker", "standby") for k in self._procs
+                )
+            )
+
     def wait(self, timeout=None):
         """Block until every instance process has exited."""
         with self._lock:
@@ -493,6 +525,33 @@ class LocalInstanceManager:
         with self._lock:
             procs = list(self._procs.values())
             self._procs.clear()
+            self._stopped_procs.extend(procs)
         for proc in procs:
             if proc.poll() is None:
                 proc.terminate()
+
+    def wait_stopped(self, grace_secs):
+        """After :meth:`stop_relaunch_and_remove_all_pods`: block until
+        the processes it signalled have exited, SIGKILLing whatever is
+        still alive after ``grace_secs``. A worker holds its accelerator
+        until it exits, and an elastic worker answers SIGTERM by
+        finishing what it is doing (landing a checkpoint, leaving the
+        world), so a caller that is about to hand the machine to someone
+        else waits here first."""
+        import time
+
+        with self._lock:
+            procs, self._stopped_procs = self._stopped_procs, []
+        deadline = time.monotonic() + grace_secs
+        for proc in procs:
+            try:
+                proc.wait(timeout=max(0.0, deadline - time.monotonic()))
+            except subprocess.TimeoutExpired:
+                logger.warning(
+                    "instance pid %d still alive %.0fs after SIGTERM; "
+                    "killing it",
+                    proc.pid,
+                    grace_secs,
+                )
+                proc.kill()
+                proc.wait()

@@ -556,7 +556,16 @@ class Master:
             self.instance_manager.start_workers()
 
     def run(self, poll_secs=30):
-        """Poll until all tasks are done (reference master.py:178-195)."""
+        """Poll until all tasks are done (reference master.py:178-195).
+
+        Returns the job's exit code: 0 when the ledger drained (or a
+        stop was requested), 1 when a local job's worker processes are
+        all gone for good with tasks still outstanding — nobody would
+        ever take them, so polling on would hang the job forever."""
+        rc = 0
+        exhausted = getattr(
+            self.instance_manager, "workers_exhausted", None
+        )
         try:
             while not self._stop_requested.is_set():
                 if self.task_d.finished():
@@ -564,12 +573,21 @@ class Master:
                         continue  # a SAVE_MODEL task was just queued
                     self._linger_for_pollers()
                     break
+                if exhausted is not None and exhausted():
+                    logger.error(
+                        "every worker process has exited and none will "
+                        "be relaunched, with tasks outstanding (%s): "
+                        "failing the job",
+                        self.task_d.queue_depths(),
+                    )
+                    rc = 1
+                    break
                 self._stop_requested.wait(poll_secs)
         except KeyboardInterrupt:
             logger.warning("Master stopping")
         finally:
             self.stop()
-        return 0
+        return rc
 
     def _linger_for_pollers(self):
         """Serve briefly past the last ack when REMOTE workers exist.
@@ -674,10 +692,8 @@ def main():
     import os as _os
 
     from elasticdl_tpu.common.args import parse_master_args
-    from elasticdl_tpu.common.jax_platform import honor_jax_platforms_env
     from elasticdl_tpu.utils import profiling
 
-    honor_jax_platforms_env()
     args = parse_master_args()
     # name this process in every span id / postmortem header (entry
     # points only: in-process masters keep the owning process's tag)

@@ -74,9 +74,9 @@ class StandbyPool:
     to membership until the instance manager :meth:`activate`-s it with
     a real worker id, at which point the poll returns that id and the
     standby proceeds into the ordinary worker path. This converts the
-    relaunch cost of a kill — measured at ~45-50 s of the ~65 s total
-    recovery in BASELINE.md r3, almost all of it a fresh process
-    importing jax — into membership-only cost."""
+    relaunch cost of a kill — measured at ~45-50 s of a ~65 s total
+    recovery, almost all of it a fresh process importing jax — into
+    membership-only cost."""
 
     def __init__(self):
         self._lock = threading.Lock()

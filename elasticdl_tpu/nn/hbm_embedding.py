@@ -35,7 +35,6 @@ from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from elasticdl_tpu.nn.sparse_comms import padded_unique
-from elasticdl_tpu.parallel.ring_attention import shard_map
 
 
 METRICS_COLLECTION = "metrics"
@@ -133,12 +132,12 @@ def sharded_lookup(table, ids, mesh, axis):
     batch_axis = "data" if ("data" in axes and axis != "data") else None
     ids_spec = P(*([batch_axis] + [None] * (ids.ndim - 1)))
     out_spec = P(*([batch_axis] + [None] * ids.ndim))
-    return shard_map(
+    return jax.shard_map(
         _lookup,
         mesh=mesh,
         in_specs=(P(axis, None), ids_spec),
         out_specs=out_spec,
-        check_rep=False,
+        check_vma=False,
     )(table, ids)
 
 
@@ -319,12 +318,12 @@ def all_to_all_lookup(
         return rows, n_over
 
     out_spec = P(batch_axis, None)
-    out = shard_map(
+    out = jax.shard_map(
         _lookup,
         mesh=mesh,
         in_specs=(P(axis, None), P(batch_axis)),
         out_specs=(out_spec, P()) if return_overflow else out_spec,
-        check_rep=False,
+        check_vma=False,
     )(table, flat)
     if return_overflow:
         rows, n_over = out

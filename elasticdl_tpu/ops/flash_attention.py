@@ -21,8 +21,10 @@ with ``delta = rowsum(dO * O)``. Peak memory in backward is O(block^2)
 per core — no (L, L) materialization anywhere (round-1 advisor finding:
 the previous backward re-ran dense reference attention).
 
-On non-TPU backends the kernels run in Pallas interpret mode (tests), so
-numerics are identical everywhere.
+On a TPU backend the kernels compile through Mosaic. They run in Pallas
+interpret mode only in a process that was explicitly put on the CPU
+(tests, rehearsals); a CPU backend JAX fell back to, or any other
+platform, raises (:func:`kernel_interpret_mode`).
 """
 
 import functools
@@ -34,6 +36,22 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 _LANES = 128  # stats are broadcast across a full lane register
+
+# stable kernel names: the Mosaic custom call carries them as
+# ``kernel_name``, which is how a lowered step (and a profiler trace)
+# shows that the fused kernels are in it
+FWD_KERNEL = "edl_flash_fwd"
+BWD_DQ_KERNEL = "edl_flash_bwd_dq"
+BWD_DKV_KERNEL = "edl_flash_bwd_dkv"
+
+# every grid is (batch*heads, outer tile, inner tile) and accumulates
+# over the innermost axis only. No vmem_limit_bytes: on v5e / libtpu
+# 0.0.34 the default 1024x1024 tiles (and 1024x2048 at L=2048) compile
+# under Mosaic's default scoped-VMEM limit, with or without these
+# parameters (chip run, PR 21).
+_COMPILER_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary")
+)
 
 
 def _fwd_kernel(
@@ -245,16 +263,19 @@ def _unfold_heads(x, b, h):
 def divisible(lq, lk, block_q, block_k):
     """True when the fused kernels can tile these lengths.
 
-    On real TPU hardware Mosaic additionally needs the (possibly
-    clamped) block sizes aligned to the 8-sublane register shape;
-    interpret mode (tests) has no such constraint.
+    On a TPU the Pallas lowering additionally wants each (possibly
+    clamped) block size along the sequence divisible by 8, or equal to
+    the whole length, whatever the dtype; interpret mode has no such
+    constraint. (v5e / libtpu 0.0.34, chip run, PR 21: bf16 and f32
+    alike, 8x8 up to 1000x1000 and whole odd lengths 7, 36, 100 build;
+    4, 12, 20 or 50 rows out of a longer sequence are refused.)
     """
     bq, bk = min(block_q, lq), min(block_k, lk)
     if lq % bq or lk % bk:
         return False
-    if _use_interpret():
+    if kernel_interpret_mode():
         return True
-    return bq % 8 == 0 and bk % 8 == 0
+    return (bq % 8 == 0 or bq == lq) and (bk % 8 == 0 or bk == lk)
 
 
 def _block_sizes(lq, lk, block_q, block_k):
@@ -301,7 +322,9 @@ def _flash_fwd(q, k, v, causal, block_q, block_k, interpret):
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, _LANES), jnp.float32),
         ],
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
+        name=FWD_KERNEL,
     )(qf, kf, vf)
     return (
         _unfold_heads(out, b, h),
@@ -355,7 +378,9 @@ def _flash_bwd(
             (1, block_q, d), lambda i, qi, kj: (i, qi, 0)
         ),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
+        name=BWD_DQ_KERNEL,
     )(qf, kf, vf, dof, lse_l, delta_l)
 
     stat_spec_kmajor = pl.BlockSpec(
@@ -384,7 +409,9 @@ def _flash_bwd(
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
+        name=BWD_DKV_KERNEL,
     )(qf, kf, vf, dof, lse_l, delta_l)
     return (
         _unfold_heads(dq, b, h),
@@ -393,18 +420,39 @@ def _flash_bwd(
     )
 
 
-def _use_interpret():
-    return jax.default_backend() not in ("tpu",)
+def kernel_interpret_mode():
+    """False on a TPU backend (the kernels compile through Mosaic); True
+    only when this process was explicitly put on the CPU, i.e. ``cpu``
+    leads ``jax_platforms`` (``JAX_PLATFORMS=cpu``, or the
+    ``EDL_DIST_PLATFORM=cpu`` world bring-up, which sets that config).
+
+    Anything else raises: with the platform left open, a TPU whose
+    start-up failed (held by another process, say) leaves JAX on the
+    CPU, and interpreting the kernels there would train at a crawl
+    while looking like a device run."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    requested = (jax.config.jax_platforms or "").split(",")[0].strip()
+    if backend == "cpu" and requested == "cpu":
+        return True
+    raise RuntimeError(
+        "flash attention: the JAX backend is %r but jax_platforms is %r; "
+        "the Pallas kernels compile on TPU and are interpreted only "
+        "where the CPU was asked for by name (JAX_PLATFORMS=cpu). A TPU "
+        "that failed to initialise, or is held by another process, "
+        "lands here." % (backend, jax.config.jax_platforms)
+    )
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def _flash_with_lse(q, k, v, causal, block_q, block_k):
-    return _flash_fwd(q, k, v, causal, block_q, block_k, _use_interpret())
+    return _flash_fwd(q, k, v, causal, block_q, block_k, kernel_interpret_mode())
 
 
 def _fwd_rule(q, k, v, causal, block_q, block_k):
     out, lse = _flash_fwd(
-        q, k, v, causal, block_q, block_k, _use_interpret()
+        q, k, v, causal, block_q, block_k, kernel_interpret_mode()
     )
     return (out, lse), (q, k, v, out, lse)
 
@@ -422,7 +470,7 @@ def _bwd_rule(causal, block_q, block_k, residuals, cotangents):
         causal,
         block_q,
         block_k,
-        _use_interpret(),
+        kernel_interpret_mode(),
         g_lse=g_lse,
     )
 
@@ -440,11 +488,10 @@ def auto_blocks(lq, lk, block_q=None, block_k=None):
     path). Larger q-tiles amortize the streamed K/V; an r4 re-sweep
     found 1024-row q-tiles a further win everywhere measured (L=1024
     b16 h12: 6.92 vs 7.14 ms; L=4096 b4 h8: 14.45 vs 15.68 ms; L=2048
-    tied) — the k-tile caps at 1024 to keep the (block_q, block_k)
-    score tile within VMEM alongside the backward's recompute buffers
-    (2048-wide k-tiles fail to compile). Explicit sizes always win;
-    None picks the largest measured-good divisor of the sequence
-    length.
+    tied). The k-tile caps at 1024 because nothing wider was swept, not
+    because it cannot be built: 1024x2048 compiles on v5e / libtpu
+    0.0.34 (chip run, PR 21). Explicit sizes always win; None picks the
+    largest measured-good divisor of the sequence length.
     """
     if block_q is None:
         block_q = next(
@@ -478,6 +525,22 @@ def flash_attention(q, k, v, causal=False, block_q=None, block_k=None):
     """(B, L, H, D) fused attention; trains with the blockwise backward."""
     out, _ = flash_attention_with_lse(q, k, v, causal, block_q, block_k)
     return out
+
+
+def attention_in_step(step_facts):
+    """Name the attention a built step runs, from the facts
+    ``ElasticDPTrainer.describe_step`` reads off that step: ``"pallas"``
+    (the fused kernels are Mosaic custom calls in the lowered module),
+    ``"pallas-interpret"`` (the kernels are in the jaxpr, interpreted:
+    a process put on the CPU by request) or ``"xla"`` (the reference
+    attention :func:`pick_causal_attention` hands short or untileable
+    lengths)."""
+    flash = {FWD_KERNEL, BWD_DQ_KERNEL, BWD_DKV_KERNEL}
+    if flash <= set(step_facts["mosaic_kernels"]):
+        return "pallas"
+    if flash <= set(step_facts["pallas_kernels"]):
+        return "pallas-interpret"
+    return "xla"
 
 
 def pick_causal_attention(seq_len, use_flash=True, min_flash_len=1024):

@@ -25,10 +25,12 @@ by the elastic trainer, the bench harness, and the tests:
   (edlint R5); the thread is daemonized AND joined on shutdown (R4); a
   hint for a size that never materializes is simply dropped.
 
-- :func:`enable_persistent_cache` — wires jax's persistent compilation
-  cache (``EDL_COMPILE_CACHE_DIR``) so a FRESH PROCESS (relaunched pod,
-  promoted standby) skips the XLA compile too: the in-memory cache
-  cannot outlive the process, but the HLO-keyed disk cache does.
+- :func:`enable_persistent_cache` — turns on jax's persistent
+  compilation cache (under ``JAX_COMPILATION_CACHE_DIR`` when that is
+  set, else the checkout's ``.jax_cache``) so a FRESH PROCESS
+  (relaunched pod, promoted standby) skips the XLA compile too: the
+  in-memory cache cannot outlive the process, but the HLO-keyed disk
+  cache does.
 
 Scope note: in-memory reuse pays off whenever the backend survives the
 resize (single-process elastic planes, the CPU test/bench meshes built
@@ -45,6 +47,17 @@ from elasticdl_tpu.common.log_utils import default_logger as logger
 from elasticdl_tpu.utils import profiling
 
 
+# Where compiled programs are kept when nobody says otherwise: one fixed
+# directory inside the checkout. The directory is part of jax's cache
+# key, so a path that moves between runs never hits.
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    ),
+    ".jax_cache",
+)
+
+
 def _cpu_platform_selected():
     """Is this process pinned to the CPU backend? Answered from env and
     jax config ONLY — probing the backend itself (jax.default_backend)
@@ -54,20 +67,17 @@ def _cpu_platform_selected():
 
     if os.environ.get("EDL_DIST_PLATFORM") == "cpu":
         return True
-    selected = os.environ.get("JAX_PLATFORMS") or ""
-    if not selected:
-        try:
-            selected = jax.config.jax_platforms or ""
-        except AttributeError:
-            selected = ""
+    selected = jax.config.jax_platforms or ""
     return selected.split(",")[0].strip().lower() == "cpu"
 
 
-def enable_persistent_cache(cache_dir=None, probe_backend=False):
-    """Point jax's persistent compilation cache at ``cache_dir`` (or
-    ``$EDL_COMPILE_CACHE_DIR``). Idempotent; a no-op when neither is
-    set. Survives ``clear_backends`` (it is jax config, not backend
-    state), so one call at process start covers every re-formed world.
+def enable_persistent_cache(probe_backend=False):
+    """Turn on jax's persistent compilation cache. One rule for where:
+    if ``JAX_COMPILATION_CACHE_DIR`` is set, jax already keeps its cache
+    there and nothing is set here; otherwise the cache goes to
+    :data:`DEFAULT_CACHE_DIR`. Idempotent. Survives ``clear_backends``
+    (it is jax config, not backend state), so one call at process start
+    covers every re-formed world.
 
     CPU processes skip the cache unless ``EDL_COMPILE_CACHE_CPU=1``
     forces it: on this toolchain, EXECUTING a cache-reloaded executable
@@ -75,7 +85,9 @@ def enable_persistent_cache(cache_dir=None, probe_backend=False):
     (measured: the local allreduce train resumed against a warm cache
     aborts in glibc inside the first train_step; the same drive with a
     cold cache, or without donation, is clean). The accelerator path is
-    the production target and reloads cleanly.
+    the production target and reloads cleanly. (A CPU process started
+    with ``JAX_COMPILATION_CACHE_DIR`` set caches all the same: that is
+    jax obeying its own variable, not this function.)
 
     ``probe_backend=True`` additionally asks the live backend when the
     platform env/config is silent — catching an accelerator-less box
@@ -84,9 +96,6 @@ def enable_persistent_cache(cache_dir=None, probe_backend=False):
     default False and are covered by the env answer
     (``EDL_DIST_PLATFORM=cpu`` is the documented CPU bring-up there).
     """
-    cache_dir = cache_dir or os.environ.get("EDL_COMPILE_CACHE_DIR")
-    if not cache_dir:
-        return False
     import jax
 
     on_cpu = _cpu_platform_selected()
@@ -106,24 +115,22 @@ def enable_persistent_cache(cache_dir=None, probe_backend=False):
             "set EDL_COMPILE_CACHE_CPU=1 to force)"
         )
         return False
-
-    try:
-        if jax.config.jax_compilation_cache_dir == cache_dir:
-            return True
-    except AttributeError:
-        logger.debug("jax build without a compilation-cache config")
-        return False
-    os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    # jax's min-compile-time threshold (~1s) is kept DELIBERATELY: it
-    # admits exactly the executables worth amortizing (the train steps)
-    # while keeping the myriad tiny placement/broadcast programs out —
-    # on this toolchain, reloading certain tiny cached CPU executables
-    # crashes natively (measured: resume-from-checkpoint with a
-    # zero-threshold warm cache segfaults in deserialization; with the
-    # default threshold the same drive is clean, and the step compiles
-    # still hit)
-    logger.info("persistent compilation cache -> %s", cache_dir)
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return True
+    if jax.config.jax_compilation_cache_dir != DEFAULT_CACHE_DIR:
+        os.makedirs(DEFAULT_CACHE_DIR, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", DEFAULT_CACHE_DIR)
+        # jax's min-compile-time threshold (~1s) is kept DELIBERATELY:
+        # it admits exactly the executables worth amortizing (the train
+        # steps) while keeping the myriad tiny placement/broadcast
+        # programs out — on this toolchain, reloading certain tiny
+        # cached CPU executables crashes natively (measured:
+        # resume-from-checkpoint with a zero-threshold warm cache
+        # segfaults in deserialization; with the default threshold the
+        # same drive is clean, and the step compiles still hit)
+        logger.info(
+            "persistent compilation cache -> %s", DEFAULT_CACHE_DIR
+        )
     return True
 
 

@@ -23,8 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from elasticdl_tpu.parallel.ring_attention import shard_map
-
 
 def topk_gate(logits, k):
     """(T, E) gate logits -> (expert_idx (T, k), gate_probs (T, k)).
@@ -156,11 +154,11 @@ def make_moe_fn(
         )
 
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(expert_axis), P(batch_axis), P(batch_axis)),
         out_specs=P(batch_axis),
-        check_rep=False,
+        check_vma=False,
     )
     def _moe(stacked_params, x, gate_logits):
         cap = _capacity(x.shape[0], int(mesh.shape[expert_axis]))

@@ -27,8 +27,6 @@ import numpy as np
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from elasticdl_tpu.parallel.ring_attention import shard_map
-
 
 def stack_stage_params(per_stage_params):
     """[params_stage0, ...] -> one pytree with a leading (S,) stage dim."""
@@ -107,14 +105,14 @@ def make_pipeline_fn(mesh, stage_fn, pipe_axis="pipe", batch_axis=None):
     """
 
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(
             P(pipe_axis),
             P(None, batch_axis),
         ),
         out_specs=P(None, batch_axis),
-        check_rep=False,
+        check_vma=False,
     )
     def _pipe(stacked_params, microbatches):
         out = pipeline_apply(

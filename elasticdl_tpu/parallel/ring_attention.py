@@ -16,29 +16,10 @@ No counterpart exists in the reference (no attention models, SURVEY.md
 """
 
 import functools
-import inspect
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-
-try:
-    from jax import shard_map as _shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-# the replication-check kwarg was renamed check_rep -> check_vma in jax 0.8
-_CHECK_KW = (
-    "check_vma"
-    if "check_vma" in inspect.signature(_shard_map).parameters
-    else "check_rep"
-)
-
-
-def shard_map(f, **kwargs):
-    if "check_rep" in kwargs:
-        kwargs[_CHECK_KW] = kwargs.pop("check_rep")
-    return _shard_map(f, **kwargs)
 
 
 def _block_attend(q, k, v, bias=None):
@@ -246,7 +227,10 @@ def _ring_flash_fwd_rule(q, k, v, axis_name, causal, block_q, block_k):
 def _ring_flash_bwd_rule(
     axis_name, causal, block_q, block_k, residuals, g
 ):
-    from elasticdl_tpu.ops.flash_attention import _flash_bwd, _use_interpret
+    from elasticdl_tpu.ops.flash_attention import (
+        _flash_bwd,
+        kernel_interpret_mode,
+    )
 
     q, k, v, out, lse = residuals
     n = jax.lax.psum(1, axis_name)
@@ -254,7 +238,7 @@ def _ring_flash_bwd_rule(
     # becomes a hoisted PartitionId the SPMD partitioner rejects
     my_idx = jax.lax.axis_index(axis_name) if causal else None
     perm = [(i, (i + 1) % n) for i in range(n)]
-    interpret = _use_interpret()
+    interpret = kernel_interpret_mode()
 
     def block_bwd(kk, vv, block_causal):
         return _flash_bwd(
@@ -331,11 +315,11 @@ def make_ring_attention(
     spec = P(batch_axis, seq_axis, head_axis, None)
 
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,
-        check_rep=False,
+        check_vma=False,
     )
     def _ring(q, k, v):
         from elasticdl_tpu.ops.flash_attention import divisible

@@ -60,8 +60,8 @@ class AllReduceTrainer:
         self._sharded_paths = {}
         # the persistent compile cache covers this plane too: a
         # restarted local job re-jits the identical step HLO, which the
-        # disk cache (EDL_COMPILE_CACHE_DIR) satisfies without an XLA
-        # compile (docs/compile_plane.md)
+        # disk cache satisfies without an XLA compile
+        # (docs/compile_plane.md)
         from elasticdl_tpu.parallel.compile_plane import (
             enable_persistent_cache,
         )

@@ -266,10 +266,8 @@ class ParameterServer:
 
 def main():
     from elasticdl_tpu.common.args import parse_ps_args
-    from elasticdl_tpu.common.jax_platform import honor_jax_platforms_env
     from elasticdl_tpu.utils import profiling
 
-    honor_jax_platforms_env()
     args = parse_ps_args()
     # name this process in every span id / postmortem header (entry
     # points only: embedded test instances keep the pid default)

@@ -87,11 +87,9 @@ def build_scorer(args):
 
 def main():
     from elasticdl_tpu.common.args import parse_scorer_args
-    from elasticdl_tpu.common.jax_platform import honor_jax_platforms_env
     from elasticdl_tpu.serving.server import ScorerServer
     from elasticdl_tpu.utils import profiling
 
-    honor_jax_platforms_env()
     args = parse_scorer_args()
     profiling.spans.set_process("scorer-%d" % args.scorer_id)
     profiling.maybe_arm_flight_recorder()

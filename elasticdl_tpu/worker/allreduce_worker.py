@@ -354,8 +354,14 @@ class AllReduceWorker:
 
     # -- main loop ----------------------------------------------------------
 
+    # a step that fails this many times in a row, with no step
+    # succeeding in between, is failing for a reason a requeue cannot
+    # cure (a kernel the compiler refuses, a shape the model rejects)
+    MAX_CONSECUTIVE_STEP_FAILURES = 3
+
     def run(self):
         losses = []
+        failures_in_a_row = 0
         while True:
             dataset = self._task_data_service.get_dataset()
             if not dataset:
@@ -375,9 +381,11 @@ class AllReduceWorker:
                 try:
                     loss, count = self._train_batch(dataset_batch)
                     losses.append(loss)
+                    failures_in_a_row = 0
                 except Exception as e:  # report, don't die: task requeues
                     err_msg = str(e)
                     logger.exception("train step failed")
+                    failures_in_a_row += 1
                     # drain exactly the head task so it fail-reports and
                     # requeues now; when no task is pending (failure after
                     # the task drained) charge the batch size instead of
@@ -387,6 +395,13 @@ class AllReduceWorker:
                         or len(dataset_batch[1])
                     )
                 self._task_data_service.report_record_done(count, err_msg)
+                if failures_in_a_row >= self.MAX_CONSECUTIVE_STEP_FAILURES:
+                    # requeueing forever would hide a deterministic
+                    # failure behind a job that never ends
+                    raise RuntimeError(
+                        "train step failed %d times in a row; last "
+                        "error: %s" % (failures_in_a_row, err_msg)
+                    )
                 self._save_ckpt_if_due()
             if self._job_type == JobType.TRAINING_WITH_EVALUATION:
                 self._evaluate_only()

@@ -1,7 +1,7 @@
 """Elastic multi-process ALLREDUCE worker: one process per TPU host.
 
 The reference's north-star behavior — a job that survives killing half its
-workers (BASELINE.md config 3) — exists there only for the PS plane, where
+workers — exists there only for the PS plane, where
 workers never talk to each other. This worker realizes it for the
 collective plane: each process pulls tasks from the master exactly like a
 PS worker (same dispatcher, same recover_tasks elasticity), but trains via
@@ -363,9 +363,8 @@ class ElasticAllReduceWorker:
         # compile-plane fast path (docs/compile_plane.md): the fixed
         # minibatch lets speculative AOT compiles derive the exact batch
         # shapes a future establish will step with; the persistent
-        # compile cache (EDL_COMPILE_CACHE_DIR) makes relaunched
-        # processes and re-formed worlds skip XLA compiles they have
-        # paid before
+        # compile cache makes relaunched processes and re-formed worlds
+        # skip XLA compiles they have paid before
         self.trainer.default_minibatch_size = minibatch_size
         self.trainer.speculative_compile = bool(speculative_compile)
         from elasticdl_tpu.parallel.compile_plane import (
@@ -429,6 +428,9 @@ class ElasticAllReduceWorker:
             )
         self._restore_attempted = False
         self._last_ckpt_version = 0
+        self._step_reported = False  # the once-per-process step report
+        self._losses_reported = 0  # losses already in a train_window event
+        self._window_t0 = None
         self._batch_gen = None
         self._retry_batch = None
         # one-batch lookahead for the H2D overlap: _NO_PEEK means
@@ -784,7 +786,7 @@ class ElasticAllReduceWorker:
         # standby's death-bump is DEFERRED waiting for exactly this
         # registration, so announcing first lets the survivors pause
         # and settle in parallel with our dataset/reader priming
-        # (measured ~5.7 s serial before this, BASELINE.md r5). The
+        # (measured ~5.7 s serial before this). The
         # awaiting=False poll registers without confirming a formation
         # we are not yet ready to join.
         try:
@@ -885,6 +887,7 @@ class ElasticAllReduceWorker:
             from elasticdl_tpu.utils.profiling import maybe_start_trace
 
             maybe_start_trace()  # safe only now: the backend is world-aware
+            self._report_step_built()
             outcome = self._train_epoch(world, losses)
             if outcome in ("done", "preempted"):
                 break
@@ -892,8 +895,96 @@ class ElasticAllReduceWorker:
                 # the announced drain exits through the ordinary reform
                 # pause ("reform"); a drained worker must not re-join
                 break
+        self._report_losses(losses)
+        # ship now, while the master still serves: landing the last
+        # checkpoint in _finalize can outlast its exit grace, and run()'s
+        # closing ship then finds nobody listening
+        self._telemetry.ship(self._stub, force=True)
         self._finalize()
         return losses
+
+    def _report_step_built(self):
+        """Say once, after the first establish, what this process
+        trains on and with: the mesh's devices, the attention the built
+        step holds (read off the step, see describe_step), the record
+        reader, and where compiled programs are kept. One log line and
+        one ``step_built`` event (scalar fields: it ships to the master's
+        event log with the next task report)."""
+        if self._step_reported:
+            return
+        self._step_reported = True
+        # the first train_window's clock starts here: describe_step
+        # does the step's trace and lowering, which the first step call
+        # then reuses, so they belong to that window's cost
+        self._window_t0 = time.time()
+        import jax
+
+        from elasticdl_tpu.data.recordio import reader_kind
+        from elasticdl_tpu.ops.flash_attention import attention_in_step
+        from elasticdl_tpu.utils import profiling
+
+        try:
+            facts = self.trainer.describe_step()
+        except Exception:
+            # a report must not pre-empt the step-failure path: whatever
+            # stops the step from tracing here stops the step itself a
+            # moment later, where the failure is accounted for
+            logger.warning(
+                "could not describe the built step", exc_info=True
+            )
+            return
+        devices = self.trainer.mesh.devices
+        report = {
+            "platform": devices.flat[0].platform,
+            "device_kind": devices.flat[0].device_kind,
+            "device_count": int(devices.size),
+            "mesh": ",".join(
+                "%s=%d" % kv for kv in self.trainer.mesh.shape.items()
+            ),
+            "attention": attention_in_step(facts),
+            "pallas_calls": facts["pallas_calls"],
+            "pallas_interpreted": facts["pallas_interpreted"],
+            "tpu_custom_calls": facts["tpu_custom_calls"],
+            "mosaic_kernels": ",".join(facts["mosaic_kernels"]),
+            "record_reader": reader_kind(),
+            "compile_cache_dir": jax.config.jax_compilation_cache_dir
+            or "",
+        }
+        logger.info(
+            "step built: %s",
+            " ".join("%s=%s" % kv for kv in report.items()),
+        )
+        profiling.events.emit(
+            "step_built", worker=self._worker_id, **report
+        )
+
+    def _report_losses(self, losses):
+        """One ``train_window`` event for the losses recorded since the
+        last one: how many steps, the first and last loss, how many were
+        not finite, the wall seconds the window took (the first
+        window's includes the step's trace, lowering and compile), and
+        on how many devices the train state sits. Called at sync
+        points, where the deferred losses of the window have just been
+        drained, BEFORE the window's task reports go out, so the event
+        rides them to the master."""
+        window = losses[self._losses_reported :]
+        if not window:
+            return
+        from elasticdl_tpu.utils import profiling
+
+        self._losses_reported = len(losses)
+        now = time.time()
+        profiling.events.emit(
+            "train_window",
+            worker=self._worker_id,
+            steps=len(window),
+            first_loss=float(window[0]),
+            last_loss=float(window[-1]),
+            nonfinite=int(np.sum(~np.isfinite(window))),
+            seconds=round(now - (self._window_t0 or now), 3),
+            state_on_devices=self.trainer.state_device_coverage(),
+        )
+        self._window_t0 = now
 
     def _restore_latest_checkpoint(self):
         """Resume from the newest restorable checkpoint; a partial or
@@ -1174,6 +1265,7 @@ class ElasticAllReduceWorker:
               # path as a failed step — the just-synced window already
               # validated and flushed, so no accounting is lost
               try:
+                self._report_losses(losses)
                 self._flush_unreported()
                 if batch is not None:
                     # step overlap: pull batch N+1 now — its H2D

@@ -18,9 +18,6 @@ from elasticdl_tpu.worker.worker import Worker
 
 
 def main():
-    from elasticdl_tpu.common.jax_platform import honor_jax_platforms_env
-
-    honor_jax_platforms_env()
     args = parse_worker_args()
     if args.distribution_strategy == "AllreduceStrategy":
         # the elastic worker must not touch the JAX backend before its
@@ -140,8 +137,8 @@ def _run(args):
         )
         if getattr(args, "standby", False):
             # pre-warmed spare: the cold start (jax/flax import chain
-            # plus worker construction — ~all of a relaunch's 45-50 s,
-            # BASELINE.md r3) was just paid ABOVE; park until the master
+            # plus worker construction — ~all of a relaunch's 45-50 s)
+            # was just paid ABOVE; park until the master
             # promotes this process, then adopt the assigned id. No
             # device is touched while parked (that would pin the
             # backend and break the world formation after promotion).

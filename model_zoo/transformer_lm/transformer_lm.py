@@ -618,8 +618,13 @@ def optimizer(lr=3e-3):
 
 
 def dataset_fn(dataset, mode, _):
+    # sequence length follows the record (parse_example reshapes to the
+    # spec, and -1 takes whatever the record holds); batching requires
+    # the records of one job to agree
+    spec = {"tokens": FixedLenFeature([-1], np.int64)}
+
     def _parse_data(record):
-        r = parse_example(record, {"tokens": FixedLenFeature([64], np.int64)})
+        r = parse_example(record, spec)
         tokens = r["tokens"].astype(np.int32)
         features = {"tokens": tokens}
         if mode == Mode.PREDICTION:

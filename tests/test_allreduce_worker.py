@@ -162,3 +162,23 @@ def test_allreduce_worker_resumes_from_sharded_checkpoint(tmp_path):
     assert v2 == v1 + 8, (v1, v2)
     versions_after_2 = ShardedCheckpointManager(ckpt_dir).versions()
     assert max(versions_after_2) > max(versions_after_1)
+
+
+def test_allreduce_worker_gives_up_on_a_step_that_fails_every_time():
+    """A step failing for a reason no requeue can cure (a kernel the
+    compiler refuses, say) must end the job loudly, not spin in a
+    fail-report/requeue loop forever."""
+    import pytest
+
+    task_d, master, worker = _job(num_epochs=1)
+    calls = []
+
+    def refuse(batch):
+        calls.append(1)
+        raise RuntimeError("Mosaic refuses this kernel")
+
+    worker._train_batch = refuse
+    with pytest.raises(RuntimeError, match="3 times in a row"):
+        worker.run()
+    assert len(calls) == AllReduceWorker.MAX_CONSECUTIVE_STEP_FAILURES
+    assert not task_d.finished()

@@ -451,11 +451,11 @@ def test_take_staged_mismatched_batch_places_inline():
     t.close()
 
 
-def test_persistent_cache_skipped_on_cpu(tmp_path, monkeypatch):
+def test_persistent_cache_skipped_on_cpu(monkeypatch):
     """CPU-pinned processes must NOT take the persistent cache (reloaded
     donated executables crash this toolchain; see compile_plane)."""
     prev = jax.config.jax_compilation_cache_dir
-    monkeypatch.setenv("EDL_COMPILE_CACHE_DIR", str(tmp_path / "cc"))
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     monkeypatch.delenv("EDL_COMPILE_CACHE_CPU", raising=False)
     try:
         assert compile_plane.enable_persistent_cache() is False
@@ -464,24 +464,38 @@ def test_persistent_cache_skipped_on_cpu(tmp_path, monkeypatch):
         jax.config.update("jax_compilation_cache_dir", prev)
 
 
-def test_persistent_cache_config(tmp_path, monkeypatch):
+def test_persistent_cache_placed_by_one_rule(tmp_path, monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR set: jax keeps its cache there and the
+    code sets nothing. Unset: the fixed directory inside the checkout."""
     prev = jax.config.jax_compilation_cache_dir
-    monkeypatch.setenv("EDL_COMPILE_CACHE_DIR", str(tmp_path / "cc"))
-    # the suite runs CPU-pinned; exercise the config path via the
-    # explicit override the caveat documents
+    # the suite runs CPU-pinned; exercise the rule via the explicit
+    # override the caveat documents
     monkeypatch.setenv("EDL_COMPILE_CACHE_CPU", "1")
+    monkeypatch.setattr(
+        compile_plane, "DEFAULT_CACHE_DIR", str(tmp_path / "fixed")
+    )
     try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "env"))
+        assert compile_plane.enable_persistent_cache() is True
+        assert jax.config.jax_compilation_cache_dir == prev  # untouched
+        assert not (tmp_path / "fixed").exists()
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
         assert compile_plane.enable_persistent_cache() is True
         assert jax.config.jax_compilation_cache_dir == str(
-            tmp_path / "cc"
+            tmp_path / "fixed"
         )
+        assert (tmp_path / "fixed").is_dir()
         # idempotent
         assert compile_plane.enable_persistent_cache() is True
-        # unset env: a no-op (config untouched, returns False)
-        monkeypatch.delenv("EDL_COMPILE_CACHE_DIR")
-        assert compile_plane.enable_persistent_cache() is False
-        assert jax.config.jax_compilation_cache_dir == str(
-            tmp_path / "cc"
-        )
     finally:
         jax.config.update("jax_compilation_cache_dir", prev)
+
+
+def test_default_cache_dir_is_inside_the_checkout():
+    import os
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert compile_plane.DEFAULT_CACHE_DIR == os.path.join(
+        repo, ".jax_cache"
+    )

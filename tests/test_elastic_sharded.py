@@ -885,7 +885,7 @@ def test_mirror_round_trip_pp_dp_mesh():
     """Same round trip on a ("data", "pipe") mesh with the collective
     pipelined transformer: the range-based capture/assembly handles
     stage subtrees sharded over the trailing axis (replicated across
-    data groups) — the generalization VERDICT r4 item 1 asked for."""
+    data groups)."""
     from elasticdl_tpu.parallel.distributed import WorldSpec
     from elasticdl_tpu.parallel.elastic import ElasticDPTrainer
     from model_zoo.transformer_lm import transformer_lm as tzoo
@@ -1675,7 +1675,7 @@ def test_padded_checkpoint_restores_across_paddings(tmp_path):
 
 @pytest.mark.slow
 def test_sharded_kill_prime_vocab_reshards_no_disk(tmp_path, monkeypatch):
-    """VERDICT r4 item 6's bar: SIGKILL one of 3 workers on a sharded
+    """SIGKILL one of 3 workers on a sharded
     job whose vocab (97, prime) divides NEITHER the old nor the
     survivor world. PadDim0 placement pads per world (97 -> 99 on 3
     procs, 98 on 2), the range-based replica assembly bridges the two

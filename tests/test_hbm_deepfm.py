@@ -1,5 +1,5 @@
-"""HBM-sharded DeepFM as a training strategy (VERDICT r1 item 3 /
-BASELINE.json north star): tables row-sharded over mesh HBM, all_to_all
+"""HBM-sharded DeepFM as a training strategy (BASELINE.json north
+star): tables row-sharded over mesh HBM, all_to_all
 row routing, sparse update inside the jitted step, checkpointed through
 the params pytree.
 

@@ -484,7 +484,7 @@ def test_pipelined_transformer_matches_plain():
 
 
 def test_pipeline_job_path_through_worker(tmp_path):
-    """The VERDICT done-criterion: a zoo config trains through the job
+    """A zoo config trains through the job
     path with stages > 1 — master task dispatch, the single-process
     ALLREDUCE worker (the CLI local-mode engine), pipelined model."""
     from elasticdl_tpu.common.constants import JobType

@@ -196,7 +196,6 @@ def test_collective_dedup_matches_naive():
         a2a_dedup_lookup_collective,
         a2a_lookup_collective,
     )
-    from elasticdl_tpu.parallel.ring_attention import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = create_mesh({"data": 8}, axis_names=("data",))
@@ -207,12 +206,12 @@ def test_collective_dedup_matches_naive():
     ).astype(np.int32)
 
     def run(body):
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda t, i: body(t, i, "data"),
             mesh=mesh,
             in_specs=(P("data", None), P("data")),
             out_specs=P("data", None),
-            check_rep=False,
+            check_vma=False,
         )
         return np.asarray(jax.jit(fn)(table, ids))
 

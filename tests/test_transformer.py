@@ -106,3 +106,28 @@ def test_dp_tp_sp_fused_step():
     np.testing.assert_allclose(
         float(loss), float(loss_ref), rtol=2e-4
     )
+
+
+def test_dataset_fn_takes_sequence_length_from_the_record():
+    """The job path must be able to train at any length the records
+    hold (the kernel takes over at 1024): no length constant in the zoo."""
+    from elasticdl_tpu.common.constants import Mode
+    from elasticdl_tpu.data.dataset import Dataset
+    from elasticdl_tpu.data.example import encode_example
+
+    for length in (64, 1024):
+        records = [
+            encode_example(
+                {"tokens": np.arange(length, dtype=np.int64) + i}
+            )
+            for i in range(4)
+        ]
+        parsed = zoo.dataset_fn(
+            Dataset.from_generator(lambda r=records: iter(r)),
+            Mode.EVALUATION,
+            None,
+        )
+        features, labels = next(iter(parsed.batch(4)))
+        assert features["tokens"].shape == (4, length)
+        assert features["tokens"].dtype == np.int32
+        np.testing.assert_array_equal(labels[1], np.arange(length) + 1)
