@@ -3,16 +3,26 @@
 import os
 import subprocess
 import sys
+import tempfile
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 
 
 def build(verbose=True):
+    """Compile the library and put it in place whole: g++ writes under
+    a temporary name in the same directory and ``os.replace`` renames
+    it, so a process that finds ``libedl_native.so`` (several test
+    workers build and load at once on a fresh checkout) never loads a
+    half-written one."""
     sources = [
         os.path.join(_DIR, "recordio_reader.cc"),
         os.path.join(_DIR, "recordio_writer.cc"),
     ]
     out = os.path.join(_DIR, "libedl_native.so")
+    fd, tmp = tempfile.mkstemp(
+        dir=_DIR, prefix="libedl_native.", suffix=".so"
+    )
+    os.close(fd)
     cmd = [
         "g++",
         "-O2",
@@ -22,11 +32,16 @@ def build(verbose=True):
         *sources,
         "-lz",
         "-o",
-        out,
+        tmp,
     ]
     if verbose:
         print(" ".join(cmd))
-    subprocess.check_call(cmd)
+    try:
+        subprocess.check_call(cmd)
+        os.replace(tmp, out)
+    finally:
+        if os.path.exists(tmp):
+            os.remove(tmp)
     return out
 
 

@@ -296,7 +296,7 @@ def aot_compile(entry, abstract_args, stats=None):
     if compiled is not None:
         return compiled
     t0 = time.perf_counter()
-    with profiling.annotate("compile_plane/aot_compile"):
+    with profiling.span("compile_plane/aot_compile"):
         compiled = entry.step_fn.lower(*abstract_args).compile()
     entry.aot[sig] = compiled
     if stats is not None:
@@ -387,7 +387,7 @@ class SpeculativeCompiler:
                 break
             try:
                 t0 = time.perf_counter()
-                with profiling.annotate("compile_plane/speculative"):
+                with profiling.span("compile_plane/speculative"):
                     built = self._compile_fn(size)
                 if built:
                     self.stats.inc("speculative_builds")

@@ -1180,6 +1180,19 @@ class ElasticDPTrainer:
             for leaf in jax.tree_util.tree_leaves(self._ts)
         )
 
+    def peak_hbm_bytes(self):
+        """The most device memory any local device of the mesh has held
+        since the process started (``memory_stats()``'s
+        ``peak_bytes_in_use``); None between worlds and where the
+        backend reports none (the CPU)."""
+        if self._mesh is None:
+            return None
+        peaks = [
+            (d.memory_stats() or {}).get("peak_bytes_in_use")
+            for d in self._mesh.local_devices
+        ]
+        return max((p for p in peaks if p is not None), default=None)
+
     def _build_init_ts(self, example_batch):
         features = example_batch[0]
         # slice before transfer: a device leaf would otherwise D2H the
@@ -1303,7 +1316,7 @@ class ElasticDPTrainer:
         self._checked_ts = self._ts
         self._placed_epoch = distributed.backend_epoch()
         self._spec_example = example_batch or self._last_local
-        with profiling.annotate("elastic/establish/compile"):
+        with profiling.span("elastic/establish/compile"):
             cache_hit = self._acquire_step_fn()
         t_compile = _time.time()
         logger.info(
@@ -1904,10 +1917,11 @@ class ElasticDPTrainer:
             return []
         out = []
         try:
-            for loss in pending:
-                out.append(
-                    loss if isinstance(loss, float) else float(loss)
-                )
+            with profiling.phases.measure("fetch"):
+                for loss in pending:
+                    out.append(
+                        loss if isinstance(loss, float) else float(loss)
+                    )
         except Exception:
             logger.warning(
                 "deferred loss fetch failed (broken collective?); "
@@ -2042,7 +2056,7 @@ class ElasticDPTrainer:
             # re-form), the old buffers are gone and the snapshot
             # interchange below (sharded checkpoints) is the path.
             try:
-                with profiling.annotate("elastic/resize/relayout"):
+                with profiling.span("elastic/resize/relayout"):
 
                     def move(target, leaf, sharding):
                         t_shape = tuple(target.shape)
@@ -2851,100 +2865,108 @@ class ElasticDPTrainer:
         are validated at the next ``sync=True`` call; a collective
         failure then rolls the snapshot back to the last validated
         state (bounded by the caller's sync cadence)."""
-        rows = self.local_rows(minibatch_size)
-        has_data = features is not None
-        staged = None
-        if has_data:
-            leaf = jax.tree_util.tree_leaves(features)[0]
-            count = int(np.asarray(leaf).shape[0])
-            # step overlap: a placement staged via stage_next (padded +
-            # placed on the feeder thread while the previous sync step's
-            # fetch blocked) is byte-identical to the inline path — same
-            # _pad_local/_place_batch code on the same host arrays
-            staged = self._take_staged(features, labels)
-            if staged is not None:
-                local = staged[0]
+        # pad + place the batch, the weights and the epochs (the wait
+        # for a staged placement when one was taken)
+        with profiling.phases.measure("batch_place"):
+            rows = self.local_rows(minibatch_size)
+            has_data = features is not None
+            staged = None
+            if has_data:
+                leaf = jax.tree_util.tree_leaves(features)[0]
+                count = int(np.asarray(leaf).shape[0])
+                # step overlap: a placement staged via stage_next (padded +
+                # placed on the feeder thread while the previous sync step's
+                # fetch blocked) is byte-identical to the inline path — same
+                # _pad_local/_place_batch code on the same host arrays
+                staged = self._take_staged(features, labels)
+                if staged is not None:
+                    local = staged[0]
+                else:
+                    local = (
+                        self._pad_local(features, rows),
+                        self._pad_local(labels, rows),
+                    )
+                self._last_local = local
             else:
-                local = (
-                    self._pad_local(features, rows),
-                    self._pad_local(labels, rows),
-                )
-            self._last_local = local
-        else:
-            count = 0
-            if self._last_local is None:
-                raise RuntimeError(
-                    "cannot run a weight-0 step before the first data step"
-                )
-            local = self._last_local
-        n_local = mesh_local_count(self._mesh)
-        # partial batches pad by repeating the last example; weighting the
-        # whole process by its true row fraction keeps a 1-row tail batch
-        # from contributing a full step's worth of gradient
-        w_value = min(1.0, count / rows) if has_data else 0.0
-        w_local = np.full((n_local,), w_value, dtype=np.float32)
-        row_spec = row_partition_spec(self._mesh)
-        if staged is not None:
-            g_features, g_labels = staged[1], staged[2]
-        else:
-            g_features = self._place_batch(local[0])
-            g_labels = self._place_batch(local[1])
-        g_weights = jax.make_array_from_process_local_data(
-            NamedSharding(self._mesh, row_spec),
-            w_local,
-            (self._mesh.devices.size,),
-        )
-        g_epochs = jax.make_array_from_process_local_data(
-            NamedSharding(self._mesh, row_spec),
-            np.full((n_local,), int(epoch_hint), dtype=np.int32),
-            (self._mesh.devices.size,),
-        )
+                count = 0
+                if self._last_local is None:
+                    raise RuntimeError(
+                        "cannot run a weight-0 step before the first data step"
+                    )
+                local = self._last_local
+            n_local = mesh_local_count(self._mesh)
+            # partial batches pad by repeating the last example; weighting the
+            # whole process by its true row fraction keeps a 1-row tail batch
+            # from contributing a full step's worth of gradient
+            w_value = min(1.0, count / rows) if has_data else 0.0
+            w_local = np.full((n_local,), w_value, dtype=np.float32)
+            row_spec = row_partition_spec(self._mesh)
+            if staged is not None:
+                g_features, g_labels = staged[1], staged[2]
+            else:
+                g_features = self._place_batch(local[0])
+                g_labels = self._place_batch(local[1])
+            g_weights = jax.make_array_from_process_local_data(
+                NamedSharding(self._mesh, row_spec),
+                w_local,
+                (self._mesh.devices.size,),
+            )
+            g_epochs = jax.make_array_from_process_local_data(
+                NamedSharding(self._mesh, row_spec),
+                np.full((n_local,), int(epoch_hint), dtype=np.int32),
+                (self._mesh.devices.size,),
+            )
         self._host_step += 1
         host_step = self._host_step
 
         def _dispatch():
             # everything device-touching — eager PRNG ops, the jit
             # call, the sync fetches — runs on the sacrificial thread
-            rng = jax.random.fold_in(
-                jax.random.PRNGKey(self._seed), host_step
-            )
-            args = (
-                self._ts,
-                g_features,
-                g_labels,
-                g_weights,
-                g_epochs,
-                rng,
-            )
-            fn = self._step_callable_for(args)
-            with self._mesh:
-                try:
-                    new_ts, loss, n, epoch_seen = fn(*args)
-                except (TypeError, ValueError):
-                    if fn is self._step_fn:
-                        raise
-                    # a speculative AOT executable whose signature check
-                    # disagreed with the live call: drop it and dispatch
-                    # through the jit path (retraces, stays correct)
-                    logger.warning(
-                        "AOT executable rejected the step call; "
-                        "falling back to jit dispatch",
-                        exc_info=True,
-                    )
-                    if self._step_entry is not None:
-                        self._step_entry.aot.clear()
-                        self._step_entry.dispatch_memo.clear()
-                    new_ts, loss, n, epoch_seen = self._step_fn(*args)
+            with profiling.phases.measure("dispatch"):
+                rng = jax.random.fold_in(
+                    jax.random.PRNGKey(self._seed), host_step
+                )
+                args = (
+                    self._ts,
+                    g_features,
+                    g_labels,
+                    g_weights,
+                    g_epochs,
+                    rng,
+                )
+                fn = self._step_callable_for(args)
+                with self._mesh:
+                    try:
+                        new_ts, loss, n, epoch_seen = fn(*args)
+                    except (TypeError, ValueError):
+                        if fn is self._step_fn:
+                            raise
+                        # a speculative AOT executable whose signature
+                        # check disagreed with the live call: drop it
+                        # and dispatch through the jit path (retraces,
+                        # stays correct)
+                        logger.warning(
+                            "AOT executable rejected the step call; "
+                            "falling back to jit dispatch",
+                            exc_info=True,
+                        )
+                        if self._step_entry is not None:
+                            self._step_entry.aot.clear()
+                            self._step_entry.dispatch_memo.clear()
+                        new_ts, loss, n, epoch_seen = self._step_fn(
+                            *args
+                        )
             if not sync:
                 # collect-later: the loss scalar stays on device (it is
                 # already a future); drain_metrics fetches at boundaries
                 return new_ts, loss, None, None
-            return (
-                new_ts,
-                float(host_copy(loss)),
-                int(host_copy(n)),
-                int(host_copy(epoch_seen)),
-            )
+            with profiling.phases.measure("fetch"):
+                return (
+                    new_ts,
+                    float(host_copy(loss)),
+                    int(host_copy(n)),
+                    int(host_copy(epoch_seen)),
+                )
 
         new_ts, loss_v, n_v, epoch_seen_v = self._escapable(_dispatch)
         self._ts = new_ts

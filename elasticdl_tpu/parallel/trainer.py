@@ -210,7 +210,7 @@ class AllReduceTrainer:
             # cache (no retrace/recompile); only the state re-placement
             # below is per-resize work — annotated so it separates in
             # traces
-            with profiling.annotate("allreduce/resize/replace"):
+            with profiling.span("allreduce/resize/replace"):
                 self._ts = self._place(old_ts)
 
     def get_host_state(self):

@@ -5,13 +5,16 @@ wired behind one small surface so any worker, bench, or test can turn
 them on without plumbing:
 
 - :func:`trace` — context manager around ``jax.profiler`` writing a
-  TensorBoard-loadable trace (``xplane.pb``) to a directory.
-- :func:`annotate` — named ``TraceAnnotation`` for host-side phases so
-  task pulls / input pipeline / step dispatch separate in the timeline.
-- :func:`enable_xla_dump` — set before the first compilation to dump HLO
-  (pre/post optimization) for compiler-level inspection.
-- :func:`step_timer` — lightweight wall-clock step statistics when a full
-  trace is too heavy (the bench uses it for its profile line).
+  TensorBoard-loadable trace (``xplane.pb``) to a directory. The
+  profiler's Python tracer is off: the host lines carry the runtime's
+  own TraceMes and this program's names (spans and phases, below), not
+  every Python frame.
+- :data:`phases` — the process-wide :class:`PhaseClock`: the elastic
+  allreduce worker's step loop charges each piece of a step to one of
+  :data:`STEP_PHASES`; the totals ride every ``train_window`` event
+  (docs/observability.md "The allreduce worker's phases"). While a
+  profiler trace is open each measured call is also a
+  ``TraceAnnotation`` ``edl/step/<phase>`` on the profiler's clock.
 - :data:`counters` — a process-wide named-counter registry
   (:class:`Counters`); the compile plane threads its cache hit/miss and
   compile-time numbers through it so workers, bench sections, and tests
@@ -35,7 +38,9 @@ them on without plumbing:
   rpc clients, task ``trace_id``s as trace roots). Worker spans ship
   to the master on the existing ``report_telemetry`` snapshots; the
   master's ``/trace`` endpoint exports Chrome trace-event JSON
-  (:func:`chrome_trace`) loadable in Perfetto.
+  (:func:`chrome_trace`) loadable in Perfetto. While a profiler trace
+  is open in this process a span is also a ``TraceAnnotation`` of the
+  same name, so the one primitive lands on both clocks.
 - :data:`flight_recorder` — the crash :class:`FlightRecorder`: on a
   triggering job event (PS shard failure, master epoch change, task
   requeue, chaos kill) it freezes the last N spans + events to a
@@ -43,11 +48,11 @@ them on without plumbing:
   a readable timeline of its own death.
 
 Env toggles (read by workers at startup): ``EDL_PROFILE_DIR`` enables
-tracing into that directory; ``EDL_XLA_DUMP_DIR`` enables HLO dumps;
-``EDL_METRICS=0`` turns the telemetry instrumentation into no-ops (the
-bench's overhead A/B arm — spans, events, and the flight recorder all
-honor it); ``EDL_FLIGHT_RECORDER_DIR`` arms the flight recorder in any
-process (:func:`maybe_arm_flight_recorder`).
+tracing into that directory; ``EDL_METRICS=0`` turns the telemetry
+instrumentation into no-ops (the bench's overhead A/B arm — spans,
+phases, events, and the flight recorder all honor it);
+``EDL_FLIGHT_RECORDER_DIR`` arms the flight recorder in any process
+(:func:`maybe_arm_flight_recorder`).
 """
 
 import bisect
@@ -71,10 +76,15 @@ def _start(log_dir):
     import jax
 
     os.makedirs(log_dir, exist_ok=True)
+    # no Python-frame events: they are millions a minute, and the host
+    # lines already carry the program's own names (spans, phases)
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
     jax.profiler.start_trace(
         log_dir,
         create_perfetto_link=False,
         create_perfetto_trace=False,
+        profiler_options=options,
     )
     _trace_dir = log_dir
     logger.info("profiler trace started -> %s", log_dir)
@@ -95,7 +105,7 @@ def _stop():
 
 
 @contextlib.contextmanager
-def trace(log_dir, host_tracer_level=2):
+def trace(log_dir):
     """Capture a jax.profiler trace into ``log_dir``."""
     _start(log_dir)
     try:
@@ -104,21 +114,17 @@ def trace(log_dir, host_tracer_level=2):
         _stop()
 
 
-def annotate(name):
-    """Host-phase annotation visible in the profiler timeline."""
+def _annotation(name, **fields):
+    """An entered ``TraceAnnotation`` while this process has a profiler
+    trace open, else None: how spans and phases get onto the profiler's
+    clock. The caller leaves it with ``__exit__``."""
+    if _trace_dir is None:
+        return None
     import jax
 
-    return jax.profiler.TraceAnnotation(name)
-
-
-def enable_xla_dump(dump_dir):
-    """Dump HLO for every compilation (set BEFORE first jit)."""
-    flags = os.environ.get("XLA_FLAGS", "")
-    if "xla_dump_to" not in flags:
-        os.environ["XLA_FLAGS"] = (
-            flags + " --xla_dump_to=" + dump_dir
-        ).strip()
-    os.makedirs(dump_dir, exist_ok=True)
+    ann = jax.profiler.TraceAnnotation(name, **fields)
+    ann.__enter__()
+    return ann
 
 
 def maybe_profile():
@@ -767,7 +773,10 @@ class Span:
     monotonic ``perf_counter`` pair. Use as a context manager; entering
     pushes onto the per-thread context stack so nested spans inherit
     trace and parent, and exiting records the finished span into the
-    owning :class:`SpanLog`."""
+    owning :class:`SpanLog`. While this process has a profiler trace
+    open the span is also a ``TraceAnnotation`` of the same name (its
+    trace id and the fields it was opened with as arguments): that is
+    the span on the profiler's clock, beside the device's ops."""
 
     __slots__ = (
         "name",
@@ -779,6 +788,7 @@ class Span:
         "_ts",
         "_t0",
         "_thread",
+        "_ann",
     )
 
     def __init__(self, log, name, trace_id, span_id, parent_id, fields):
@@ -791,6 +801,7 @@ class Span:
         self._ts = None
         self._t0 = None
         self._thread = None
+        self._ann = None
 
     def add(self, **fields):
         """Attach fields to the (still open) span."""
@@ -811,10 +822,18 @@ class Span:
         self._t0 = time.perf_counter()
         self._thread = threading.current_thread().name
         _stack().append(self)
+        if _trace_dir is not None:
+            args = dict(self.fields)
+            if self.trace_id is not None:
+                args.setdefault("trace", self.trace_id)
+            self._ann = _annotation(self.name, **args)
         return self
 
     def __exit__(self, exc_type, exc, tb):
         dur = time.perf_counter() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+            self._ann = None
         stack = _stack()
         if stack and stack[-1] is self:
             stack.pop()
@@ -892,6 +911,15 @@ class SpanLog:
             parent_id,
             {k: _json_scalar(v) for k, v in fields.items()},
         )
+
+    def record(self, name, ts, dur, **fields):
+        """Log a span that was timed elsewhere: ``ts`` wall seconds at
+        its start, ``dur`` seconds (the allreduce worker's
+        ``train/window``, whose clocks are the window's own)."""
+        span = self.begin(name, **fields)
+        span._ts = ts
+        span._thread = threading.current_thread().name
+        self._finish(span, dur)
 
     def _finish(self, span, dur):
         rec = {
@@ -1077,6 +1105,121 @@ def chrome_trace(span_records):
         for (proc, tname), tid in threads.items()
     ]
     return {"traceEvents": meta + out, "displayTimeUnit": "ms"}
+
+
+# ---------------------------------------------------------------------------
+# the allreduce worker's step phases (docs/observability.md)
+# ---------------------------------------------------------------------------
+
+# what one iteration of the elastic allreduce worker's loop is made of,
+# each measured where the work happens:
+#   world_poll   the per-step get_comm_world RPC
+#   input_wait   pulling the next batch from the input plane
+#   batch_place  pad + place the batch, weights and epochs on the mesh
+#   dispatch     the tiny per-step programs and the step call, to its
+#                return (a dispatch that waits for buffers waits here)
+#   fetch        the host waiting for the device: a sync step's reads,
+#                the deferred losses
+#   report       the window's event, task reports, the telemetry ship
+#   stage_next   pulling and staging batch N+1 at a sync point
+#   cadence      checkpoint, in-plane evaluation, mirror refresh
+STEP_PHASES = (
+    "world_poll",
+    "input_wait",
+    "batch_place",
+    "dispatch",
+    "fetch",
+    "report",
+    "stage_next",
+    "cadence",
+)
+
+
+class _PhaseCall:
+    """One measured call of a phase (see :meth:`PhaseClock.measure`)."""
+
+    __slots__ = ("_clock", "_phase", "_t0", "_ann")
+
+    def __init__(self, clock, phase):
+        self._clock = clock
+        self._phase = phase
+
+    def __enter__(self):
+        open_ = self._clock._open
+        if getattr(open_, "phase", None) is not None:
+            raise RuntimeError(
+                "phase %r opened inside phase %r: the phases of a step "
+                "are disjoint" % (self._phase, open_.phase)
+            )
+        self._ann = _annotation("edl/step/" + self._phase)
+        open_.phase = self._phase
+        self._t0 = self._clock._now()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        dur = self._clock._now() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        self._clock._open.phase = None
+        self._clock._add(self._phase, dur)
+        return False
+
+
+class PhaseClock:
+    """Where the step loop's time went, by phase, over one window.
+
+    Per-step phases make no span records (45 a second would swamp the
+    telemetry drain): a measured call is two clock reads added to the
+    open window's total for its phase. The loop closes the window where
+    it emits ``train_window`` (:meth:`close_window`), so the totals
+    cover exactly the event's ``seconds``. The single longest call of
+    the window is remembered with its phase and the loop's ``step``:
+    one slow call in a window of ordinary ones is what a stall looks
+    like from inside. Phases are disjoint: opening one inside another
+    on the same thread raises. The step's dispatch runs on a thread of
+    its own while the loop thread waits for it, so the open phase is
+    kept per thread and the totals under a lock."""
+
+    def __init__(self, clock=time.perf_counter):
+        self._now = clock
+        self._lock = threading.Lock()
+        self._open = threading.local()
+        self.step = 0  # the loop's step index, written by the loop
+        self._reset()
+
+    def _reset(self):
+        self._totals = dict.fromkeys(STEP_PHASES, 0.0)
+        self._slowest = (0.0, "", 0)
+
+    def measure(self, phase):
+        """Context manager charging its body to ``phase``."""
+        if not _metrics_on:
+            return NULL_SPAN
+        if phase not in self._totals:
+            raise ValueError("no step phase %r" % (phase,))
+        return _PhaseCall(self, phase)
+
+    def _add(self, phase, dur):
+        with self._lock:
+            self._totals[phase] += dur
+            if dur > self._slowest[0]:
+                self._slowest = (dur, phase, self.step)
+
+    def close_window(self):
+        """The window's account as flat ``train_window`` fields
+        (``<phase>_s`` for every phase and ``slowest_call_s`` /
+        ``_phase`` / ``_step``), and a fresh window."""
+        with self._lock:
+            totals, slowest = self._totals, self._slowest
+            self._reset()
+        fields = {p + "_s": round(t, 5) for p, t in totals.items()}
+        fields["slowest_call_s"] = round(slowest[0], 5)
+        fields["slowest_call_phase"] = slowest[1]
+        fields["slowest_call_step"] = slowest[2]
+        return fields
+
+
+phases = PhaseClock()
 
 
 # ---------------------------------------------------------------------------
@@ -1291,45 +1434,3 @@ def _counters_collector():
 
 
 metrics.register_collector(_counters_collector)
-
-
-def _nearest_rank(xs, pct):
-    """Nearest-rank percentile (ceil indexing) over SORTED ``xs``.
-
-    ``xs[ceil(pct/100 * n) - 1]`` — the textbook definition; the old
-    ``xs[n // 2]`` / ``xs[int(n * 0.99)]`` indices were biased high for
-    small n (for n=2 they returned the max as the median)."""
-    n = len(xs)
-    rank = -(-pct * n // 100)  # ceil(pct*n/100) without floats
-    return xs[max(0, min(n - 1, int(rank) - 1))]
-
-
-class step_timer:
-    """Rolling wall-clock stats for the hot loop (mean/p50/p99 ms)."""
-
-    def __init__(self, capacity=1024):
-        self._times = []
-        self._capacity = capacity
-        self._last = None
-
-    def tick(self):
-        now = time.perf_counter()
-        if self._last is not None:
-            self._times.append(now - self._last)
-            if len(self._times) > self._capacity:
-                self._times = self._times[-self._capacity :]
-        self._last = now
-
-    def stats(self):
-        if not self._times:
-            return {}
-        xs = sorted(self._times)
-        n = len(xs)
-        return {
-            "steps": n,
-            "mean_ms": 1e3 * sum(xs) / n,
-            "p50_ms": 1e3 * _nearest_rank(xs, 50),
-            "p90_ms": 1e3 * _nearest_rank(xs, 90),
-            "p99_ms": 1e3 * _nearest_rank(xs, 99),
-            "max_ms": 1e3 * xs[-1],
-        }

@@ -431,6 +431,7 @@ class ElasticAllReduceWorker:
         self._step_reported = False  # the once-per-process step report
         self._losses_reported = 0  # losses already in a train_window event
         self._window_t0 = None
+        self._window_cpu0 = None  # the loop thread's CPU at _window_t0
         self._batch_gen = None
         self._retry_batch = None
         # one-batch lookahead for the H2D overlap: _NO_PEEK means
@@ -913,16 +914,19 @@ class ElasticAllReduceWorker:
         if self._step_reported:
             return
         self._step_reported = True
-        # the first train_window's clock starts here: describe_step
-        # does the step's trace and lowering, which the first step call
-        # then reuses, so they belong to that window's cost
-        self._window_t0 = time.time()
         import jax
 
         from elasticdl_tpu.data.recordio import reader_kind
         from elasticdl_tpu.ops.flash_attention import attention_in_step
         from elasticdl_tpu.utils import profiling
 
+        # the first train_window's clocks start here: describe_step
+        # does the step's trace and lowering, which the first step call
+        # then reuses, so they belong to that window's cost (to its
+        # seconds, and to no phase of the loop)
+        self._window_t0 = time.time()
+        self._window_cpu0 = time.thread_time()
+        profiling.phases.close_window()
         try:
             facts = self.trainer.describe_step()
         except Exception:
@@ -962,29 +966,52 @@ class ElasticAllReduceWorker:
         """One ``train_window`` event for the losses recorded since the
         last one: how many steps, the first and last loss, how many were
         not finite, the wall seconds the window took (the first
-        window's includes the step's trace, lowering and compile), and
-        on how many devices the train state sits. Called at sync
+        window's includes the step's trace, lowering and compile), on
+        how many devices the train state sits, and the loop's own
+        account of those seconds (docs/observability.md "The allreduce
+        worker's phases"): ``<phase>_s`` for each of
+        ``profiling.STEP_PHASES``, the single slowest call, the loop
+        thread's CPU seconds, the devices' peak memory where the
+        backend reports it. The same fields go to the span plane as one
+        ``train/window`` span, so ``/trace`` and the flight recorder
+        hold the last windows of a worker that died. Called at sync
         points, where the deferred losses of the window have just been
         drained, BEFORE the window's task reports go out, so the event
-        rides them to the master."""
+        rides them to the master. The clocks restart here: what this
+        method does after closing the window is the next window's
+        ``report``."""
         window = losses[self._losses_reported :]
         if not window:
             return
         from elasticdl_tpu.utils import profiling
 
         self._losses_reported = len(losses)
-        now = time.time()
-        profiling.events.emit(
-            "train_window",
-            worker=self._worker_id,
-            steps=len(window),
-            first_loss=float(window[0]),
-            last_loss=float(window[-1]),
-            nonfinite=int(np.sum(~np.isfinite(window))),
-            seconds=round(now - (self._window_t0 or now), 3),
-            state_on_devices=self.trainer.state_device_coverage(),
-        )
-        self._window_t0 = now
+        now, cpu = time.time(), time.thread_time()
+        if self._window_t0 is None:  # no step was ever built
+            self._window_t0, self._window_cpu0 = now, cpu
+        t0, cpu0 = self._window_t0, self._window_cpu0
+        self._window_t0, self._window_cpu0 = now, cpu
+        account = profiling.phases.close_window()
+        with profiling.phases.measure("report"):
+            account["loop_cpu_s"] = round(cpu - cpu0, 5)
+            peak = self.trainer.peak_hbm_bytes()
+            if peak is not None:
+                account["peak_hbm_bytes"] = peak
+            fields = dict(
+                worker=self._worker_id,
+                steps=len(window),
+                first_loss=float(window[0]),
+                last_loss=float(window[-1]),
+                nonfinite=int(np.sum(~np.isfinite(window))),
+                seconds=round(now - t0, 3),
+                state_on_devices=self.trainer.state_device_coverage(),
+                **account,
+            )
+            profiling.events.emit("train_window", **fields)
+            if profiling.metrics_enabled():
+                profiling.spans.record(
+                    "train/window", t0, now - t0, **fields
+                )
 
     def _restore_latest_checkpoint(self):
         """Resume from the newest restorable checkpoint; a partial or
@@ -1129,8 +1156,11 @@ class ElasticAllReduceWorker:
         return verdict
 
     def _train_epoch(self, world, losses):
+        from elasticdl_tpu.utils.profiling import phases
+
         step_i = 0
         while True:
+            phases.step = step_i + 1
             if self._preempted and not self._drain_announced:
                 # graceful drain rides the ORDINARY reform protocol:
                 # announce the departure so the master bumps the epoch
@@ -1173,10 +1203,12 @@ class ElasticAllReduceWorker:
                 # below (the collective rounds must line up across
                 # ranks); the replicated plane scores a local snapshot
                 # and can drain whenever
-                self._evaluate_only()
-            w = self._stub.get_comm_world(
-                self._worker_id, self._host, awaiting=False
-            )
+                with phases.measure("cadence"):
+                    self._evaluate_only()
+            with phases.measure("world_poll"):
+                w = self._stub.get_comm_world(
+                    self._worker_id, self._host, awaiting=False
+                )
             # membership-service size hint: the live+lobby head count is
             # the world the next growth bump would form — feed it to the
             # speculative compiler so that establish finds its
@@ -1204,7 +1236,8 @@ class ElasticAllReduceWorker:
             # in-step pmax consensus — read back at aligned sync indices,
             # which are the same step for every member — triggers the
             # pause below.
-            batch = self._next_batch()
+            with phases.measure("input_wait"):
+                batch = self._next_batch()
             step_i += 1
             # syncing (a device->host round trip) every step stalls the
             # dispatch pipeline; data steps sync every sync_every steps,
@@ -1266,7 +1299,8 @@ class ElasticAllReduceWorker:
               # validated and flushed, so no accounting is lost
               try:
                 self._report_losses(losses)
-                self._flush_unreported()
+                with phases.measure("report"):
+                    self._flush_unreported()
                 if batch is not None:
                     # step overlap: pull batch N+1 now — its H2D
                     # placement runs on the feeder thread while the
@@ -1277,8 +1311,10 @@ class ElasticAllReduceWorker:
                     # settled ledger the unpeeked loop's next
                     # _next_batch would — get_dataset never refuses
                     # over records this very iteration consumed.
-                    self._peek_and_stage_next()
-                self._alarm_on_embedding_overflow()
+                    with phases.measure("stage_next"):
+                        self._peek_and_stage_next()
+                with phases.measure("cadence"):
+                    self._alarm_on_embedding_overflow()
                 consensus = self.trainer.epoch_consensus
                 if (
                     aligned_sync
@@ -1310,56 +1346,8 @@ class ElasticAllReduceWorker:
                                 exc_info=True,
                             )
                     return self._settle_and_leave("reform", losses=losses)
-                if (
-                    self._ckpt is not None
-                    and (
-                        world.process_id == 0 or self.trainer.is_sharded
-                    )
-                    and self._ckpt.is_enabled()
-                    # sharded checkpoints are only restorable when EVERY
-                    # rank wrote the same version, so the cadence must
-                    # trigger at rank-aligned sync points alone
-                    and (aligned_sync or not self.trainer.is_sharded)
-                ):
-                    # checkpoints land at sync points, so the cadence is
-                    # "at least checkpoint_steps versions since the last
-                    # save" rather than an exact modulo (which would
-                    # silently degrade to lcm(sync_every, steps)). Rank 0
-                    # alone suffices on the replicated plane (it holds
-                    # replica 0 of every leaf); with sharded parameters
-                    # EVERY rank writes — each owns distinct table rows,
-                    # and the per-process manifests only assemble into a
-                    # restorable checkpoint when all ranks contributed.
-                    # Versions agree across ranks (lockstep collective
-                    # steps), so all ranks pick the same cadence points.
-                    version = self.trainer.version
-                    if (
-                        version - self._last_ckpt_version
-                        >= self._ckpt.steps
-                    ):
-                        self._ckpt.save(self.trainer._ts, version)
-                        self._last_ckpt_version = version
-                if (
-                    aligned_sync
-                    and self.trainer.is_sharded
-                    and self._job_type
-                    == JobType.TRAINING_WITH_EVALUATION
-                ):
-                    # in-plane eval: a lockstep protocol (consensus
-                    # gather + collective forwards), so it must run at
-                    # the same aligned index on every rank — exactly
-                    # here, after the pause check agreed nobody is
-                    # re-forming this round
-                    self._collective_evaluate()
-                if aligned_sync and self.trainer.mirror_enabled():
-                    # replica-plane cadence: same aligned-sync trigger
-                    # discipline as the checkpoint cadence (the refresh
-                    # is a collective — every rank must take it at the
-                    # same step, which the version-based predicate
-                    # guarantees)
-                    self.trainer.maybe_refresh_mirror(
-                        self.trainer.version
-                    )
+                with phases.measure("cadence"):
+                    self._sync_cadence(world, aligned_sync)
               except Exception as cadence_err:
                 # the reform path is only for WORLD failures — a peer
                 # loss surfacing as WorldBroken (escaped wedge) or as a
@@ -1393,6 +1381,52 @@ class ElasticAllReduceWorker:
                 if self._drained:
                     return "done"
                 time.sleep(0.2)
+
+    def _sync_cadence(self, world, aligned_sync):
+        """What rides a sync point besides the reports: the checkpoint
+        cadence, in-plane evaluation, the replica-plane refresh."""
+        if (
+            self._ckpt is not None
+            and (world.process_id == 0 or self.trainer.is_sharded)
+            and self._ckpt.is_enabled()
+            # sharded checkpoints are only restorable when EVERY
+            # rank wrote the same version, so the cadence must
+            # trigger at rank-aligned sync points alone
+            and (aligned_sync or not self.trainer.is_sharded)
+        ):
+            # checkpoints land at sync points, so the cadence is
+            # "at least checkpoint_steps versions since the last
+            # save" rather than an exact modulo (which would
+            # silently degrade to lcm(sync_every, steps)). Rank 0
+            # alone suffices on the replicated plane (it holds
+            # replica 0 of every leaf); with sharded parameters
+            # EVERY rank writes — each owns distinct table rows,
+            # and the per-process manifests only assemble into a
+            # restorable checkpoint when all ranks contributed.
+            # Versions agree across ranks (lockstep collective
+            # steps), so all ranks pick the same cadence points.
+            version = self.trainer.version
+            if version - self._last_ckpt_version >= self._ckpt.steps:
+                self._ckpt.save(self.trainer._ts, version)
+                self._last_ckpt_version = version
+        if (
+            aligned_sync
+            and self.trainer.is_sharded
+            and self._job_type == JobType.TRAINING_WITH_EVALUATION
+        ):
+            # in-plane eval: a lockstep protocol (consensus
+            # gather + collective forwards), so it must run at
+            # the same aligned index on every rank — exactly
+            # here, after the pause check agreed nobody is
+            # re-forming this round
+            self._collective_evaluate()
+        if aligned_sync and self.trainer.mirror_enabled():
+            # replica-plane cadence: same aligned-sync trigger
+            # discipline as the checkpoint cadence (the refresh
+            # is a collective — every rank must take it at the
+            # same step, which the version-based predicate
+            # guarantees)
+            self.trainer.maybe_refresh_mirror(self.trainer.version)
 
     def _alarm_on_embedding_overflow(self):
         """Surface a2a capacity overflow (ids silently trained on zero

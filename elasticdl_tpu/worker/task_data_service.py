@@ -547,12 +547,10 @@ class TaskDataService:
         # the dispatcher's trace id labels the prefetch-warm span, so a
         # profiler timeline joins this read to the same task's train
         # span on the consumer thread (docs/observability.md)
-        from elasticdl_tpu.utils.profiling import annotate
-
         trace_id = (getattr(task, "extended_config", None) or {}).get(
             "trace_id", "untraced"
         )
-        with annotate("edl/task/%s/warm" % trace_id), profiling.span(
+        with profiling.span(
             "task/warm", trace_id=trace_id, records=warm
         ), self.stats.timed("read_s"):
             for _ in range(max(0, warm)):

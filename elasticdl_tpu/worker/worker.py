@@ -59,7 +59,6 @@ from elasticdl_tpu.training.step import (
     make_grad_fn,
 )
 from elasticdl_tpu.utils import profiling
-from elasticdl_tpu.utils.profiling import annotate
 from elasticdl_tpu.worker.task_data_service import TaskDataService
 
 
@@ -1180,9 +1179,10 @@ class Worker:
                     train_with_local_model = True
 
                 batch_count = self._batch_count(dataset_batch)
-                # the dispatcher's task trace id labels the train span,
-                # so profiler timelines join pull/prefetch/decode/train
-                # across processes (docs/observability.md). The "step"
+                # the dispatcher's task trace id labels the train span
+                # (and, under an open profiler trace, its annotation),
+                # so timelines join pull/prefetch/decode/train across
+                # processes (docs/observability.md). The "step"
                 # span is the per-minibatch trace root the critical-path
                 # breakdown (tools/tracetool.py) decomposes; its
                 # children (pull_model/compute/grad_push/...) inherit
@@ -1190,9 +1190,7 @@ class Worker:
                 trace_id = (task.extended_config or {}).get(
                     "trace_id", "untraced"
                 )
-                with annotate(
-                    "edl/task/%s/train" % trace_id
-                ), profiling.span(
+                with profiling.span(
                     "step",
                     trace_id=trace_id,
                     task=getattr(task, "task_id", None),

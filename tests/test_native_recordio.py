@@ -2,7 +2,6 @@
 
 import os
 import subprocess
-import sys
 
 import pytest
 
@@ -15,13 +14,20 @@ from elasticdl_tpu.native import NativeRecordIOReader, native_lib
 
 
 def _ensure_built():
-    if native_lib() is None:
-        subprocess.check_call(
-            [sys.executable, "-m", "elasticdl_tpu.native.build"]
-        )
-        # reset the load cache
-        import elasticdl_tpu.native as native_mod
+    """Build the library where a fresh checkout has none, then load it.
+    Every xdist worker collects this module at once: ``build`` puts the
+    library in place whole (os.replace), so whichever worker finds it
+    can load it, and the load is tried again only after this process's
+    own build has returned."""
+    import elasticdl_tpu.native as native_mod
+    from elasticdl_tpu.native import build
 
+    if native_lib() is None:
+        try:
+            build.build(verbose=False)
+        except (OSError, subprocess.CalledProcessError):
+            return False
+        # forget the load that failed before the library was there
         native_mod._load_failed = False
         native_mod._handle = None
     return native_lib() is not None

@@ -6,8 +6,7 @@ scripts/check.sh), the Prometheus text exposition (golden parse), the
 JSONL event log (monotonic ids across a simulated resize + task
 requeue), the dispatcher's task-lifecycle tracing, the worker
 snapshot -> master aggregation path, the /metrics HTTP endpoint, the
-RPC-layer instrumentation, the TensorBoard export, and the step_timer
-percentile fix.
+RPC-layer instrumentation, and the TensorBoard export.
 """
 
 import json
@@ -25,11 +24,7 @@ from elasticdl_tpu.master.telemetry import (
     TelemetryTBExporter,
 )
 from elasticdl_tpu.utils import profiling
-from elasticdl_tpu.utils.profiling import (
-    EventLog,
-    MetricsRegistry,
-    step_timer,
-)
+from elasticdl_tpu.utils.profiling import EventLog, MetricsRegistry
 from elasticdl_tpu.worker.telemetry import WorkerTelemetry
 
 
@@ -553,27 +548,6 @@ def test_telemetry_tb_exporter_concurrent_flush_exactness(tmp_path):
         exporter.close()
     # close() ran one final flush after the join
     assert exporter._flushes == n * per + 1
-
-
-# ---------------------------------------------------------------------------
-# step_timer percentile fix
-# ---------------------------------------------------------------------------
-
-
-def test_step_timer_nearest_rank_percentiles():
-    t = step_timer()
-    # inject a known sample set: 1..4 (seconds)
-    t._times = [4.0, 1.0, 3.0, 2.0]
-    s = t.stats()
-    # nearest-rank: p50 of [1,2,3,4] is the 2nd value, NOT the 3rd
-    # (the old n//2 indexing returned 3.0 here)
-    assert s["p50_ms"] == 2000.0
-    assert s["p90_ms"] == 4000.0
-    assert s["p99_ms"] == 4000.0
-    assert s["max_ms"] == 4000.0
-    # n=2: the old code called the MAX the median
-    t._times = [1.0, 9.0]
-    assert t.stats()["p50_ms"] == 1000.0
 
 
 def test_worker_ships_snapshot_through_stub():
