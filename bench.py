@@ -737,7 +737,7 @@ def bench_elastic_tax(quick=False):
         trainer._ts = elastic_mod.broadcast_from_device0(
             trainer._mesh, trainer._host_ts
         )
-        trainer._checked_ts = trainer._ts
+        trainer._keep_checked(trainer._ts)
         trainer._step_fn = elastic_mod.make_elastic_train_step(
             model, zoo.loss, trainer._optimizer, trainer._mesh
         )
@@ -746,8 +746,8 @@ def bench_elastic_tax(quick=False):
     def measure_elastic_step(trainer):
         """The weighted-lockstep STEP FN alone (pre-placed inputs, same
         batch residency as the fused baseline): isolates the machinery
-        tax — weight scaling, pmax rider, psum, no-donation double
-        buffering — from input shipping."""
+        tax — weight scaling, pmax rider, psum — from input
+        shipping."""
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         mesh = trainer._mesh
@@ -780,6 +780,8 @@ def bench_elastic_tax(quick=False):
                 )
             final = float(loss)
             dt = time.perf_counter() - t0
+        # a process-local mesh's step donated the state it was given
+        trainer._ts = ts
         assert np.isfinite(final)
         return batch * steps / dt
 
@@ -923,7 +925,7 @@ def bench_compile(quick=False):
             t._host_ts = t.snapshot()
         t._mesh = Mesh(all_devices[:k], ("data",))
         t._ts = elastic_mod.broadcast_from_device0(t._mesh, t._host_ts)
-        t._checked_ts = t._ts
+        t._keep_checked(t._ts)
         t._spec_example = phases[0][1][0]
         t._acquire_step_fn()
 

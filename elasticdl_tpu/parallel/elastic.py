@@ -30,11 +30,15 @@ solutions here:
   reference's workers-re-push-to-PS re-init (ps/servicer.py:70-79).
 
 - **Failure visibility.** A peer death mid-collective surfaces as an
-  error from the jitted step on every survivor. Step inputs are not
-  donated, so the pre-step state is still addressable afterwards; the
-  worker fetches it, waits for the master to bump the epoch, and
-  re-forms. (The single-process trainer donates; here the double
-  buffering is the price of kill-anywhere recovery.)
+  error from the jitted step on every survivor. On a mesh that spans
+  processes the step's inputs are not donated, so the pre-step state is
+  still addressable afterwards; the worker fetches it, waits for the
+  master to bump the epoch, and re-forms. That double buffering is the
+  price of kill-anywhere recovery, and it is paid where a peer exists:
+  on a mesh whose devices all belong to this process no collective can
+  fail under a live process, so there the step donates its train state
+  (:func:`state_donation`) and the trainer keeps no second device copy
+  (``ElasticDPTrainer._keep_checked``).
 """
 
 import re
@@ -118,6 +122,33 @@ def mesh_local_count(mesh):
     resize onto a device subset), and jax refuses process-local data
     that does not fit the sharding it is placed with."""
     return len(mesh.local_devices)
+
+
+def state_donation(mesh):
+    """``donate_argnums`` of a train step built for ``mesh``: the
+    TrainState (argument 0) when every device of the mesh belongs to
+    this process, nothing when the mesh spans processes. A step whose
+    collective a dying peer can fail must leave its input addressable
+    for the survivors' re-form (module docstring, "Failure
+    visibility"); a process-local mesh has no such peer, and holding
+    the state as input and output at once only costs memory and lets
+    the host run no further ahead than the allocator has room for.
+    Read off the mesh alone, so an executable cached or speculatively
+    compiled for a world follows that world's rule."""
+    return () if mesh.is_multi_process else (0,)
+
+
+def count_donated_inputs(lowered_text):
+    """Inputs a lowered module (its MLIR text) donates. A donated input
+    carries one of two attributes: the output it aliases where the
+    lowering paired them, else the bare donor mark the compiler pairs
+    later."""
+    return len(
+        re.findall(
+            r"tf\.aliasing_output = \d+|jax\.buffer_donor = true",
+            lowered_text,
+        )
+    )
 
 
 def host_copy(tree):
@@ -765,9 +796,9 @@ def make_elastic_train_step(
         out_specs=(ts_spec, P(), P(), P()),
         check_vma=False,
     )
-    # no donation: the pre-step state must survive a failed collective so
-    # survivors can re-form from it (see module docstring)
-    return jax.jit(sharded)
+    # the state is donated where no peer can fail the collective, and
+    # kept where survivors must re-form from it (see state_donation)
+    return jax.jit(sharded, donate_argnums=state_donation(mesh))
 
 
 def specs_use_axis(sharded_paths, axis):
@@ -861,9 +892,10 @@ def make_pjit_train_step(
     not per-device), mutable model state (batch stats) updates from
     the full global batch including weight-0 devices' stale rows (use
     the replicated plane for batch-stat models), and the MoE aux loss
-    adds once globally rather than per device. No donation, same as
-    the elastic step: the pre-step state must survive a failed
-    collective for re-forms.
+    adds once globally rather than per device. Donation follows the
+    elastic step's rule (:func:`state_donation`): the state is donated
+    on a process-local mesh and kept, for re-forms after a failed
+    collective, on one that spans processes.
     """
     from elasticdl_tpu.training.precision import get_policy
     from elasticdl_tpu.training.step import make_remat_forward
@@ -922,7 +954,9 @@ def make_pjit_train_step(
     # re-replicate a sharded parameter on the way out and the "bigger
     # than one device" property would evaporate after the first step
     return jax.jit(
-        step, out_shardings=(ts_shardings, rep, rep, rep)
+        step,
+        out_shardings=(ts_shardings, rep, rep, rep),
+        donate_argnums=state_donation(mesh),
     )
 
 
@@ -1106,7 +1140,9 @@ class ElasticDPTrainer:
         self._mesh = None
         self._spec = None
         self._ts = None
-        self._checked_ts = None  # last fetch-validated device state
+        # last fetch-validated device state; kept only on a mesh that
+        # spans processes (see _keep_checked)
+        self._checked_ts = None
         self._host_ts = None  # latest host snapshot (re-form source)
         self._step_fn = None
         self._eval_fn = None  # in-plane eval forward (built on demand)
@@ -1313,7 +1349,7 @@ class ElasticDPTrainer:
                 self._mesh, offer, source_process=source
             )
         t_place = _time.time()
-        self._checked_ts = self._ts
+        self._keep_checked(self._ts)
         self._placed_epoch = distributed.backend_epoch()
         self._spec_example = example_batch or self._last_local
         with profiling.span("elastic/establish/compile"):
@@ -1561,7 +1597,10 @@ class ElasticDPTrainer:
         own trace and lowering rather than off the flags that asked for
         it: the Pallas kernels in the jaxpr, by name, and how many of
         the calls are interpreted; and the Mosaic custom calls in the
-        lowered module, by kernel name. Asked BEFORE the first step it
+        lowered module, by kernel name; and how many of the module's
+        inputs it donates (the state's leaves on a process-local mesh,
+        none on one that spans processes: :func:`state_donation`).
+        Asked BEFORE the first step it
         costs about nothing: jax caches the trace and the lowering, and
         the step's own first call reuses both (CPU, 8 layers: 4.4 s
         here + 5.5 s first step, against a 10.3 s first step alone).
@@ -1586,6 +1625,7 @@ class ElasticDPTrainer:
             "mosaic_kernels": sorted(
                 set(re.findall(r'kernel_name = "([^"]+)"', lowered_text))
             ),
+            "donated_inputs": count_donated_inputs(lowered_text),
         }
 
     def _step_callable_for(self, args):
@@ -2989,8 +3029,18 @@ class ElasticDPTrainer:
         # the fetch proves every dispatched collective up to here
         # completed; checkpoint that state as the re-form fallback
         self.epoch_consensus = epoch_seen_v
-        self._checked_ts = new_ts
+        self._keep_checked(new_ts)
         return loss_v, n_v, count
+
+    def _keep_checked(self, ts):
+        """Remember ``ts`` as the fetch-validated device state a failed
+        collective rolls back to: where a peer exists. On a
+        process-local mesh the next step donates these very buffers
+        (:func:`state_donation`), so a kept reference would be a
+        deleted array one dispatch later, and there is no failed
+        collective to roll back from: nothing is kept, and
+        :meth:`snapshot` falls back to the host snapshot."""
+        self._checked_ts = ts if self._mesh.is_multi_process else None
 
     def _escapable(self, fn):
         """Run a device-touching callable so the host thread can escape
@@ -3026,8 +3076,8 @@ class ElasticDPTrainer:
         """Force-complete all dispatched work; True if it all succeeded.
 
         On success the latest state becomes the checked (re-form
-        fallback) state; on failure the checked state is left at the
-        last validated point.
+        fallback) state, where one is kept (:meth:`_keep_checked`); on
+        failure the checked state is left at the last validated point.
         """
         if self._ts is None:
             return True
@@ -3040,14 +3090,16 @@ class ElasticDPTrainer:
         except Exception:
             logger.warning("validation failed: a dispatched step errored")
             return False
-        self._checked_ts = self._ts
+        self._keep_checked(self._ts)
         return True
 
     def snapshot(self):
         """Pull current state to host (the re-form / checkpoint source).
 
         Falls back to the last fetch-validated state when the newest
-        buffers carry a failed collective (unsynced steps roll back).
+        buffers carry a failed collective (unsynced steps roll back);
+        on a process-local mesh, whose step donates its input and keeps
+        no such state, to the latest host snapshot.
         Sharded-parameter jobs return None: one process's host copy of a
         sharded leaf would be its shard alone — the sharded checkpoint
         plane (save_sharded / restore on establish) is their snapshot
@@ -3114,7 +3166,7 @@ class ElasticDPTrainer:
             directory, shardings, target_shapes=target_shapes or None
         )
         self._ts = ts
-        self._checked_ts = ts
+        self._keep_checked(ts)
         self._host_ts = host_copy(ts)
         logger.info(
             "restored sharded checkpoint v%d from %s", version, directory

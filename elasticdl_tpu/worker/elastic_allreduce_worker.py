@@ -21,9 +21,13 @@ Run loop shape:
 Epoch bumps are observed at batch boundaries (a cheap get_comm_world call
 per step — the PS worker pays a get_model RPC per step for the same
 cadence, reference worker.py:630-637). A peer death mid-collective instead
-surfaces as a step error; the pre-step state is still addressable
-(elastic step does not donate), so the worker snapshots, waits for the
-master to notice the death and bump the epoch, and re-forms. Evaluation
+surfaces as a step error; the pre-step state is still addressable (on a
+mesh that spans processes the elastic step does not donate: double
+buffering is the price of kill-anywhere recovery, paid where a peer
+exists), so the worker snapshots, waits for the master to notice the
+death and bump the epoch, and re-forms. A world of this process alone has
+no peer to lose: its step donates the train state, and a step that fails
+there leaves the latest host snapshot as the only source. Evaluation
 tasks run between steps on host-fetched params over local devices only —
 never on the global mesh — so slow eval can't wedge the collective plane.
 
@@ -907,8 +911,9 @@ class ElasticAllReduceWorker:
     def _report_step_built(self):
         """Say once, after the first establish, what this process
         trains on and with: the mesh's devices, the attention the built
-        step holds (read off the step, see describe_step), the record
-        reader, and where compiled programs are kept. One log line and
+        step holds and how many of its inputs it donates (both read off
+        the step, see describe_step), the record reader, and where
+        compiled programs are kept. One log line and
         one ``step_built`` event (scalar fields: it ships to the master's
         event log with the next task report)."""
         if self._step_reported:
@@ -950,6 +955,7 @@ class ElasticAllReduceWorker:
             "pallas_interpreted": facts["pallas_interpreted"],
             "tpu_custom_calls": facts["tpu_custom_calls"],
             "mosaic_kernels": ",".join(facts["mosaic_kernels"]),
+            "donated_inputs": facts["donated_inputs"],
             "record_reader": reader_kind(),
             "compile_cache_dir": jax.config.jax_compilation_cache_dir
             or "",

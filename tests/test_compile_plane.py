@@ -77,7 +77,7 @@ def _establish_at(trainer, k):
     trainer._ts = elastic_mod.broadcast_from_device0(
         trainer._mesh, trainer._host_ts
     )
-    trainer._checked_ts = trainer._ts
+    trainer._keep_checked(trainer._ts)
     trainer._spec_example = _batch()
     return trainer._acquire_step_fn()
 

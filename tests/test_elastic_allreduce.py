@@ -453,10 +453,10 @@ def _count_successes(task_d):
     completed = []
     orig_report = task_d.report
 
-    def counting_report(task_id, success):
+    def counting_report(task_id, success, **kwargs):
         if success:
             completed.append(task_id)
-        return orig_report(task_id, success)
+        return orig_report(task_id, success, **kwargs)
 
     task_d.report = counting_report
     return completed

@@ -293,7 +293,12 @@ def test_peak_hbm_bytes_is_none_where_the_backend_gives_none(
 
 STEPS, MINIBATCH, SYNC_EVERY = 40, 8, 8
 SLOW_CALL = 12  # the _next_batch call that sleeps (the first is _prime's)
-SLOW_SECONDS = 0.3
+# longer than a whole window of device work on a loaded CPU: since the
+# step donates its state the host runs a window ahead, and the sync
+# step's one `fetch` waits for all of it (0.5 s here when the box is
+# quiet, 1.6 s seen under six-way load), which would otherwise be the
+# window's slowest call
+SLOW_SECONDS = 4.0
 
 # test-only steering of the worker process: sitecustomize on its path
 HOOK = """
@@ -327,7 +332,7 @@ def job(tmp_path_factory):
     rng = np.random.default_rng(0)
     with create_recordio(str(data / "tokens.edlr")) as w:
         for _ in range(STEPS * MINIBATCH):
-            tokens = rng.integers(0, 64, size=128).astype(np.int64)
+            tokens = rng.integers(0, 64, size=256).astype(np.int64)
             w.write(encode_example({"tokens": tokens}))
     env = dict(
         os.environ,
@@ -349,8 +354,12 @@ def job(tmp_path_factory):
             "--model_zoo", os.path.join(REPO, "model_zoo"),
             "--model_def", "transformer_lm.transformer_lm.custom_model",
             "--model_params",
-            "vocab_size=64,num_layers=2,num_heads=4,head_dim=16,"
-            "embed_dim=64,mlp_dim=256,use_flash=False",
+            # wide enough that a step is ~70 ms of device work: with
+            # the host running ahead of the device the loop's glue
+            # between phases competes with the step for the CPU, and at
+            # 15 ms a step under load it reached a fifth of a window
+            "vocab_size=64,num_layers=2,num_heads=4,head_dim=32,"
+            "embed_dim=128,mlp_dim=512,use_flash=False",
             "--training_data", str(data),
             "--minibatch_size", str(MINIBATCH),
             "--num_minibatches_per_task", str(SYNC_EVERY),
