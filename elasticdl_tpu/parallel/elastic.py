@@ -1216,6 +1216,21 @@ class ElasticDPTrainer:
             for leaf in jax.tree_util.tree_leaves(self._ts)
         )
 
+    def routing_state(self):
+        """A host copy of the model's expert-routing state
+        (``parallel/expert.MOE_STATE_COLLECTION``: a selection bias and
+        wrapping assignment counters an expert layer, a few hundred
+        bytes), or None between worlds and for a model that keeps none.
+        Asked once a window, beside the loss drain, and accounted to
+        the same ``fetch`` phase: it waits for the window's last step."""
+        from elasticdl_tpu.parallel.expert import MOE_STATE_COLLECTION
+
+        state = None if self._ts is None else self._ts.state
+        if not isinstance(state, dict) or MOE_STATE_COLLECTION not in state:
+            return None
+        with profiling.phases.measure("fetch"):
+            return host_copy(state[MOE_STATE_COLLECTION])
+
     def peak_hbm_bytes(self):
         """The most device memory any local device of the mesh has held
         since the process started (``memory_stats()``'s
@@ -1349,6 +1364,7 @@ class ElasticDPTrainer:
                 self._mesh, offer, source_process=source
             )
         t_place = _time.time()
+        self._check_routing_state()
         self._keep_checked(self._ts)
         self._placed_epoch = distributed.backend_epoch()
         self._spec_example = example_batch or self._last_local
@@ -1517,6 +1533,35 @@ class ElasticDPTrainer:
             "EDL_ALLOW_CROSS_LEAF_OPT=1 if the coupling is known to "
             "exclude the sharded leaves."
         )
+
+    def _check_routing_state(self):
+        """Refuse a model with held-share expert layers
+        (``parallel/expert.MOE_STATE_COLLECTION`` in its state) on a
+        mesh of more than one device. The layer counts the assignments
+        of its own device's tokens, and the step's weighted average
+        leaves integer state as each device has it ("counters advance
+        identically everywhere"): the replicas' counters and selection
+        biases would drift apart unseen, and :meth:`routing_state`
+        would read one device's. Until the counts are summed over the
+        data axes inside the step, fail before the first one."""
+        from elasticdl_tpu.parallel.expert import MOE_STATE_COLLECTION
+
+        state = self._ts.state
+        if (
+            self._mesh.size > 1
+            and isinstance(state, dict)
+            and MOE_STATE_COLLECTION in state
+        ):
+            raise NotImplementedError(
+                "this model keeps expert-routing state (%r) and the mesh "
+                "has %d devices: each device would count only its own "
+                "tokens' assignments, so the selection bias and the "
+                "routing counters would differ between replicas. The "
+                "held-share expert layer runs on a mesh of one device "
+                "(--num_workers 1 on a one-chip host) until its counts "
+                "are summed over the data axes."
+                % (MOE_STATE_COLLECTION, self._mesh.size)
+            )
 
     # -- compile-plane fast path (parallel/compile_plane.py) ---------------
 

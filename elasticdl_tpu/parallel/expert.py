@@ -1,20 +1,53 @@
-"""Expert parallelism: MoE dispatch over an ``expert`` mesh axis.
+"""Expert parallelism: two mixture-of-experts layers.
 
-The reference has no expert parallelism (SURVEY.md §2.2: absent). This
-is the TPU-native form: each device along the ``expert`` axis owns one
-(or more) experts' parameters; tokens are gated top-1, packed into
-capacity-bounded per-expert buckets, shipped to their expert with
-``lax.all_to_all``, transformed, and shipped back — the same explicit
-routing fabric as the HBM embedding plane (nn/hbm_embedding.py), which
-is exactly the point: on TPU, "expert parallel" and "vocab-sharded
-lookup" are the same all_to_all pattern over ICI with different
-per-shard compute.
+The reference has no expert parallelism (SURVEY.md section 2.2: absent).
+Two layers live here, and they are different layers, not two settings
+of one.
 
-Capacity semantics follow the standard MoE recipe: each expert accepts
-at most ``capacity`` tokens per shard per step; overflow tokens bypass
-the experts (identity/zero contribution), weighted out by their gate.
-Gradients flow through dispatch, experts, combine, and the gate (via the
-gate-probability scaling).
+**The exchanged, capacity-bounded layer** (:func:`moe_apply`,
+:func:`make_moe_fn`, :func:`reference_moe`; the zoo's ``MoEMlp``). Each
+device along the ``expert`` mesh axis owns exactly ONE expert
+(``moe_apply`` squeezes the leading dim of its parameter slice, so the
+axis has as many devices as the layer has experts). Tokens are gated by
+a softmax over the experts, top-k with the selected probabilities
+renormalised (:func:`topk_gate`), packed into per-expert buckets of at
+most ``capacity`` rows, shipped to their expert with
+``lax.all_to_all``, transformed, and shipped back: the same routing
+fabric as the HBM embedding plane (nn/hbm_embedding.py). A token past
+an expert's capacity is DROPPED (zero contribution). On a mesh without
+an ``expert`` axis ``reference_moe`` runs every expert on every token.
+:func:`load_balancing_loss` is the Switch auxiliary loss that keeps its
+routing even.
+
+**The held-share, dropless layer** (:func:`sigmoid_topk_route`,
+:func:`held_experts_apply`, :func:`expert_bias_update`; the zoo's
+``hybrid_moe_lm``). A device is told which experts it holds
+(``first_expert_held``, ``experts_held``: MANY a device), routes over
+all ``num_experts`` of the layer, and computes the part of the result
+its own experts give. Scores are sigmoids, selection adds a bias that
+takes no gradient and is steered by the load (no auxiliary loss), the
+gates are normalised over all the selected experts, held or not. The
+``T * k`` assignments are sorted so that those of held experts come
+first, grouped by expert, and go through ops/grouped_matmul.py: a
+static buffer of ``T * k`` rows, no capacity, no dropped token. With
+``experts_held == num_experts`` it is the whole layer. It has NO
+exchange yet: it is one chip's share of an expert-parallel deployment
+whose other chips are absent, and what their experts would have added
+is left out. Its state (the bias, the assignment counters) is that of
+ONE device's tokens: nothing sums the counts over a data axis, so the
+elastic trainer refuses a model that keeps it on a mesh of more than
+one device (parallel/elastic.py ``_check_routing_state``).
+
+Once the held-share layer has its exchange over an ``expert`` axis (a
+ragged all_to_all of the rows each peer's experts own, before and
+after the grouped products) it replaces, of the older layer: the
+capacity buckets and the drop (``moe_apply``'s ``send``/``slot``
+arithmetic, ``make_moe_fn._capacity``), the one-expert-a-device squeeze,
+and ``reference_moe``'s every-expert-every-token fallback (the held
+share with ``experts_held == num_experts`` is that layer at the cost of
+the selected experts only). ``topk_gate``, ``load_balancing_loss`` and
+the softmax router stay: they are another model's routing, not a
+worse form of this one.
 """
 
 import functools
@@ -188,3 +221,165 @@ def reference_moe(expert_fn, per_expert_params, x, gate_logits, num_selected=1):
         for j in range(num_selected)
     )
     return picked
+
+
+# ---------------------------------------------------------------------------
+# The held-share, dropless layer
+# ---------------------------------------------------------------------------
+
+# state collection of an expert layer of this kind: ``expert_bias``
+# (E,) float32 and ``assignments`` (E,) int32, the assignments each
+# expert has had since the job's first step (it wraps; readers take
+# differences)
+MOE_STATE_COLLECTION = "moe_state"
+
+
+def sigmoid_topk_route(router_logits, expert_bias, k, scaling=1.0):
+    """(T, E) router logits -> (selected (T, k) int32, gates (T, k) f32).
+
+    ``s = sigmoid(logits)`` in float32; ``selected = top_k(s + bias)``
+    (the bias steers the selection and nothing else: it takes no
+    gradient and is not in the gates); ``gate_e = s_e / (sum of s over
+    the selected + 1e-6) * scaling``."""
+    scores = jax.nn.sigmoid(router_logits.astype(jnp.float32))
+    _, selected = jax.lax.top_k(
+        scores + jax.lax.stop_gradient(expert_bias.astype(jnp.float32)), k
+    )
+    picked = jnp.take_along_axis(scores, selected, axis=-1)
+    gates = picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-6)
+    return selected.astype(jnp.int32), gates * scaling
+
+
+def expert_assignments(selected, num_experts):
+    """(E,) int32: how many of the (T, k) assignments each expert got."""
+    return jnp.bincount(selected.reshape(-1), length=num_experts).astype(
+        jnp.int32
+    )
+
+
+def expert_bias_update(expert_bias, assignments, rate):
+    """The selection bias after a step that made ``assignments``
+    (auxiliary-loss-free balancing, Wang et al., arXiv:2408.15664):
+    ``b_e + rate * sign(mean(c) - c_e)``: up for an expert under the
+    mean load, down for one over it. No gradient passes."""
+    load = jax.lax.stop_gradient(assignments.astype(jnp.float32))
+    return expert_bias + rate * jnp.sign(jnp.mean(load) - load)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _rows_for_assignments(x, order, inverse, held_assignment, k):
+    """(T, d) tokens -> (T * k, d): row ``r`` is the token of
+    assignment ``order[r]``. The transpose is a gather too (by
+    ``inverse``), never a scatter; assignments of absent experts give
+    nothing back."""
+    return x[order // k]
+
+
+def _rows_fwd(x, order, inverse, held_assignment, k):
+    return x[order // k], (inverse, held_assignment)
+
+
+def _rows_bwd(k, residuals, g):
+    inverse, held_assignment = residuals
+    back = jnp.where(held_assignment[:, None], g[inverse], 0)
+    return back.reshape(-1, k, g.shape[-1]).sum(axis=1), None, None, None
+
+
+_rows_for_assignments.defvjp(_rows_fwd, _rows_bwd)
+
+
+@jax.custom_vjp
+def _permute_rows(x, perm, inverse):
+    """``x[perm]`` whose transpose is ``g[inverse]``."""
+    return x[perm]
+
+
+_permute_rows.defvjp(
+    lambda x, perm, inverse: (x[perm], (perm, inverse)),
+    lambda residuals, g: (g[residuals[1]], None, None),
+)
+
+
+def held_experts_apply(x, selected, gates, w_in, w_out, first_expert_held):
+    """This device's share of a SwiGLU expert layer's result.
+
+    ``x`` (T, d); ``selected``, ``gates`` (T, k) over ALL the layer's
+    experts (:func:`sigmoid_topk_route`); ``w_in`` (G, d, 2f): the held
+    experts' ``W_1 | W_3`` side by side; ``w_out`` (G, f, d): their
+    ``W_2``. The device holds experts ``first_expert_held`` ..
+    ``first_expert_held + G - 1``. Returns (T, d):
+
+        sum over e in selected(t), e held, of
+            gate_e(t) * W_2e (silu(x W_1e) * (x W_3e))
+
+    The ``T * k`` assignments are sorted (stable) so that rows of held
+    experts come first, grouped by expert, and rows of absent experts
+    last, covered by no group of the three grouped products
+    (ops/grouped_matmul.py), whose tiles past the held rows are never
+    computed. What those rows hold in between is undefined and is
+    masked where a result leaves (here and in the transposes)."""
+    from elasticdl_tpu.ops.grouped_matmul import grouped_matmul
+
+    tokens, k = selected.shape
+    held = w_in.shape[0]
+    width = w_out.shape[1]
+    local = selected.reshape(-1) - first_expert_held
+    held_assignment = jnp.logical_and(local >= 0, local < held)
+    group = jnp.where(held_assignment, local, held)  # absent: last
+    order = jnp.argsort(group, stable=True).astype(jnp.int32)
+    inverse = jnp.argsort(order).astype(jnp.int32)
+    group_sizes = jnp.bincount(group, length=held + 1)[:held].astype(
+        jnp.int32
+    )
+
+    rows = _rows_for_assignments(x, order, inverse, held_assignment, k)
+    up = grouped_matmul(rows, w_in, group_sizes)
+    act = (jax.nn.silu(up[:, :width]) * up[:, width:]).astype(x.dtype)
+    y = grouped_matmul(act, w_out, group_sizes)
+    back = _permute_rows(y, inverse, order).reshape(tokens, k, -1)
+    # masked BEFORE the gate multiplies it: the gate's gradient is this
+    # product's other factor, and an undefined row times zero is not zero
+    back = jnp.where(
+        held_assignment.reshape(tokens, k, 1), back.astype(jnp.float32), 0.0
+    )
+    return (back * gates[..., None]).sum(axis=1).astype(x.dtype)
+
+
+def window_routing_counters(before, after, first_expert_held, experts_held):
+    """What a window's ``train_window`` event says of the routing, from
+    two host copies of the model's :data:`MOE_STATE_COLLECTION` (the
+    start and the end of the window; ``before`` None at the first):
+
+    ``moe_rows_here``: assignments to held experts, summed over the
+    window's steps and the expert layers; ``moe_rows_routed``: all
+    assignments (steps x layers x T x k); ``moe_rows_max_expert`` and
+    ``moe_rows_mean_expert``: the most and the mean over the (layer,
+    held expert) pairs, each one group of a grouped product, of the
+    window's totals; ``expert_bias_abs_max`` at the window's end."""
+    import numpy as np
+
+    def leaves(tree, name):
+        return [
+            np.asarray(leaf)
+            for path, leaf in jax.tree_util.tree_leaves_with_path(tree)
+            if path[-1].key == name
+        ]
+
+    now = np.stack(leaves(after, "assignments"))  # (layers, E) int32
+    if before is None:
+        made = now.astype(np.int64)
+    else:
+        # the counters wrap; a window's difference does not
+        made = (now - np.stack(leaves(before, "assignments"))).astype(
+            np.int64
+        )
+    here = made[:, first_expert_held : first_expert_held + experts_held]
+    return {
+        "moe_rows_here": int(here.sum()),
+        "moe_rows_routed": int(made.sum()),
+        "moe_rows_max_expert": int(here.max()),
+        "moe_rows_mean_expert": float(here.mean()),
+        "expert_bias_abs_max": float(
+            max(np.abs(b).max() for b in leaves(after, "expert_bias"))
+        ),
+    }

@@ -434,6 +434,8 @@ class ElasticAllReduceWorker:
         self._last_ckpt_version = 0
         self._step_reported = False  # the once-per-process step report
         self._losses_reported = 0  # losses already in a train_window event
+        self._model_facts = {}  # the model's own step_built facts
+        self._routing_seen = None  # routing state at the last window's end
         self._window_t0 = None
         self._window_cpu0 = None  # the loop thread's CPU at _window_t0
         self._batch_gen = None
@@ -960,6 +962,11 @@ class ElasticAllReduceWorker:
             "compile_cache_dir": jax.config.jax_compilation_cache_dir
             or "",
         }
+        # what the model says of its own layout (a zoo module's
+        # ``step_facts``: layers by kind, experts held and routed over);
+        # absent on a model that has nothing to say
+        self._model_facts = getattr(self._model, "step_facts", dict)()
+        report.update(self._model_facts)
         logger.info(
             "step built: %s",
             " ".join("%s=%s" % kv for kv in report.items()),
@@ -978,7 +985,9 @@ class ElasticAllReduceWorker:
         worker's phases"): ``<phase>_s`` for each of
         ``profiling.STEP_PHASES``, the single slowest call, the loop
         thread's CPU seconds, the devices' peak memory where the
-        backend reports it. The same fields go to the span plane as one
+        backend reports it, and, of a model with held-share expert
+        layers, the window's routing counters (:meth:`_window_routing`).
+        The same fields go to the span plane as one
         ``train/window`` span, so ``/trace`` and the flight recorder
         hold the last windows of a worker that died. Called at sync
         points, where the deferred losses of the window have just been
@@ -992,6 +1001,9 @@ class ElasticAllReduceWorker:
         from elasticdl_tpu.utils import profiling
 
         self._losses_reported = len(losses)
+        # with the loss drain, inside the window it closes: its wait
+        # for the window's last step is this window's ``fetch``
+        routing = self._window_routing()
         now, cpu = time.time(), time.thread_time()
         if self._window_t0 is None:  # no step was ever built
             self._window_t0, self._window_cpu0 = now, cpu
@@ -1012,12 +1024,33 @@ class ElasticAllReduceWorker:
                 seconds=round(now - t0, 3),
                 state_on_devices=self.trainer.state_device_coverage(),
                 **account,
+                **routing,
             )
             profiling.events.emit("train_window", **fields)
             if profiling.metrics_enabled():
                 profiling.spans.record(
                     "train/window", t0, now - t0, **fields
                 )
+
+    def _window_routing(self):
+        """The window's routing counters of a model with held-share
+        expert layers (``moe_rows_here``, ``moe_rows_routed``,
+        ``moe_rows_max_expert``, ``moe_rows_mean_expert``,
+        ``expert_bias_abs_max``: parallel/expert.py
+        ``window_routing_counters``), from one small host copy of the
+        model's routing state; no fields for a model without."""
+        routing = self.trainer.routing_state()
+        if routing is None or "experts_held" not in self._model_facts:
+            return {}
+        from elasticdl_tpu.parallel.expert import window_routing_counters
+
+        before, self._routing_seen = self._routing_seen, routing
+        return window_routing_counters(
+            before,
+            routing,
+            self._model_facts["first_expert_held"],
+            self._model_facts["experts_held"],
+        )
 
     def _restore_latest_checkpoint(self):
         """Resume from the newest restorable checkpoint; a partial or

@@ -31,11 +31,12 @@ from elasticdl_tpu.parallel.ring_attention import (
 )
 
 
-def _rotary(x, positions):
-    """Rotary position embedding over the last (head) dim."""
+def _rotary(x, positions, theta=10000.0):
+    """Rotary position embedding over the last (head) dim, base
+    ``theta``."""
     d = x.shape[-1]
     half = d // 2
-    freqs = 1.0 / (10000 ** (jnp.arange(half, dtype=jnp.float32) / half))
+    freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
     angles = positions[..., None].astype(jnp.float32) * freqs  # (B, L, half)
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]

@@ -12,6 +12,7 @@ uninitialized relaunch — plus the scripted fault plane's determinism.
 """
 
 import threading
+import time
 
 import numpy as np
 import optax
@@ -216,7 +217,13 @@ def test_epoch_bumped_shard_never_gets_a_resent_push():
             ],
             0,
         )
-        # the relaunch happens while that push is still in flight
+        # the relaunch happens while that push is still in flight: wait
+        # until the pool's thread has reached the parked stub (it lost
+        # the race with the two constructors below on a slow wake-up,
+        # and then pushed to the relaunched shard it was never sent to)
+        in_flight = time.time() + 5
+        while not calls["push"] and time.time() < in_flight:
+            time.sleep(0.001)
         p2 = Parameters()
         relaunched = PserverServicer(
             p2, 1, optax.sgd(0.1), use_async=True, shard_epoch=2,

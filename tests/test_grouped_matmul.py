@@ -1,0 +1,208 @@
+"""ops/grouped_matmul.py, interpreted on the CPU as the flash kernels'
+tests are: the product and its gradients against a per-group einsum,
+the visit scheme, and the kernels' build for the chip at the widths the
+benchmark's expert layer hands them."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from elasticdl_tpu.ops import grouped_matmul as gmm
+
+ROWS, K, N, TILING = 24, 16, 32, (8, 8, 16)
+# group sizes over 24 rows in tiles of 8
+CASES = {
+    "an_empty_group_and_rows_past_the_end": [5, 0, 9, 3],
+    "a_group_that_crosses_two_tiles": [2, 19, 1, 0],
+    "groups_on_tile_edges": [8, 8, 8, 0],
+    "every_group_empty": [0, 0, 0, 0],
+    "one_group_owns_every_row": [0, 24, 0, 0],
+    "most_rows_past_the_last_group": [1, 1, 1, 1],
+}
+
+
+def _operands(seed=0):
+    a, b, c = jax.random.split(jax.random.PRNGKey(seed), 3)
+    return (
+        jax.random.normal(a, (ROWS, K)),
+        jax.random.normal(b, (len(CASES["groups_on_tile_edges"]), K, N)),
+        jax.random.normal(c, (ROWS, N)),
+    )
+
+
+def _per_group(lhs, rhs, sizes):
+    """The same product as one masked einsum a group."""
+    out = jnp.zeros((lhs.shape[0], rhs.shape[2]), jnp.float32)
+    row, start = jnp.arange(lhs.shape[0]), 0
+    for g, size in enumerate(sizes):
+        mine = (row >= start) & (row < start + size)
+        product = jnp.einsum("rk,kn->rn", lhs, rhs[g], precision="highest")
+        out = out + jnp.where(mine[:, None], product, 0.0)
+        start += size
+    return out
+
+
+def _owned(x, sizes):
+    """``x`` with the rows past the last group's end, which the kernel
+    leaves undefined, masked as a caller has to mask them."""
+    return jnp.where(jnp.arange(x.shape[0])[:, None] < sum(sizes), x, 0.0)
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_forward_matches_a_per_group_einsum(case):
+    lhs, rhs, _ = _operands()
+    sizes = CASES[case]
+    got = gmm.grouped_matmul(lhs, rhs, jnp.array(sizes, jnp.int32), TILING)
+    np.testing.assert_allclose(
+        _owned(got, sizes), _per_group(lhs, rhs, sizes), atol=1e-5
+    )
+
+
+@pytest.mark.parametrize("argument", ["lhs", "rhs"])
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_gradient_matches_a_per_group_einsum(case, argument):
+    lhs, rhs, weight = _operands(1)
+    sizes = CASES[case]
+    which = ("lhs", "rhs").index(argument)
+
+    def through_kernel(lhs, rhs):
+        out = gmm.grouped_matmul(lhs, rhs, jnp.array(sizes, jnp.int32), TILING)
+        return jnp.sum(_owned(out, sizes) * weight)
+
+    got = jax.grad(through_kernel, which)(lhs, rhs)
+    if argument == "lhs":
+        got = _owned(got, sizes)
+    want = jax.grad(
+        lambda lhs, rhs: jnp.sum(_per_group(lhs, rhs, sizes) * weight), which
+    )(lhs, rhs)
+    np.testing.assert_allclose(got, want, atol=1e-4)
+
+
+def test_group_sizes_take_no_gradient_and_jit_picks_its_own_tiles():
+    lhs, rhs, _ = _operands(2)
+    sizes = jnp.array(CASES["an_empty_group_and_rows_past_the_end"], jnp.int32)
+    got = jax.jit(gmm.grouped_matmul)(lhs, rhs, sizes)
+    sizes = [int(s) for s in sizes]
+    np.testing.assert_allclose(
+        _owned(got, sizes), _per_group(lhs, rhs, sizes), atol=1e-5
+    )
+    assert gmm.auto_tiles(32768, 2048, 3072) == (512, 1024, 1024)
+    assert gmm.auto_tiles(32768, 1536, 1536) == (512, 768, 768)
+
+
+def test_nothing_a_row_past_the_end_holds_reaches_a_result():
+    """The rows past the last group's end are undefined on the way out,
+    so a chained product gets them undefined on the way in: not finite
+    there, in ``lhs`` and in the cotangent, and the rows the groups own
+    and ``rhs``'s gradient are what they were."""
+    lhs, rhs, weight = _operands(3)
+    sizes = CASES["an_empty_group_and_rows_past_the_end"]
+    past = jnp.arange(ROWS)[:, None] >= sum(sizes)
+
+    def through_kernel(lhs, rhs, weight):
+        out = gmm.grouped_matmul(lhs, rhs, jnp.array(sizes, jnp.int32), TILING)
+        # the cotangent of the rows past the end is ``weight``'s there
+        return jnp.sum(jnp.where(past, 0.0, out) * weight) + jnp.sum(
+            jnp.where(past, out * 0.0, 0.0) * weight
+        )
+
+    clean = jax.value_and_grad(through_kernel, (0, 1))(lhs, rhs, weight)
+    dirty = jax.value_and_grad(through_kernel, (0, 1))(
+        jnp.where(past[:, :1], jnp.nan, lhs),
+        rhs,
+        jnp.where(past[:, :1], jnp.nan, weight),
+    )
+    np.testing.assert_allclose(
+        _owned(dirty[1][0], sizes), _owned(clean[1][0], sizes), atol=1e-6
+    )
+    np.testing.assert_allclose(dirty[1][1], clean[1][1], atol=1e-6)
+    assert np.isfinite(np.asarray(dirty[1][1])).all()
+
+
+def test_visits_walk_each_tile_of_each_group_once():
+    sizes = jnp.array([5, 0, 9, 3], jnp.int32)
+    (offsets, groups, tiles), count = gmm.visits(sizes, 24, 8, False)
+    assert list(offsets) == [0, 5, 5, 14, 17]
+    # group 0 in tile 0; group 2 in tiles 0 and 1; group 3 in tiles 1, 2
+    assert int(count) == 5
+    assert list(zip(groups[:5].tolist(), tiles[:5].tolist())) == [
+        (0, 0), (2, 0), (2, 1), (3, 1), (3, 2),
+    ]  # fmt: skip
+    # the transposed product also visits the empty group, to zero it
+    (_, groups, _), count = gmm.visits(sizes, 24, 8, True)
+    assert int(count) == 6 and groups[:6].tolist() == [0, 1, 2, 2, 3, 3]
+
+
+def test_shapes_that_do_not_tile_are_refused():
+    lhs, rhs, _ = _operands()
+    with pytest.raises(ValueError, match="does not divide"):
+        gmm.grouped_matmul(lhs, rhs, jnp.zeros((4,), jnp.int32), (16, 8, 16))
+    with pytest.raises(ValueError, match="group_sizes"):
+        gmm.grouped_matmul(lhs, rhs, jnp.zeros((3,), jnp.int32))
+
+
+# ---------------------------------------------------------------------------
+# the kernels build for the chip at the benchmark's widths (no chip: the
+# TPU's compiler is installed here and compiles for a described v5e)
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    import os
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+    return SingleDeviceSharding(topo.devices[0])
+
+
+# an expert layer of the benchmark's LFM2 share: 8,192 tokens x 4
+# assignments, 8 experts held, hidden 2048, expert width 1536
+BUFFER_ROWS, HELD, HIDDEN, WIDTH = 32768, 8, 2048, 1536
+
+
+@pytest.mark.parametrize(
+    "kernel, k, n",
+    [
+        ("fwd", HIDDEN, 2 * WIDTH),
+        ("fwd", WIDTH, HIDDEN),
+        ("dlhs", 2 * WIDTH, HIDDEN),
+        ("dlhs", HIDDEN, WIDTH),
+        ("tgmm", HIDDEN, 2 * WIDTH),
+        ("tgmm", WIDTH, HIDDEN),
+    ],
+)
+def test_kernel_compiles_for_the_v5e_at_the_benchmarks_widths(
+    one_chip, kernel, k, n
+):
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    sizes = shape(HELD, dtype=jnp.int32)
+    if kernel == "tgmm":
+        fn = lambda lhs, rhs, s: gmm._tgmm(lhs, rhs, s, jnp.bfloat16, None, False)
+        args = (shape(BUFFER_ROWS, k), shape(BUFFER_ROWS, n), sizes)
+    else:
+        transposed = kernel == "dlhs"
+        fn = lambda lhs, rhs, s: gmm._gmm(lhs, rhs, s, transposed, None, False)
+        rhs = shape(HELD, n, k) if transposed else shape(HELD, k, n)
+        args = (shape(BUFFER_ROWS, k), rhs, sizes)
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        text = jax.jit(fn).lower(*args).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+    assert "tpu_custom_call" in text
+    name = gmm.TGMM_KERNEL if kernel == "tgmm" else "%s_k%d_%s" % (
+        gmm.GMM_KERNEL, k, kernel
+    )
+    assert name in text
