@@ -8,6 +8,33 @@ while the running max / normalizer / accumulator persist in VMEM scratch
 across the innermost k axis — the standard TPU flash pipeline.
 Accumulation is float32 while inputs may be bfloat16 (MXU native).
 
+Inside a grid step the tile is not computed whole (PR 29). The body
+cuts it into sub-blocks of ``w`` rows (:func:`sub_block`: 256) and,
+under the causal mask, does per sub-block only what the diagonal
+leaves (:func:`_walk_tile`): a tile above the diagonal does
+nothing, a tile below it runs every sub-block with no mask at all, and
+a tile the diagonal crosses runs each sub-block trimmed to the columns
+its rows can see, masked there and only there. At L = 2,048 in
+1,024-tiles the kernels perform 2.25 tiles of scores a head where the
+whole-tile bodies performed 3 and the mask keeps 2
+(:func:`causal_work_ratio` 1.125, from 1.5). The big tiles stay: they
+amortise the DMA and the grid steps. All three kernels cut along q: a
+sub-block is ``w`` rows of the tile against every column those rows can
+see (cutting dkv along k instead read 1% faster, cutting dq along k 2%
+slower: not worth a second order).
+
+The forward keeps ONE online-softmax step a tile however many
+sub-blocks it has: the row maximum is collected lane by lane across the
+sub-blocks and reduced across lanes once, and the normalizer's row sum
+is taken by the matrix unit (``p @ [v | 1]``: at head size 64 half of
+the 128 result lanes are idle). Per-sub-block recurrences doubled the
+forward's time on the chip; cross-lane reductions and (rows, 128)
+statistics are what a tile's forward is made of, not its area. The
+softmax scale is folded into q once a tile. Operands reach the matrix
+unit as float32 (Mosaic contracts them in one bf16 pass on v5e);
+handing them over in bf16 measured 1-3% SLOWER in all three kernels
+(chip runs, PR 29) and was left out.
+
 Training path: the forward saves only (out, logsumexp) per row — O(L)
 extra — and the backward runs two more blockwise kernels that recompute
 ``p = exp(qk^T - lse)`` per tile:
@@ -46,12 +73,113 @@ BWD_DKV_KERNEL = "edl_flash_bwd_dkv"
 
 # every grid is (batch*heads, outer tile, inner tile) and accumulates
 # over the innermost axis only. No vmem_limit_bytes: on v5e / libtpu
-# 0.0.34 the default 1024x1024 tiles (and 1024x2048 at L=2048) compile
-# under Mosaic's default scoped-VMEM limit, with or without these
-# parameters (chip run, PR 21).
+# 0.0.34 the default 1024x1024 tiles compile under Mosaic's default
+# scoped-VMEM limit (chip runs, PR 21 and PR 29; 2048x2048 tiles do
+# not: the dq kernel runs out of VMEM, PR 29).
 _COMPILER_PARAMS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "arbitrary")
 )
+
+
+def _run_if(pred):
+    """``pl.when`` for Python booleans: what :func:`causal_work_ratio`
+    walks a tile with."""
+    return lambda fn: fn() if pred else None
+
+
+def _walk_tile(qi, kj, block_q, block_k, w, causal, step, when=pl.when):
+    """Call ``step(strips)`` with the sub-blocks of tile ``(qi, kj)`` in
+    which the causal mask leaves work: ``strips`` is a static list of
+    ``(rows, cols, off)``.
+
+    ``rows`` and ``cols`` are static slices of the tile. ``off`` is None
+    where every score of the sub-block is kept (no mask is built), else
+    the sub-block's first q position minus its first k position: the
+    score at local ``(r, c)`` is kept when ``r + off >= c``.
+
+    The tile is cut along q into ``block_q // w`` sub-blocks of ``w``
+    rows, in ascending order; a ``w`` that does not divide ``block_q``
+    leaves the tile whole.
+
+    - not causal: every sub-block whole, ``off`` None;
+    - causal, square tiles: the diagonal passes only through tiles with
+      ``qi == kj``, corner to corner. Tiles above it do nothing, tiles
+      below it step as a non-causal tile does, and a tile on it steps
+      with each sub-block trimmed to the columns its rows can see: a
+      static slice, and a static ``off``;
+    - causal, other tiles (explicit sizes): nothing is cut. The tile
+      is skipped, masked or whole by a predicate on the program ids.
+    """
+    if block_q % w:
+        w = block_q
+    all_rows, all_cols = slice(0, block_q), slice(0, block_k)
+
+    def strips(trimmed):
+        for lo in range(0, block_q, w):
+            cols = slice(0, lo + w) if trimmed else all_cols
+            yield slice(lo, lo + w), cols, lo if trimmed else None
+
+    whole = functools.partial(step, list(strips(False)))
+    if not causal:
+        whole()
+    elif block_q == block_k:
+        when(kj < qi)(whole)
+        when(kj == qi)(functools.partial(step, list(strips(True))))
+    else:
+        q_lo, k_lo = qi * block_q, kj * block_k
+        q_hi, k_hi = q_lo + block_q - 1, k_lo + block_k - 1
+        when(q_lo >= k_hi)(
+            functools.partial(step, [(all_rows, all_cols, None)])
+        )
+        when((q_hi >= k_lo) & (q_lo < k_hi))(
+            functools.partial(step, [(all_rows, all_cols, q_lo - k_lo)])
+        )
+
+
+def _each(strip):
+    """A step that treats its sub-blocks one by one."""
+
+    def step(strips):
+        for s in strips:
+            strip(*s)
+
+    return step
+
+
+def _scaled_q(q_ref, scale):
+    """The q tile with the softmax scale folded in: once an element of
+    q, not once a score."""
+    return q_ref[0].astype(jnp.float32) * scale
+
+
+def _scores(q, k, off):
+    """q k^T for one sub-block (q carries the softmax scale), masked to
+    NEG_INF above the diagonal where ``off`` says it passes."""
+    s = jax.lax.dot_general(
+        q,
+        k.astype(jnp.float32),
+        (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    if off is None:
+        return s
+    row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    return jnp.where(row + off >= col, s, NEG_INF)
+
+
+def _lane_max(x):
+    """(rows, cols) -> (rows, 128): the maximum over the groups of 128
+    columns, lane by lane. The elementwise half of a row maximum; the
+    cross-lane half is taken once a tile, over all rows."""
+    cols = x.shape[1]
+    if cols % _LANES:  # narrow sub-block (a short length, the tests')
+        return jnp.broadcast_to(
+            jnp.max(x, axis=1, keepdims=True), (x.shape[0], _LANES)
+        )
+    return functools.reduce(
+        jnp.maximum, [x[:, c:c + _LANES] for c in range(0, cols, _LANES)]
+    )
 
 
 def _fwd_kernel(
@@ -62,85 +190,76 @@ def _fwd_kernel(
     lse_ref,
     acc_ref,
     m_ref,
-    l_ref,
+    v1_ref,
     *,
     causal,
     scale,
+    w,
 ):
     qi = pl.program_id(1)
     kj = pl.program_id(2)
     nk = pl.num_programs(2)
+    d = q_ref.shape[2]
 
     @pl.when(kj == 0)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
 
-    block_q = q_ref.shape[1]
-    block_k = k_ref.shape[1]
+    q = _scaled_q(q_ref, scale)
+    # v beside columns of ones: p @ [v | 1] leaves the row sums of p in
+    # the lanes the head size leaves empty, so the matrix unit takes
+    # the normalizer's row reduction and acc_ref[:, d:] carries l
+    # through the same rescale as the accumulator
+    v1_ref[:, :d] = v_ref[0].astype(jnp.float32)
+    v1_ref[:, d:] = jnp.ones((v1_ref.shape[0], v1_ref.shape[1] - d))
 
-    def compute():
-        q = q_ref[0].astype(jnp.float32)
-        k_blk = k_ref[0].astype(jnp.float32)
-        v_blk = v_ref[0].astype(jnp.float32)
-        s = (
-            jax.lax.dot_general(q, k_blk, (((1,), (1,)), ((), ())))
-            * scale
-        )  # (block_q, block_k)
-        if causal:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0
+    def step(strips):
+        # ONE step of the online-softmax recurrence for the tile, its
+        # products and exponentials taken sub-block by sub-block.
+        # m_ref holds the running maximum in every lane; between the
+        # sub-blocks it collects the per-lane part of the new one, and
+        # the cross-lane reduction and the rescale run once a tile.
+        m_prev = m_ref[:, :1]
+        scores = []
+        for rows, cols, off in strips:
+            s = _scores(q[rows], k_ref[0, cols, :], off)
+            m_ref[rows, :] = jnp.maximum(m_ref[rows, :], _lane_max(s))
+            scores.append(s)
+        m_new = jnp.max(m_ref[:], axis=1, keepdims=True)
+        acc_ref[:] = acc_ref[:] * jnp.exp(m_prev - m_new)
+        for (rows, cols, _), s in zip(strips, scores):
+            acc_ref[rows, :] += jax.lax.dot(
+                jnp.exp(s - m_new[rows]),
+                v1_ref[cols, :],
+                preferred_element_type=jnp.float32,
             )
-            k_pos = kj * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1
-            )
-            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-        m_prev = m_ref[:, :1]  # (block_q, 1)
-        l_prev = l_ref[:, :1]
-        m_cur = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)  # (block_q, 1)
-        l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot(p, v_blk)
         m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
 
-    if causal:
-        # blocks entirely above the diagonal contribute nothing
-        @pl.when(kj * block_k <= qi * block_q + block_q - 1)
-        def _():
-            compute()
-
-    else:
-        compute()
+    _walk_tile(qi, kj, q_ref.shape[1], k_ref.shape[1], w, causal, step)
 
     @pl.when(kj == nk - 1)
     def _finish():
-        l_fin = l_ref[:, :1]
-        o_ref[0] = (acc_ref[:] / l_fin).astype(o_ref.dtype)
+        l_fin = acc_ref[:, d:d + 1]
+        o_ref[0] = (acc_ref[:, :d] / l_fin).astype(o_ref.dtype)
         lse_ref[:] = jnp.broadcast_to(
             m_ref[:, :1] + jnp.log(l_fin), lse_ref.shape
         )
 
 
-def _recompute_p(q_ref, k_ref, lse_ref, qi, kj, causal, scale):
-    """exp(qk^T * scale - lse) for one tile — shared by both bwd passes."""
-    block_q = q_ref.shape[1]
-    block_k = k_ref.shape[1]
-    q = q_ref[0].astype(jnp.float32)
-    k_blk = k_ref[0].astype(jnp.float32)
-    s = jax.lax.dot_general(q, k_blk, (((1,), (1,)), ((), ()))) * scale
-    if causal:
-        q_pos = qi * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0
-        )
-        k_pos = kj * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1
-        )
-        s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-    return jnp.exp(s - lse_ref[0, :, :1])
+def _p_and_ds(q, k_ref, v_ref, do, lse_ref, delta_ref, rows, cols, off):
+    """One sub-block of both backward passes: ``p = exp(q k^T - lse)``
+    recomputed, and ``ds = p * (dO V^T - delta)``."""
+    p = jnp.exp(
+        _scores(q[rows], k_ref[0, cols, :], off) - lse_ref[0, rows, :1]
+    )
+    dp = jax.lax.dot_general(
+        do[rows],
+        v_ref[0, cols, :].astype(jnp.float32),
+        (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    return p, p * (dp - delta_ref[0, rows, :1])
 
 
 def _bwd_dq_kernel(
@@ -155,6 +274,7 @@ def _bwd_dq_kernel(
     *,
     causal,
     scale,
+    w,
 ):
     qi = pl.program_id(1)
     kj = pl.program_id(2)
@@ -164,30 +284,27 @@ def _bwd_dq_kernel(
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    block_q = q_ref.shape[1]
-    block_k = k_ref.shape[1]
+    q = _scaled_q(q_ref, scale)
+    do = do_ref[0].astype(jnp.float32)
 
-    def compute():
-        p = _recompute_p(q_ref, k_ref, lse_ref, qi, kj, causal, scale)
-        do = do_ref[0].astype(jnp.float32)
-        v_blk = v_ref[0].astype(jnp.float32)
-        dp = jax.lax.dot_general(
-            do, v_blk, (((1,), (1,)), ((), ()))
-        )  # (block_q, block_k)
-        ds = p * (dp - delta_ref[0, :, :1])
-        dq_acc[:] += jax.lax.dot(ds, k_ref[0].astype(jnp.float32)) * scale
+    def strip(rows, cols, off):
+        _, ds = _p_and_ds(
+            q, k_ref, v_ref, do, lse_ref, delta_ref, rows, cols, off
+        )
+        dq_acc[rows, :] += jax.lax.dot(
+            ds,
+            k_ref[0, cols, :].astype(jnp.float32),
+            preferred_element_type=jnp.float32,
+        )
 
-    if causal:
-        @pl.when(kj * block_k <= qi * block_q + block_q - 1)
-        def _():
-            compute()
-
-    else:
-        compute()
+    _walk_tile(
+        qi, kj, q_ref.shape[1], k_ref.shape[1], w, causal, _each(strip)
+    )
 
     @pl.when(kj == nk - 1)
     def _finish():
-        dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
+        # ds k is the gradient of (q scale): the scale comes back once
+        dq_ref[0] = (dq_acc[:] * scale).astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(
@@ -204,6 +321,7 @@ def _bwd_dkv_kernel(
     *,
     causal,
     scale,
+    w,
 ):
     kj = pl.program_id(1)
     qi = pl.program_id(2)
@@ -214,34 +332,26 @@ def _bwd_dkv_kernel(
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    block_q = q_ref.shape[1]
-    block_k = k_ref.shape[1]
+    # the scaled q serves both products: s = (q scale) k^T, and
+    # dk = ds^T (q scale) needs no scale of its own
+    q = _scaled_q(q_ref, scale)
+    do = do_ref[0].astype(jnp.float32)
+    lhs_t = (((0,), (0,)), ((), ()))
 
-    def compute():
-        p = _recompute_p(q_ref, k_ref, lse_ref, qi, kj, causal, scale)
-        do = do_ref[0].astype(jnp.float32)
-        dv_acc[:] += jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ()))
-        )  # p^T dO: (block_k, d)
-        dp = jax.lax.dot_general(
-            do, v_ref[0].astype(jnp.float32), (((1,), (1,)), ((), ()))
+    def strip(rows, cols, off):
+        p, ds = _p_and_ds(
+            q, k_ref, v_ref, do, lse_ref, delta_ref, rows, cols, off
         )
-        ds = p * (dp - delta_ref[0, :, :1])
-        dk_acc[:] += (
-            jax.lax.dot_general(
-                ds, q_ref[0].astype(jnp.float32), (((0,), (0,)), ((), ()))
-            )
-            * scale
-        )  # ds^T Q: (block_k, d)
+        dv_acc[cols, :] += jax.lax.dot_general(
+            p, do[rows], lhs_t, preferred_element_type=jnp.float32
+        )  # p^T dO
+        dk_acc[cols, :] += jax.lax.dot_general(
+            ds, q[rows], lhs_t, preferred_element_type=jnp.float32
+        )  # ds^T (q scale)
 
-    if causal:
-        # q blocks entirely above the diagonal see this k block masked
-        @pl.when(qi * block_q + block_q - 1 >= kj * block_k)
-        def _():
-            compute()
-
-    else:
-        compute()
+    _walk_tile(
+        qi, kj, q_ref.shape[1], k_ref.shape[1], w, causal, _each(strip)
+    )
 
     @pl.when(qi == nq - 1)
     def _finish():
@@ -278,6 +388,55 @@ def divisible(lq, lk, block_q, block_k):
     return (bq % 8 == 0 or bq == lq) and (bk % 8 == 0 or bk == lk)
 
 
+def sub_block(d):
+    """Rows of the sub-blocks a tile body cuts its tile into: 256 at
+    both head sizes measured, so ``d`` does not enter yet.
+
+    TPU v5e, libtpu 0.0.34, jax 0.9.0, bf16, causal, tiles 1,024 x
+    1,024, fwd + dq + dkv in ms a call (chip runs, PR 29). d = 64 (every
+    cell's head size), 96 x 2,048 x 64, each kernel cut along its best
+    axis: the tile whole 1.18 + 1.62 + 2.13 = 4.93; w = 512: 1.05 +
+    1.42 + 1.87 = 4.35; **w = 256: 0.99 + 1.32 + 1.74 = 4.04**; w =
+    128: 0.95 + 1.31 + 1.93 = 4.20; as committed (all three along q,
+    w = 256) 1.00 + 1.32 + 1.75 = 4.07, against the whole-tile bodies'
+    1.43 + 1.56 + 2.17 = 5.16. d = 128, 32 x 2,048 x 128: w = 1,024
+    1.63, 512 1.40, **256 1.30** (whole-tile bodies 1.55). Narrower
+    sub-blocks skip more of the masked area (0.75, 0.625, 0.5625 of a
+    tile on the diagonal) but make more and smaller products, and dkv
+    passes over its (columns, d) accumulators once a sub-block: at 256
+    the two meet. A tile that 256 does not divide (the tests' 16-row
+    tiles, a short whole length) is left whole."""
+    del d
+    return 256
+
+
+def causal_work_ratio(lq, lk, block_q, block_k, w, causal=True):
+    """Score elements the kernels perform over score elements the mask
+    keeps: 1.0 means no score is computed only to be masked away.
+
+    Static, from shapes alone: it walks every tile with the kernels' own
+    :func:`_walk_tile` and adds up the sub-blocks' areas, so one number
+    serves all three kernels. L = 2,048 in 1,024-tiles:
+    1.5 with the tile whole, 1.125 at w = 256; L = 1,024: 2.0 and 1.25;
+    L = 4,096: 1.25 and 1.06."""
+    block_q, block_k = _block_sizes(lq, lk, block_q, block_k)
+    kept = sum(min(r + 1, lk) for r in range(lq)) if causal else lq * lk
+    done = []
+
+    def step(strips):
+        done.extend(
+            (rows.stop - rows.start) * (cols.stop - cols.start)
+            for rows, cols, _ in strips
+        )
+
+    for qi in range(lq // block_q):
+        for kj in range(lk // block_k):
+            _walk_tile(
+                qi, kj, block_q, block_k, w, causal, step, when=_run_if
+            )
+    return sum(done) / kept
+
+
 def _block_sizes(lq, lk, block_q, block_k):
     block_q = min(block_q, lq)
     block_k = min(block_k, lk)
@@ -289,15 +448,32 @@ def _block_sizes(lq, lk, block_q, block_k):
     return block_q, block_k
 
 
-def _flash_fwd(q, k, v, causal, block_q, block_k, interpret):
+# Both halves are jitted INLINE with everything but the arrays static:
+# jax then traces a kernel body once for each distinct call (shapes,
+# tile sizes, causal) and not once a layer, and hands every layer the
+# same kernel jaxpr, which lets the step's lowering build each Mosaic
+# module once and copy it in place: the lowered step still holds three
+# custom calls a layer. The sub-blocked bodies are four times the
+# equations of the whole-tile ones; traced and lowered per layer they
+# cost `lm125m-l2048` 9 s of set-up on the chip (PR 29).
+_STATIC = ("causal", "block_q", "block_k", "interpret", "w")
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC, inline=True)
+def _flash_fwd(q, k, v, causal, block_q, block_k, interpret, w=None):
     q, k, v = jnp.asarray(q), jnp.asarray(k), jnp.asarray(v)
     b, lq, h, d = q.shape
     lk = k.shape[1]
     block_q, block_k = _block_sizes(lq, lk, block_q, block_k)
+    w = w or sub_block(d)
     scale = d ** -0.5
     qf, kf, vf = _fold_heads(q), _fold_heads(k), _fold_heads(v)
 
-    kernel = functools.partial(_fwd_kernel, causal=causal, scale=scale)
+    kernel = functools.partial(
+        _fwd_kernel, causal=causal, scale=scale, w=w
+    )
+    # room for v and at least one column of ones, in whole lanes
+    d_ones = -(-(d + 1) // _LANES) * _LANES
     out, lse = pl.pallas_call(
         kernel,
         out_shape=[
@@ -318,9 +494,9 @@ def _flash_fwd(q, k, v, causal, block_q, block_k, interpret):
             ),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, d_ones), jnp.float32),
             pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
+            pltpu.VMEM((block_k, d_ones), jnp.float32),
         ],
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
@@ -332,12 +508,25 @@ def _flash_fwd(q, k, v, causal, block_q, block_k, interpret):
     )
 
 
+@functools.partial(jax.jit, static_argnames=_STATIC, inline=True)
 def _flash_bwd(
-    q, k, v, out, lse, g, causal, block_q, block_k, interpret, g_lse=None
+    q,
+    k,
+    v,
+    out,
+    lse,
+    g,
+    causal,
+    block_q,
+    block_k,
+    interpret,
+    g_lse=None,
+    w=None,
 ):
     b, lq, h, d = q.shape
     lk = k.shape[1]
     block_q, block_k = _block_sizes(lq, lk, block_q, block_k)
+    w = w or sub_block(d)
     scale = d ** -0.5
     qf, kf, vf = _fold_heads(q), _fold_heads(k), _fold_heads(v)
     dof = _fold_heads(g.astype(q.dtype))
@@ -363,7 +552,9 @@ def _flash_bwd(
         (1, block_q, _LANES), lambda i, qi, kj: (i, qi, 0)
     )
     dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, causal=causal, scale=scale),
+        functools.partial(
+            _bwd_dq_kernel, causal=causal, scale=scale, w=w
+        ),
         out_shape=jax.ShapeDtypeStruct(qf.shape, q.dtype),
         grid=(b * h, lq // block_q, lk // block_k),
         in_specs=[
@@ -387,7 +578,9 @@ def _flash_bwd(
         (1, block_q, _LANES), lambda i, kj, qi: (i, qi, 0)
     )
     dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, causal=causal, scale=scale),
+        functools.partial(
+            _bwd_dkv_kernel, causal=causal, scale=scale, w=w
+        ),
         out_shape=[
             jax.ShapeDtypeStruct(kf.shape, k.dtype),
             jax.ShapeDtypeStruct(vf.shape, v.dtype),
@@ -492,6 +685,15 @@ def auto_blocks(lq, lk, block_q=None, block_k=None):
     because it cannot be built: 1024x2048 compiles on v5e / libtpu
     0.0.34 (chip run, PR 21). Explicit sizes always win; None picks the
     largest measured-good divisor of the sequence length.
+
+    Re-swept with the sub-blocked bodies (v5e, libtpu 0.0.34, jax 0.9.0,
+    bf16, causal, 96 x 2,048 x 64, fwd + dq + dkv a call; chip runs,
+    PR 29): 1,024 x 1,024 tiles 4.07 ms (w = 256), 512 x 512 tiles 6.79
+    ms (w = 256) and 7.12 (w = 128), 2,048 x 2,048 tiles refused (dq
+    out of VMEM). The sub-blocks did not move the optimum: 1,024 stays.
+    Tiles that are not square (explicit sizes) are never cut: 512 x
+    1,024 runs 5.47 ms (the whole-tile bodies 5.56), 1,024 x 512 5.72
+    (6.63).
     """
     if block_q is None:
         block_q = next(

@@ -153,3 +153,162 @@ def test_flash_lse_cotangent_propagates():
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), rtol=3e-4, atol=3e-4
         )
+
+
+# ---------------------------------------------------------------------------
+# sub-blocks inside the tile (PR 29): the bodies cut a tile into sub-blocks of
+# width w and skip, trim or mask each by where the diagonal passes. The public
+# functions derive w from the head size; the private ones take it, so that a
+# test can force several sub-blocks into a toy tile.
+# ---------------------------------------------------------------------------
+
+# lq, lk, block_q, block_k, w
+GEOMETRIES = [
+    pytest.param(64, 64, 32, 32, 8, id="four-sub-blocks-a-tile"),
+    pytest.param(64, 64, 64, 64, 16, id="one-tile-a-head"),
+    pytest.param(64, 64, 32, 16, 8, id="block_q-over-block_k"),
+    pytest.param(64, 64, 16, 32, 8, id="block_k-over-block_q"),
+    pytest.param(64, 64, 32, 32, 12, id="width-dividing-nothing"),
+    pytest.param(256, 256, 256, 256, 128, id="sub-blocks-of-whole-lanes"),
+]
+# both ways, and one call whose lengths differ (a ring block's shape; the
+# models' causal calls have lq == lk)
+CASES = [
+    pytest.param(*g.values, causal, id="%s-%s" % (g.id, name))
+    for g in GEOMETRIES
+    for causal, name in ((False, "full"), (True, "causal"))
+] + [pytest.param(64, 32, 32, 16, 8, False, id="lq-over-lk-full")]
+
+
+def _dense(q, k, v, causal):
+    """(out, lse) the long way, in f32."""
+    import jax.numpy as jnp
+
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * q.shape[-1] ** -0.5
+    if causal:
+        keep = jnp.arange(q.shape[1])[:, None] >= jnp.arange(k.shape[1])
+        s = jnp.where(keep, s, -jnp.inf)
+    lse = jax.scipy.special.logsumexp(s, axis=-1)
+    out = jnp.einsum("bhqk,bkhd->bqhd", jnp.exp(s - lse[..., None]), v)
+    return out, lse
+
+
+def _geometry_inputs(lq, lk):
+    rng = np.random.default_rng(lq + lk)
+    q, k, v = (
+        rng.standard_normal((2, l, 2, 16)).astype(np.float32)
+        for l in (lq, lk, lk)
+    )
+    return q, k, v
+
+
+@pytest.mark.parametrize("lq, lk, block_q, block_k, w, causal", CASES)
+def test_sub_blocked_forward_matches_dense(
+    lq, lk, block_q, block_k, w, causal
+):
+    from elasticdl_tpu.ops.flash_attention import _flash_fwd
+
+    q, k, v = _geometry_inputs(lq, lk)
+    out, lse = _flash_fwd(q, k, v, causal, block_q, block_k, True, w=w)
+    want_out, want_lse = _dense(q, k, v, causal)
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(want_out), rtol=2e-4, atol=2e-5
+    )
+    np.testing.assert_allclose(
+        np.asarray(lse), np.asarray(want_lse), rtol=2e-4, atol=2e-5
+    )
+
+
+@pytest.mark.parametrize("lq, lk, block_q, block_k, w, causal", CASES)
+def test_sub_blocked_gradients_match_dense(
+    lq, lk, block_q, block_k, w, causal
+):
+    """dq, dk, dv under a cotangent on out AND on lse."""
+    from elasticdl_tpu.ops.flash_attention import _flash_bwd, _flash_fwd
+
+    q, k, v = _geometry_inputs(lq, lk)
+    rng = np.random.default_rng(7)
+    g = rng.standard_normal(q.shape).astype(np.float32)
+    g_lse = rng.standard_normal((2, 2, lq)).astype(np.float32)
+
+    def loss(q, k, v):
+        out, lse = _dense(q, k, v, causal)
+        return (out * g).sum() + (lse * g_lse).sum()
+
+    want = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+    out, lse = _flash_fwd(q, k, v, causal, block_q, block_k, True, w=w)
+    got = _flash_bwd(
+        q, k, v, out, lse, g, causal, block_q, block_k, True,
+        g_lse=g_lse, w=w,
+    )
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=3e-4, atol=3e-4
+        )
+
+
+@pytest.mark.parametrize(
+    "length, w, performed_tiles, kept",
+    [
+        # L = 2,048 in 1,024-tiles: two tiles on the diagonal, one below
+        (2048, 1024, 3.0, 2048 * 2049 // 2),
+        (2048, 512, 2 * 0.75 + 1, 2048 * 2049 // 2),
+        (2048, 256, 2 * 0.625 + 1, 2048 * 2049 // 2),
+        (2048, 128, 2 * 0.5625 + 1, 2048 * 2049 // 2),
+        # L = 1,024: one tile a head, all of it on the diagonal
+        (1024, 1024, 1.0, 1024 * 1025 // 2),
+        (1024, 256, 0.625, 1024 * 1025 // 2),
+        # L = 4,096: four on the diagonal, six below
+        (4096, 256, 4 * 0.625 + 6, 4096 * 4097 // 2),
+    ],
+)
+def test_causal_work_ratio_against_hand_counts(
+    length, w, performed_tiles, kept
+):
+    from elasticdl_tpu.ops.flash_attention import causal_work_ratio
+
+    got = causal_work_ratio(length, length, 1024, 1024, w)
+    assert got == pytest.approx(performed_tiles * 1024 * 1024 / kept)
+    # against L^2 / 2, which is what the benchmark's flops count
+    assert got == pytest.approx(
+        performed_tiles * 1024 * 1024 / (length * length / 2), rel=1e-3
+    )
+
+
+def test_causal_work_ratio_where_nothing_is_trimmed():
+    from elasticdl_tpu.ops.flash_attention import causal_work_ratio
+
+    # nothing masked, nothing skipped
+    assert causal_work_ratio(2048, 2048, 1024, 1024, 256, causal=False) == 1.0
+    assert causal_work_ratio(64, 32, 32, 16, 8, causal=False) == 1.0
+    # tiles that are not square are skipped or kept whole: of the eight
+    # 32 x 16 tiles of a 64 x 64 call two lie above the diagonal
+    assert causal_work_ratio(64, 64, 32, 16, 8) == pytest.approx(
+        6 * 32 * 16 / (64 * 65 / 2)
+    )
+
+
+def test_the_width_is_derived_and_no_caller_can_set_it():
+    import inspect
+
+    from elasticdl_tpu.ops import flash_attention as fa
+
+    assert fa.sub_block(64) == fa.sub_block(128) == 256  # both swept
+    for fn in (fa.flash_attention, fa.flash_attention_with_lse):
+        assert list(inspect.signature(fn).parameters) == [
+            "q", "k", "v", "causal", "block_q", "block_k",
+        ]
+
+
+def test_three_kernels_a_layer_under_their_names():
+    """The benchmark counts the Mosaic calls of a built step and reads the
+    kernels by name: sub-blocking must not split or rename them."""
+    q, k, v = _qkv(l=32)
+
+    def loss(q, k, v):
+        return (flash_attention(q, k, v, True, 16, 16) ** 2).sum()
+
+    text = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v))
+    assert text.count("pallas_call") == 3
+    for name in ("edl_flash_fwd", "edl_flash_bwd_dq", "edl_flash_bwd_dkv"):
+        assert text.count("name=%s" % name) == 1, name

@@ -206,3 +206,43 @@ def test_kernel_compiles_for_the_v5e_at_the_benchmarks_widths(
         gmm.GMM_KERNEL, k, kernel
     )
     assert name in text
+
+
+# The flash kernels' real-size compile lives here and not beside their
+# parity tests: one process may describe the chip, so every such test
+# shares this file's fixture (and with it one xdist worker).
+@pytest.mark.parametrize(
+    "batch, heads, length, causal",
+    [
+        pytest.param(8, 12, 2048, True, id="lm125m-l2048"),
+        pytest.param(2, 16, 2048, True, id="lm350m-l2048"),
+        pytest.param(4, 32, 2048, True, id="lfm2moe-ep8-l2048"),
+        pytest.param(16, 12, 1024, True, id="one-tile-a-head"),
+        pytest.param(8, 12, 2048, False, id="not-causal"),
+    ],
+)
+def test_flash_kernels_compile_for_the_v5e_at_the_benchmarks_shapes(
+    one_chip, batch, heads, length, causal
+):
+    """Sub-blocks of 256 inside 1,024-tiles at head size 64: the static
+    slices, the widened accumulator and the scoped VMEM are Mosaic's to
+    refuse, and interpret mode refuses none of them."""
+    from elasticdl_tpu.ops import flash_attention as fa
+
+    x = jax.ShapeDtypeStruct(
+        (batch, length, heads, 64), jnp.bfloat16, sharding=one_chip
+    )
+    tiles = fa.auto_blocks(length, length)
+
+    def fwd_and_bwd(q, k, v, g):
+        out, lse = fa._flash_fwd(q, k, v, causal, *tiles, False)
+        return out, fa._flash_bwd(q, k, v, out, lse, g, causal, *tiles, False)
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        text = jax.jit(fwd_and_bwd).lower(x, x, x, x).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+    assert text.count("tpu_custom_call") >= 3
+    for name in (fa.FWD_KERNEL, fa.BWD_DQ_KERNEL, fa.BWD_DKV_KERNEL):
+        assert name in text
