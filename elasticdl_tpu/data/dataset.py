@@ -38,7 +38,7 @@ def _tree_stack(elements):
     Legacy per-element recursive assembly. Kept as the fallback for leaf
     types the preallocated fast path cannot host (bytes/str/object
     leaves, where a common dtype must be computed across the whole
-    batch) and as the reference arm for equivalence tests/benches.
+    batch) and as the reference arm for equivalence tests.
     """
     first = elements[0]
     if isinstance(first, dict):
@@ -261,7 +261,8 @@ class Dataset:
         ``vectorized`` (default) assembles each batch into preallocated
         per-leaf buffers filled in place — one pass, no per-element
         ``np.stack`` recursion; False keeps the legacy ``_tree_stack``
-        path (the equivalence/bench reference arm). Both produce
+        path (the reference arm of tests/test_input_pipeline.py's
+        equivalence cases). Both produce
         identical arrays for numeric pytrees; bytes/str/object leaves
         take the legacy path either way.
         """

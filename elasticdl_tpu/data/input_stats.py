@@ -5,8 +5,8 @@ data service charges task-starvation/read time, ``Dataset.map`` charges
 parse time, ``Dataset.batch`` charges batch-assembly time, and
 ``Dataset.prefetch`` charges the time its consumer spent waiting on an
 empty buffer. The worker logs a snapshot at every task-stream boundary
-(docs/input_pipeline.md has the counter glossary), and ``bench.py
---input`` reports the same counters for its serial vs pipelined arms.
+(docs/input_pipeline.md has the counter glossary);
+tests/test_input_pipeline.py reads the same counters.
 
 Time counters are wall seconds as seen by the charging stage; with
 parallel decode the parse counter aggregates across pool threads, so it

@@ -50,7 +50,7 @@ class LocalInstanceManager:
         self._ps_command = ps_command
         # external-supervisor form (docs/master_recovery.md): when this
         # manager runs OUTSIDE the master (the chaos harness / fleet
-        # tests / bench drive it from a driver process), it also owns
+        # tests drive it from a driver process), it also owns
         # the master process — SIGKILL relaunches on the crash budget,
         # the rc-75 drain-journal-and-exit path relaunches budget-FREE
         # (PS-plane parity). ``master_command() -> argv``.

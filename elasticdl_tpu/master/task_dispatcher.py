@@ -98,7 +98,7 @@ class TaskDispatcher:
         # only, the journal's writer thread owns all IO, so holding
         # the ledger lock across an append never blocks (edlint R5)
         self._journal = journal
-        # deterministic task order for chaos/bench replays: the
+        # deterministic task order for chaos replays: the
         # dispatcher's shuffle is the one entropy source a multi-run
         # divergence gate cannot pin from outside the process
         seed = os.environ.get("EDL_TASK_SHUFFLE_SEED")

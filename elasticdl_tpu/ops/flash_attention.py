@@ -748,11 +748,11 @@ def attention_in_step(step_facts):
 def pick_causal_attention(seq_len, use_flash=True, min_flash_len=1024):
     """Causal attention fn for a model at this sequence length.
 
-    One home for the measured policy (bench.py --flash on v5e): the
-    fused kernel wins from L=1024 up (1.3-2.2x fwd+bwd) but loses to
-    XLA's unfused path at short L, and needs 128-divisible lengths to
-    tile. Both the plain and pipelined transformer builds call this so
-    the threshold lives in exactly one place."""
+    One home for the policy: 1,024 is the length from which the cells
+    use the kernels; ``lm125m-l512`` is the cell on the other side; the
+    crossover between them is not measured on the chip (ROADMAP S10).
+    The kernels need 128-divisible lengths to tile. Both transformer
+    builds call this so the threshold lives in exactly one place."""
     if (
         use_flash
         and seq_len >= min_flash_len

@@ -7,7 +7,7 @@ dominated by XLA compile time rather than by state movement — the
 elastic-native cost ElasWave (arxiv 2510.00606) attacks with plan reuse
 and the pjit scaling paper (arxiv 2204.06514) amortizes with
 ahead-of-time lowering. This module is that amortization layer, shared
-by the elastic trainer, the bench harness, and the tests:
+by the elastic trainer and the tests:
 
 - :class:`ExecutableCache` — jitted step callables (plus their AOT
   ``Compiled`` executables) keyed by (backend epoch, mesh signature,
@@ -33,7 +33,7 @@ by the elastic trainer, the bench harness, and the tests:
   cache does.
 
 Scope note: in-memory reuse pays off whenever the backend survives the
-resize (single-process elastic planes, the CPU test/bench meshes built
+resize (single-process elastic planes, the CPU test meshes built
 over device subsets). A real multi-host re-form tears the backend down
 (parallel/distributed.py), where the speculative compiles still warm the
 persistent disk cache. docs/compile_plane.md has the full policy.
@@ -137,7 +137,7 @@ def enable_persistent_cache(probe_backend=False):
 class CompileStats:
     """Per-owner compile-plane counters (a private
     :class:`profiling.Counters`), mirrored into the process-wide
-    profiling registry so traces and bench lines see the same numbers
+    profiling registry so traces and tests see the same numbers
     without sharing the per-trainer tallies."""
 
     def __init__(self, prefix="compile_plane"):
@@ -310,7 +310,7 @@ class SpeculativeCompiler:
 
     ``compile_fn(size)`` does the whole job for one hinted size (build
     mesh + step fn + AOT compile + cache insert) and is provided by the
-    owner (the elastic trainer / the bench harness); it runs on a
+    owner (the elastic trainer); it runs on a
     DAEMON thread, one size at a time, strictly outside this class's
     lock. ``hint(sizes)`` is non-blocking and deduplicates against both
     the pending queue and everything already attempted this generation.
@@ -411,7 +411,7 @@ class SpeculativeCompiler:
             return len(self._pending)
 
     def idle(self):
-        """True when nothing is pending or in flight (test/bench sync)."""
+        """True when nothing is pending or in flight (test sync)."""
         with self._lock:
             busy = bool(self._pending) or self._wake.is_set()
         return not busy

@@ -1168,7 +1168,7 @@ class ElasticDPTrainer:
         self.compile_stats = self._exec_cache.stats
         self._step_entry = None  # cache entry backing _step_fn (or None)
         # speculative AOT compiles for likely next world sizes; the
-        # worker (or bench) opts in and feeds membership hints
+        # worker opts in and feeds membership hints
         self.speculative_compile = False
         self._spec_compiler = None
         self._spec_example = None  # host example batch (abstract args)

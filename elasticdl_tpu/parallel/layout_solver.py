@@ -125,8 +125,8 @@ class ScoredLayout:
 
 def memory_budget_from_env(env=os.environ):
     """Per-device budget in bytes from ``EDL_LAYOUT_MEM_BUDGET_MB``
-    (same MiB convention as the bench's EDL_BENCH_DEVICE_BUDGET_MB);
-    None when unset/unparseable — every layout memory-feasible."""
+    (MiB); None when unset/unparseable — every layout
+    memory-feasible."""
     raw = env.get("EDL_LAYOUT_MEM_BUDGET_MB", "")
     try:
         mb = float(raw)

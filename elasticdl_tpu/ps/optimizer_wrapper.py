@@ -45,10 +45,11 @@ ulp on adam, verified on the CPU backend — and no
 ``xla_allow_excess_precision`` / fast-math flag disables it). With one
 executable on both planes, host-vs-device divergence can only come
 from storage handling, which is exactly what the parity suite
-(tests/test_ps_device_parity.py) is meant to catch. The speedup the
-device plane is benched on (bench.py --ps) is the honest part that
-remains: deleted H2D/D2H boundary crossings, zero-copy gradient
-ingest, donation, and no per-row Python dict walks.
+(tests/test_ps_device_parity.py) is meant to catch. What separates
+the planes is then the storage boundary alone: deleted H2D/D2H
+boundary crossings, zero-copy gradient ingest, donation, and no
+per-row Python dict walks (what that is worth is not measured on the
+chip: the PS plane has no cell).
 
 Sparse jit shapes are padded to the next power of two (padded lanes
 carry zero gradients against zero rows and are dropped at writeback),

@@ -129,7 +129,7 @@ class ParameterServer:
         methods = self.servicer.rpc_methods()
         delay_ms = getattr(self._args, "rpc_inject_delay_ms", 0.0) or 0.0
         if delay_ms > 0:
-            # bench/test fault injection (--rpc_inject_delay_ms):
+            # test fault injection (--rpc_inject_delay_ms):
             # emulate cross-pod RTT on a loopback fleet by sleeping in
             # every handler before serving it
             def delayed(fn, delay_s=delay_ms / 1e3):

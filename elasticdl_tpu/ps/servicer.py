@@ -344,8 +344,9 @@ class PserverServicer:
         state rolled back (version), and whether it needs the model
         re-pushed (initialized False — relaunch with no snapshot).
         A tiered shard (docs/tiered_store.md) additionally reports its
-        aggregated tier counters under ``tiered`` — the bench's
-        disk-tier-exercised gate reads them here."""
+        aggregated tier counters under ``tiered`` — how a driver
+        sees that the disk tier was exercised
+        (tests/test_tiered_store.py reads them here)."""
         resp = {
             "version": self._parameters.version,
             "initialized": bool(self._parameters.initialized),

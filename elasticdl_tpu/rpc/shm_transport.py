@@ -155,7 +155,7 @@ class ShmRing:
         if unlink:
             # balance the tracker ledger BEFORE unlink: the attach-time
             # detach (and same-process create+attach topologies —
-            # tests, the loopback bench — where the set-backed ledger
+            # the tests — where the set-backed ledger
             # collapses the two registrations into one) can leave this
             # name untracked, and unlink()'s built-in unregister would
             # then crash the tracker's exit sweep. register is a

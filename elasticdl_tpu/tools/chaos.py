@@ -17,8 +17,6 @@ tests drive the PS data plane (tests/fake_ps.py):
   :class:`~elasticdl_tpu.master.local_instance_manager.
   LocalInstanceManager` — or any object with ``kill_ps``/
   ``terminate_ps`` — logging every executed op for post-run asserts.
-  ``bench.py --chaos`` uses the same schedule format with its own
-  process management.
 
 :func:`seeded_schedule` derives a reproducible schedule from a seed so
 a failing chaos run is a (seed, schedule) pair anyone can replay.
@@ -225,7 +223,7 @@ class FleetChaos:
     """Executes a fleet-level schedule against live processes.
 
     ``manager``: anything with ``kill_ps(id)`` / ``terminate_ps(id)``
-    (the LocalInstanceManager, or bench.py's own process table via a
+    (the LocalInstanceManager, or a driver's own process table via a
     small adapter) — plus ``kill_master()`` / ``terminate_master()``
     when the schedule carries master ops. ``status_fn(shard) -> dict``
     reads a shard's ``ps_status`` (version + epoch);

@@ -170,10 +170,9 @@ def iter_source_files(root):
     """Scanned scope: the package tree, the model zoo, scripts, and the
     top-level entry points. Tests are deliberately out of scope — they
     hold known-bad fixtures for these very rules."""
-    for name in ("__graft_entry__.py", "bench.py"):
-        path = os.path.join(root, name)
-        if os.path.exists(path):
-            yield path
+    path = os.path.join(root, "__graft_entry__.py")
+    if os.path.exists(path):
+        yield path
     for pkg in ("elasticdl_tpu", "model_zoo", "scripts"):
         top = os.path.join(root, pkg)
         for dirpath, dirnames, names in os.walk(top):

@@ -35,12 +35,6 @@ ALLOW = {
             "interpreter pinned to the virtual CPU platform, where "
             "there is no device transport to wedge",
         },
-        "bench.py": {
-            "max": 3,
-            "reason": "bench device sections run in subprocesses "
-            "under hard section timeouts; a wedge times the section "
-            "out instead of hanging the driver",
-        },
     },
     "R2": {
         "elasticdl_tpu/common/async_checkpoint.py": {

@@ -13,9 +13,9 @@ the named phases — ``step/pull_model``, ``step/compute`` (which nests
 The breakdown sums direct-child durations per step:
 
 - **attribution** (a.k.a. coverage): child-time / step-time — how much
-  of the step's wall clock the named phases explain. The bench gate
-  requires >= 90% on a live job; low attribution means an
-  uninstrumented phase is eating the step.
+  of the step's wall clock the named phases explain.
+  tests/test_tracing.py holds a live job to >= 90%; low attribution
+  means an uninstrumented phase is eating the step.
 - **phase shares**: each phase's share of total step time across the
   capture — the marginal-cost signal the ROADMAP-3 autoscaling policy
   needs (a fleet whose steps are dominated by ``task/wait`` gains
@@ -105,7 +105,8 @@ def critical_path(doc):
     Returns ``{"steps", "total_step_s", "attribution", "phases":
     {name: {"total_s", "share", "count"}}, "slowest": [...],
     "p99_s"}`` — ``attribution`` is the fraction of total step wall
-    time explained by direct-child spans (the bench's >=90% gate), and
+    time explained by direct-child spans (held to >= 90% on a live
+    job by tests/test_tracing.py), and
     ``slowest`` lists the steps at/above the p99 duration with each
     one's dominant phase flagged.
     """

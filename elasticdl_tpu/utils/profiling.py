@@ -1,7 +1,7 @@
 """Profiling / tracing hooks (SURVEY.md §5.1 first-class improvement).
 
 The reference has no profiler at all; here the standard JAX/XLA tools are
-wired behind one small surface so any worker, bench, or test can turn
+wired behind one small surface so any worker or test can turn
 them on without plumbing:
 
 - :func:`trace` — context manager around ``jax.profiler`` writing a
@@ -17,8 +17,8 @@ them on without plumbing:
   ``TraceAnnotation`` ``edl/step/<phase>`` on the profiler's clock.
 - :data:`counters` — a process-wide named-counter registry
   (:class:`Counters`); the compile plane threads its cache hit/miss and
-  compile-time numbers through it so workers, bench sections, and tests
-  all read one surface.
+  compile-time numbers through it so workers and tests all read one
+  surface.
 - :data:`metrics` — the process-wide :class:`MetricsRegistry`: labeled
   counters, gauges, and fixed-bucket histograms, exportable in
   Prometheus text format (docs/observability.md). The RPC layer and
@@ -49,8 +49,8 @@ them on without plumbing:
 
 Env toggles (read by workers at startup): ``EDL_PROFILE_DIR`` enables
 tracing into that directory; ``EDL_METRICS=0`` turns the telemetry
-instrumentation into no-ops (the bench's overhead A/B arm — spans,
-phases, events, and the flight recorder all honor it);
+instrumentation into no-ops (spans, phases, events, and the flight
+recorder all honor it);
 ``EDL_FLIGHT_RECORDER_DIR`` arms the flight recorder in any process
 (:func:`maybe_arm_flight_recorder`).
 """
@@ -167,9 +167,9 @@ _metrics_on = os.environ.get("EDL_METRICS", "1") != "0"
 
 
 def metrics_enabled():
-    """False disables every telemetry write (EDL_METRICS=0; the bench's
-    instrumented-off A/B arm). Metric objects still exist — their
-    record methods just return immediately."""
+    """False disables every telemetry write (EDL_METRICS=0). Metric
+    objects still exist — their record methods just return
+    immediately."""
     return _metrics_on
 
 

@@ -836,7 +836,7 @@ class BoundPS:
     shared-memory payload path at first call (``transport_hello``) and
     silently keeps the bytes path cross-host or on any attach/setup
     failure; ``"off"`` (default — the conservative choice for direct
-    constructions in tests/benches) never negotiates. Slot geometry
+    constructions in tests) never negotiates. Slot geometry
     rides ``shm_slots`` x ``shm_slot_mb``.
     """
 

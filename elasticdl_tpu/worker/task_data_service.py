@@ -246,7 +246,7 @@ class TaskDataService:
         self._parked_export_task = None
         self._clear_ledger()
         if data_reader is not None:
-            # injected reader (tests/bench fault injection)
+            # injected reader (the tests' fault injection)
             self.data_reader = data_reader
         else:
             reader_kwargs = dict(data_reader_params or {})

@@ -4,8 +4,7 @@ Parity: reference model_zoo/imagenet_resnet50/imagenet_resnet50.py (Keras
 builtin ResNet50 over JPEG-encoded records). Here the shared flax ResNet-50
 (resnet50_subclass/resnet50_model.py) is instantiated with 1000 classes and
 bfloat16 compute — the MXU-native dtype — while parameters stay float32.
-This is the model bench.py's ResNet section times
-(examples/sec/chip).
+No benchmark cell trains it: its rate is not measured on the chip.
 """
 
 import jax.numpy as jnp

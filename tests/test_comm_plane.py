@@ -217,7 +217,7 @@ def test_plane_parity_ps_hbm_hybrid():
     dense twin with tables seeded from the same store rows.
 
     PS vs hybrid is BITWISE (same bucket-gather graph for the
-    PS-resident table — the bench pre-pass gates on exactly this);
+    PS-resident table: the hybrid plane moves bytes, not numerics);
     the HBM-only twin's LOOKUPS are bitwise too, while its logits and
     gradients agree to float tolerance only — its full-table take
     changes downstream XLA fusion, which reassociates the final

@@ -3,7 +3,7 @@ trainer's establish/step integration).
 
 Everything runs single-process on the virtual 8-device CPU mesh,
 driving the SAME trainer surfaces the elastic worker uses — the mesh is
-swapped in-process (the bench_compile recipe) so the backend survives
+swapped in-process (``_establish_at`` below) so the backend survives
 resizes and the in-memory executable reuse is observable. The
 trace-counting tests use a loss_fn that bumps a Python counter: the
 counter only advances while jax is TRACING, so "no retrace" is asserted
@@ -69,8 +69,10 @@ def _make_trainer(loss_fn=None, minibatch=BATCH):
 
 
 def _establish_at(trainer, k):
-    """In-process resize: the establish phases minus the world RPC —
-    exactly what bench.py --compile times."""
+    """In-process resize: the establish phases minus the world RPC
+    (re-form the mesh over the first k devices, re-broadcast the state,
+    acquire the step fn), so a journey can revisit sizes on one live
+    backend."""
     if trainer._ts is not None:
         trainer._host_ts = trainer.snapshot()
     trainer._mesh = Mesh(np.asarray(jax.devices()[:k]), ("data",))
@@ -143,27 +145,57 @@ def test_batch_shape_change_misses_instead_of_stale_reuse():
     t.close()
 
 
-def test_cached_executable_matches_fresh_build_bitwise():
+def _resize_journey(cache_enabled, speculative=False):
+    """8 -> 4 -> 8 with three steps at each size; returns the final
+    host state and the trainer's compile counters."""
+    sizes = (8, 4, 8)
     batches = [_batch(seed) for seed in (10, 11, 12)]
+    t = _make_trainer()
+    t.compile_cache_enabled = cache_enabled
+    t.speculative_compile = speculative
+    for i, k in enumerate(sizes):
+        _establish_at(t, k)
+        for features, labels in batches:
+            t.train_step(features, labels, BATCH, sync=True)
+        if speculative and i + 1 < len(sizes):
+            # the membership service's role in a live job: hint the
+            # next size in steady state, and let the build land before
+            # the resize
+            if t._spec_compiler is None:
+                t._start_speculative_compiler()
+            t.hint_world_sizes([sizes[i + 1]])
+            assert _wait(t._spec_compiler.idle, timeout=120.0)
+    host = t.snapshot()
+    stats = t.compile_stats.snapshot()
+    t.close()
+    return host, stats
 
-    def journey(cache_enabled):
-        t = _make_trainer()
-        t.compile_cache_enabled = cache_enabled
-        for k in (8, 4, 8):
-            _establish_at(t, k)
-            for features, labels in batches:
-                t.train_step(features, labels, BATCH, sync=True)
-        host = t.snapshot()
-        t.close()
-        return host
 
-    cold = journey(cache_enabled=False)
-    cached = journey(cache_enabled=True)
-    for a, b in zip(
-        jax.tree_util.tree_leaves(cold.params),
-        jax.tree_util.tree_leaves(cached.params),
-    ):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+@pytest.fixture(scope="module")
+def cold_journey_params():
+    host, _ = _resize_journey(cache_enabled=False)
+    return [np.asarray(x) for x in jax.tree_util.tree_leaves(host.params)]
+
+
+@pytest.mark.parametrize("speculative", [False, True])
+def test_cached_executable_matches_fresh_build_bitwise(
+    cold_journey_params, speculative
+):
+    """The resize journey ends in bit-identical parameters whether every
+    establish compiles afresh, reuses the cached executable, or finds
+    one the speculative compiler built ahead of the resize: a reused or
+    AOT-built executable that changed the math would be a correctness
+    bug, not a faster resize."""
+    cached, stats = _resize_journey(
+        cache_enabled=True, speculative=speculative
+    )
+    if speculative:
+        # the first visit to 4 ran the executable built off the loop
+        assert stats["speculative_hits"] >= 1, stats
+    got = jax.tree_util.tree_leaves(cached.params)
+    assert len(got) == len(cold_journey_params)
+    for a, b in zip(cold_journey_params, got):
+        np.testing.assert_array_equal(a, np.asarray(b))
 
 
 def test_cache_evicts_entries_from_dead_backends():

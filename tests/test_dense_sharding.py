@@ -16,6 +16,8 @@ Three contracts pinned here:
   bytes are produced, never which bytes.
 """
 
+import time
+
 import numpy as np
 import optax
 import pytest
@@ -337,6 +339,79 @@ def test_trainer_resize_journey_relayout(singleton_world):
         assert np.isfinite(loss_81) and np.isfinite(loss_24)
     finally:
         tsh.close()
+
+
+def test_budget_forced_resolve_carries_state_through_establish(
+    singleton_world,
+):
+    """A :class:`LayoutPlanner` in the trainer's establish path: the
+    first establish derives the model profile and lays the 8 devices
+    out dp-widest; then a per-device memory budget lands that rules
+    dp-only out, and the next establish re-solves to a tp >= 2 layout
+    and moves parameters AND optimizer slots there bitwise. With
+    speculation on, the planner's layout hints had the post-budget
+    winner's executable built before the resize asked for it."""
+    from elasticdl_tpu.parallel import layout_solver
+    from elasticdl_tpu.parallel.layout_solver import LayoutPlanner
+
+    rows = 128  # the global batch stays; the layout changes under it
+    batches = _batches(5, batch=rows)
+    model = tzoo.custom_model(**KW)
+
+    def builder(mesh):
+        # one module for every mesh: the executable cache keys on it
+        return model, tzoo.param_shardings(mesh, tensor_parallel=2)
+
+    planner = LayoutPlanner(memory_budget=None)
+    t = ElasticDPTrainer(
+        model,
+        tzoo.loss,
+        optax.adam(1e-3),
+        distributed_builder=builder,
+        layout_planner=planner,
+    )
+    t.speculative_compile = True
+    spec_of = lambda epoch: WorldSpec(
+        coordinator="", num_processes=1, process_id=0, epoch=epoch
+    )
+    try:
+        t.establish(spec_of(0), example_batch=batches[0])
+        assert planner.profile is not None, "no profile was derived"
+        pre = planner.last_plan.layout
+        assert dict(t.mesh.shape) == layout_solver.mesh_axes_for(pre)
+        for features, labels in batches[:3]:
+            t.train_step(features, labels, rows, sync=True)
+
+        # the budget: replicated state + half the tp-shardable state +
+        # the smallest micro-batch's activations, so no dp-only layout
+        # fits at any micro-batch and tp = 2 just fits at the smallest
+        prof = planner.profile
+        planner.memory_budget = (
+            prof.replicated_bytes
+            + prof.tp_bytes / 2.0
+            + prof.activation_bytes_per_row * min(planner.microbatches)
+        )
+        post = layout_solver.best(
+            8, prof, planner.memory_budget, planner.microbatches
+        ).layout
+        assert post.tp >= 2 and (post.dp, post.tp) != (pre.dp, pre.tp)
+
+        t.hint_world_sizes([8])
+        deadline = time.monotonic() + 120.0
+        while not t._spec_compiler.idle():
+            assert time.monotonic() < deadline, "speculation never landed"
+            time.sleep(0.05)
+        hits = t.compile_stats.get("speculative_hits")
+
+        before = _gather(t._ts)
+        t.establish(spec_of(1), example_batch=batches[3])
+        assert dict(t.mesh.shape) == layout_solver.mesh_axes_for(post)
+        _assert_trees_close(before, _gather(t._ts))
+        assert t.compile_stats.get("speculative_hits") == hits + 1
+        loss, _, count = t.train_step(*batches[4], rows, sync=True)
+        assert np.isfinite(loss) and count == rows
+    finally:
+        t.close()
 
 
 def test_direct_relayout_matches_checkpoint_interchange(

@@ -571,10 +571,9 @@ def test_elastic_allreduce_two_process_job(tmp_path):
     manager.stop_relaunch_and_remove_all_pods()
 
 
-def run_three_worker_job(tmp_path, kill=True):
-    """The 3-worker/2-epoch elastic job, with or without a mid-job
-    SIGKILL — the shared harness for the kill rung and for bench.py
-    --preemption's same-config clean/killed comparison."""
+def run_three_worker_job(tmp_path):
+    """The 3-worker/2-epoch elastic job with a mid-job SIGKILL of one
+    worker: the kill rung's harness."""
     create_recordio_file(
         384, DatasetName.IMAGE_DEFAULT, (28, 28), temp_dir=str(tmp_path)
     )
@@ -600,16 +599,15 @@ def run_three_worker_job(tmp_path, kill=True):
     )
     runner.start()
 
-    if kill:
-        # wait for real collective progress, then kill a worker mid-job
-        deadline = time.time() + 240
-        while len(completed) < 2:
-            assert time.time() < deadline, "job made no progress"
-            assert runner.is_alive(), "master exited early"
-            time.sleep(0.5)
-        victims = manager.live_workers()
-        assert victims, "no live workers to kill"
-        manager.kill_worker(victims[-1])
+    # wait for real collective progress, then kill a worker mid-job
+    deadline = time.time() + 240
+    while len(completed) < 2:
+        assert time.time() < deadline, "job made no progress"
+        assert runner.is_alive(), "master exited early"
+        time.sleep(0.5)
+    victims = manager.live_workers()
+    assert victims, "no live workers to kill"
+    manager.kill_worker(victims[-1])
 
     runner.join(timeout=420)
     assert not runner.is_alive(), "master did not finish"
@@ -621,7 +619,7 @@ def run_three_worker_job(tmp_path, kill=True):
 
 @pytest.mark.slow
 def test_elastic_allreduce_survives_worker_kill(tmp_path):
-    run_three_worker_job(tmp_path, kill=True)
+    run_three_worker_job(tmp_path)
 
 
 @pytest.mark.slow

@@ -324,6 +324,59 @@ def test_task_prefetch_with_queued_acks_equivalent():
     assert all(msg == "" for _, msg in stub.reports)
 
 
+def test_whole_pipelined_plane_yields_the_serial_batches():
+    """Every stage switched at once, as the worker runs them: task
+    prefetch with whole-task warm read-ahead, ordered parallel decode,
+    vectorized batch assembly and the boundary-drained ack queue yield
+    the IDENTICAL batches in the IDENTICAL order as the serial plane
+    (no prefetch, serial map, ``_tree_stack`` batches, synchronous
+    acks), every record once, and leave nothing in the master's
+    doing-set."""
+    n_tasks, per_task, batch_size = 8, 48, 16
+
+    def parse(record):
+        shard, i = record.decode().split(":")
+        seed = int(shard.split("_")[1]) * per_task + int(i)
+        x = np.random.default_rng(seed).standard_normal(32)
+        x = np.tanh(x.astype(np.float32)) * np.float32(seed % 7 + 1)
+        return {"x": x, "y": np.int64(seed)}
+
+    def run(pipelined):
+        stub = StubMaster(n_tasks, per_task)
+        service = make_service(
+            stub,
+            task_prefetch=2 if pipelined else 0,
+            ack_queue_size=8 if pipelined else 0,
+            prefetch_warm_records=per_task,
+        )
+        batches = []
+        while True:
+            ds = service.get_dataset()
+            if ds is None:
+                break
+            ds = (
+                ds.map(parse, num_parallel_calls=4 if pipelined else None)
+                .batch(batch_size, vectorized=pipelined)
+                .prefetch(2)
+            )
+            for b in ds:
+                batches.append(b)
+                service.report_record_done(int(b["y"].shape[0]))
+            service.drain_acks()
+        assert not stub.doing
+        assert sorted(t for t, _ in stub.reports) == list(
+            range(1, n_tasks + 1)
+        )
+        return batches
+
+    serial, pipelined = run(False), run(True)
+    assert len(serial) == len(pipelined) == n_tasks * per_task // batch_size
+    for sb, pb in zip(serial, pipelined):
+        _assert_tree_equal(sb, pb)
+    seen = np.concatenate([b["y"] for b in pipelined])
+    np.testing.assert_array_equal(seen, np.arange(n_tasks * per_task))
+
+
 def test_task_prefetch_propagates_reader_errors_and_hands_task_back():
     class BoomReader(ListReader):
         def read_records(self, task):

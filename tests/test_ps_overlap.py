@@ -48,7 +48,7 @@ def test_fanout_pull_matches_serial_and_overlaps():
 
 def test_fanout_wall_tracks_slowest_shard_not_sum():
     """One injected slow shard: wall time ~= the slow shard, not the
-    sum over shards (the acceptance-criteria microbench shape)."""
+    sum over shards."""
     inners = [TablePS() for _ in range(4)]
     stubs = [
         FaultyPS(t, delay_s=(0.4 if i == 2 else 0.05))

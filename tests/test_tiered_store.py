@@ -74,6 +74,18 @@ def _drain(t):
         pass
 
 
+def _await_disk_rows(table, timeout=10.0):
+    """Press the BACKGROUND demoter until rows are resident on disk.
+    Mid-apply spills of a step's own rows are legal and get superseded
+    by the apply's warm write (set pops the disk entry), so "a spill
+    happened" is not enough."""
+    deadline = time.time() + timeout
+    while time.time() < deadline and not table.stats()["disk_rows"]:
+        table.signal_pressure()
+        time.sleep(0.02)
+    assert table.stats()["disk_rows"] > 0
+
+
 # ---------------------------------------------------------------------------
 # value transparency
 # ---------------------------------------------------------------------------
@@ -608,17 +620,8 @@ def test_servicer_forwards_apply_notes_and_reports_tier_stats(tmp_path):
         table = p.embedding_params["emb"]
         # the delta note reached the tiered table's pin ring
         assert table._applied
-        # overflow exists; the BACKGROUND demoter spills it (the one
-        # thread-driven path in this suite). Mid-apply spills of a
-        # step's own rows are legal and get superseded by the apply's
-        # warm write (set pops the disk entry), so wait until rows are
-        # actually RESIDENT on disk, not merely until a spill happened.
-        deadline = time.time() + 10.0
-        while time.time() < deadline:
-            if table.stats()["disk_rows"] > 0:
-                break
-            table.signal_pressure()
-            time.sleep(0.02)
+        # overflow exists; the BACKGROUND demoter spills it
+        _await_disk_rows(table)
         assert table.stats()["spilled_rows"] > 0
         resp = s.ps_status({})
         assert resp["tiered"]["spilled_rows"] > 0
@@ -635,6 +638,80 @@ def test_servicer_forwards_apply_notes_and_reports_tier_stats(tmp_path):
         assert s.ps_status({})["tiered"]["cold_pull_rows"] > 0
     finally:
         p.close()
+
+
+def test_tiered_shard_matches_memory_shard_under_demotion_churn(tmp_path):
+    """One all-in-memory and one tiered PS shard, driven from one common
+    id-keyed lazy init through an identical power-law pull/push stream
+    under adam (so the slot tables tier too). The tiered shard's warm
+    budget is an eighth of the id pool and its BACKGROUND demoter is
+    live, so rows churn between tiers on every step; lookups, applied
+    rows and the final full-table read must match bitwise — a tier move
+    that drops, duplicates or stales one row (or one slot row) fails
+    here."""
+    dim, warm_rows, pool_n, steps = 16, 64, 512, 8
+    rng = np.random.default_rng(11)
+    pool = rng.permutation(5383)[:pool_n]
+    w = 1.0 / np.arange(1, pool_n + 1) ** 1.2
+    w /= w.sum()
+    stream = [
+        np.unique(rng.choice(pool, size=96, p=w)).astype(np.int64)
+        for _ in range(steps)
+    ]
+
+    def shard(tier):
+        p = Parameters(tier_config=tier)
+        s = PserverServicer(p, 1, optax.adam(0.05), use_async=True)
+        s.push_model(
+            {
+                "version": 0,
+                "params": [Tensor("w", np.ones((4, 4), np.float32))],
+                "embedding_infos": [{"name": "emb", "dim": dim}],
+            }
+        )
+        return p, s
+
+    def rows_of(s, ids):
+        return np.asarray(
+            s.pull_embedding_vector({"name": "emb", "ids": ids})["rows"]
+        )
+
+    p_mem, s_mem = shard(None)
+    p_tier, s_tier = shard(
+        {
+            "warm_rows": warm_rows,
+            "spill_dir": os.path.join(str(tmp_path), "spill"),
+        }
+    )
+    try:
+        for step, ids in enumerate(stream):
+            np.testing.assert_array_equal(
+                rows_of(s_mem, ids), rows_of(s_tier, ids)
+            )
+            grad = rng.standard_normal((len(ids), dim)).astype(np.float32)
+            for s in (s_mem, s_tier):
+                s.push_gradient(
+                    {
+                        "model_version": step,
+                        "gradients": [Tensor("emb", grad, indices=ids)],
+                    }
+                )
+            np.testing.assert_array_equal(
+                rows_of(s_mem, ids), rows_of(s_tier, ids)
+            )
+        # the full-table read has to cross the tiers, not find a lucky
+        # all-warm table
+        table = p_tier.embedding_params["emb"]
+        _await_disk_rows(table)
+        every = np.unique(np.concatenate(stream))
+        np.testing.assert_array_equal(
+            rows_of(s_mem, every), rows_of(s_tier, every)
+        )
+        stats = table.stats()
+        assert stats["spilled_rows"] > 0 and stats["cold_pull_rows"] > 0
+    finally:
+        p_tier.close()
+        p_mem.close()
 
 
 def test_tiered_metrics_collector_exports_labeled_series(tmp_path):

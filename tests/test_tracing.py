@@ -14,6 +14,8 @@ wiring). Runs under EDL_LOCKTRACE=1 in scripts/check.sh.
 
 import json
 import os
+import threading
+import time
 import urllib.request
 
 import pytest
@@ -568,6 +570,99 @@ def test_tracetool_cli_round_trip(tmp_path):
     assert tracetool.main([str(tmp_path / "missing.json")]) == 2
 
 
+def test_live_job_serves_metrics_and_a_trace_that_explains_its_steps(
+    tmp_path,
+):
+    """A real local job: the master's own gRPC server and telemetry
+    endpoint, a Worker on a MasterClient. Scraped from the job's start
+    on, ``/metrics`` comes to carry the per-worker rate, the RPC latency
+    histograms of both sides and the live queue depth; after the job,
+    ``/trace`` round-trips through tracetool and the worker's named
+    child spans explain at least 90% of its steps' wall time (less
+    means a phase of the step carries no span)."""
+    from elasticdl_tpu.common.args import parse_master_args
+    from elasticdl_tpu.master.master import Master
+    from elasticdl_tpu.master.rpc_service import MasterClient
+    from elasticdl_tpu.worker.worker import Worker
+    from tests.test_utils import (
+        MODEL_ZOO_PATH,
+        DatasetName,
+        create_recordio_file,
+    )
+
+    create_recordio_file(
+        96, DatasetName.IMAGE_DEFAULT, (28, 28), temp_dir=str(tmp_path)
+    )
+    model_def = "mnist_subclass.mnist_subclass.CustomModel"
+    args = parse_master_args(
+        [
+            "--job_name", "trace-live",
+            "--model_zoo", MODEL_ZOO_PATH,
+            "--model_def", model_def,
+            "--minibatch_size", "16",
+            "--training_data", str(tmp_path),
+            "--num_workers", "0",
+            "--num_ps_pods", "0",
+            "--use_async", "true",
+            "--port", "0",
+            "--telemetry_port", "0",
+            "--telemetry_report_secs", "0.2",
+        ]
+    )
+    master = Master(args)
+    master.prepare()
+    stub = MasterClient("localhost:%d" % master.port)
+    worker = Worker(
+        0,
+        master.job_type,
+        16,
+        MODEL_ZOO_PATH,
+        model_def,
+        stub=stub,
+        telemetry_report_secs=0.2,
+    )
+    worker_err = []
+
+    def drive():
+        try:
+            worker.run()
+        except Exception as e:  # asserted on below
+            worker_err.append(e)
+
+    base = "http://127.0.0.1:%d" % master.telemetry_port
+    required = [
+        'edl_worker_examples_per_sec{worker="0"}',
+        "edl_rpc_client_latency_seconds_bucket",
+        'edl_rpc_server_latency_seconds_bucket{role="master"',
+        "edl_task_queue_depth",
+    ]
+    missing = list(required)
+    t = threading.Thread(target=drive, name="edl-test-live-worker")
+    t.start()
+    try:
+        deadline = time.monotonic() + 120
+        while time.monotonic() < deadline and missing and not worker_err:
+            text = urllib.request.urlopen(
+                base + "/metrics", timeout=10
+            ).read().decode("utf-8")
+            missing = [m for m in required if m not in text]
+            time.sleep(0.1)
+        t.join(timeout=120)
+        assert not t.is_alive()
+        trace_doc = json.loads(
+            urllib.request.urlopen(base + "/trace", timeout=10).read()
+        )
+    finally:
+        master.request_stop()
+        master.run(poll_secs=0.1)
+        stub.close()
+    assert not worker_err, worker_err
+    assert not missing, "families never served: %s" % missing
+    report = critical_path(trace_doc)
+    assert report["steps"] >= 96 // 16
+    assert report["attribution"] >= 0.90, report["phases"]
+
+
 # ---------------------------------------------------------------------------
 # flight recorder
 # ---------------------------------------------------------------------------
@@ -600,6 +695,40 @@ def test_flight_recorder_dumps_on_trigger_event(tmp_path):
     assert {"worker_join", "ps_shard_failure"} <= kinds
     span_names = [s["name"] for s in body if s["type"] == "span"]
     assert "step" in span_names
+
+
+def test_dead_shard_rpc_failure_leaves_a_postmortem(tmp_path):
+    """The whole chain, from the wire: a PS shard dies under a bound
+    client, the client's next call fails terminally and emits
+    ``ps_shard_failure`` itself, and the armed recorder writes a
+    postmortem whose every line parses and which holds the trigger
+    event and the spans recorded before it (the last step that still
+    completed)."""
+    from elasticdl_tpu.worker.ps_client import BoundPS, PSRpcError
+    from tests.fake_ps import serve_slow_ps
+
+    profiling.flight_recorder.arm(str(tmp_path), min_interval_s=0.0)
+    server, addr = serve_slow_ps(delay_s=0.0)
+    bound = BoundPS(addr, deadline_s=2.0, retries=0)
+    try:
+        with profiling.span("step", trace_id="drill-1"):
+            assert "model_init_status" in bound.pull_variable({})
+        assert os.listdir(str(tmp_path)) == []
+        server.stop(None)  # the shard is gone: no drain, no goodbye
+        with pytest.raises(PSRpcError):
+            with profiling.span("step", trace_id="drill-2"):
+                bound.pull_variable({})
+    finally:
+        bound.close()
+        server.stop(None)
+    (dump,) = os.listdir(str(tmp_path))
+    header, body = _read_postmortem(os.path.join(str(tmp_path), dump))
+    assert header["postmortem"] == "ps_shard_failure"
+    assert header["trigger"]["addr"] == addr
+    assert header["trigger"]["method"] == "pull_variable"
+    kinds = {e["kind"] for e in body if e["type"] == "event"}
+    assert "ps_shard_failure" in kinds
+    assert "drill-1" in {s["trace"] for s in body if s["type"] == "span"}
 
 
 def test_flight_recorder_rate_limit_and_prune(tmp_path):
