@@ -48,6 +48,38 @@ with ``delta = rowsum(dO * O)``. Peak memory in backward is O(block^2)
 per core — no (L, L) materialization anywhere (round-1 advisor finding:
 the previous backward re-ran dense reference attention).
 
+What moves through HBM besides q, k, v, o, dO and the gradients
+(PR 31). The two per-row statistics, ``lse`` and ``delta``, are one f32
+a row and travel as ``(batch*heads, 1, L)``, L along the lanes, in
+blocks of ``(1, 1, block_q)``: 4 KiB a tile where the ``(rows, 128)``
+blocks they rode in until PR 30, every lane the same number, were
+512 KiB (100.7 MB an array a layer at 96 x 2,048, 704.6 MB over the
+three kernels, and two XLA broadcasts a layer to write them).
+``_flash_fwd`` hands its callers ``(b, h, L)`` as ever; XLA computes
+``delta`` (and folds an lse cotangent in) at ``(batch*heads, L)`` and
+broadcasts nothing. Each kernel reads or writes them its own way:
+
+- the forward keeps its orientation, scores as (q rows, k columns):
+  transposed, ``p v`` would stream 64 rows past a weight matrix of
+  scores. Its running maximum fills ``(rows, 128)`` as before, and once
+  a FINISHED q tile one transpose of the logsumexp puts the rows on the
+  lanes to write them;
+- both backward kernels form the TRANSPOSED scores of a sub-block,
+  ``s^T = k q^T`` and ``dp^T = v dO^T`` (both the NT product the forward
+  uses), so that ``lse`` and ``delta`` are row vectors that broadcast
+  down the sublanes with no relayout. ``dv += p^T dO`` and
+  ``dk += ds^T q`` are then plain products, where until PR 30 both
+  contracted over the left operand's rows; ``dq += (ds^T)^T k`` is now
+  the one that does.
+
+And a grid step the causal mask skips fetches nothing (PR 31): the
+index maps of the blocks that follow a grid's inner axis (k and v in
+the forward and dq, q, dO and both statistics in dkv) clamp it to the
+nearest tile with work (:func:`_plan`), so the skipped step names the
+block its neighbour computes with and the pipeline issues no copy. At
+L = 2,048 that is one step in four. :func:`hbm_traffic` walks the grids
+with the kernels' own index maps and counts the bytes.
+
 On a TPU backend the kernels compile through Mosaic. They run in Pallas
 interpret mode only in a process that was explicitly put on the CPU
 (tests, rehearsals); a CPU backend JAX fell back to, or any other
@@ -55,6 +87,8 @@ platform, raises (:func:`kernel_interpret_mode`).
 """
 
 import functools
+import itertools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -62,7 +96,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
-_LANES = 128  # stats are broadcast across a full lane register
+_LANES = 128  # the forward's running statistics fill a lane register
 
 # stable kernel names: the Mosaic custom call carries them as
 # ``kernel_name``, which is how a lowered step (and a profiler trace)
@@ -85,6 +119,21 @@ def _run_if(pred):
     """``pl.when`` for Python booleans: what :func:`causal_work_ratio`
     walks a tile with."""
     return lambda fn: fn() if pred else None
+
+
+def _lower(a, b):
+    """``min`` for program ids and for Python ints alike: an index map
+    runs on the former inside ``pallas_call`` and on the latter under
+    :func:`hbm_traffic`'s walk."""
+    if isinstance(a, int) and isinstance(b, int):
+        return min(a, b)
+    return jnp.minimum(a, b)
+
+
+def _higher(a, b):
+    if isinstance(a, int) and isinstance(b, int):
+        return max(a, b)
+    return jnp.maximum(a, b)
 
 
 def _walk_tile(qi, kj, block_q, block_k, w, causal, step, when=pl.when):
@@ -242,24 +291,48 @@ def _fwd_kernel(
     def _finish():
         l_fin = acc_ref[:, d:d + 1]
         o_ref[0] = (acc_ref[:, :d] / l_fin).astype(o_ref.dtype)
-        lse_ref[:] = jnp.broadcast_to(
-            m_ref[:, :1] + jnp.log(l_fin), lse_ref.shape
-        )
+        # the statistics leave with L along the lanes: m_ref holds the
+        # maximum in every lane, so one transpose of the (rows, 128)
+        # logsumexp a finished q tile puts the rows on the lanes of its
+        # first row
+        lse_ref[0] = (m_ref[:] + jnp.log(l_fin)).T[:1]
 
 
-def _p_and_ds(q, k_ref, v_ref, do, lse_ref, delta_ref, rows, cols, off):
-    """One sub-block of both backward passes: ``p = exp(q k^T - lse)``
-    recomputed, and ``ds = p * (dO V^T - delta)``."""
-    p = jnp.exp(
-        _scores(q[rows], k_ref[0, cols, :], off) - lse_ref[0, rows, :1]
-    )
-    dp = jax.lax.dot_general(
-        do[rows],
-        v_ref[0, cols, :].astype(jnp.float32),
+def _scores_t(k, q, off):
+    """k q^T for one sub-block (q carries the softmax scale): the
+    TRANSPOSED scores, k positions down the sublanes and q positions
+    along the lanes, so that a statistic of the q rows is a row vector.
+    Masked to NEG_INF above the diagonal where ``off`` says it passes:
+    the score of q row ``r`` and k column ``c`` is kept when
+    ``r + off >= c``."""
+    s_t = jax.lax.dot_general(
+        k.astype(jnp.float32),
+        q,
         (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
-    return p, p * (dp - delta_ref[0, rows, :1])
+    if off is None:
+        return s_t
+    col = jax.lax.broadcasted_iota(jnp.int32, s_t.shape, 0)
+    row = jax.lax.broadcasted_iota(jnp.int32, s_t.shape, 1)
+    return jnp.where(row + off >= col, s_t, NEG_INF)
+
+
+def _p_and_ds_t(q, k_ref, v_ref, do, lse_ref, delta_ref, rows, cols, off):
+    """One sub-block of both backward passes, transposed: ``p^T =
+    exp(k q^T - lse)`` recomputed and ``ds^T = p^T * (V dO^T - delta)``,
+    both (columns, rows). ``lse`` and ``delta`` arrive as (1, rows)
+    and broadcast down the sublanes: no relayout."""
+    p_t = jnp.exp(
+        _scores_t(k_ref[0, cols, :], q[rows], off) - lse_ref[0, :, rows]
+    )
+    dp_t = jax.lax.dot_general(
+        v_ref[0, cols, :].astype(jnp.float32),
+        do[rows],
+        (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    return p_t, p_t * (dp_t - delta_ref[0, :, rows])
 
 
 def _bwd_dq_kernel(
@@ -286,16 +359,18 @@ def _bwd_dq_kernel(
 
     q = _scaled_q(q_ref, scale)
     do = do_ref[0].astype(jnp.float32)
+    lhs_t = (((0,), (0,)), ((), ()))
 
     def strip(rows, cols, off):
-        _, ds = _p_and_ds(
+        _, ds_t = _p_and_ds_t(
             q, k_ref, v_ref, do, lse_ref, delta_ref, rows, cols, off
         )
-        dq_acc[rows, :] += jax.lax.dot(
-            ds,
+        dq_acc[rows, :] += jax.lax.dot_general(
+            ds_t,
             k_ref[0, cols, :].astype(jnp.float32),
+            lhs_t,
             preferred_element_type=jnp.float32,
-        )
+        )  # (ds^T)^T k
 
     _walk_tile(
         qi, kj, q_ref.shape[1], k_ref.shape[1], w, causal, _each(strip)
@@ -332,21 +407,20 @@ def _bwd_dkv_kernel(
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    # the scaled q serves both products: s = (q scale) k^T, and
+    # the scaled q serves both products: s^T = k (q scale)^T, and
     # dk = ds^T (q scale) needs no scale of its own
     q = _scaled_q(q_ref, scale)
     do = do_ref[0].astype(jnp.float32)
-    lhs_t = (((0,), (0,)), ((), ()))
 
     def strip(rows, cols, off):
-        p, ds = _p_and_ds(
+        p_t, ds_t = _p_and_ds_t(
             q, k_ref, v_ref, do, lse_ref, delta_ref, rows, cols, off
         )
-        dv_acc[cols, :] += jax.lax.dot_general(
-            p, do[rows], lhs_t, preferred_element_type=jnp.float32
+        dv_acc[cols, :] += jax.lax.dot(
+            p_t, do[rows], preferred_element_type=jnp.float32
         )  # p^T dO
-        dk_acc[cols, :] += jax.lax.dot_general(
-            ds, q[rows], lhs_t, preferred_element_type=jnp.float32
+        dk_acc[cols, :] += jax.lax.dot(
+            ds_t, q[rows], preferred_element_type=jnp.float32
         )  # ds^T (q scale)
 
     _walk_tile(
@@ -374,18 +448,24 @@ def divisible(lq, lk, block_q, block_k):
     """True when the fused kernels can tile these lengths.
 
     On a TPU the Pallas lowering additionally wants each (possibly
-    clamped) block size along the sequence divisible by 8, or equal to
-    the whole length, whatever the dtype; interpret mode has no such
-    constraint. (v5e / libtpu 0.0.34, chip run, PR 21: bf16 and f32
-    alike, 8x8 up to 1000x1000 and whole odd lengths 7, 36, 100 build;
-    4, 12, 20 or 50 rows out of a longer sequence are refused.)
+    clamped) block size along the sequence to suit the dimension it
+    lands on, or to equal the whole length, whatever the dtype:
+    ``block_k`` is only ever a sublane dimension (of k, v, dk, dv) and
+    has to divide by 8; ``block_q`` is that too (q, o, dO, dq) and,
+    since the statistics travel as ``(batch*heads, 1, L)``, the LANE
+    dimension of their ``(1, 1, block_q)`` block, so it has to divide by
+    128. Interpret mode has no such constraint. (v5e / libtpu 0.0.34,
+    chip run, PR 21: bf16 and f32 alike, 8x8 up to 1000x1000 and whole
+    odd lengths 7, 36, 100 build; 4, 12, 20 or 50 rows out of a longer
+    sequence are refused.) :func:`auto_blocks` and
+    :func:`pick_causal_attention` only hand out multiples of 128.
     """
     bq, bk = min(block_q, lq), min(block_k, lk)
     if lq % bq or lk % bk:
         return False
     if kernel_interpret_mode():
         return True
-    return (bq % 8 == 0 or bq == lq) and (bk % 8 == 0 or bk == lk)
+    return (bq % _LANES == 0 or bq == lq) and (bk % 8 == 0 or bk == lk)
 
 
 def sub_block(d):
@@ -405,7 +485,26 @@ def sub_block(d):
     tile on the diagonal) but make more and smaller products, and dkv
     passes over its (columns, d) accumulators once a sub-block: at 256
     the two meet. A tile that 256 does not divide (the tests' 16-row
-    tiles, a short whole length) is left whole."""
+    tiles, a short whole length) is left whole.
+
+    Since PR 31 a sub-block of the two backward kernels is TRANSPOSED,
+    (the columns its rows can see, ``w``): ``w`` is its lane dimension
+    and the statistics' ``(1, w)`` slices broadcast down its sublanes.
+    Same chip and versions, same shapes and way of timing (chip run,
+    PR 31; the parent's bodies in the same process 0.965 + 1.360 +
+    1.687 = 4.012 on that machine), step by step: skipped grid steps
+    clamped to a neighbour's blocks, bodies untouched, 0.964 + 1.294 +
+    1.500 = 3.758; with that the statistics as ``(bh, 1, L)`` and each
+    turned from a row into a column once a grid step (the parent's
+    orientation) 0.960 + 1.295 + 1.693 = 3.949, so a relayout a step
+    costs what the traffic gives back; dq in the parent's orientation
+    off a ``(rows, 128)`` scratch filled once a q tile and dkv
+    transposed 0.960 + 1.205 + 1.404 = 3.569; **both transposed, as
+    committed, 0.960 + 1.125 + 1.404 = 3.490 (-13.0%)**: a statistic
+    broadcast along the lanes was itself a cost, beside its bytes.
+    ``w`` again, transposed: 128: 0.929 + 1.755 + 1.391 = 4.076; 512:
+    1.026 + 1.211 + 1.534 = 3.772: 256 stays. 32 x 2,048 x 64:
+    0.316 + 0.377 + 0.487 = 1.179 -> 0.316 + 0.356 + 0.457 = 1.129."""
     del d
     return 256
 
@@ -448,6 +547,107 @@ def _block_sizes(lq, lk, block_q, block_k):
     return block_q, block_k
 
 
+_STATISTICS = ("lse", "delta")
+
+
+def _plan(kernel, bh, lq, lk, d, block_q, block_k, causal):
+    """``(grid, inputs, outputs)`` of one kernel's ``pallas_call``, the
+    last two as lists of ``(name, BlockSpec)``: what the call is built
+    from, and what :func:`hbm_traffic` walks.
+
+    q, dO, o, dq and the statistics move in tiles of ``block_q`` rows,
+    k, v, dk and dv in tiles of ``block_k``; a statistic is
+    ``(bh, 1, L)`` f32 and its tile ``(1, 1, block_q)``, L along the
+    lanes. The forward and dq run k tiles inside a q tile, dkv q tiles
+    inside a k tile.
+
+    Under ``causal`` the blocks that follow the inner axis are clamped
+    to the nearest tile with work, by positions, so square and other
+    tiles alike: a q tile's last row sees k positions up to its own, so
+    its last k tile with work is ``((qi + 1) block_q - 1) // block_k``
+    and k, v take the lower of that and ``kj``; a k tile is first seen
+    by q tile ``kj block_k // block_q`` and q, dO and the statistics
+    take the higher of that and ``qi`` (square tiles: ``min(kj, qi)``
+    and ``max(qi, kj)``). A grid step :func:`_walk_tile` skips then
+    names the block its neighbour in the walk computes with, and the
+    pipeline issues no copy for it. Blocks that follow the outer axis,
+    every output among them, are left alone."""
+    nq, nk = lq // block_q, lk // block_k
+
+    def k_tile(qi, kj):
+        if not causal:
+            return kj
+        return _lower(kj, ((qi + 1) * block_q - 1) // block_k)
+
+    def q_tile(kj, qi):
+        if not causal:
+            return qi
+        return _lower(_higher(qi, kj * block_k // block_q), nq - 1)
+
+    if kernel == BWD_DKV_KERNEL:
+        grid = (bh, nk, nq)
+        rows = lambda i, kj, qi: (i, q_tile(kj, qi), 0)
+        cols = lambda i, kj, qi: (i, kj, 0)
+        stat = lambda i, kj, qi: (i, 0, q_tile(kj, qi))
+    else:
+        grid = (bh, nq, nk)
+        rows = lambda i, qi, kj: (i, qi, 0)
+        cols = lambda i, qi, kj: (i, k_tile(qi, kj), 0)
+        stat = lambda i, qi, kj: (i, 0, qi)
+    by_rows = pl.BlockSpec((1, block_q, d), rows)
+    by_cols = pl.BlockSpec((1, block_k, d), cols)
+    statistic = pl.BlockSpec((1, 1, block_q), stat)
+    qkv = [("q", by_rows), ("k", by_cols), ("v", by_cols)]
+    if kernel == FWD_KERNEL:
+        return grid, qkv, [("o", by_rows), ("lse", statistic)]
+    inputs = qkv + [
+        ("dO", by_rows), ("lse", statistic), ("delta", statistic)
+    ]
+    if kernel == BWD_DQ_KERNEL:
+        return grid, inputs, [("dq", by_rows)]
+    return grid, inputs, [("dk", by_cols), ("dv", by_cols)]
+
+
+def hbm_traffic(bh, lq, lk, d, block_q, block_k, causal=True, itemsize=2):
+    """Bytes each kernel moves between HBM and VMEM in one call, split
+    into ``tensors`` (q, k, v, o, dO and the gradients, ``itemsize``
+    bytes an element) and ``statistics`` (lse and delta, f32), with the
+    count of blocks moved under each operand's name (``blocks``).
+
+    Static, from shapes alone: it walks each kernel's grid in the order
+    the chip does, with the kernel's OWN index maps (:func:`_plan`),
+    and adds a block's bytes whenever its index differs from the step
+    before, which is when the pipeline copies it. At 96 x 2,048 x 64,
+    bf16, causal, 1,024-tiles: statistics 3.9 MB over the three
+    kernels (704.6 MB as ``(bh, L, 128)`` blocks that dkv fetched at
+    every step, until PR 30), tensors 377.5 MB (528.5), k and v tiles a
+    head in the forward and dq 2 (4), q, dO and statistics tiles a head
+    in dkv 2 (4). Not causal nothing is clamped: 4 and 4."""
+    block_q, block_k = _block_sizes(lq, lk, block_q, block_k)
+    traffic = {}
+    for kernel in (FWD_KERNEL, BWD_DQ_KERNEL, BWD_DKV_KERNEL):
+        grid, inputs, outputs = _plan(
+            kernel, bh, lq, lk, d, block_q, block_k, causal
+        )
+        moved = {"tensors": 0, "statistics": 0, "blocks": {}}
+        at = {}
+        for step in itertools.product(*map(range, grid)):
+            for name, spec in inputs + outputs:
+                index = spec.index_map(*step)
+                if at.get(name) == index:
+                    continue
+                at[name] = index
+                moved["blocks"][name] = moved["blocks"].get(name, 0) + 1
+                kind, size = (
+                    ("statistics", 4)
+                    if name in _STATISTICS
+                    else ("tensors", itemsize)
+                )
+                moved[kind] += size * math.prod(spec.block_shape)
+        traffic[kernel] = moved
+    return traffic
+
+
 # Both halves are jitted INLINE with everything but the arrays static:
 # jax then traces a kernel body once for each distinct call (shapes,
 # tile sizes, causal) and not once a layer, and hands every layer the
@@ -457,6 +657,25 @@ def _block_sizes(lq, lk, block_q, block_k):
 # equations of the whole-tile ones; traced and lowered per layer they
 # cost `lm125m-l2048` 9 s of set-up on the chip (PR 29).
 _STATIC = ("causal", "block_q", "block_k", "interpret", "w")
+
+
+def _call(
+    kernel, body, shapes, out_shape, scratch_shapes, interpret, **static
+):
+    """The ``pallas_call`` of ``kernel``: its grid and block specs are
+    :func:`_plan`'s for ``shapes``, ``static`` are the body's keywords."""
+    grid, inputs, outputs = _plan(kernel, *shapes)
+    return pl.pallas_call(
+        functools.partial(body, **static),
+        out_shape=out_shape,
+        grid=grid,
+        in_specs=[spec for _, spec in inputs],
+        out_specs=[spec for _, spec in outputs],
+        scratch_shapes=scratch_shapes,
+        compiler_params=_COMPILER_PARAMS,
+        interpret=interpret,
+        name=kernel,
+    )
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC, inline=True)
@@ -469,43 +688,27 @@ def _flash_fwd(q, k, v, causal, block_q, block_k, interpret, w=None):
     scale = d ** -0.5
     qf, kf, vf = _fold_heads(q), _fold_heads(k), _fold_heads(v)
 
-    kernel = functools.partial(
-        _fwd_kernel, causal=causal, scale=scale, w=w
-    )
     # room for v and at least one column of ones, in whole lanes
     d_ones = -(-(d + 1) // _LANES) * _LANES
-    out, lse = pl.pallas_call(
-        kernel,
-        out_shape=[
+    out, lse = _call(
+        FWD_KERNEL,
+        _fwd_kernel,
+        (b * h, lq, lk, d, block_q, block_k, causal),
+        [
             jax.ShapeDtypeStruct(qf.shape, q.dtype),
-            jax.ShapeDtypeStruct((b * h, lq, _LANES), jnp.float32),
+            jax.ShapeDtypeStruct((b * h, 1, lq), jnp.float32),
         ],
-        grid=(b * h, lq // block_q, lk // block_k),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda i, qi, kj: (i, qi, 0)),
-            pl.BlockSpec((1, block_k, d), lambda i, qi, kj: (i, kj, 0)),
-            pl.BlockSpec((1, block_k, d), lambda i, qi, kj: (i, kj, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda i, qi, kj: (i, qi, 0)),
-            pl.BlockSpec(
-                (1, block_q, _LANES),
-                lambda i, qi, kj: (i, qi, 0),
-            ),
-        ],
-        scratch_shapes=[
+        [
             pltpu.VMEM((block_q, d_ones), jnp.float32),
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_k, d_ones), jnp.float32),
         ],
-        compiler_params=_COMPILER_PARAMS,
-        interpret=interpret,
-        name=FWD_KERNEL,
+        interpret,
+        causal=causal,
+        scale=scale,
+        w=w,
     )(qf, kf, vf)
-    return (
-        _unfold_heads(out, b, h),
-        lse[:, :, 0].reshape(b, h, lq),
-    )
+    return _unfold_heads(out, b, h), lse.reshape(b, h, lq)
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC, inline=True)
@@ -541,71 +744,42 @@ def _flash_bwd(
         delta = delta - jnp.asarray(g_lse, jnp.float32).reshape(
             b * h, lq
         )
-    lse_l = jnp.broadcast_to(
-        lse.reshape(b * h, lq, 1), (b * h, lq, _LANES)
+    # both statistics enter as they are, (b*h, 1, lq): no broadcast
+    operands = (
+        qf,
+        kf,
+        vf,
+        dof,
+        lse.reshape(b * h, 1, lq),
+        delta.reshape(b * h, 1, lq),
     )
-    delta_l = jnp.broadcast_to(
-        delta[..., None], (b * h, lq, _LANES)
-    )
+    shapes = b * h, lq, lk, d, block_q, block_k, causal
+    static = dict(causal=causal, scale=scale, w=w)
 
-    stat_spec_q = pl.BlockSpec(
-        (1, block_q, _LANES), lambda i, qi, kj: (i, qi, 0)
-    )
-    dq = pl.pallas_call(
-        functools.partial(
-            _bwd_dq_kernel, causal=causal, scale=scale, w=w
-        ),
-        out_shape=jax.ShapeDtypeStruct(qf.shape, q.dtype),
-        grid=(b * h, lq // block_q, lk // block_k),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda i, qi, kj: (i, qi, 0)),
-            pl.BlockSpec((1, block_k, d), lambda i, qi, kj: (i, kj, 0)),
-            pl.BlockSpec((1, block_k, d), lambda i, qi, kj: (i, kj, 0)),
-            pl.BlockSpec((1, block_q, d), lambda i, qi, kj: (i, qi, 0)),
-            stat_spec_q,
-            stat_spec_q,
-        ],
-        out_specs=pl.BlockSpec(
-            (1, block_q, d), lambda i, qi, kj: (i, qi, 0)
-        ),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=_COMPILER_PARAMS,
-        interpret=interpret,
-        name=BWD_DQ_KERNEL,
-    )(qf, kf, vf, dof, lse_l, delta_l)
-
-    stat_spec_kmajor = pl.BlockSpec(
-        (1, block_q, _LANES), lambda i, kj, qi: (i, qi, 0)
-    )
-    dk, dv = pl.pallas_call(
-        functools.partial(
-            _bwd_dkv_kernel, causal=causal, scale=scale, w=w
-        ),
-        out_shape=[
+    (dq,) = _call(
+        BWD_DQ_KERNEL,
+        _bwd_dq_kernel,
+        shapes,
+        [jax.ShapeDtypeStruct(qf.shape, q.dtype)],
+        [pltpu.VMEM((block_q, d), jnp.float32)],
+        interpret,
+        **static,
+    )(*operands)
+    dk, dv = _call(
+        BWD_DKV_KERNEL,
+        _bwd_dkv_kernel,
+        shapes,
+        [
             jax.ShapeDtypeStruct(kf.shape, k.dtype),
             jax.ShapeDtypeStruct(vf.shape, v.dtype),
         ],
-        grid=(b * h, lk // block_k, lq // block_q),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda i, kj, qi: (i, qi, 0)),
-            pl.BlockSpec((1, block_k, d), lambda i, kj, qi: (i, kj, 0)),
-            pl.BlockSpec((1, block_k, d), lambda i, kj, qi: (i, kj, 0)),
-            pl.BlockSpec((1, block_q, d), lambda i, kj, qi: (i, qi, 0)),
-            stat_spec_kmajor,
-            stat_spec_kmajor,
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda i, kj, qi: (i, kj, 0)),
-            pl.BlockSpec((1, block_k, d), lambda i, kj, qi: (i, kj, 0)),
-        ],
-        scratch_shapes=[
+        [
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
-        compiler_params=_COMPILER_PARAMS,
-        interpret=interpret,
-        name=BWD_DKV_KERNEL,
-    )(qf, kf, vf, dof, lse_l, delta_l)
+        interpret,
+        **static,
+    )(*operands)
     return (
         _unfold_heads(dq, b, h),
         _unfold_heads(dk, b, h),

@@ -212,27 +212,37 @@ def test_kernel_compiles_for_the_v5e_at_the_benchmarks_widths(
 # parity tests: one process may describe the chip, so every such test
 # shares this file's fixture (and with it one xdist worker).
 @pytest.mark.parametrize(
-    "batch, heads, length, causal",
+    "batch, heads, length, causal, tiles",
     [
-        pytest.param(8, 12, 2048, True, id="lm125m-l2048"),
-        pytest.param(2, 16, 2048, True, id="lm350m-l2048"),
-        pytest.param(4, 32, 2048, True, id="lfm2moe-ep8-l2048"),
-        pytest.param(16, 12, 1024, True, id="one-tile-a-head"),
-        pytest.param(8, 12, 2048, False, id="not-causal"),
+        pytest.param(8, 12, 2048, True, None, id="lm125m-l2048"),
+        pytest.param(2, 16, 2048, True, None, id="lm350m-l2048"),
+        pytest.param(4, 32, 2048, True, None, id="lfm2moe-ep8-l2048"),
+        pytest.param(16, 12, 1024, True, None, id="one-tile-a-head"),
+        pytest.param(8, 12, 2048, False, None, id="not-causal"),
+        # PR 31: block_q is the lane dimension of a statistic's block, and
+        # tiles that are not square are clamped by positions
+        pytest.param(2, 16, 2048, True, (512, 1024), id="tiles-512x1024"),
+        pytest.param(2, 16, 2048, True, (1024, 512), id="tiles-1024x512"),
+        pytest.param(2, 4, 1024, False, (128, 128), id="ring-default-tiles"),
+        pytest.param(2, 4, 100, True, (100, 100), id="a-whole-odd-length"),
     ],
 )
 def test_flash_kernels_compile_for_the_v5e_at_the_benchmarks_shapes(
-    one_chip, batch, heads, length, causal
+    one_chip, batch, heads, length, causal, tiles
 ):
     """Sub-blocks of 256 inside 1,024-tiles at head size 64: the static
     slices, the widened accumulator and the scoped VMEM are Mosaic's to
-    refuse, and interpret mode refuses none of them."""
+    refuse, and interpret mode refuses none of them. So are (PR 31) the
+    statistics' (1, 1, block_q) blocks with L along the lanes, the
+    forward's transpose of its (rows, 128) logsumexp and the backward
+    kernels' transposed scores; and what XLA compiles around the kernels
+    holds no (batch*heads, L, 128) f32 array any more."""
     from elasticdl_tpu.ops import flash_attention as fa
 
     x = jax.ShapeDtypeStruct(
         (batch, length, heads, 64), jnp.bfloat16, sharding=one_chip
     )
-    tiles = fa.auto_blocks(length, length)
+    tiles = tiles or fa.auto_blocks(length, length)
 
     def fwd_and_bwd(q, k, v, g):
         out, lse = fa._flash_fwd(q, k, v, causal, *tiles, False)
@@ -246,3 +256,5 @@ def test_flash_kernels_compile_for_the_v5e_at_the_benchmarks_shapes(
     assert text.count("tpu_custom_call") >= 3
     for name in (fa.FWD_KERNEL, fa.BWD_DQ_KERNEL, fa.BWD_DKV_KERNEL):
         assert name in text
+    assert "f32[%d,%d,128]" % (batch * heads, length) not in text
+    assert "f32[%d,1,%d]" % (batch * heads, length) in text
