@@ -80,6 +80,14 @@ block its neighbour computes with and the pipeline issues no copy. At
 L = 2,048 that is one step in four. :func:`hbm_traffic` walks the grids
 with the kernels' own index maps and counts the bytes.
 
+Under a SELECTION (PR 32, :func:`flash_attention_selected`): the same
+three bodies take one more input, a per-sequence ``(L, L)`` int8 mask
+shared by a sequence's heads, whose tile follows both tile axes and
+whose test takes the diagonal's place in :func:`_scores` and
+:func:`_scores_t`; the calls carry names of their own
+(:data:`SELECTED`). A call without a selection is built exactly as
+before: same names, operands and block specs.
+
 On a TPU backend the kernels compile through Mosaic. They run in Pallas
 interpret mode only in a process that was explicitly put on the CPU
 (tests, rehearsals); a CPU backend JAX fell back to, or any other
@@ -92,6 +100,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -104,6 +113,13 @@ _LANES = 128  # the forward's running statistics fill a lane register
 FWD_KERNEL = "edl_flash_fwd"
 BWD_DQ_KERNEL = "edl_flash_bwd_dq"
 BWD_DKV_KERNEL = "edl_flash_bwd_dkv"
+# the same three bodies under a selection (:func:`flash_attention_selected`)
+# are calls of their own, so that a trace tells them apart
+SELECTED = {
+    FWD_KERNEL: "edl_flash_sel_fwd",
+    BWD_DQ_KERNEL: "edl_flash_sel_bwd_dq",
+    BWD_DKV_KERNEL: "edl_flash_sel_bwd_dkv",
+}
 
 # every grid is (batch*heads, outer tile, inner tile) and accumulates
 # over the innermost axis only. No vmem_limit_bytes: on v5e / libtpu
@@ -201,15 +217,28 @@ def _scaled_q(q_ref, scale):
     return q_ref[0].astype(jnp.float32) * scale
 
 
-def _scores(q, k, off):
+def _kept(sel_ref, down, along):
+    """The selection's sub-block as booleans, or None without one. The
+    forward's block is (q rows, k columns), the backward kernels' the
+    transpose; the caller names the two slices in the block's order."""
+    if sel_ref is None:
+        return None
+    return sel_ref[0, down, along].astype(jnp.int32) != 0
+
+
+def _scores(q, k, off, keep=None):
     """q k^T for one sub-block (q carries the softmax scale), masked to
-    NEG_INF above the diagonal where ``off`` says it passes."""
+    NEG_INF above the diagonal where ``off`` says it passes, or
+    wherever ``keep`` is False: a selection lies inside the causal
+    triangle, so it is the diagonal's mask too."""
     s = jax.lax.dot_general(
         q,
         k.astype(jnp.float32),
         (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
+    if keep is not None:
+        return jnp.where(keep, s, NEG_INF)
     if off is None:
         return s
     row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
@@ -244,6 +273,7 @@ def _fwd_kernel(
     causal,
     scale,
     w,
+    sel_ref=None,
 ):
     qi = pl.program_id(1)
     kj = pl.program_id(2)
@@ -272,7 +302,9 @@ def _fwd_kernel(
         m_prev = m_ref[:, :1]
         scores = []
         for rows, cols, off in strips:
-            s = _scores(q[rows], k_ref[0, cols, :], off)
+            s = _scores(
+                q[rows], k_ref[0, cols, :], off, _kept(sel_ref, rows, cols)
+            )
             m_ref[rows, :] = jnp.maximum(m_ref[rows, :], _lane_max(s))
             scores.append(s)
         m_new = jnp.max(m_ref[:], axis=1, keepdims=True)
@@ -298,19 +330,22 @@ def _fwd_kernel(
         lse_ref[0] = (m_ref[:] + jnp.log(l_fin)).T[:1]
 
 
-def _scores_t(k, q, off):
+def _scores_t(k, q, off, keep_t=None):
     """k q^T for one sub-block (q carries the softmax scale): the
     TRANSPOSED scores, k positions down the sublanes and q positions
     along the lanes, so that a statistic of the q rows is a row vector.
     Masked to NEG_INF above the diagonal where ``off`` says it passes:
     the score of q row ``r`` and k column ``c`` is kept when
-    ``r + off >= c``."""
+    ``r + off >= c``; or wherever ``keep_t``, the transposed selection,
+    is False."""
     s_t = jax.lax.dot_general(
         k.astype(jnp.float32),
         q,
         (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
+    if keep_t is not None:
+        return jnp.where(keep_t, s_t, NEG_INF)
     if off is None:
         return s_t
     col = jax.lax.broadcasted_iota(jnp.int32, s_t.shape, 0)
@@ -318,13 +353,19 @@ def _scores_t(k, q, off):
     return jnp.where(row + off >= col, s_t, NEG_INF)
 
 
-def _p_and_ds_t(q, k_ref, v_ref, do, lse_ref, delta_ref, rows, cols, off):
+def _p_and_ds_t(
+    q, k_ref, v_ref, do, lse_ref, delta_ref, rows, cols, off, sel_ref=None
+):
     """One sub-block of both backward passes, transposed: ``p^T =
     exp(k q^T - lse)`` recomputed and ``ds^T = p^T * (V dO^T - delta)``,
     both (columns, rows). ``lse`` and ``delta`` arrive as (1, rows)
-    and broadcast down the sublanes: no relayout."""
+    and broadcast down the sublanes: no relayout. ``sel_ref`` is the
+    TRANSPOSED selection's block, (columns, rows) as the scores."""
     p_t = jnp.exp(
-        _scores_t(k_ref[0, cols, :], q[rows], off) - lse_ref[0, :, rows]
+        _scores_t(
+            k_ref[0, cols, :], q[rows], off, _kept(sel_ref, cols, rows)
+        )
+        - lse_ref[0, :, rows]
     )
     dp_t = jax.lax.dot_general(
         v_ref[0, cols, :].astype(jnp.float32),
@@ -348,6 +389,7 @@ def _bwd_dq_kernel(
     causal,
     scale,
     w,
+    sel_ref=None,
 ):
     qi = pl.program_id(1)
     kj = pl.program_id(2)
@@ -363,7 +405,7 @@ def _bwd_dq_kernel(
 
     def strip(rows, cols, off):
         _, ds_t = _p_and_ds_t(
-            q, k_ref, v_ref, do, lse_ref, delta_ref, rows, cols, off
+            q, k_ref, v_ref, do, lse_ref, delta_ref, rows, cols, off, sel_ref
         )
         dq_acc[rows, :] += jax.lax.dot_general(
             ds_t,
@@ -397,6 +439,7 @@ def _bwd_dkv_kernel(
     causal,
     scale,
     w,
+    sel_ref=None,
 ):
     kj = pl.program_id(1)
     qi = pl.program_id(2)
@@ -414,7 +457,7 @@ def _bwd_dkv_kernel(
 
     def strip(rows, cols, off):
         p_t, ds_t = _p_and_ds_t(
-            q, k_ref, v_ref, do, lse_ref, delta_ref, rows, cols, off
+            q, k_ref, v_ref, do, lse_ref, delta_ref, rows, cols, off, sel_ref
         )
         dv_acc[cols, :] += jax.lax.dot(
             p_t, do[rows], preferred_element_type=jnp.float32
@@ -550,7 +593,7 @@ def _block_sizes(lq, lk, block_q, block_k):
 _STATISTICS = ("lse", "delta")
 
 
-def _plan(kernel, bh, lq, lk, d, block_q, block_k, causal):
+def _plan(kernel, bh, lq, lk, d, block_q, block_k, causal, heads=None):
     """``(grid, inputs, outputs)`` of one kernel's ``pallas_call``, the
     last two as lists of ``(name, BlockSpec)``: what the call is built
     from, and what :func:`hbm_traffic` walks.
@@ -571,7 +614,15 @@ def _plan(kernel, bh, lq, lk, d, block_q, block_k, causal):
     and ``max(qi, kj)``). A grid step :func:`_walk_tile` skips then
     names the block its neighbour in the walk computes with, and the
     pipeline issues no copy for it. Blocks that follow the outer axis,
-    every output among them, are left alone."""
+    every output among them, are left alone.
+
+    ``heads``, where given, says the call has a selection, one for each
+    run of ``heads`` rows of the grid's first axis (a sequence's
+    heads): the last input, int8, in tiles that follow BOTH tile axes,
+    clamped as the operands of each axis are. The forward reads it as
+    ``(bh // heads, lq, lk)`` in ``(1, block_q, block_k)`` tiles; both
+    backward kernels form transposed scores and read its transpose,
+    ``(bh // heads, lk, lq)`` in ``(1, block_k, block_q)`` tiles."""
     nq, nk = lq // block_q, lk // block_k
 
     def k_tile(qi, kj):
@@ -589,20 +640,27 @@ def _plan(kernel, bh, lq, lk, d, block_q, block_k, causal):
         rows = lambda i, kj, qi: (i, q_tile(kj, qi), 0)
         cols = lambda i, kj, qi: (i, kj, 0)
         stat = lambda i, kj, qi: (i, 0, q_tile(kj, qi))
+        sel_t = lambda i, kj, qi: (i // heads, kj, q_tile(kj, qi))
     else:
         grid = (bh, nq, nk)
         rows = lambda i, qi, kj: (i, qi, 0)
         cols = lambda i, qi, kj: (i, k_tile(qi, kj), 0)
         stat = lambda i, qi, kj: (i, 0, qi)
+        sel = lambda i, qi, kj: (i // heads, qi, k_tile(qi, kj))
+        sel_t = lambda i, qi, kj: (i // heads, k_tile(qi, kj), qi)
     by_rows = pl.BlockSpec((1, block_q, d), rows)
     by_cols = pl.BlockSpec((1, block_k, d), cols)
     statistic = pl.BlockSpec((1, 1, block_q), stat)
     qkv = [("q", by_rows), ("k", by_cols), ("v", by_cols)]
     if kernel == FWD_KERNEL:
+        if heads:
+            qkv.append(("sel", pl.BlockSpec((1, block_q, block_k), sel)))
         return grid, qkv, [("o", by_rows), ("lse", statistic)]
     inputs = qkv + [
         ("dO", by_rows), ("lse", statistic), ("delta", statistic)
     ]
+    if heads:
+        inputs.append(("sel_t", pl.BlockSpec((1, block_k, block_q), sel_t)))
     if kernel == BWD_DQ_KERNEL:
         return grid, inputs, [("dq", by_rows)]
     return grid, inputs, [("dk", by_cols), ("dv", by_cols)]
@@ -659,12 +717,33 @@ def hbm_traffic(bh, lq, lk, d, block_q, block_k, causal=True, itemsize=2):
 _STATIC = ("causal", "block_q", "block_k", "interpret", "w")
 
 
+def _selecting(body, at):
+    """``body`` for a call whose input number ``at``, its last, is the
+    selection: the refs come as inputs, outputs, scratch."""
+
+    def kernel(*refs, **static):
+        return body(*refs[:at], *refs[at + 1 :], sel_ref=refs[at], **static)
+
+    return kernel
+
+
 def _call(
-    kernel, body, shapes, out_shape, scratch_shapes, interpret, **static
+    kernel,
+    body,
+    shapes,
+    out_shape,
+    scratch_shapes,
+    interpret,
+    heads=None,
+    **static
 ):
     """The ``pallas_call`` of ``kernel``: its grid and block specs are
-    :func:`_plan`'s for ``shapes``, ``static`` are the body's keywords."""
-    grid, inputs, outputs = _plan(kernel, *shapes)
+    :func:`_plan`'s for ``shapes``, ``static`` are the body's keywords.
+    With ``heads`` (a selection is the last input, :func:`_plan`) the
+    call goes under its :data:`SELECTED` name."""
+    grid, inputs, outputs = _plan(kernel, *shapes, heads=heads)
+    if heads:
+        body, kernel = _selecting(body, len(inputs) - 1), SELECTED[kernel]
     return pl.pallas_call(
         functools.partial(body, **static),
         out_shape=out_shape,
@@ -679,7 +758,11 @@ def _call(
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC, inline=True)
-def _flash_fwd(q, k, v, causal, block_q, block_k, interpret, w=None):
+def _flash_fwd(
+    q, k, v, causal, block_q, block_k, interpret, w=None, selection=None
+):
+    """``selection``, where given: (b, lq, lk) int8, non-zero where a
+    query reads a key, the same for every head of a sequence."""
     q, k, v = jnp.asarray(q), jnp.asarray(k), jnp.asarray(v)
     b, lq, h, d = q.shape
     lk = k.shape[1]
@@ -704,10 +787,11 @@ def _flash_fwd(q, k, v, causal, block_q, block_k, interpret, w=None):
             pltpu.VMEM((block_k, d_ones), jnp.float32),
         ],
         interpret,
+        heads=None if selection is None else h,
         causal=causal,
         scale=scale,
         w=w,
-    )(qf, kf, vf)
+    )(qf, kf, vf, *(() if selection is None else (selection,)))
     return _unfold_heads(out, b, h), lse.reshape(b, h, lq)
 
 
@@ -725,7 +809,10 @@ def _flash_bwd(
     interpret,
     g_lse=None,
     w=None,
+    selection_t=None,
 ):
+    """``selection_t``, where given: the forward's selection
+    transposed, (b, lk, lq) int8."""
     b, lq, h, d = q.shape
     lk = k.shape[1]
     block_q, block_k = _block_sizes(lq, lk, block_q, block_k)
@@ -755,6 +842,9 @@ def _flash_bwd(
     )
     shapes = b * h, lq, lk, d, block_q, block_k, causal
     static = dict(causal=causal, scale=scale, w=w)
+    if selection_t is not None:
+        operands += (selection_t,)
+        static["heads"] = h
 
     (dq,) = _call(
         BWD_DQ_KERNEL,
@@ -903,6 +993,59 @@ def flash_attention(q, k, v, causal=False, block_q=None, block_k=None):
     return out
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _flash_selected(q, k, v, selection, block_q, block_k):
+    return _selected_fwd_rule(q, k, v, selection, block_q, block_k)[0]
+
+
+def _selected_fwd_rule(q, k, v, selection, block_q, block_k):
+    out, lse = _flash_fwd(
+        q, k, v, True, block_q, block_k, kernel_interpret_mode(),
+        selection=selection,
+    )  # fmt: skip
+    return out, (q, k, v, out, lse, selection)
+
+
+def _selected_bwd_rule(block_q, block_k, residuals, g):
+    q, k, v, out, lse, selection = residuals
+    grads = _flash_bwd(
+        q, k, v, out, lse, g, True, block_q, block_k, kernel_interpret_mode(),
+        # one transpose a call serves both backward kernels
+        selection_t=selection.transpose(0, 2, 1),
+    )  # fmt: skip
+    # the selection is discrete: its cotangent has no value
+    return grads + (np.zeros(selection.shape, jax.dtypes.float0),)
+
+
+_flash_selected.defvjp(_selected_fwd_rule, _selected_bwd_rule)
+
+
+def flash_attention_selected(
+    q, k, v, selection, block_q=None, block_k=None
+):
+    """(B, L, H, D) fused causal attention in which query ``t`` of
+    sequence ``b`` reads key ``s`` only where ``selection[b, t, s]`` is
+    non-zero: (B, Lq, Lk) int8, the same for every head of a sequence,
+    inside the causal triangle and at least one key a query (what
+    :func:`elasticdl_tpu.ops.sparse_select.select_keys` returns).
+
+        o_t = sum_{s in S_t} softmax_{s in S_t}(q_t k_s / sqrt(D)) v_s
+
+    The three kernel bodies of :func:`flash_attention`, under names of
+    their own (:data:`SELECTED`), with the selection's tile as one more
+    input (1 MiB beside a 1,024 x 1,024 tile of scores) and its test in
+    place of the diagonal's. They COMPUTE every causal score and mask:
+    a tile is skipped where the causal mask skips it, not where the
+    selection leaves it empty, so the time is that of causal attention
+    however few keys are kept. The selection takes no gradient."""
+    block_q, block_k = auto_blocks(
+        q.shape[1], k.shape[1], block_q, block_k
+    )
+    return _flash_selected(
+        q, k, v, jnp.asarray(selection, jnp.int8), block_q, block_k
+    )
+
+
 def attention_in_step(step_facts):
     """Name the attention a built step runs, from the facts
     ``ElasticDPTrainer.describe_step`` reads off that step: ``"pallas"``
@@ -911,12 +1054,43 @@ def attention_in_step(step_facts):
     a process put on the CPU by request) or ``"xla"`` (the reference
     attention :func:`pick_causal_attention` hands short or untileable
     lengths)."""
-    flash = {FWD_KERNEL, BWD_DQ_KERNEL, BWD_DKV_KERNEL}
-    if flash <= set(step_facts["mosaic_kernels"]):
-        return "pallas"
-    if flash <= set(step_facts["pallas_kernels"]):
-        return "pallas-interpret"
+    for flash in (set(SELECTED), set(SELECTED.values())):
+        if flash <= set(step_facts["mosaic_kernels"]):
+            return "pallas"
+        if flash <= set(step_facts["pallas_kernels"]):
+            return "pallas-interpret"
     return "xla"
+
+
+def selected_reference_attention(q, k, v, selection):
+    """:func:`flash_attention_selected` in plain XLA, the (L, L) scores
+    whole: what short or untileable lengths get, and the tests'
+    yardstick."""
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) * q.shape[-1] ** -0.5
+    scores = jnp.where(
+        selection[:, None] != 0, scores.astype(jnp.float32), NEG_INF
+    )
+    return jnp.einsum(
+        "bhqk,bkhd->bqhd",
+        jax.nn.softmax(scores, axis=-1),
+        v.astype(jnp.float32),
+    ).astype(q.dtype)
+
+
+def _takes_the_kernels(seq_len, use_flash, min_flash_len):
+    return (
+        use_flash
+        and seq_len >= min_flash_len
+        and divisible(seq_len, seq_len, 128, 128)
+    )
+
+
+def pick_selected_attention(seq_len, use_flash=True, min_flash_len=1024):
+    """:func:`pick_causal_attention`'s policy for attention under a
+    selection: ``fn(q, k, v, selection)``."""
+    if _takes_the_kernels(seq_len, use_flash, min_flash_len):
+        return flash_attention_selected
+    return selected_reference_attention
 
 
 def pick_causal_attention(seq_len, use_flash=True, min_flash_len=1024):
@@ -927,11 +1101,7 @@ def pick_causal_attention(seq_len, use_flash=True, min_flash_len=1024):
     crossover between them is not measured on the chip (ROADMAP S10).
     The kernels need 128-divisible lengths to tile. Both transformer
     builds call this so the threshold lives in exactly one place."""
-    if (
-        use_flash
-        and seq_len >= min_flash_len
-        and divisible(seq_len, seq_len, 128, 128)
-    ):
+    if _takes_the_kernels(seq_len, use_flash, min_flash_len):
         return lambda q, k, v: flash_attention(q, k, v, True)
     from elasticdl_tpu.parallel.ring_attention import reference_attention
 

@@ -20,16 +20,21 @@ an ``expert`` axis ``reference_moe`` runs every expert on every token.
 routing even.
 
 **The held-share, dropless layer** (:func:`sigmoid_topk_route`,
-:func:`held_experts_apply`, :func:`expert_bias_update`; the zoo's
+:func:`softmax_topk_route`, :func:`held_experts_apply`,
+:func:`held_experts_apply_masked`, :func:`expert_bias_update`; the zoo's
 ``hybrid_moe_lm``). A device is told which experts it holds
 (``first_expert_held``, ``experts_held``: MANY a device), routes over
 all ``num_experts`` of the layer, and computes the part of the result
 its own experts give. Scores are sigmoids, selection adds a bias that
-takes no gradient and is steered by the load (no auxiliary loss), the
+takes no gradient and is steered by the load (no auxiliary loss), or
+a softmax with no bias at all; either way the
 gates are normalised over all the selected experts, held or not. The
 ``T * k`` assignments are sorted so that those of held experts come
 first, grouped by expert, and go through ops/grouped_matmul.py: a
-static buffer of ``T * k`` rows, no capacity, no dropped token. With
+static buffer of ``T * k`` rows, no capacity, no dropped token
+(:func:`held_experts_apply_masked` gives the same share with no
+dispatch at all, every held expert over every token, in a time the
+routing cannot move). With
 ``experts_held == num_experts`` it is the whole layer. It has NO
 exchange yet: it is one chip's share of an expert-parallel deployment
 whose other chips are absent, and what their experts would have added
@@ -54,6 +59,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 
@@ -250,6 +256,19 @@ def sigmoid_topk_route(router_logits, expert_bias, k, scaling=1.0):
     return selected.astype(jnp.int32), gates * scaling
 
 
+def softmax_topk_route(router_logits, k, scaling=1.0):
+    """Softmax scores and no bias: ``p = softmax(logits)`` in float32;
+    ``selected`` the ``k`` largest (ties to the lower index,
+    ``lax.top_k``'s rule); ``gate_e = p_e / (sum of p over the
+    selected) * scaling``. The largest probability is among the
+    selected and is at least ``1 / num_experts``, so the sum is never
+    zero and nothing stands beside it."""
+    probs = jax.nn.softmax(router_logits.astype(jnp.float32), axis=-1)
+    picked, selected = jax.lax.top_k(probs, k)
+    gates = picked / jnp.sum(picked, axis=-1, keepdims=True)
+    return selected.astype(jnp.int32), gates * scaling
+
+
 def expert_assignments(selected, num_experts):
     """(E,) int32: how many of the (T, k) assignments each expert got."""
     return jnp.bincount(selected.reshape(-1), length=num_experts).astype(
@@ -345,6 +364,67 @@ def held_experts_apply(x, selected, gates, w_in, w_out, first_expert_held):
     return (back * gates[..., None]).sum(axis=1).astype(x.dtype)
 
 
+def _masked_share(x, gate, w_in, w_out):
+    """``sum_g gate[:, g] * W_2g (silu(x W_1g) * (x W_3g))`` as three
+    plain ``(T, d) x (d, G * f)`` products; ``gate`` (T, G) float32."""
+    held, d, _ = w_in.shape
+    width = w_out.shape[1]
+
+    def side_by_side(w):  # (G, d, f) -> (d, G * f)
+        return w.transpose(1, 0, 2).reshape(d, held * width)
+
+    # W_1 and W_3 are parted and laid out here, where that moves a
+    # matrix, and not behind a product, where it would move (T, G, 2f)
+    up = checkpoint_name(x @ side_by_side(w_in[..., :width]), "held_up")
+    across = checkpoint_name(x @ side_by_side(w_in[..., width:]), "held_up")
+    hidden = jax.nn.silu(up) * across
+    # an expert's gate over its own columns, column block by block: a
+    # (T, G, f) view of ``hidden`` is another tiling, a copy each way
+    act = jnp.concatenate(
+        [
+            hidden[:, g * width : (g + 1) * width].astype(jnp.float32)
+            * gate[:, g : g + 1]
+            for g in range(held)
+        ],
+        axis=1,
+    ).astype(x.dtype)
+    return (act @ w_out.reshape(held * width, d)).astype(x.dtype)
+
+
+def held_experts_apply_masked(
+    x, selected, gates, w_in, w_out, first_expert_held
+):
+    """:func:`held_experts_apply`'s result by shapes alone: EVERY held
+    expert over every token, its gate zero where the token did not
+    select it. No sort, no gather and no grouped product: three plain
+    matrix products over ``G * f`` hidden units (the gate multiplies
+    the hidden units of its expert in front of ``W_2``, so the sum over
+    experts is the last product's own accumulation). What it costs is
+    ``G`` experts a token whatever was routed, against
+    ``num_experts_per_tok * G / num_experts`` routed here in
+    expectation: it is for a share whose time must not follow the
+    routing (a router without balancing sends every token of a batch
+    to the same few experts, and how many of those are held is luck),
+    and affordable only where ``G`` is a few times
+    ``num_experts_per_tok``. The backward pass keeps the first two
+    products' results and recomputes what is elementwise behind them
+    (the float32 hidden units are four times those two)."""
+    held = w_in.shape[0]
+    local = selected - first_expert_held
+    gate = jnp.sum(
+        jnp.where(
+            local[..., None] == jnp.arange(held, dtype=local.dtype),
+            gates[..., None].astype(jnp.float32),
+            0.0,
+        ),
+        axis=1,
+    )  # (T, G): an expert is selected at most once a token
+    return jax.checkpoint(
+        _masked_share,
+        policy=jax.checkpoint_policies.save_only_these_names("held_up"),
+    )(x, gate, w_in, w_out)
+
+
 def window_routing_counters(before, after, first_expert_held, experts_held):
     """What a window's ``train_window`` event says of the routing, from
     two host copies of the model's :data:`MOE_STATE_COLLECTION` (the
@@ -355,7 +435,13 @@ def window_routing_counters(before, after, first_expert_held, experts_held):
     assignments (steps x layers x T x k); ``moe_rows_max_expert`` and
     ``moe_rows_mean_expert``: the most and the mean over the (layer,
     held expert) pairs, each one group of a grouped product, of the
-    window's totals; ``expert_bias_abs_max`` at the window's end."""
+    window's totals. Of a routing that keeps a selection bias:
+    ``expert_bias_abs_max`` at the window's end. Of a model whose
+    attention selects its keys (``sel_pairs_kept`` in its state):
+    ``sel_pairs_kept`` and ``sel_pairs_causal``, the (query, key) pairs
+    the selections kept and the causal pairs they chose among, over the
+    window's steps, sequences and selecting layers (not times the
+    heads)."""
     import numpy as np
 
     def leaves(tree, name):
@@ -374,12 +460,22 @@ def window_routing_counters(before, after, first_expert_held, experts_held):
             np.int64
         )
     here = made[:, first_expert_held : first_expert_held + experts_held]
-    return {
+    counters = {
         "moe_rows_here": int(here.sum()),
         "moe_rows_routed": int(made.sum()),
         "moe_rows_max_expert": int(here.max()),
         "moe_rows_mean_expert": float(here.mean()),
-        "expert_bias_abs_max": float(
-            max(np.abs(b).max() for b in leaves(after, "expert_bias"))
-        ),
     }
+    biases = leaves(after, "expert_bias")
+    if biases:
+        counters["expert_bias_abs_max"] = float(
+            max(np.abs(b).max() for b in biases)
+        )
+    for name in ("sel_pairs_kept", "sel_pairs_causal"):
+        now = leaves(after, name)
+        if now:
+            pairs = np.stack(now)  # (selecting layers,) int32, wrapping
+            if before is not None:
+                pairs = pairs - np.stack(leaves(before, name))
+            counters[name] = int(pairs.astype(np.int64).sum())
+    return counters

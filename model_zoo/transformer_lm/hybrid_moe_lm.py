@@ -1,15 +1,21 @@
 """Hybrid sparse decoder LM: gated short convolutions beside grouped-head
-attention, a dense SwiGLU MLP in the leading layers and a mixture of
-experts in the rest, of which this device holds a share.
+attention, full or over a learned selection of keys, a dense SwiGLU MLP
+in the leading layers and a mixture of experts in the rest, of which
+this device holds a share.
 
 The layer stack is built from one pattern string, a letter a layer:
-``c`` a gated short convolution, ``a`` full causal attention
+``c`` a gated short convolution, ``a`` full causal attention, ``s``
+attention over the ``select_topk`` keys an indexer picks for each query
 (``layer_pattern=caccc``: no comma, it travels in ``--model_params``).
-The first ``num_dense_layers`` layers have the dense MLP, the others the
-expert layer. With ``h = RMSNorm(x)`` (weight only, no bias anywhere):
+The first ``num_dense_layers`` layers (none is fine) have the dense
+MLP, the others the expert layer. With ``h = RMSNorm(x)`` (weight only,
+no bias anywhere):
 
     x <- x + Op(RMSNorm(x));  x <- x + FF(RMSNorm(x))
-    logits = RMSNorm(x_last) E^T                      (tied head)
+    logits = RMSNorm(x_last) E^T          (``tie_head``, the default)
+    logits = RMSNorm(x_last) W_head       (untied: a matrix of its own)
+
+Embedding and head are over the slice of the vocabulary held here.
 
 - ``c``: ``[B, C, X] = split(h W_in, 3)``; ``u = B * X``;
   ``v_t = sum_{j<K} k_j * u_{t-j}`` (depthwise, causal, ``u_{<0} = 0``);
@@ -20,18 +26,45 @@ expert layer. With ``h = RMSNorm(x)`` (weight only, no bias anywhere):
   ``j`` serves query heads ``j*g .. j*g+g-1``. The KV heads are repeated
   in front of ops/flash_attention.py (grouped heads inside the kernel
   are not built).
+- ``s``: ``a`` with ``o_t = sum_{s in S_t} softmax_{s in S_t}(q_t k_s /
+  sqrt(hd)) v_s``. The indexer, float32, on ``hb = stop_gradient(h)``:
+  ``qI = hb W_qI`` (``indexer_heads`` x ``indexer_dim``), ``kI = hb
+  W_kI`` (one key head), ``w = hb W_w``; ``I[t, s] = sum_j w[t, j]
+  relu(qI[t, j] . kI[s])``; ``S_t`` the ``s <= t`` with the
+  ``select_topk`` largest, every ``s <= t`` while ``t < select_topk``
+  (ops/sparse_select.py). No rotation, norm or scale inside it. The
+  selection is discrete, so under the LM loss the indexer's three
+  matrices get no gradient; this module's ``optimizer`` keeps them out
+  of AdamW altogether (no moments, no decay): a step hands them back
+  bit for bit. The loss that would train them (alignment with the
+  attention's own probabilities) is not built.
 - dense FF: ``W_2 (silu(h W_1) * (h W_3))``.
-- expert FF: parallel/expert.py's held-share, dropless layer: sigmoid
-  scores over ``num_experts`` in float32, ``num_experts_per_tok``
-  selected with a bias that only steers the selection, gates
-  normalised over the selected; this device computes the part
+- expert FF: parallel/expert.py's held-share, dropless layer: scores
+  over ``num_experts`` in float32, ``num_experts_per_tok`` selected,
+  gates normalised over the selected; this device computes the part
   experts ``first_expert_held .. first_expert_held + experts_held - 1``
-  give, and that partial result goes on to the next layer.
+  give, and that partial result goes on to the next layer. ``routing``
+  names the scores: ``sigmoid_bias`` (the default), a sigmoid of each
+  logit, selected with a bias added that only steers the selection and
+  follows the load (``expert_bias_rate`` a step; no auxiliary loss),
+  gates ``score_e / (sum of the scores selected + 1e-6)``;
+  ``softmax``, ``p = softmax(h W_r)``, the largest selected, no bias
+  and no bias state, gates ``p_e / sum of the p selected``.
+  ``expert_apply`` names how the share is computed: ``grouped`` (the
+  default), the assignments sorted by expert and three grouped
+  products over the rows routed here, whose time follows those rows;
+  ``masked``, every held expert over every token with its gate zero
+  where the token did not select it, two plain products whose time
+  follows from the shapes alone, at ``experts_held`` experts a token
+  instead of the ``num_experts_per_tok * experts_held / num_experts``
+  routed here in expectation. Both give the same result.
 
-``expert_bias`` and the ``assignments`` counters are state, not
-parameters (collection ``moe_state``): written only where the
-collection is mutable (a training step), and with the collection
-absent the bias is zero, its initial value.
+``expert_bias`` (of ``sigmoid_bias`` routing only), the ``assignments``
+counters and a selecting attention's ``sel_pairs_kept`` /
+``sel_pairs_causal`` counters are state, not parameters (collection
+``moe_state``): written only where the collection is mutable (a
+training step), and with the collection absent the bias is zero, its
+initial value.
 
 What the two LMs of this directory share comes from the sibling module
 (a zoo module is loaded by path, not as a package): ``_rotary``,
@@ -45,9 +78,14 @@ from typing import Any
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import optax
 
 from elasticdl_tpu.common.model_utils import load_module
-from elasticdl_tpu.ops.flash_attention import pick_causal_attention
+from elasticdl_tpu.ops import sparse_select
+from elasticdl_tpu.ops.flash_attention import (
+    pick_causal_attention,
+    pick_selected_attention,
+)
 from elasticdl_tpu.parallel import expert
 
 _lm = load_module(
@@ -56,11 +94,47 @@ _lm = load_module(
     )
 )
 loss = _lm.loss
-optimizer = _lm.optimizer
 dataset_fn = _lm.dataset_fn
 eval_metrics_fn = _lm.eval_metrics_fn
 
-CONV, ATTENTION = "c", "a"
+CONV, ATTENTION, SELECTING = "c", "a", "s"
+ROUTINGS = ("sigmoid_bias", "softmax")
+EXPERT_APPLIES = ("grouped", "masked")
+# every parameter of an indexer lies under a module of this name
+INDEXER = "indexer"
+
+
+def optimizer(lr=3e-3):
+    """The sibling LM's optimizer. Of a model whose attention selects
+    its keys, over every leaf but the indexers': those get no gradient
+    from the LM loss, and AdamW's decay alone would shrink them; they
+    are left out of it (no moments, no decay, no update). A model
+    without an indexer gets the sibling's optimizer and state as they
+    are."""
+    plain = _lm.optimizer(lr)
+
+    def labels(params):
+        return jax.tree_util.tree_map_with_path(
+            lambda path, _: "frozen"
+            if any(getattr(key, "key", None) == INDEXER for key in path)
+            else "trained",
+            params,
+        )
+
+    sparing = optax.multi_transform(
+        {"trained": plain, "frozen": optax.set_to_zero()}, labels
+    )
+
+    def pick(tree):
+        held = jax.tree_util.tree_leaves(labels(tree))
+        return sparing if "frozen" in held else plain
+
+    return optax.GradientTransformation(
+        lambda params: pick(params).init(params),
+        lambda updates, state, params=None: pick(updates).update(
+            updates, state, params
+        ),
+    )
 
 
 def _per_expert_init():
@@ -103,8 +177,42 @@ class ShortConv(nn.Module):
         )(c * v)
 
 
+class Indexer(nn.Module):
+    """Which keys each query reads: (B, L, L) int8, float32 inside."""
+
+    heads: int
+    head_dim: int
+    topk: int
+
+    @nn.compact
+    def __call__(self, h):
+        h = jax.lax.stop_gradient(h).astype(jnp.float32)
+
+        def project(features, name):
+            return nn.DenseGeneral(
+                features=features,
+                use_bias=False,
+                dtype=jnp.float32,
+                precision=jax.lax.Precision.HIGHEST,
+                name=name,
+            )(h)
+
+        operands = (
+            project((self.heads, self.head_dim), "query"),
+            project(self.head_dim, "key"),
+            project(self.heads, "weights"),
+        )
+        if self.is_initializing():
+            # the variables are made, and no shape depends on what is
+            # selected: an eager init (the trainer's) is spared the
+            # compile of every layer's loops
+            return None
+        return sparse_select.select_keys(*operands, self.topk)
+
+
 class GroupedAttention(nn.Module):
-    """The full-attention operator over grouped KV heads."""
+    """The attention operator over grouped KV heads: over every earlier
+    key, or, with ``select_topk``, over those its indexer selects."""
 
     num_heads: int
     num_kv_heads: int
@@ -113,6 +221,28 @@ class GroupedAttention(nn.Module):
     norm_eps: float
     dtype: Any
     use_flash: bool
+    select_topk: int = 0
+    indexer_heads: int = 0
+    indexer_dim: int = 0
+
+    def _selection(self, h):
+        """The indexer's selection, counted into the module's state
+        where a training step holds it mutable (the counters wrap;
+        readers take differences)."""
+        selection = Indexer(
+            self.indexer_heads, self.indexer_dim, self.select_topk,
+            name=INDEXER,
+        )(h)  # fmt: skip
+        collection = expert.MOE_STATE_COLLECTION
+        if self.is_initializing() or self.is_mutable_collection(collection):
+            zero = lambda: jnp.zeros((), jnp.int32)
+            kept = self.variable(collection, "sel_pairs_kept", zero)
+            causal = self.variable(collection, "sel_pairs_causal", zero)
+            if selection is not None:
+                b, l = h.shape[:2]
+                kept.value = kept.value + jnp.sum(selection, dtype=jnp.int32)
+                causal.value = causal.value + b * l * (l + 1) // 2
+        return selection
 
     @nn.compact
     def __call__(self, h, positions):
@@ -136,10 +266,18 @@ class GroupedAttention(nn.Module):
         q = _lm._rotary(q, positions, self.rope_theta)
         k = _lm._rotary(k, positions, self.rope_theta)
         group = self.num_heads // self.num_kv_heads
-        attention_fn = pick_causal_attention(h.shape[1], self.use_flash)
-        attn = attention_fn(
-            q, jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
-        )
+        k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
+        if self.select_topk:
+            selection = self._selection(h)
+            attn = (
+                q  # initialising: the output's shape, nothing attended
+                if selection is None
+                else pick_selected_attention(h.shape[1], self.use_flash)(
+                    q, k, v, selection
+                )
+            )
+        else:
+            attn = pick_causal_attention(h.shape[1], self.use_flash)(q, k, v)
         return nn.DenseGeneral(
             features=h.shape[-1],
             axis=(-2, -1),
@@ -175,6 +313,8 @@ class HeldExperts(nn.Module):
     routed_scaling_factor: float
     expert_bias_rate: float
     dtype: Any
+    routing: str = ROUTINGS[0]
+    apply: str = EXPERT_APPLIES[0]
 
     @nn.compact
     def __call__(self, h):
@@ -202,22 +342,32 @@ class HeldExperts(nn.Module):
         )
         collection = expert.MOE_STATE_COLLECTION
         has_state = self.is_initializing() or self.has_variable(
-            collection, "expert_bias"
+            collection, "assignments"
         )
-        bias = jnp.zeros((self.num_experts,), jnp.float32)
         if has_state:
-            bias_state = self.variable(
-                collection, "expert_bias", lambda: bias
-            )
             made = self.variable(
                 collection,
                 "assignments",
                 lambda: jnp.zeros((self.num_experts,), jnp.int32),
             )
-            bias = bias_state.value
-        selected, gates = expert.sigmoid_topk_route(
-            logits, bias, self.num_experts_per_tok, self.routed_scaling_factor
-        )
+        biased = self.routing == ROUTINGS[0]
+        if biased:
+            bias = jnp.zeros((self.num_experts,), jnp.float32)
+            if has_state:
+                bias_state = self.variable(
+                    collection, "expert_bias", lambda: bias
+                )
+                bias = bias_state.value
+            selected, gates = expert.sigmoid_topk_route(
+                logits,
+                bias,
+                self.num_experts_per_tok,
+                self.routed_scaling_factor,
+            )
+        else:
+            selected, gates = expert.softmax_topk_route(
+                logits, self.num_experts_per_tok, self.routed_scaling_factor
+            )
         if (
             has_state
             and not self.is_initializing()
@@ -226,11 +376,17 @@ class HeldExperts(nn.Module):
             # after the step, outside the gradient: this step selected
             # with the bias as it was
             counts = expert.expert_assignments(selected, self.num_experts)
-            bias_state.value = expert.expert_bias_update(
-                bias, counts, self.expert_bias_rate
-            )
+            if biased:
+                bias_state.value = expert.expert_bias_update(
+                    bias, counts, self.expert_bias_rate
+                )
             made.value = made.value + counts
-        out = expert.held_experts_apply(
+        apply = (
+            expert.held_experts_apply
+            if self.apply == EXPERT_APPLIES[0]
+            else expert.held_experts_apply_masked
+        )
+        out = apply(
             tokens,
             selected,
             gates,
@@ -260,20 +416,36 @@ class HybridMoELM(nn.Module):
     norm_eps: float = 1e-5
     routed_scaling_factor: float = 1.0
     expert_bias_rate: float = 1e-3
+    routing: str = ROUTINGS[0]
+    select_topk: int = 0
+    indexer_heads: int = 4
+    indexer_dim: int = 16
+    tie_head: bool = True
+    expert_apply: str = EXPERT_APPLIES[0]
     dtype: Any = jnp.float32
     use_flash: bool = True
 
     def step_facts(self):
         """What the worker's ``step_built`` event says of this model's
         layout (scalars), and what its window counters are read with."""
-        return {
+        facts = {
             "expert_layers": len(self.layer_pattern) - self.num_dense_layers,
             "experts_held": self.experts_held,
             "experts_routed": self.num_experts,
             "first_expert_held": self.first_expert_held,
+            "routing": self.routing,
+            "tie_head": int(self.tie_head),
+            "expert_apply": self.expert_apply,
             "conv_layers": self.layer_pattern.count(CONV),
             "attention_layers": self.layer_pattern.count(ATTENTION),
         }
+        if SELECTING in self.layer_pattern:
+            facts.update(
+                sparse_layers=self.layer_pattern.count(SELECTING),
+                select_topk=self.select_topk,
+                indexer_heads=self.indexer_heads,
+            )
+        return facts
 
     @nn.compact
     def __call__(self, features, training=False):
@@ -310,6 +482,9 @@ class HybridMoELM(nn.Module):
                     norm_eps=self.norm_eps,
                     dtype=self.dtype,
                     use_flash=self.use_flash,
+                    select_topk=self.select_topk if kind == SELECTING else 0,
+                    indexer_heads=self.indexer_heads,
+                    indexer_dim=self.indexer_dim,
                     name=layer + "attention",
                 )(h, positions)
             h = norm(layer + "ffn_norm")(x)
@@ -326,23 +501,53 @@ class HybridMoELM(nn.Module):
                         routed_scaling_factor=self.routed_scaling_factor,
                         expert_bias_rate=self.expert_bias_rate,
                         dtype=self.dtype,
+                        routing=self.routing,
+                        apply=self.expert_apply,
                         name=layer + "moe",
                     )(h)
         x = norm("final_norm")(x)
-        # weight-tied head over the slice of the vocabulary held here
-        return embed_layer.attend(x.astype(jnp.float32))
+        # the head over the slice of the vocabulary held here
+        if self.tie_head:
+            return embed_layer.attend(x.astype(jnp.float32))
+        return nn.Dense(
+            self.vocab_size, use_bias=False, dtype=self.dtype, name="head"
+        )(x)
 
 
 def custom_model(dtype="float32", **sizes):
     """``HybridMoELM(**sizes)``; every size has the toy default of the
     class, and a name it does not know is refused."""
     pattern = str(sizes.get("layer_pattern", HybridMoELM.layer_pattern))
-    if not pattern or set(pattern) - {CONV, ATTENTION}:
+    unknown = sorted(set(pattern) - {CONV, ATTENTION, SELECTING})
+    if not pattern or unknown:
         raise ValueError(
-            "layer_pattern %r: a letter a layer, %r a short convolution, "
-            "%r full attention" % (pattern, CONV, ATTENTION)
+            "layer_pattern %r holds %s: a letter a layer, %r a short "
+            "convolution, %r full attention, %r attention over selected keys"
+            % (pattern, unknown or "no layer", CONV, ATTENTION, SELECTING)
         )
     model = HybridMoELM(dtype=jnp.dtype(dtype), **sizes)
+    if model.routing not in ROUTINGS:
+        raise ValueError(
+            "routing %r is not one of %s" % (model.routing, ", ".join(ROUTINGS))
+        )
+    if model.expert_apply not in EXPERT_APPLIES:
+        raise ValueError(
+            "expert_apply %r is not one of %s"
+            % (model.expert_apply, ", ".join(EXPERT_APPLIES))
+        )
+    if SELECTING in pattern and not (
+        model.select_topk > 0 and model.indexer_heads > 0 and model.indexer_dim > 0
+    ):
+        raise ValueError(
+            "layer_pattern %r selects keys: select_topk=%r, indexer_heads=%r "
+            "and indexer_dim=%r all have to be positive"
+            % (pattern, model.select_topk, model.indexer_heads, model.indexer_dim)
+        )
+    if not 0 < model.num_experts_per_tok <= model.num_experts:
+        raise ValueError(
+            "num_experts_per_tok=%r is not between 1 and num_experts=%r"
+            % (model.num_experts_per_tok, model.num_experts)
+        )
     if not 0 <= model.num_dense_layers <= len(pattern):
         raise ValueError("num_dense_layers outside the pattern")
     if model.num_heads % model.num_kv_heads:

@@ -258,3 +258,81 @@ def test_flash_kernels_compile_for_the_v5e_at_the_benchmarks_shapes(
         assert name in text
     assert "f32[%d,%d,128]" % (batch * heads, length) not in text
     assert "f32[%d,1,%d]" % (batch * heads, length) in text
+
+
+def test_flash_kernels_under_a_selection_compile_for_the_v5e_at_the_cells_shape(
+    one_chip,
+):
+    """`keyevl2-ep8-l8192`: one sequence of 8,192, 32 heads of 128, the
+    selection as (1, 8192, 8192) int8 in (1, 1024, 1024) tiles beside
+    the 1,024 x 1,024 tile of scores. An int8 tile, its widening and
+    the scoped VMEM with it are Mosaic's to refuse; interpret mode
+    refuses none of them. The calls go under their own names."""
+    from elasticdl_tpu.ops import flash_attention as fa
+
+    length, heads = 8192, 32
+    x = jax.ShapeDtypeStruct(
+        (1, length, heads, 128), jnp.bfloat16, sharding=one_chip
+    )
+    selection = jax.ShapeDtypeStruct(
+        (1, length, length), jnp.int8, sharding=one_chip
+    )
+    tiles = fa.auto_blocks(length, length)
+    assert tiles == (1024, 1024)
+
+    def fwd_and_bwd(q, k, v, g, selection):
+        out, lse = fa._flash_fwd(
+            q, k, v, True, *tiles, False, selection=selection
+        )
+        return out, fa._flash_bwd(
+            q, k, v, out, lse, g, True, *tiles, False,
+            selection_t=selection.transpose(0, 2, 1),
+        )  # fmt: skip
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        text = (
+            jax.jit(fwd_and_bwd).lower(x, x, x, x, selection).compile().as_text()
+        )
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+    assert text.count("tpu_custom_call") >= 3
+    for name in fa.SELECTED.values():
+        assert name in text
+    for name in fa.SELECTED:
+        assert name + '"' not in text
+
+
+def test_the_key_selection_compiles_for_the_v5e_as_loops_that_carry_int8_blocks(
+    one_chip,
+):
+    """The cell's selection (16 heads of 64, 2,048 of 8,192 keys): the
+    radix search's unsigned compares and the block loops are XLA's to
+    build for the chip, and `select_ms_per_step` finds the selection in
+    a trace by exactly this: `while` ops whose carried tuple holds a
+    4-d int8 array, one a run of queries."""
+    import re
+
+    from elasticdl_tpu.ops import sparse_select
+
+    def shape(*dims):
+        return jax.ShapeDtypeStruct(dims, jnp.float32, sharding=one_chip)
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        text = (
+            jax.jit(lambda q, k, w: sparse_select.select_keys(q, k, w, 2048))
+            .lower(shape(1, 8192, 16, 64), shape(1, 8192, 64), shape(1, 8192, 16))
+            .compile()
+            .as_text()
+        )
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+    loops = [
+        line
+        for line in text.splitlines()
+        if re.match(r"^\s*%?while[.\d]* = \(.*?\bs8\[\d+,\d+,\d+,\d+\]", line)
+    ]
+    assert len(loops) == 4
+    for keys in (2048, 4096, 6144, 8192):
+        assert any("s8[4,1,512,%d]" % keys in line for line in loops)
