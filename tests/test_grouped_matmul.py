@@ -310,7 +310,10 @@ def test_the_key_selection_compiles_for_the_v5e_as_loops_that_carry_int8_blocks(
     radix search's unsigned compares and the block loops are XLA's to
     build for the chip, and `select_ms_per_step` finds the selection in
     a trace by exactly this: `while` ops whose carried tuple holds a
-    4-d int8 array, one a run of queries."""
+    4-d int8 array, one a run of queries. The rows under the top-k
+    (queries 0..2,047) are the causal triangle and have no loop; the
+    other twelve blocks go in six runs of two, each against the keys up
+    to its own end."""
     import re
 
     from elasticdl_tpu.ops import sparse_select
@@ -333,6 +336,7 @@ def test_the_key_selection_compiles_for_the_v5e_as_loops_that_carry_int8_blocks(
         for line in text.splitlines()
         if re.match(r"^\s*%?while[.\d]* = \(.*?\bs8\[\d+,\d+,\d+,\d+\]", line)
     ]
-    assert len(loops) == 4
-    for keys in (2048, 4096, 6144, 8192):
-        assert any("s8[4,1,512,%d]" % keys in line for line in loops)
+    runs = sparse_select.block_runs(8192, 2048, 512)[1]
+    assert len(loops) == len(runs) == 6
+    for lo, hi in runs:
+        assert any("s8[%d,1,512,%d]" % (hi - lo, hi * 512) in line for line in loops)

@@ -355,24 +355,144 @@ def test_every_key_below_the_topk_and_exactly_k_above_it(reference, topk, ids):
     assert (kept, of) == (got[0].sum(), causal.sum())
 
 
-@pytest.mark.parametrize("block, spans", [(16, 4), (16, 3), (64, 4), (32, 1)])
-def test_the_blocking_of_the_selection_changes_no_result(block, spans):
+@pytest.mark.parametrize("block, topk", [(16, TOPK), (8, TOPK), (64, TOPK), (32, 40)])
+def test_the_blocking_of_the_selection_changes_no_result(block, topk):
     q, k, w = _indexer_inputs(LENGTH, 5, seed=2)
-    whole = sparse_select.select_keys(q, k, w, TOPK, block=LENGTH, spans=1)
-    cut = sparse_select.select_keys(q, k, w, TOPK, block=block, spans=spans)
+    whole = sparse_select.select_keys(q, k, w, topk, block=LENGTH)
+    cut = sparse_select.select_keys(q, k, w, topk, block=block)
     np.testing.assert_array_equal(np.asarray(whole), np.asarray(cut))
 
 
-def test_the_kth_largest_counts_duplicates_and_signed_zeros():
+def _scored_inputs(scores):
+    """Indexer operands of one head of one dimension, so that the test
+    writes the scores down: that of (t, s) is ``weight * relu(query[t]
+    * key[s])``."""
+    query, key, weight = scores
+    length = len(key)
+    return (
+        jnp.asarray(query, jnp.float32).reshape(1, length, 1, 1),
+        jnp.asarray(key, jnp.float32).reshape(1, length, 1),
+        jnp.full((1, length, 1), weight, jnp.float32),
+    )
+
+
+def _tied_scores(case, length):
+    """Rows of scores that the tie rule decides, as :func:`_scored_inputs`
+    takes them."""
+    ones = np.ones(length, np.float32)
+    position = np.arange(length)
+    if case == "all_equal":
+        return ones, ones, 1.0
+    if case == "all_zero":  # relu of a negative product: 0.0 everywhere
+        return ones, -ones, 1.0
+    if case == "zeros_and_minus_infinity":
+        # -inf (an infinite product under a negative weight), -0.0 and
+        # -1.0 mixed; the keys after a row are -inf too
+        key = np.where(position % 4 == 1, np.inf, np.where(position % 4 == 2, 0.0, 1.0))
+        return ones, key, -1.0
+    if case == "runs_at_the_threshold":
+        # runs of eight equal keys: the threshold falls inside a run
+        return ones, (position // 8 % 5).astype(np.float32), 1.0
+    raise ValueError(case)
+
+
+@pytest.mark.parametrize(
+    "case", ["all_equal", "all_zero", "zeros_and_minus_infinity", "runs_at_the_threshold"]
+)
+@pytest.mark.parametrize("length, topk, block", [(64, 16, 16), (64, 40, 16), (32, 48, 16), (96, 20, 32)])
+def test_ties_at_the_threshold_go_to_the_lower_index(reference, case, length, topk, block):
+    """Whole rows of equal scores, zeros of both signs and runs of
+    equal keys across the threshold, at lengths on both sides of the
+    top-k and with the top-k inside a block: the reference's
+    ``lax.top_k`` bit for bit."""
+    q, k, w = _scored_inputs(_tied_scores(case, length))
+    got = np.asarray(sparse_select.select_keys(q, k, w, topk, block=block))
+    want = reference.selection(q, k, w, 0, topk, reference._product(lambda x: x))
+    np.testing.assert_array_equal(got != 0, np.asarray(want))
+    np.testing.assert_array_equal(
+        got[0].sum(-1), np.minimum(np.arange(length) + 1, topk)
+    )
+
+
+@pytest.mark.parametrize("radix_bits", [1, 2, 4, 8, 16])
+def test_the_kth_largest_counts_duplicates_and_signed_zeros(monkeypatch, radix_bits):
+    """The answer is the bits' and not the passes': 32, 16, 8, 4 or 2
+    passes find the same threshold (2 bits a pass is the module's)."""
+    assert sparse_select._RADIX_BITS == 2
+    monkeypatch.setattr(sparse_select, "_RADIX_BITS", radix_bits)
     x = jax.random.normal(jax.random.PRNGKey(0), (3, 50))
     x = x.at[0, :10].set(0.0).at[0, 10:20].set(-0.0).at[1, :5].set(-jnp.inf)
     keys = sparse_select._ordered_bits(x)
     for k in (1, 7, 25, 50):
         want = jnp.sort(x + 0.0, axis=-1)[:, ::-1][:, k - 1]
         np.testing.assert_array_equal(
-            np.asarray(sparse_select.kth_largest(keys, k)),
+            # a row of x a column: the search runs down the major axis
+            np.asarray(sparse_select.kth_largest(keys.T, k)),
             np.asarray(sparse_select._ordered_bits(want)),
         )
+
+
+def test_the_kth_largest_takes_a_k_a_row_and_keys_of_few_bits():
+    """The tie rule's use: small integers (a key's place from the
+    row's end, 0 where it does not count), a k of each row's own."""
+    places = jnp.asarray([[0, 9, 0, 7, 6, 0, 4, 0, 2, 1], [10, 9, 8, 0, 0, 0, 0, 3, 2, 1]], jnp.uint32)
+    got = sparse_select.kth_largest(places.T, jnp.asarray([3, 5]), bits=4)
+    np.testing.assert_array_equal(np.asarray(got), [6, 2])
+
+
+PARENT_RUNS = [(0, 4), (4, 8), (8, 12), (12, 16)]  # four runs over every block
+
+
+@pytest.mark.parametrize(
+    "length, topk, block, runs, scores, pairs",
+    [
+        # the layout until PR 32: 512 x 512 x 4 x (4 + 8 + 12 + 16)
+        (8192, 2048, 512, PARENT_RUNS, 41943040, 31460352),
+        # rows 0..2047 unscored, six runs of two: 2 x (6 + 8 + .. + 16)
+        (8192, 2048, 512, None, 34603008, 31460352),
+        # a row a run scores its own keys and no other
+        (64, 16, 1, [(t, t + 1) for t in range(16, 64)], 1992, 1992),
+        # the top-k inside a block: rows 32..39 are scored and not needed
+        (64, 40, 16, None, 2 * 16 * 64, 24 * (41 + 64) // 2),
+        # nothing to score
+        (64, 64, 16, None, 0, 0),
+    ],
+    ids=["parent", "cell", "row_a_run", "topk_inside_a_block", "under_the_topk"],
+)
+def test_the_selections_work_ratio_against_hand_counts(length, topk, block, runs, scores, pairs):
+    ratio = sparse_select.select_work_ratio(length, topk, block, runs)
+    assert ratio == (scores / pairs if pairs else 1.0)
+    if runs is None and pairs:
+        assert ratio <= 1.15 or length // block - topk // block < 4
+
+
+@pytest.mark.parametrize(
+    "length, topk, block, free, runs",
+    [
+        (8192, 2048, 512, 4, [(4, 6), (6, 8), (8, 10), (10, 12), (12, 14), (14, 16)]),
+        (16384, 2048, 512, 4, [(4, 9), (9, 14), (14, 19), (19, 24), (24, 28), (28, 32)]),
+        (4096, 2048, 512, 4, [(4, 6), (6, 8)]),
+        (64, 40, 16, 2, [(2, 4)]),
+        (64, 20, 16, 1, [(1, 4)]),
+        (2048, 2048, 512, 4, []),
+        (1024, 2048, 512, 2, []),
+    ],
+)
+def test_the_runs_of_blocks_follow_length_topk_and_block(length, topk, block, free, runs):
+    """Whole blocks under the top-k are not scored; the others go in at
+    most six runs of two blocks or more, each up to its own end."""
+    assert sparse_select.block_runs(length, min(topk, length), block) == (free, runs)
+    assert len(runs) <= sparse_select.MAX_RUNS
+
+
+def test_rows_under_the_topk_are_made_without_a_loop():
+    """At a length within the top-k the whole selection is the causal
+    triangle: no product, no search, no loop in the program."""
+    q, k, w = _indexer_inputs(LENGTH, 5)
+    text = jax.jit(lambda q, k, w: sparse_select.select_keys(q, k, w, LENGTH, block=16)).lower(q, k, w).as_text()
+    assert "while" not in text and "dot_general" not in text
+    got = np.asarray(sparse_select.select_keys(q, k, w, LENGTH, block=16))
+    np.testing.assert_array_equal(got[0], np.tril(np.ones((LENGTH, LENGTH), np.int8)))
 
 
 # ---------------------------------------------------------------------------
