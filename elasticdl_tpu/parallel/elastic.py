@@ -28,6 +28,10 @@ solutions here:
   (rank 0 = the longest-lived survivor) wins, XLA broadcasts it. A fresh
   joiner offers garbage and receives the survivors' state — replacing the
   reference's workers-re-push-to-PS re-init (ps/servicer.py:70-79).
+  That holds the offer and the picked copy on the device at once; on a
+  mesh whose devices all belong to this process there is nobody to
+  adopt from, and :func:`place_from_host` puts the host tree onto the
+  mesh once (a state over half a chip's memory starts that way).
 
 - **Failure visibility.** A peer death mid-collective surfaces as an
   error from the jitted step on every survivor. On a mesh that spans
@@ -192,6 +196,23 @@ def broadcast_from_device0(mesh, host_tree, source_process=0):
         out_shardings=NamedSharding(mesh, P()),
     )
     return pick(stacked)
+
+
+def place_from_host(mesh, host_tree):
+    """Place ``host_tree`` replicated on a mesh whose devices all belong
+    to this process: each leaf goes from the host straight onto its
+    replicated sharding. Device memory holds the tree once while this
+    runs and once when it returns, where :func:`broadcast_from_device0`
+    holds the stacked offer beside the picked copy; the values are the
+    host's, bit for bit, either way. Not for a mesh that spans
+    processes (``device_put`` cannot target shards another process
+    addresses, and there a source has to win)."""
+    if mesh.is_multi_process:
+        raise ValueError("the mesh spans processes: broadcast_from_device0")
+    return jax.device_put(
+        jax.tree_util.tree_map(np.asarray, host_tree),
+        NamedSharding(mesh, P()),
+    )
 
 
 def _is_sharded_spec(spec):
@@ -1231,18 +1252,31 @@ class ElasticDPTrainer:
         with profiling.phases.measure("fetch"):
             return host_copy(state[MOE_STATE_COLLECTION])
 
+    def _most_on_a_device(self, stat):
+        """The largest ``memory_stats()[stat]`` over the mesh's local
+        devices; None between worlds and where the backend reports
+        none (the CPU)."""
+        if self._mesh is None:
+            return None
+        read = [
+            (d.memory_stats() or {}).get(stat)
+            for d in self._mesh.local_devices
+        ]
+        return max((b for b in read if b is not None), default=None)
+
+    def device_bytes_in_use(self):
+        """The most device memory any local device of the mesh holds
+        right now (``bytes_in_use``): read as establish returns, it
+        says how many copies of the train state the placement left
+        behind. None as :meth:`_most_on_a_device`."""
+        return self._most_on_a_device("bytes_in_use")
+
     def peak_hbm_bytes(self):
         """The most device memory any local device of the mesh has held
         since the process started (``memory_stats()``'s
         ``peak_bytes_in_use``); None between worlds and where the
         backend reports none (the CPU)."""
-        if self._mesh is None:
-            return None
-        peaks = [
-            (d.memory_stats() or {}).get("peak_bytes_in_use")
-            for d in self._mesh.local_devices
-        ]
-        return max((p for p in peaks if p is not None), default=None)
+        return self._most_on_a_device("peak_bytes_in_use")
 
     def _build_init_ts(self, example_batch):
         features = example_batch[0]
@@ -1360,9 +1394,14 @@ class ElasticDPTrainer:
                     abstract,
                 )
             t_init = _time.time()
-            self._ts = broadcast_from_device0(
-                self._mesh, offer, source_process=source
-            )
+            if self._mesh.is_multi_process:
+                self._ts = broadcast_from_device0(
+                    self._mesh, offer, source_process=source
+                )
+            else:
+                # nobody to adopt from: the state goes onto the mesh
+                # once, where the broadcast would hold it twice
+                self._ts = place_from_host(self._mesh, offer)
         t_place = _time.time()
         self._check_routing_state()
         self._keep_checked(self._ts)
@@ -1392,6 +1431,7 @@ class ElasticDPTrainer:
             compile_s=round(t_compile - t_place, 3),
             compile_phase=compile_phase,
             cache_hit=bool(cache_hit),
+            state_device_bytes=self.device_bytes_in_use(),
             resize_layout={
                 "old": old_layout,
                 "new": self._layout_fields(),

@@ -1,31 +1,53 @@
-"""Hybrid sparse decoder LM: gated short convolutions beside grouped-head
-attention, full or over a learned selection of keys, a dense SwiGLU MLP
-in the leading layers and a mixture of experts in the rest, of which
-this device holds a share.
+"""Hybrid sparse decoder LM: gated short convolutions and Mamba-2
+state-space layers beside grouped-head attention, full or over a
+learned selection of keys, a dense SwiGLU MLP in the leading layers and
+a mixture of experts in the rest, of which this device holds a share.
 
 The layer stack is built from one pattern string, a letter a layer:
-``c`` a gated short convolution, ``a`` full causal attention, ``s``
-attention over the ``select_topk`` keys an indexer picks for each query
-(``layer_pattern=caccc``: no comma, it travels in ``--model_params``).
-The first ``num_dense_layers`` layers (none is fine) have the dense
-MLP, the others the expert layer. With ``h = RMSNorm(x)`` (weight only,
-no bias anywhere):
+``c`` a gated short convolution, ``m`` a Mamba-2 state-space layer,
+``a`` full causal attention, ``s`` attention over the ``select_topk``
+keys an indexer picks for each query (``layer_pattern=caccc``: no
+comma, it travels in ``--model_params``). The first
+``num_dense_layers`` layers (none is fine, and so is all of them: a
+stack with no expert layer keeps no routing state) have the dense MLP,
+the others the expert layer. With ``h = RMSNorm(x)`` (weight only) and
+``r = residual_multiplier``:
 
-    x <- x + Op(RMSNorm(x));  x <- x + FF(RMSNorm(x))
-    logits = RMSNorm(x_last) E^T          (``tie_head``, the default)
-    logits = RMSNorm(x_last) W_head       (untied: a matrix of its own)
+    x_0 = embedding_multiplier * E[tok]
+    x <- x + r * Op(RMSNorm(x));  x <- x + r * FF(RMSNorm(x))
+    logits = RMSNorm(x_last) E^T / logits_scaling      (``tie_head``, the default)
+    logits = RMSNorm(x_last) W_head / logits_scaling   (untied: a matrix of its own)
 
+The three multipliers are 1 by default and then multiply nothing.
 Embedding and head are over the slice of the vocabulary held here.
+``remat_layers`` recomputes each layer in the backward pass
+(``jax.checkpoint`` a layer: what is kept for the backward is a layer's
+input, not its activations; a model parameter, since the worker's
+``--remat`` wraps the whole forward, which does not lower the peak).
 
 - ``c``: ``[B, C, X] = split(h W_in, 3)``; ``u = B * X``;
   ``v_t = sum_{j<K} k_j * u_{t-j}`` (depthwise, causal, ``u_{<0} = 0``);
   ``out = (C * v) W_out``.
+- ``m`` (ops/ssd.py has the scan), with ``H = ssm_heads``, ``P =
+  ssm_head_dim``, ``N = ssm_state``, ``G = ssm_groups``:
+  ``[z | xBC | dt] = h W_in`` (``H P | H P + 2 G N | H``);
+  ``xBC = silu(causal_depthwise_conv(xBC) + b_conv)``
+  (``ssm_conv_kernel`` taps); ``[X | B | C] = xBC``;
+  ``delta = softplus(dt + dt_bias)``, ``A = -exp(A_log)`` (a head
+  each, float32, no clamp);
+  ``S_t = exp(delta_t A) S_{t-1} + delta_t X_t (x) B_t``,
+  ``y_t = S_t C_t + D X_t``; ``out = (RMSNorm(y * silu(z)) * w) W_out``
+  (one norm over all ``H P``). Initial ``A_log = log(1..H)``, ``D =
+  1``, ``dt_bias`` the inverse softplus of a log-uniform draw in
+  [0.001, 0.1] (Mamba-2's published initialisation).
 - ``a``: ``num_heads`` query heads over ``num_kv_heads`` key/value
-  heads, a learned RMSNorm over each head of q and of k, rotary
-  positions of base ``rope_theta``, causal softmax attention; KV head
-  ``j`` serves query heads ``j*g .. j*g+g-1``. The KV heads are repeated
-  in front of ops/flash_attention.py (grouped heads inside the kernel
-  are not built).
+  heads, a learned RMSNorm over each head of q and of k (``qk_norm``,
+  on by default), rotary positions of base ``rope_theta`` (``rope``, on
+  by default; off, the layer has no positions at all), causal softmax
+  attention of ``attention_scale * q k^T`` (``head_dim ** -0.5`` by
+  default); KV head ``j`` serves query heads ``j*g .. j*g+g-1``. The KV
+  heads are repeated in front of ops/flash_attention.py (grouped heads
+  inside the kernel are not built).
 - ``s``: ``a`` with ``o_t = sum_{s in S_t} softmax_{s in S_t}(q_t k_s /
   sqrt(hd)) v_s``. The indexer, float32, on ``hb = stop_gradient(h)``:
   ``qI = hb W_qI`` (``indexer_heads`` x ``indexer_dim``), ``kI = hb
@@ -72,6 +94,7 @@ What the two LMs of this directory share comes from the sibling module
 one attention policy ``pick_causal_attention``.
 """
 
+import math
 import os
 from typing import Any
 
@@ -81,7 +104,7 @@ import jax.numpy as jnp
 import optax
 
 from elasticdl_tpu.common.model_utils import load_module
-from elasticdl_tpu.ops import sparse_select
+from elasticdl_tpu.ops import sparse_select, ssd
 from elasticdl_tpu.ops.flash_attention import (
     pick_causal_attention,
     pick_selected_attention,
@@ -97,7 +120,7 @@ loss = _lm.loss
 dataset_fn = _lm.dataset_fn
 eval_metrics_fn = _lm.eval_metrics_fn
 
-CONV, ATTENTION, SELECTING = "c", "a", "s"
+CONV, ATTENTION, SELECTING, MAMBA = "c", "a", "s", "m"
 ROUTINGS = ("sigmoid_bias", "softmax")
 EXPERT_APPLIES = ("grouped", "masked")
 # every parameter of an indexer lies under a module of this name
@@ -146,6 +169,17 @@ def _per_expert_init():
     )  # fmt: skip
 
 
+def _causal_depthwise_conv(u, taps):
+    """``v_t = sum_j taps[j] * u_{t-j}`` a channel, ``u_{<0} = 0``."""
+    length, last = u.shape[1], taps.shape[0] - 1
+    padded = jnp.pad(u, ((0, 0), (last, 0), (0, 0)))
+    # tap j multiplies u_{t-j}: the padded sequence from K-1-j on
+    return sum(
+        taps[j] * padded[:, last - j : last - j + length]
+        for j in range(taps.shape[0])
+    )
+
+
 class ShortConv(nn.Module):
     """The gated short convolution operator."""
 
@@ -165,16 +199,87 @@ class ShortConv(nn.Module):
             nn.initializers.normal(self.kernel_size**-0.5),
             (self.kernel_size, d),
         ).astype(self.dtype)
-        length, last = u.shape[1], self.kernel_size - 1
-        padded = jnp.pad(u, ((0, 0), (last, 0), (0, 0)))
-        # tap j multiplies u_{t-j}: the padded sequence from K-1-j on
-        v = sum(
-            taps[j] * padded[:, last - j : last - j + length]
-            for j in range(self.kernel_size)
-        )
         return nn.Dense(
             d, use_bias=False, dtype=self.dtype, name="out_proj"
-        )(c * v)
+        )(c * _causal_depthwise_conv(u, taps))
+
+
+def _dt_bias_init(key, shape, low=1e-3, high=1e-1, floor=1e-4):
+    """The inverse softplus of a log-uniform draw in [low, high]."""
+    dt = jnp.exp(
+        jax.random.uniform(key, shape) * (math.log(high) - math.log(low))
+        + math.log(low)
+    )
+    dt = jnp.maximum(dt, floor)
+    return dt + jnp.log(-jnp.expm1(-dt))
+
+
+class Mamba2(nn.Module):
+    """The Mamba-2 state-space operator."""
+
+    heads: int
+    head_dim: int
+    state: int
+    groups: int
+    conv_kernel: int
+    chunk: int
+    norm_eps: float
+    dtype: Any
+
+    @nn.compact
+    def __call__(self, h):
+        inner = self.heads * self.head_dim
+        shared = self.groups * self.state
+        projected = nn.Dense(
+            2 * inner + 2 * shared + self.heads,
+            use_bias=False,
+            dtype=self.dtype,
+            name="in_proj",
+        )(h)
+        z, xbc, dt = jnp.split(
+            projected, (inner, 2 * inner + 2 * shared), axis=-1
+        )
+        taps = self.param(
+            "conv_kernel",
+            nn.initializers.normal(self.conv_kernel**-0.5),
+            (self.conv_kernel, inner + 2 * shared),
+        ).astype(self.dtype)
+        conv_bias = self.param(
+            "conv_bias", nn.initializers.zeros, (inner + 2 * shared,)
+        ).astype(self.dtype)
+        xbc = nn.silu(_causal_depthwise_conv(xbc, taps) + conv_bias)
+        x, b, c = jnp.split(xbc, (inner, inner + shared), axis=-1)
+        per_head = (self.heads,)
+        dt_bias = self.param("dt_bias", _dt_bias_init, per_head)
+        a_log = self.param(
+            "A_log",
+            lambda key, shape: jnp.log(jnp.arange(1.0, shape[0] + 1.0)),
+            per_head,
+        )
+        skip = self.param("D", nn.initializers.ones, per_head)
+        x = x.reshape(x.shape[:2] + (self.heads, self.head_dim))
+        if self.is_initializing():
+            # the variables are made, and no shape depends on the
+            # state: an eager init (the trainer's) is spared the scan
+            y = x
+        else:
+            by_group = b.shape[:2] + (self.groups, self.state)
+            y = ssd.ssd_scan(
+                x,
+                jax.nn.softplus(dt.astype(jnp.float32) + dt_bias),
+                -jnp.exp(a_log),
+                b.reshape(by_group),
+                c.reshape(by_group),
+                self.chunk,
+            )
+        y = y + skip.astype(self.dtype)[:, None] * x
+        gated = y.reshape(z.shape) * nn.silu(z)
+        normed = nn.RMSNorm(
+            epsilon=self.norm_eps, dtype=self.dtype, name="norm"
+        )(gated)
+        return nn.Dense(
+            h.shape[-1], use_bias=False, dtype=self.dtype, name="out_proj"
+        )(normed)
 
 
 class Indexer(nn.Module):
@@ -224,6 +329,9 @@ class GroupedAttention(nn.Module):
     select_topk: int = 0
     indexer_heads: int = 0
     indexer_dim: int = 0
+    rope: bool = True
+    qk_norm: bool = True
+    attention_scale: float = 0.0
 
     def _selection(self, h):
         """The indexer's selection, counted into the module's state
@@ -260,11 +368,19 @@ class GroupedAttention(nn.Module):
                 epsilon=self.norm_eps, dtype=self.dtype, name=name
             )
 
-        q = head_norm("q_norm")(heads(self.num_heads, "query"))
-        k = head_norm("k_norm")(heads(self.num_kv_heads, "key"))
+        q, k = heads(self.num_heads, "query"), heads(self.num_kv_heads, "key")
         v = heads(self.num_kv_heads, "value")
-        q = _lm._rotary(q, positions, self.rope_theta)
-        k = _lm._rotary(k, positions, self.rope_theta)
+        if self.qk_norm:
+            q, k = head_norm("q_norm")(q), head_norm("k_norm")(k)
+        if self.rope:
+            q = _lm._rotary(q, positions, self.rope_theta)
+            k = _lm._rotary(k, positions, self.rope_theta)
+        if self.attention_scale:
+            # the attention of ops/flash_attention.py scales by
+            # head_dim ** -0.5: q carries what is left of the scale
+            q = q * jnp.asarray(
+                self.attention_scale * self.head_dim**0.5, q.dtype
+            )
         group = self.num_heads // self.num_kv_heads
         k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
         if self.select_topk:
@@ -422,6 +538,19 @@ class HybridMoELM(nn.Module):
     indexer_dim: int = 16
     tie_head: bool = True
     expert_apply: str = EXPERT_APPLIES[0]
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 0
+    ssm_conv_kernel: int = 0
+    ssm_chunk: int = 0
+    rope: bool = True
+    qk_norm: bool = True
+    attention_scale: float = 0.0
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    remat_layers: bool = False
     dtype: Any = jnp.float32
     use_flash: bool = True
 
@@ -439,6 +568,16 @@ class HybridMoELM(nn.Module):
             "conv_layers": self.layer_pattern.count(CONV),
             "attention_layers": self.layer_pattern.count(ATTENTION),
         }
+        if self.remat_layers:
+            facts["remat_layers"] = 1
+        if MAMBA in self.layer_pattern:
+            facts.update(
+                mamba_layers=self.layer_pattern.count(MAMBA),
+                ssm_heads=self.ssm_heads,
+                ssm_head_dim=self.ssm_head_dim,
+                ssm_state=self.ssm_state,
+                ssm_chunk=self.ssm_chunk,
+            )
         if SELECTING in self.layer_pattern:
             facts.update(
                 sparse_layers=self.layer_pattern.count(SELECTING),
@@ -465,16 +604,38 @@ class HybridMoELM(nn.Module):
             self.vocab_size, self.embed_dim, dtype=self.dtype, name="embed"
         )
         x = embed_layer(tokens)
-        for i, kind in enumerate(self.layer_pattern):
-            layer = "layer_%d_" % i
-            h = norm(layer + "operator_norm")(x)
+        if self.embedding_multiplier != 1.0:
+            x = x * jnp.asarray(self.embedding_multiplier, x.dtype)
+
+        def joined(x, out):
+            if self.residual_multiplier != 1.0:
+                out = out * jnp.asarray(self.residual_multiplier, out.dtype)
+            return x + out
+
+        def layer(self, x, i):
+            """Layer ``i`` of the pattern, its variables under this
+            module by the layer's own names."""
+            kind, name = self.layer_pattern[i], "layer_%d_" % i
+            h = norm(name + "operator_norm")(x)
             if kind == CONV:
                 with jax.named_scope("edl/short_conv"):
-                    x = x + ShortConv(
-                        self.conv_kernel, self.dtype, name=layer + "conv"
+                    out = ShortConv(
+                        self.conv_kernel, self.dtype, name=name + "conv"
                     )(h)
+            elif kind == MAMBA:
+                out = Mamba2(
+                    heads=self.ssm_heads,
+                    head_dim=self.ssm_head_dim,
+                    state=self.ssm_state,
+                    groups=self.ssm_groups,
+                    conv_kernel=self.ssm_conv_kernel,
+                    chunk=self.ssm_chunk,
+                    norm_eps=self.norm_eps,
+                    dtype=self.dtype,
+                    name=name + "mamba",
+                )(h)
             else:
-                x = x + GroupedAttention(
+                out = GroupedAttention(
                     num_heads=self.num_heads,
                     num_kv_heads=self.num_kv_heads,
                     head_dim=self.head_dim,
@@ -485,14 +646,18 @@ class HybridMoELM(nn.Module):
                     select_topk=self.select_topk if kind == SELECTING else 0,
                     indexer_heads=self.indexer_heads,
                     indexer_dim=self.indexer_dim,
-                    name=layer + "attention",
+                    rope=self.rope,
+                    qk_norm=self.qk_norm,
+                    attention_scale=self.attention_scale,
+                    name=name + "attention",
                 )(h, positions)
-            h = norm(layer + "ffn_norm")(x)
+            x = joined(x, out)
+            h = norm(name + "ffn_norm")(x)
             if i < self.num_dense_layers:
-                x = x + SwiGLU(self.mlp_dim, self.dtype, name=layer + "mlp")(h)
+                out = SwiGLU(self.mlp_dim, self.dtype, name=name + "mlp")(h)
             else:
                 with jax.named_scope("edl/moe"):
-                    x = x + HeldExperts(
+                    out = HeldExperts(
                         num_experts=self.num_experts,
                         experts_held=self.experts_held,
                         first_expert_held=self.first_expert_held,
@@ -503,29 +668,89 @@ class HybridMoELM(nn.Module):
                         dtype=self.dtype,
                         routing=self.routing,
                         apply=self.expert_apply,
-                        name=layer + "moe",
+                        name=name + "moe",
                     )(h)
+            return joined(x, out)
+
+        if self.remat_layers and not self.is_initializing():
+            # static_argnums counts the module: the layer's index
+            layer = nn.remat(layer, static_argnums=(2,))
+        for i in range(len(self.layer_pattern)):
+            x = layer(self, x, i)
         x = norm("final_norm")(x)
         # the head over the slice of the vocabulary held here
         if self.tie_head:
-            return embed_layer.attend(x.astype(jnp.float32))
-        return nn.Dense(
-            self.vocab_size, use_bias=False, dtype=self.dtype, name="head"
-        )(x)
+            logits = embed_layer.attend(x.astype(jnp.float32))
+        else:
+            logits = nn.Dense(
+                self.vocab_size, use_bias=False, dtype=self.dtype, name="head"
+            )(x)
+        if self.logits_scaling != 1.0:
+            logits = logits / jnp.asarray(self.logits_scaling, logits.dtype)
+        return logits
 
 
 def custom_model(dtype="float32", **sizes):
     """``HybridMoELM(**sizes)``; every size has the toy default of the
     class, and a name it does not know is refused."""
     pattern = str(sizes.get("layer_pattern", HybridMoELM.layer_pattern))
-    unknown = sorted(set(pattern) - {CONV, ATTENTION, SELECTING})
+    unknown = sorted(set(pattern) - {CONV, ATTENTION, SELECTING, MAMBA})
     if not pattern or unknown:
         raise ValueError(
             "layer_pattern %r holds %s: a letter a layer, %r a short "
-            "convolution, %r full attention, %r attention over selected keys"
-            % (pattern, unknown or "no layer", CONV, ATTENTION, SELECTING)
-        )
+            "convolution, %r a Mamba-2 state-space layer, %r full attention, "
+            "%r attention over selected keys"
+            % (
+                pattern, unknown or "no layer",
+                CONV, MAMBA, ATTENTION, SELECTING,
+            )
+        )  # fmt: skip
     model = HybridMoELM(dtype=jnp.dtype(dtype), **sizes)
+    ssm_sizes = {
+        name: getattr(model, name)
+        for name in (
+            "ssm_heads", "ssm_head_dim", "ssm_state", "ssm_groups",
+            "ssm_conv_kernel", "ssm_chunk",
+        )
+    }  # fmt: skip
+    if MAMBA in pattern:
+        if not all(isinstance(v, int) and v > 0 for v in ssm_sizes.values()):
+            raise ValueError(
+                "layer_pattern %r holds a state-space layer: %s all have to "
+                "be positive whole numbers" % (pattern, ssm_sizes)
+            )
+        if model.ssm_heads % model.ssm_groups:
+            raise ValueError(
+                "ssm_heads=%r is not a multiple of ssm_groups=%r"
+                % (model.ssm_heads, model.ssm_groups)
+            )
+    elif any(ssm_sizes.values()):
+        raise ValueError(
+            "layer_pattern %r holds no state-space layer (%r), so %s say "
+            "nothing" % (pattern, MAMBA, sorted(k for k, v in ssm_sizes.items() if v))
+        )
+    for name in ("rope", "qk_norm", "remat_layers"):
+        if not isinstance(getattr(model, name), bool):
+            raise ValueError(
+                "%s=%r is neither True nor False" % (name, getattr(model, name))
+            )
+    attends = ATTENTION in pattern or SELECTING in pattern
+    if not attends and not (
+        model.rope and model.qk_norm and not model.attention_scale
+    ):
+        raise ValueError(
+            "layer_pattern %r holds no attention layer, so rope, qk_norm "
+            "and attention_scale say nothing" % pattern
+        )
+    if model.attention_scale < 0 or not math.isfinite(model.attention_scale):
+        raise ValueError(
+            "attention_scale=%r: a positive number, or 0 for head_dim ** -0.5"
+            % model.attention_scale
+        )
+    for name in ("embedding_multiplier", "residual_multiplier", "logits_scaling"):
+        value = getattr(model, name)
+        if not (value > 0 and math.isfinite(value)):
+            raise ValueError("%s=%r is not a positive number" % (name, value))
     if model.routing not in ROUTINGS:
         raise ValueError(
             "routing %r is not one of %s" % (model.routing, ", ".join(ROUTINGS))

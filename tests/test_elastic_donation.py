@@ -8,6 +8,10 @@ and on no other (parallel/elastic.py ``state_donation``).
   ``validate()`` and ``version`` after unsynced donating steps read the
   newest state, and a state lost to a failed step falls back to the
   host snapshot, never to a deleted array;
+- establish on such a mesh puts the host tree onto it once: leaf for
+  leaf the host's values, and no second device copy of any leaf alive
+  when it returns (``place_from_host``; the broadcast a mesh that spans
+  processes needs holds the stacked offer beside the picked copy);
 - on a mesh that spans processes (a real two-process world) the input
   survives, the lowering donates nothing, ``_checked_ts`` is kept as
   before, and a world of one re-formed as a world of two carries its
@@ -134,6 +138,82 @@ def test_trainer_keeps_nothing_donation_deletes(singleton_world, plane):
             assert int(np.asarray(trainer.snapshot().version)) == 6
     finally:
         trainer.close()
+
+
+def _shapes(arrays):
+    """(shape, dtype) -> how many of ``arrays`` have it, scalars and
+    other small arrays left out (keys, counters and the version are
+    not the state's weight)."""
+    counted = {}
+    for a in arrays:
+        if a.size >= 16:
+            key = (tuple(a.shape), str(a.dtype))
+            counted[key] = counted.get(key, 0) + 1
+    return counted
+
+
+def test_establish_places_the_state_once(singleton_world, monkeypatch):
+    """Parameters and both AdamW moments: what is alive on the devices
+    when establish returns is one array a leaf, replicated, with the
+    host tree's values, and nothing of a leaf's shape or of a stacked
+    offer's ((devices,) + shape) beside it. The broadcast is not
+    called."""
+    import elasticdl_tpu.parallel.elastic as elastic_mod
+
+    def no_broadcast(*args, **kwargs):
+        raise AssertionError("establish broadcast on a mesh one process owns")
+
+    monkeypatch.setattr(elastic_mod, "broadcast_from_device0", no_broadcast)
+    # on the CPU backend a host copy is a VIEW of the device's own
+    # buffer and keeps the init's arrays alive; on a chip it is host
+    # memory and they go. Copy, so that here they go too
+    view = elastic_mod.host_copy
+    monkeypatch.setattr(
+        elastic_mod,
+        "host_copy",
+        lambda tree: jax.tree_util.tree_map(np.array, view(tree)),
+    )
+    before = {id(a) for a in jax.live_arrays()}
+    trainer = ElasticDPTrainer(
+        tzoo.custom_model(**KW), tzoo.loss, optax.adamw(1e-3)
+    )
+    trainer.default_minibatch_size = ROWS
+    trainer.establish(SPEC, example_batch=_batch())
+    try:
+        placed = _leaves(trainer._ts)
+        n = trainer.mesh.devices.size
+        assert n == 8 and not trainer.mesh.is_multi_process
+        # counted before a shard is looked at: its view is an array too
+        alive = [a for a in jax.live_arrays() if id(a) not in before]
+        for leaf, host in zip(placed, _leaves(trainer._host_ts)):
+            assert leaf.sharding.is_fully_replicated
+            assert len(leaf.addressable_shards) == n
+            for shard in leaf.addressable_shards:
+                np.testing.assert_array_equal(np.asarray(shard.data), host)
+        assert _shapes(alive) == _shapes(placed)
+        stacked = {((n,) + shape, dtype) for shape, dtype in _shapes(placed)}
+        assert not stacked & set(_shapes(alive))
+        # and the step it built trains from them
+        loss, _, _ = trainer.train_step(*_batch(1), ROWS, sync=True)
+        assert np.isfinite(loss)
+    finally:
+        trainer.close()
+
+
+def test_place_from_host_is_for_a_mesh_one_process_owns():
+    from elasticdl_tpu.parallel.elastic import place_from_host
+
+    class Spanning:
+        is_multi_process = True
+
+    with pytest.raises(ValueError, match="spans processes"):
+        place_from_host(Spanning(), {"w": np.zeros(4)})
+    mesh = Mesh(np.asarray(jax.devices()), ("data",))
+    tree = {"w": np.arange(32.0, dtype=np.float32), "n": np.int32(3)}
+    placed = place_from_host(mesh, tree)
+    assert placed["w"].dtype == np.float32 and placed["n"].dtype == np.int32
+    assert all(leaf.sharding.is_fully_replicated for leaf in _leaves(placed))
+    np.testing.assert_array_equal(np.asarray(placed["w"]), tree["w"])
 
 
 def test_snapshot_after_a_lost_state_is_the_host_snapshot(singleton_world):
