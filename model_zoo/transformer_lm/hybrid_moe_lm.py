@@ -20,10 +20,23 @@ the others the expert layer. With ``h = RMSNorm(x)`` (weight only) and
 
 The three multipliers are 1 by default and then multiply nothing.
 Embedding and head are over the slice of the vocabulary held here.
-``remat_layers`` recomputes each layer in the backward pass
-(``jax.checkpoint`` a layer: what is kept for the backward is a layer's
-input, not its activations; a model parameter, since the worker's
-``--remat`` wraps the whole forward, which does not lower the peak).
+``remat_layers``: the backward pass keeps a layer's input and the
+results of its products against weight matrices, but for an operator's
+input projection, and recomputes the rest (``jax.checkpoint`` a layer
+that keeps what is named ``KEPT``; selective recomputation, Korthikanti
+et al., arXiv:2205.05198, section 5). Kept: ``out_proj`` of a ``c`` or
+``m`` layer, ``q``, ``k``, ``v`` and ``o`` of an attention layer,
+``W_1`` and ``W_3`` of the dense FF (``W_2``'s result is needed by
+nothing): ``2 (embed_dim + 2 mlp_dim)`` bytes a token a ``c`` or ``m``
+layer in bf16 (37 KB at granite-4.0-h-micro's widths). Recomputed:
+both norms, ``in_proj`` of a ``c`` or ``m`` layer (kept, its result
+lies in HBM for the forward's own convolution and gates to read, which
+costs more than the product does: PERF.md section 6, PR 36), the
+residual scalings, ``silu`` and the gates, the depthwise convolution,
+``softplus``, the scan (ops/ssd.py), the gated norm, the attention
+itself (the kernel's forward runs twice) and an expert FF whole. A
+model parameter, since the worker's ``--remat`` wraps the whole
+forward, which does not lower the peak.
 
 - ``c``: ``[B, C, X] = split(h W_in, 3)``; ``u = B * X``;
   ``v_t = sum_{j<K} k_j * u_{t-j}`` (depthwise, causal, ``u_{<0} = 0``);
@@ -102,6 +115,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import optax
+from jax.ad_checkpoint import checkpoint_name
 
 from elasticdl_tpu.common.model_utils import load_module
 from elasticdl_tpu.ops import sparse_select, ssd
@@ -123,6 +137,11 @@ eval_metrics_fn = _lm.eval_metrics_fn
 CONV, ATTENTION, SELECTING, MAMBA = "c", "a", "s", "m"
 ROUTINGS = ("sigmoid_bias", "softmax")
 EXPERT_APPLIES = ("grouped", "masked")
+# the name of what ``remat_layers`` keeps for the backward pass, and how
+# many results a layer's operator gives that name (a dense FF: two)
+KEPT = "weight_product"
+KEPT_OF_OPERATOR = {CONV: 1, MAMBA: 1, ATTENTION: 4, SELECTING: 4}
+KEPT_OF_DENSE_FF = 2
 # every parameter of an indexer lies under a module of this name
 INDEXER = "indexer"
 
@@ -169,6 +188,10 @@ def _per_expert_init():
     )  # fmt: skip
 
 
+def _kept(product):
+    return checkpoint_name(product, KEPT)
+
+
 def _causal_depthwise_conv(u, taps):
     """``v_t = sum_j taps[j] * u_{t-j}`` a channel, ``u_{<0} = 0``."""
     length, last = u.shape[1], taps.shape[0] - 1
@@ -199,9 +222,11 @@ class ShortConv(nn.Module):
             nn.initializers.normal(self.kernel_size**-0.5),
             (self.kernel_size, d),
         ).astype(self.dtype)
-        return nn.Dense(
-            d, use_bias=False, dtype=self.dtype, name="out_proj"
-        )(c * _causal_depthwise_conv(u, taps))
+        return _kept(
+            nn.Dense(d, use_bias=False, dtype=self.dtype, name="out_proj")(
+                c * _causal_depthwise_conv(u, taps)
+            )
+        )
 
 
 def _dt_bias_init(key, shape, low=1e-3, high=1e-1, floor=1e-4):
@@ -277,9 +302,11 @@ class Mamba2(nn.Module):
         normed = nn.RMSNorm(
             epsilon=self.norm_eps, dtype=self.dtype, name="norm"
         )(gated)
-        return nn.Dense(
-            h.shape[-1], use_bias=False, dtype=self.dtype, name="out_proj"
-        )(normed)
+        return _kept(
+            nn.Dense(
+                h.shape[-1], use_bias=False, dtype=self.dtype, name="out_proj"
+            )(normed)
+        )
 
 
 class Indexer(nn.Module):
@@ -355,13 +382,15 @@ class GroupedAttention(nn.Module):
     @nn.compact
     def __call__(self, h, positions):
         def heads(n, name):
-            return nn.DenseGeneral(
-                features=(n, self.head_dim),
-                axis=-1,
-                use_bias=False,
-                dtype=self.dtype,
-                name=name,
-            )(h)
+            return _kept(
+                nn.DenseGeneral(
+                    features=(n, self.head_dim),
+                    axis=-1,
+                    use_bias=False,
+                    dtype=self.dtype,
+                    name=name,
+                )(h)
+            )
 
         def head_norm(name):
             return nn.RMSNorm(
@@ -394,13 +423,15 @@ class GroupedAttention(nn.Module):
             )
         else:
             attn = pick_causal_attention(h.shape[1], self.use_flash)(q, k, v)
-        return nn.DenseGeneral(
-            features=h.shape[-1],
-            axis=(-2, -1),
-            use_bias=False,
-            dtype=self.dtype,
-            name="out",
-        )(attn)
+        return _kept(
+            nn.DenseGeneral(
+                features=h.shape[-1],
+                axis=(-2, -1),
+                use_bias=False,
+                dtype=self.dtype,
+                name="out",
+            )(attn)
+        )
 
 
 class SwiGLU(nn.Module):
@@ -414,7 +445,9 @@ class SwiGLU(nn.Module):
                 features, use_bias=False, dtype=self.dtype, name=name
             )
 
-        gate = nn.silu(dense(self.width, "w1")(h)) * dense(self.width, "w3")(h)
+        gate = nn.silu(_kept(dense(self.width, "w1")(h))) * _kept(
+            dense(self.width, "w3")(h)
+        )
         return dense(h.shape[-1], "w2")(gate)
 
 
@@ -570,6 +603,10 @@ class HybridMoELM(nn.Module):
         }
         if self.remat_layers:
             facts["remat_layers"] = 1
+            facts["remat_kept_products"] = (
+                sum(KEPT_OF_OPERATOR[kind] for kind in self.layer_pattern)
+                + self.num_dense_layers * KEPT_OF_DENSE_FF
+            )
         if MAMBA in self.layer_pattern:
             facts.update(
                 mamba_layers=self.layer_pattern.count(MAMBA),
@@ -674,7 +711,11 @@ class HybridMoELM(nn.Module):
 
         if self.remat_layers and not self.is_initializing():
             # static_argnums counts the module: the layer's index
-            layer = nn.remat(layer, static_argnums=(2,))
+            layer = nn.remat(
+                layer,
+                static_argnums=(2,),
+                policy=jax.checkpoint_policies.save_only_these_names(KEPT),
+            )
         for i in range(len(self.layer_pattern)):
             x = layer(self, x, i)
         x = norm("final_norm")(x)

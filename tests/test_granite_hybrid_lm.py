@@ -250,23 +250,151 @@ def test_recomputing_each_layer_changes_no_number(zoo, tokens, both_sides):
     assert worst <= 1e-6
 
 
+def _grad_jaxpr(zoo, tokens, **sizes):
+    model, params = _params(zoo, tokens, **sizes)
+
+    def objective(p):
+        logits = model.apply({"params": p}, {"tokens": tokens}, training=True)
+        return zoo.loss(logits, tokens)
+
+    return jax.make_jaxpr(jax.grad(objective))(params).jaxpr
+
+
 def test_the_recomputed_layers_are_in_the_step(zoo, tokens):
     """What ``remat_layers`` buys: the backward pass holds a checkpoint
     a layer. Without it the only ones are the scan's own, of its chunk
     body, one a state-space layer."""
 
     def checkpoints(remat):
-        model, params = _params(zoo, tokens, remat_layers=remat)
-
-        def objective(p):
-            logits = model.apply({"params": p}, {"tokens": tokens}, training=True)
-            return zoo.loss(logits, tokens)
-
-        return str(jax.make_jaxpr(jax.grad(objective))(params)).count("remat2[")
+        return str(_grad_jaxpr(zoo, tokens, remat_layers=remat)).count("remat2[")
 
     scans = TOY["layer_pattern"].count("m")
     assert checkpoints(False) == scans
     assert checkpoints(True) >= scans + len(TOY["layer_pattern"])
+
+
+# ---- what ``remat_layers`` keeps: a layer's weight products ----
+
+# widths that give each weight below a shape no other array has
+DISTINCT = dict(mlp_dim=48, vocab_size=80)
+# a weight of each kind by its shape (w1 and w3 are one shape and are
+# counted together): how many matrices of it the stack holds
+KEPT_WEIGHTS = {
+    "mamba_out_proj": ((64, 32), 3),
+    "attention_query": ((32, 4, 8), 1),
+    "attention_out": ((4, 8, 32), 1),
+    "swiglu_w1_and_w3": ((32, 48), 8),
+}
+IN_PROJ = ((32, 164), 3)
+
+
+def _equations(jaxpr, name):
+    """Every equation of primitive ``name``, nested ones included."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == name:
+            found.append(eqn)
+        for inner in jax.core.jaxprs_in_params(eqn.params):
+            found += _equations(inner, name)
+    return found
+
+
+def _products_reading(jaxpr, shape):
+    """The products a weight of ``shape`` is an operand of."""
+    return sum(
+        any(v.aval.shape == shape for v in eqn.invars)
+        for eqn in _equations(jaxpr, "dot_general")
+    )
+
+
+@pytest.fixture(scope="module")
+def grad_jaxprs(zoo, tokens):
+    return {
+        remat: _grad_jaxpr(zoo, tokens, remat_layers=remat, **DISTINCT)
+        for remat in (False, True)
+    }
+
+
+@pytest.mark.parametrize("weight", sorted(KEPT_WEIGHTS))
+def test_no_kept_product_is_multiplied_a_second_time(grad_jaxprs, weight):
+    """Two products read a weight, recomputing or not: the forward's
+    and the input gradient's (its own gradient's, the third a weight
+    costs, reads the layer's input instead)."""
+    shape, matrices = KEPT_WEIGHTS[weight]
+    assert _products_reading(grad_jaxprs[False], shape) == 2 * matrices
+    assert _products_reading(grad_jaxprs[True], shape) == 2 * matrices
+
+
+@pytest.fixture(scope="module")
+def whole_layer_jaxpr(zoo, tokens):
+    """``remat_layers`` with no result given the name."""
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(zoo, "_kept", lambda product: product)
+        return _grad_jaxpr(zoo, tokens, remat_layers=True, **DISTINCT)
+
+
+@pytest.mark.parametrize("weight", sorted(KEPT_WEIGHTS))
+def test_a_layer_recomputed_whole_multiplies_it_again(whole_layer_jaxpr, weight):
+    """The name is what keeps the products: with none given it, as
+    until PR 34, the backward pass runs the forward's once more."""
+    shape, matrices = KEPT_WEIGHTS[weight]
+    assert _products_reading(whole_layer_jaxpr, shape) == 3 * matrices
+
+
+def test_the_operators_input_projection_is_recomputed(grad_jaxprs):
+    """``in_proj`` of a state-space layer is not kept (PERF.md section
+    6, PR 36): the backward pass runs it once more."""
+    shape, matrices = IN_PROJ
+    assert _products_reading(grad_jaxprs[False], shape) == 2 * matrices
+    assert _products_reading(grad_jaxprs[True], shape) == 3 * matrices
+
+
+def test_the_scan_and_the_attention_are_still_recomputed(grad_jaxprs):
+    """A state-space layer's loop runs three times (forward, recomputed
+    forward, backward) where two do without ``remat_layers``, and the
+    products under a batch dimension, the scan's and the plain
+    attention's, are recomputed with it."""
+    scans = TOY["layer_pattern"].count("m")
+    assert len(_equations(grad_jaxprs[False], "scan")) == 2 * scans
+    assert len(_equations(grad_jaxprs[True], "scan")) == 3 * scans
+
+    def batched(jaxpr):
+        return sum(
+            bool(eqn.params["dimension_numbers"][1][0])
+            for eqn in _equations(jaxpr, "dot_general")
+        )
+
+    assert batched(grad_jaxprs[True]) > batched(grad_jaxprs[False])
+
+
+def test_the_attention_kernel_runs_four_times(zoo):
+    """One attention layer at a length that takes the kernels: forward,
+    recomputed forward, dq, dkv (the benchmark's configuration states
+    four TPU custom calls: the kernel's results are not kept)."""
+    long = jnp.zeros((1, 1024), jnp.int32)
+    sizes = dict(layer_pattern="ma", num_dense_layers=2, ssm_chunk=256, use_flash=True)
+
+    def kernels(remat):
+        return len(_equations(_grad_jaxpr(zoo, long, remat_layers=remat, **sizes), "pallas_call"))
+
+    assert kernels(False) == 3
+    assert kernels(True) == 4
+
+
+KEPT_PRODUCTS = [
+    (dict(), 3 * 3 + 6),
+    (dict(layer_pattern="mmmmmammmm", num_dense_layers=10), 9 * 3 + 6),
+]
+
+
+@pytest.mark.parametrize("sizes, kept", KEPT_PRODUCTS)
+def test_step_facts_count_the_products_kept(zoo, tokens, sizes, kept):
+    facts = zoo.custom_model(**dict(TOY, remat_layers=True, **sizes)).step_facts()
+    assert facts["remat_kept_products"] == kept
+    assert "remat_kept_products" not in zoo.custom_model(**dict(TOY, **sizes)).step_facts()
+    # the count is of the names the trace gives
+    named = _equations(_grad_jaxpr(zoo, tokens, **sizes), "name")
+    assert len(named) == kept
 
 
 def test_logits_and_loss_are_over_the_slice(reference, zoo, tokens):
