@@ -45,6 +45,8 @@ solutions here:
   (``ElasticDPTrainer._keep_checked``).
 """
 
+import json
+import os
 import re
 import threading
 import time
@@ -67,7 +69,7 @@ from elasticdl_tpu.training.step import (
     aux_loss_total,
     block_device_losses,
 )
-from elasticdl_tpu.utils import profiling
+from elasticdl_tpu.utils import profiling, step_ops
 
 
 # re-exported: the trainer's historical home for the escapable-call
@@ -773,9 +775,6 @@ def make_elastic_train_step(
             red = _unsharded_axes(spec)
             return jax.lax.psum(g, red) if red else g
 
-        grads = jax.tree_util.tree_map(reduce_grad, grads, grad_specs)
-        loss = jax.lax.psum(loss * scale, axes)
-
         def wavg(x, spec):
             if _is_sharded(spec):
                 return x  # per-shard state stays local
@@ -783,23 +782,35 @@ def make_elastic_train_step(
                 return jax.lax.psum(x * w, axes) / denom
             return x  # int leaves (counters) advance identically everywhere
 
-        new_state = jax.tree_util.tree_map(
-            wavg, new_state, state_spec_tree
-        )
+        # the two scopes are how a traced run tells these ops from the
+        # forward and backward passes, which the transformations name
+        # themselves (utils/step_ops.py); metadata only, the compiled
+        # program and its cache key are the same without them
+        with jax.named_scope(step_ops.REDUCE_SCOPE):
+            grads = jax.tree_util.tree_map(reduce_grad, grads, grad_specs)
+            loss = jax.lax.psum(loss * scale, axes)
+            new_state = jax.tree_util.tree_map(
+                wavg, new_state, state_spec_tree
+            )
 
-        updates, opt_state = optimizer.update(grads, ts.opt_state, ts.params)
-        params = optax.apply_updates(ts.params, updates)
         live = n > 0
 
         def select(new, old):
             return jnp.where(live, new, old)
 
-        new_ts = TrainState(
-            params=jax.tree_util.tree_map(select, params, ts.params),
-            state=jax.tree_util.tree_map(select, new_state, ts.state),
-            opt_state=jax.tree_util.tree_map(select, opt_state, ts.opt_state),
-            version=ts.version + live.astype(jnp.int32),
-        )
+        with jax.named_scope(step_ops.OPTIMIZER_SCOPE):
+            updates, opt_state = optimizer.update(
+                grads, ts.opt_state, ts.params
+            )
+            params = optax.apply_updates(ts.params, updates)
+            new_ts = TrainState(
+                params=jax.tree_util.tree_map(select, params, ts.params),
+                state=jax.tree_util.tree_map(select, new_state, ts.state),
+                opt_state=jax.tree_util.tree_map(
+                    select, opt_state, ts.opt_state
+                ),
+                version=ts.version + live.astype(jnp.int32),
+            )
         return new_ts, loss, n, epoch_seen
 
     if state_specs is None:
@@ -1690,14 +1701,20 @@ class ElasticDPTrainer:
         the step's own first call reuses both (CPU, 8 layers: 4.4 s
         here + 5.5 s first step, against a 10.3 s first step alone).
         Asked later it would trace again, so callers ask once, right
-        after establish."""
+        after establish.
+
+        In a traced run (``EDL_PROFILE_DIR``) and only there it also
+        compiles that lowering and writes the compiled step's ops by
+        class beside the trace (:meth:`_step_ops_facts`)."""
         args = self._abstract_step_args(
             self._mesh, self._spec_example, self._state_specs
         )
         with self._mesh:
             traced = self._step_fn.trace(*args)
             jaxpr_text = str(traced.jaxpr)
-            lowered_text = traced.lower().as_text()
+            lowered = traced.lower()
+            lowered_text = lowered.as_text()
+        trace_dir = profiling.profile_dir()
         return {
             "pallas_calls": jaxpr_text.count("pallas_call["),
             "pallas_interpreted": jaxpr_text.count("interpret=True"),
@@ -1711,7 +1728,46 @@ class ElasticDPTrainer:
                 set(re.findall(r'kernel_name = "([^"]+)"', lowered_text))
             ),
             "donated_inputs": count_donated_inputs(lowered_text),
+            **(self._step_ops_facts(lowered, trace_dir) if trace_dir else {}),
         }
+
+    @staticmethod
+    def _step_ops_facts(lowered, trace_dir):
+        """Compile ``lowered`` and write, as
+        ``<trace_dir>/edl_step_ops.json``, which class each instruction
+        of the compiled step holds (``utils/step_ops.py``:
+        ``{"module": name, "ops": {"fusion.1916": "bwd", ...}}``), so
+        that a reader of the trace can sum the device's ops under the
+        program's names; one file a process, replaced when a re-formed
+        world builds its step. Returns what ``step_built`` says of it:
+        how many instructions the rule classes of how many that run,
+        and the compiler's own account of the step's memory, which
+        counts the temporaries that ``peak_hbm_bytes`` cannot see. The
+        step's own first call does not find this executable (the jitted
+        call compiles its own lowering): where compiled programs are
+        kept (``compile_cache_dir``) it loads the program once more
+        from there, elsewhere it compiles a second time. A traced run
+        pays that in its first window; the two executables are one
+        program with one set of instruction names."""
+        compiled = lowered.compile()
+        ops_map, total = step_ops.step_ops_map(compiled.as_text())
+        path = os.path.join(trace_dir, step_ops.FILE_NAME)
+        os.makedirs(trace_dir, exist_ok=True)
+        with open(path + ".tmp", "w", encoding="utf-8") as f:
+            json.dump(ops_map, f)
+        os.replace(path + ".tmp", path)
+        facts = {
+            "step_ops_named": len(ops_map["ops"]),
+            "step_ops_total": total,
+        }
+        memory = compiled.memory_analysis()
+        if memory is not None:
+            facts.update(
+                step_argument_bytes=int(memory.argument_size_in_bytes),
+                step_temp_bytes=int(memory.temp_size_in_bytes),
+                step_alias_bytes=int(memory.alias_size_in_bytes),
+            )
+        return facts
 
     def _step_callable_for(self, args):
         """An AOT-compiled executable exactly matching this call's

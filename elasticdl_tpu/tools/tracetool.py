@@ -32,10 +32,27 @@ Usage::
 Accepts the ``{"traceEvents": [...]}`` document or a bare event list,
 and (for convenience in tests) raw span-record lists from
 ``SpanLog.tail()``.
+
+``--step-split <EDL_PROFILE_DIR>`` reads a profile directory instead:
+the profiler's trace of a TPU job and the map the worker wrote beside
+it (``edl_step_ops.json``, utils/step_ops.py), and prints where the
+DEVICE's time of the last whole steps went under the program's names:
+ms a step by class (fwd, bwd, remat, optimizer, reduce, mixed,
+unnamed), the ten largest ops of each class, and the mixed ops by the
+classes the compiler fused (docs/observability.md "The device step's
+classes")::
+
+    python -m elasticdl_tpu.tools.tracetool --step-split /tmp/profile
 """
 
+import bisect
+import glob
 import json
+import os
+import re
 import sys
+
+from elasticdl_tpu.utils import step_ops
 
 STEP_SPAN = "step"
 
@@ -225,15 +242,145 @@ def format_report(report):
     return "\n".join(lines)
 
 
+STEP_SPLIT_STEPS = 16  # as many whole steps as the trace holds, at most
+_DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
+
+
+def step_split(profile_dir, max_steps=STEP_SPLIT_STEPS):
+    """The device's time of the last whole steps under the program's
+    names: the join of the profiler's trace under ``profile_dir`` with
+    the map the traced worker wrote there. Per device, the ops on the
+    ``XLA Ops`` line that lie inside the last ``max_steps`` executions
+    of the map's module on the ``XLA Modules`` line, each op's self
+    time summed under the class the map gives its instruction; averaged
+    over the devices. Raises ValueError where the directory holds no
+    trace, no map, or no execution of the map's module."""
+    traces = sorted(
+        glob.glob(
+            os.path.join(
+                profile_dir, "plugins", "profile", "*", "*.xplane.pb"
+            )
+        )
+    )
+    map_path = os.path.join(profile_dir, step_ops.FILE_NAME)
+    if not traces:
+        raise ValueError("no *.xplane.pb under %s" % profile_dir)
+    if not os.path.exists(map_path):
+        raise ValueError(
+            "no %s in %s: the job was not traced by a program that "
+            "writes it" % (step_ops.FILE_NAME, profile_dir)
+        )
+    with open(map_path, encoding="utf-8") as f:
+        ops_map = json.load(f)
+    from jax.profiler import ProfileData
+
+    merged, devices, steps = {}, 0, 0
+    for plane in ProfileData.from_file(traces[-1]).planes:
+        if not _DEVICE_PLANE.match(plane.name):
+            continue
+        lines = {line.name: line for line in plane.lines}
+        if "XLA Modules" not in lines or "XLA Ops" not in lines:
+            continue
+        runs = sorted(
+            (e.start_ns, e.start_ns + e.duration_ns)
+            for e in lines["XLA Modules"].events
+            if e.name.partition("(")[0] == ops_map["module"]
+        )
+        # the trace may stop inside its last execution: leave that out
+        runs = runs[-max_steps - 1 : -1] or runs
+        if not runs:
+            continue
+        devices, steps = devices + 1, len(runs)
+        starts = [lo for lo, _ in runs]
+        events = []
+        for e in lines["XLA Ops"].events:
+            i = bisect.bisect_right(starts, e.start_ns) - 1
+            if i >= 0 and e.start_ns < runs[i][1]:
+                events.append((e.name, e.start_ns, e.start_ns + e.duration_ns))
+        step_ops.split_by_class(events, ops_map["ops"], into=merged)
+    if not devices:
+        raise ValueError(
+            "%s holds no execution of module %r on a TPU device"
+            % (traces[-1], ops_map["module"])
+        )
+    per_step = 1e-6 / devices / steps  # ns in all -> ms a step a device
+    order = step_ops.CLASSES + (step_ops.MIXED, step_ops.UNNAMED)
+    pairs = {}
+    for (_, classes), (ns, _) in merged.get(step_ops.MIXED, {}).items():
+        pairs[classes] = pairs.get(classes, 0.0) + ns * per_step
+    return {
+        "module": ops_map["module"],
+        "devices": devices,
+        "steps": steps,
+        "ms_per_step": {
+            bucket: sum(ns for ns, _ in merged.get(bucket, {}).values())
+            * per_step
+            for bucket in order
+        },
+        "top": {
+            bucket: [
+                [name, classes, ns * per_step, calls / devices / steps]
+                for (name, classes), (ns, calls) in sorted(
+                    merged.get(bucket, {}).items(), key=lambda kv: -kv[1][0]
+                )[:10]
+            ]
+            for bucket in order
+        },
+        "mixed_pairs": dict(sorted(pairs.items(), key=lambda kv: -kv[1])),
+    }
+
+
+def format_step_split(report):
+    total = sum(report["ms_per_step"].values())
+    lines = [
+        "module %s: %d device(s), last %d steps, %.3f ms a step on the device"
+        % (report["module"], report["devices"], report["steps"], total),
+        "",
+        "ms a step by class (self times; mixed: an op that holds more "
+        "than one class, never divided):",
+    ]
+    for bucket, ms in report["ms_per_step"].items():
+        lines.append(
+            "  %-10s %9.3f  %5.1f%%" % (bucket, ms, 100.0 * ms / total)
+        )
+    lines += ["", "mixed ops by the classes fused:"]
+    for classes, ms in report["mixed_pairs"].items():
+        lines.append("  %-32s %9.3f" % (classes, ms))
+    for bucket, ops in report["top"].items():
+        if not ops:
+            continue
+        lines += ["", "largest ops of %s (ms a step, calls a step):" % bucket]
+        for name, classes, ms, calls in ops:
+            lines.append(
+                "  %-58s %8.3f %7.2f  %s"
+                % (name, ms, calls, classes if bucket == step_ops.MIXED else "")
+            )
+    return "\n".join(lines)
+
+
 def main(argv=None):
     argv = list(sys.argv[1:] if argv is None else argv)
     as_json = "--json" in argv
     argv = [a for a in argv if a != "--json"]
+    if argv[:1] == ["--step-split"] and len(argv) == 2:
+        try:
+            report = step_split(argv[1])
+        except ValueError as err:
+            print("tracetool: %s" % err)
+            return 2
+        print(
+            json.dumps(report, indent=2)
+            if as_json
+            else format_step_split(report)
+        )
+        return 0
     if not argv:
         print(__doc__.strip().splitlines()[0])
         print(
             "usage: python -m elasticdl_tpu.tools.tracetool "
-            "<trace.json | -> [--json]"
+            "<trace.json | -> [--json]\n"
+            "       python -m elasticdl_tpu.tools.tracetool "
+            "--step-split <EDL_PROFILE_DIR> [--json]"
         )
         return 2
     src = argv[0]

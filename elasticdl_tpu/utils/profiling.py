@@ -127,6 +127,14 @@ def _annotation(name, **fields):
     return ann
 
 
+def profile_dir():
+    """Where this process's profiler trace goes (``EDL_PROFILE_DIR``),
+    or None in a run that is not traced. What exists only in a traced
+    run (the compiled step's ops by class, ``utils/step_ops.py``) asks
+    here."""
+    return os.environ.get("EDL_PROFILE_DIR") or None
+
+
 def maybe_profile():
     """Context from env: EDL_PROFILE_DIR -> trace, else no-op.
 
@@ -134,7 +142,7 @@ def maybe_profile():
     call ``jax.distributed.initialize`` (elastic allreduce workers) must
     use :func:`maybe_start_trace` *after* their world forms instead.
     """
-    log_dir = os.environ.get("EDL_PROFILE_DIR")
+    log_dir = profile_dir()
     if log_dir:
         return trace(log_dir)
     return contextlib.nullcontext()
@@ -148,7 +156,7 @@ def maybe_start_trace():
     and restart after the next one forms, yielding one trace segment per
     world.
     """
-    log_dir = os.environ.get("EDL_PROFILE_DIR")
+    log_dir = profile_dir()
     if not log_dir or _trace_dir is not None:
         return False
     _start(log_dir)

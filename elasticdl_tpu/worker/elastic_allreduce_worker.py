@@ -918,14 +918,24 @@ class ElasticAllReduceWorker:
         compiled programs are kept. One log line and
         one ``step_built`` event (scalar fields: it ships to the master's
         event log with the next task report)."""
+        from elasticdl_tpu.utils import profiling, step_ops
+
         if self._step_reported:
+            if profiling.profile_dir():
+                # a re-formed world compiled its step anew: the map of
+                # its ops by class replaces the last world's
+                try:
+                    self.trainer.describe_step()
+                except Exception:
+                    logger.warning(
+                        "could not describe the re-built step", exc_info=True
+                    )
             return
         self._step_reported = True
         import jax
 
         from elasticdl_tpu.data.recordio import reader_kind
         from elasticdl_tpu.ops.flash_attention import attention_in_step
-        from elasticdl_tpu.utils import profiling
 
         # the first train_window's clocks start here: describe_step
         # does the step's trace and lowering, which the first step call
@@ -962,6 +972,11 @@ class ElasticAllReduceWorker:
             "compile_cache_dir": jax.config.jax_compilation_cache_dir
             or "",
         }
+        # a traced run's own: the compiled step's ops by class and the
+        # compiler's account of its memory (describe_step)
+        report.update(
+            (k, facts[k]) for k in step_ops.STEP_BUILT_FIELDS if k in facts
+        )
         # what the model says of its own layout (a zoo module's
         # ``step_facts``: layers by kind, experts held and routed over);
         # absent on a model that has nothing to say

@@ -433,6 +433,22 @@ def test_step_built_carries_the_models_fact(job, fact):
     assert built[fact] == FACTS[fact]
 
 
+def test_an_untraced_jobs_step_built_is_what_it_was(job):
+    """Without ``EDL_PROFILE_DIR`` the event carries no field of the
+    traced run's (the compiled step's ops by class, the compiler's
+    memory account): exactly the keys it had before they existed."""
+    _, built = job
+    assert set(built) == {
+        "kind", "id", "ts", "src_id", "src_ts", "worker",
+        "platform", "device_kind", "device_count", "mesh", "attention",
+        "pallas_calls", "pallas_interpreted", "tpu_custom_calls",
+        "mosaic_kernels", "donated_inputs", "record_reader",
+        "compile_cache_dir",
+        # the model's own (``step_facts``)
+        "routing", "expert_apply", "tie_head", *FACTS,
+    }  # fmt: skip
+
+
 def test_step_built_names_the_grouped_matmul_kernels(job):
     _, built = job
     # interpreted here; on the chip the same names are mosaic_kernels

@@ -319,8 +319,9 @@ if n:
 
 
 @pytest.fixture(scope="module")
-def job(tmp_path_factory):
-    """One traced CPU job; (train_window events, ProfileData)."""
+def job_dir(tmp_path_factory):
+    """One traced CPU job; the directory with its ``events.jsonl`` and
+    its ``trace``."""
     from elasticdl_tpu.data.example import encode_example
     from elasticdl_tpu.data.recordio import create_recordio
 
@@ -370,12 +371,22 @@ def job(tmp_path_factory):
         timeout=600,
     )  # fmt: skip
     assert got.returncode == 0, got.stderr[-3000:]
-    with open(events_path) as f:
+    return out
+
+
+def _events(job_dir, kind):
+    with open(job_dir / "events.jsonl") as f:
         events = [json.loads(line) for line in f if line.strip()]
-    windows = [e for e in events if e["kind"] == "train_window"]
+    return [e for e in events if e["kind"] == kind]
+
+
+@pytest.fixture(scope="module")
+def job(job_dir):
+    """(train_window events, ProfileData) of the traced job."""
+    windows = _events(job_dir, "train_window")
     assert sum(w["steps"] for w in windows) == STEPS
     (xplane,) = glob.glob(
-        str(out / "trace" / "plugins" / "profile" / "*" / "*.xplane.pb")
+        str(job_dir / "trace" / "plugins" / "profile" / "*" / "*.xplane.pb")
     )
     from jax.profiler import ProfileData
 
@@ -461,3 +472,25 @@ def test_trace_holds_no_python_tracer_event(job):
     _, data = job
     names = _host_event_names(data)
     assert names and not [n for n in names if n.startswith("$")]
+
+
+def test_a_traced_job_says_what_its_compiled_step_holds(job_dir):
+    """Under ``EDL_PROFILE_DIR`` the worker writes the compiled step's
+    ops by class beside the trace and ``step_built`` says how many it
+    classed, with the compiler's account of the step's memory
+    (docs/observability.md "The device step's classes")."""
+    from elasticdl_tpu.utils import step_ops
+
+    (built,) = _events(job_dir, "step_built")
+    with open(job_dir / "trace" / step_ops.FILE_NAME) as f:
+        ops_map = json.load(f)
+    assert ops_map["module"] == "jit_per_device"
+    assert 0 < built["step_ops_named"] == len(ops_map["ops"])
+    assert built["step_ops_named"] <= built["step_ops_total"]
+    held = set("+".join(ops_map["ops"].values()).split("+"))
+    assert {"fwd", "bwd", "optimizer"} <= held <= set(step_ops.CLASSES)
+    assert "remat" not in held  # this job recomputes nothing
+    for field in ("step_argument_bytes", "step_temp_bytes", "step_alias_bytes"):
+        assert isinstance(built[field], int) and built[field] > 0, field
+    # the state is the step's largest argument, donated and so aliased
+    assert built["step_alias_bytes"] <= built["step_argument_bytes"]
