@@ -13,12 +13,17 @@ the rows past the last group's end belong to no group (the assignments
 of experts this device does not hold): no tile made only of them is
 computed or written, and what they hold in the result is UNDEFINED
 (whatever the buffer held; it may not be finite). Nothing they hold on
-the way in reaches a row a group owns or a gradient of ``rhs``; the
-caller masks them once, where a result leaves the buffer
-(parallel/expert.py ``held_experts_apply``). Zeroing them here instead,
-a pass over the whole buffer after every product, costs 1.4 ms of an
-expert layer's 14.5 ms forward and backward at the benchmark's sizes,
-where seven rows of eight belong to no group (PERF.md section 6, PR 28).
+the way in reaches a row a group owns or a gradient of ``rhs``, and
+since PR 38 the caller hands in nothing there either: it gathers and
+computes the chunks that hold a group's row and leaves the rest of its
+buffers as the memory was (parallel/expert.py ``held_experts_apply``),
+and where a result leaves it picks the rows under the last group's end
+and no other. Zeroing them here instead, a pass over the whole buffer
+after every product, cost 1.4 ms of an expert layer's 14.5 ms forward
+and backward at the benchmark's sizes, where seven rows of eight
+belong to no group (PERF.md section 6, PR 28); the layer alone is
+12.2 ms that way and 4.3 ms with only the held rows moved, 2.7 of it
+these kernels (PERF.md section 6, PR 38).
 
 Two kernels, in the pattern of ops/flash_attention.py (named custom
 calls, f32 accumulation over operands that may be bfloat16, interpreted
@@ -352,8 +357,9 @@ def grouped_matmul(lhs, rhs, group_sizes, tiling=None):
     ``group_sizes`` (G,) int32: group ``g`` owns the ``group_sizes[g]``
     rows after those of the groups before it. Rows past the last
     group's end are UNDEFINED in the result and in the ``lhs``
-    gradient (never written: mask them where a result leaves the
-    buffer, before anything multiplies it); they give ``rhs`` no
+    gradient (never written: where a result leaves the buffer read
+    none of them, or mask them before anything multiplies them); they
+    give ``rhs`` no
     gradient, whatever they or their cotangent hold. ``tiling``
     (tm, tk, tn) overrides :func:`auto_tiles`; every size has to divide
     its dimension, and on a TPU ``K`` and ``N`` are multiples of 128

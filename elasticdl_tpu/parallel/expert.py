@@ -31,7 +31,9 @@ a softmax with no bias at all; either way the
 gates are normalised over all the selected experts, held or not. The
 ``T * k`` assignments are sorted so that those of held experts come
 first, grouped by expert, and go through ops/grouped_matmul.py: a
-static buffer of ``T * k`` rows, no capacity, no dropped token
+static buffer of ``T * k`` rows, no capacity, no dropped token, of
+which only the rows routed here are moved and computed, by loops
+whose length is read on the device
 (:func:`held_experts_apply_masked` gives the same share with no
 dispatch at all, every held expert over every token, in a time the
 routing cannot move). With
@@ -56,6 +58,7 @@ worse form of this one.
 """
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -285,40 +288,267 @@ def expert_bias_update(expert_bias, assignments, rate):
     return expert_bias + rate * jnp.sign(jnp.mean(load) - load)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _rows_for_assignments(x, order, inverse, held_assignment, k):
-    """(T, d) tokens -> (T * k, d): row ``r`` is the token of
-    assignment ``order[r]``. The transpose is a gather too (by
-    ``inverse``), never a scatter; assignments of absent experts give
-    nothing back."""
-    return x[order // k]
+# rows one trip of a loop over the held rows moves (and the unit the
+# rows moved round up to): a few trips at the share a device of an
+# 8-way expert-parallel job holds, each long enough that a trip's
+# fixed cost does not show
+DISPATCH_CHUNK_ROWS = 1024
 
 
-def _rows_fwd(x, order, inverse, held_assignment, k):
-    return x[order // k], (inverse, held_assignment)
+def dispatch_chunk_rows(rows):
+    """The chunk in which a pass over the held rows walks a buffer of
+    ``rows`` rows: :data:`DISPATCH_CHUNK_ROWS` for a buffer it divides
+    (a multiple of the grouped products' 512-row tile), else the
+    largest divisor of ``rows`` under it (a toy buffer: often the
+    whole of it). It divides the buffer so that the last chunk ends
+    where the buffer does."""
+    return next(
+        c
+        for c in range(min(DISPATCH_CHUNK_ROWS, rows), 0, -1)
+        if rows % c == 0
+    )
 
 
-def _rows_bwd(k, residuals, g):
-    inverse, held_assignment = residuals
-    back = jnp.where(held_assignment[:, None], g[inverse], 0)
-    return back.reshape(-1, k, g.shape[-1]).sum(axis=1), None, None, None
+class _HeldRows(NamedTuple):
+    """Where the ``n_here`` assignments to held experts are, in the two
+    orders the layer walks them in. BY EXPERT (row ``r`` of the
+    ``T * k``-row buffers the grouped products read and write; the
+    held rows are ``r < n_here``): ``assignment_of_row``. BY TOKEN
+    (position ``p < n_here``: the held assignments in token-major
+    order, so that a token's are adjacent): ``row_of_position``,
+    ``assignment_of_position`` (``T * k`` from ``n_here`` on), both
+    padded by ``k - 1`` positions so that a chunk can look past its
+    end. By token itself: ``first_position`` (T,), how many held
+    assignments the tokens before ``t`` have, and ``held_count`` (T,),
+    how many ``t`` has."""
+
+    n_here: jax.Array
+    assignment_of_row: jax.Array
+    row_of_position: jax.Array
+    assignment_of_position: jax.Array
+    first_position: jax.Array
+    held_count: jax.Array
 
 
-_rows_for_assignments.defvjp(_rows_fwd, _rows_bwd)
+def _held_rows(held_assignment, order, n_here):
+    tokens, k = held_assignment.shape
+    row = jnp.arange(tokens * k, dtype=jnp.int32)
+    # the held rows by the assignment each holds: an assignment is
+    # ``t * k + j``, so that is by token. An order is moved by a sort
+    # that carries what has to move, not by a gather: 32,768 scalars
+    # gathered cost the v5e 0.23 ms, a sort of them 0.03 (PERF.md
+    # section 6, PR 38)
+    assignment_of_position, row_of_position = jax.lax.sort(
+        (jnp.where(row < n_here, order, tokens * k), row), num_keys=1
+    )
+    held_count = held_assignment.sum(axis=1, dtype=jnp.int32)
+    return _HeldRows(
+        n_here=n_here,
+        assignment_of_row=order,
+        row_of_position=jnp.pad(row_of_position, (0, k - 1)),
+        assignment_of_position=jnp.pad(
+            assignment_of_position, (0, k - 1), constant_values=tokens * k
+        ),
+        first_position=jnp.cumsum(held_count) - held_count,
+        held_count=held_count,
+    )
+
+
+def _over_held_chunks(n_here, buffer_shape, dtype, body):
+    """A buffer whose chunks that hold a row under ``n_here`` are
+    ``body(start, chunk)`` each: as many trips as ``n_here``, read on
+    the device, asks for. What the rows past them hold is UNDEFINED, as
+    it is past the held rows of a grouped product's result: the buffer
+    is not filled first (a fill of ``T * k`` rows for every pass costs
+    the layer a fifth of its time at an eighth of the rows held)."""
+    chunk = dispatch_chunk_rows(buffer_shape[0])
+    return jax.lax.fori_loop(
+        0,
+        (n_here + chunk - 1) // chunk,
+        lambda i, buffer: jax.lax.dynamic_update_slice_in_dim(
+            buffer, body(i * chunk, chunk).astype(dtype), i * chunk, 0
+        ),
+        jax.lax.empty(buffer_shape, dtype),
+    )
+
+
+def _gather_held_rows(source, held_rows, scale=None):
+    """(T, d) ``source`` -> (T * k, d): row ``r`` under ``n_here`` is
+    the row of ``source`` of the token whose assignment was sorted to
+    ``r``, times ``scale`` (T * k,) of that assignment in float32
+    where one is given. Only the chunks that hold such a row are
+    gathered."""
+    rows = held_rows.assignment_of_row.shape[0]
+    k = rows // source.shape[0]
+
+    def body(start, chunk):
+        assignment = jax.lax.dynamic_slice_in_dim(
+            held_rows.assignment_of_row, start, chunk
+        )
+        got = source[assignment // k]
+        if scale is not None:
+            got = got.astype(jnp.float32) * scale[assignment][:, None]
+        return got
+
+    return _over_held_chunks(
+        held_rows.n_here, (rows, source.shape[1]), source.dtype, body
+    )
+
+
+def _sum_held_rows_by_token(buffer, held_rows, scale=None):
+    """(T * k, d) ``buffer`` by expert -> (T, d): a token's held rows
+    added up in float32, each times ``scale`` (T * k,) of its
+    assignment where one is given; zero for a token with none. The
+    held rows are walked by token, a chunk at a time: a token's rows
+    are adjacent there, at most ``k`` of them, so ``k - 1`` shifted
+    adds leave every token's sum at its first position, and ONE gather
+    of T rows brings the sums out. A row past ``n_here`` is never
+    added to one under it (its assignment reads as no token's),
+    whatever it holds."""
+    rows, width = buffer.shape
+    tokens = held_rows.first_position.shape[0]
+    k = rows // tokens
+
+    def body(start, chunk):
+        def ahead(values):
+            return jax.lax.dynamic_slice_in_dim(values, start, chunk + k - 1)
+
+        assignment = ahead(held_rows.assignment_of_position)
+        got = buffer[ahead(held_rows.row_of_position)].astype(jnp.float32)
+        if scale is not None:
+            got = got * scale[jnp.minimum(assignment, rows - 1)][:, None]
+        token = assignment // k
+        total = got[:chunk]
+        for j in range(1, k):
+            total = total + jnp.where(
+                (token[j : j + chunk] == token[:chunk])[:, None],
+                got[j : j + chunk],
+                0.0,
+            )
+        return total
+
+    sums = _over_held_chunks(
+        held_rows.n_here, (rows, width), buffer.dtype, body
+    )
+    return jnp.where(
+        (held_rows.held_count > 0)[:, None],
+        sums[jnp.minimum(held_rows.first_position, rows - 1)],
+        0,
+    )
 
 
 @jax.custom_vjp
-def _permute_rows(x, perm, inverse):
-    """``x[perm]`` whose transpose is ``g[inverse]``."""
-    return x[perm]
+def _rows_for_assignments(x, held_rows):
+    """(T, d) tokens -> (T * k, d): row ``r`` under ``n_here`` is the
+    token of the assignment sorted to ``r``. The transpose adds up, by
+    token, the held rows of what comes back: gathers both ways, never
+    a scatter; assignments of absent experts give nothing back."""
+    return _gather_held_rows(x, held_rows)
 
 
-_permute_rows.defvjp(
-    lambda x, perm, inverse: (x[perm], (perm, inverse)),
-    lambda residuals, g: (g[residuals[1]], None, None),
+_rows_for_assignments.defvjp(
+    lambda x, held_rows: (_rows_for_assignments(x, held_rows), held_rows),
+    lambda held_rows, g: (_sum_held_rows_by_token(g, held_rows), None),
 )
 
 
+@jax.custom_vjp
+def _gated_sum_by_token(y, gates, held_rows):
+    """(T * k, d) rows by expert, (T, k) gates -> (T, d) in ``y``'s
+    dtype: ``sum over j, (t, j) held, of gates[t, j] * y[row of
+    (t, j)]``, the products and the sum in float32. Only rows under
+    ``n_here`` are read, here and in the transposes: ``y``'s cotangent
+    is the token's times the gate, gathered for those rows alone, and
+    a gate's is its row's product with the token's cotangent, zero
+    for an assignment to an absent expert."""
+    return _sum_held_rows_by_token(y, held_rows, gates.reshape(-1))
+
+
+def _gated_sum_bwd(residuals, g):
+    y, gates, held_rows = residuals
+    rows = y.shape[0]
+    k = gates.shape[1]
+    dy = _gather_held_rows(g, held_rows, gates.reshape(-1))
+
+    def body(start, chunk):
+        assignment = jax.lax.dynamic_slice_in_dim(
+            held_rows.assignment_of_row, start, chunk
+        )
+        mine = jax.lax.dynamic_slice_in_dim(y, start, chunk)
+        return jnp.sum(
+            mine.astype(jnp.float32) * g[assignment // k].astype(jnp.float32),
+            axis=1,
+        )
+
+    by_row = _over_held_chunks(held_rows.n_here, (rows,), jnp.float32, body)
+    # back by assignment: the rows of absent experts sorted last, and
+    # what they (and the last chunk's rows past ``n_here``, where ``y``
+    # is undefined) hold masked before it is anyone's gradient
+    row = jnp.arange(rows, dtype=jnp.int32)
+    _, dgates = jax.lax.sort(
+        (
+            held_rows.assignment_of_row,
+            jnp.where(row < held_rows.n_here, by_row, 0.0),
+        ),
+        num_keys=1,
+    )
+    return dy, dgates.reshape(gates.shape).astype(gates.dtype), None
+
+
+_gated_sum_by_token.defvjp(
+    lambda y, gates, held_rows: (
+        _gated_sum_by_token(y, gates, held_rows),
+        (y, gates, held_rows),
+    ),
+    _gated_sum_bwd,
+)
+
+
+def _swiglu(up):
+    width = up.shape[1] // 2
+    return jax.nn.silu(up[:, :width]) * up[:, width:]
+
+
+@jax.custom_vjp
+def _swiglu_held_rows(up, n_here):
+    """``silu(up[:, :f]) * up[:, f:]`` over the chunks that hold a row
+    under ``n_here``, and so its cotangent."""
+    rows, width = up.shape
+    return _over_held_chunks(
+        n_here,
+        (rows, width // 2),
+        up.dtype,
+        lambda start, chunk: _swiglu(
+            jax.lax.dynamic_slice_in_dim(up, start, chunk)
+        ),
+    )
+
+
+def _swiglu_bwd(residuals, g):
+    up, n_here = residuals
+
+    def body(start, chunk):
+        _, pull = jax.vjp(
+            _swiglu, jax.lax.dynamic_slice_in_dim(up, start, chunk)
+        )
+        return pull(jax.lax.dynamic_slice_in_dim(g, start, chunk))[0]
+
+    return _over_held_chunks(n_here, up.shape, up.dtype, body), None
+
+
+_swiglu_held_rows.defvjp(
+    lambda up, n_here: (_swiglu_held_rows(up, n_here), (up, n_here)),
+    _swiglu_bwd,
+)
+
+
+# one program where it is called outside any (a model's ``init`` is an
+# eager forward pass): op by op, the loops below are traced anew at
+# every call and cost establish seconds; inside a step it is inlined
+# and changes nothing
+@functools.partial(
+    jax.jit, static_argnames=("first_expert_held",), inline=True
+)
 def held_experts_apply(x, selected, gates, w_in, w_out, first_expert_held):
     """This device's share of a SwiGLU expert layer's result.
 
@@ -335,33 +565,39 @@ def held_experts_apply(x, selected, gates, w_in, w_out, first_expert_held):
     experts come first, grouped by expert, and rows of absent experts
     last, covered by no group of the three grouped products
     (ops/grouped_matmul.py), whose tiles past the held rows are never
-    computed. What those rows hold in between is undefined and is
-    masked where a result leaves (here and in the transposes)."""
+    computed. Nothing else walks the rows of absent experts either:
+    how many rows are held is on the device (``group_sizes``), and
+    every pass that is not a kernel (the gather in front, the
+    activation, the sum by token behind, and their transposes) is a
+    loop over the chunks of :func:`dispatch_chunk_rows` rows that hold
+    a held row, as many trips as the routing asks for: the whole
+    buffer when every expert is held, none when no row came here. No
+    capacity, no dropped assignment: the result is the same for any
+    routing. What a buffer holds past the held rows is UNDEFINED, on
+    the way into a kernel (inside the last chunk, rows of absent
+    assignments; past it, whatever the memory held) as on the way out
+    of one, and nothing reads it: where a result leaves (a token's
+    sum, a gate's gradient) only rows under the held count are
+    picked."""
     from elasticdl_tpu.ops.grouped_matmul import grouped_matmul
 
-    tokens, k = selected.shape
     held = w_in.shape[0]
-    width = w_out.shape[1]
-    local = selected.reshape(-1) - first_expert_held
+    local = selected - first_expert_held
     held_assignment = jnp.logical_and(local >= 0, local < held)
-    group = jnp.where(held_assignment, local, held)  # absent: last
+    group = jnp.where(held_assignment, local, held).reshape(-1)  # absent: last
     order = jnp.argsort(group, stable=True).astype(jnp.int32)
-    inverse = jnp.argsort(order).astype(jnp.int32)
-    group_sizes = jnp.bincount(group, length=held + 1)[:held].astype(
-        jnp.int32
+    group_sizes = jnp.sum(
+        group[:, None] == jnp.arange(held, dtype=group.dtype),
+        axis=0,
+        dtype=jnp.int32,
     )
+    held_rows = _held_rows(held_assignment, order, group_sizes.sum())
 
-    rows = _rows_for_assignments(x, order, inverse, held_assignment, k)
+    rows = _rows_for_assignments(x, held_rows)
     up = grouped_matmul(rows, w_in, group_sizes)
-    act = (jax.nn.silu(up[:, :width]) * up[:, width:]).astype(x.dtype)
+    act = _swiglu_held_rows(up, held_rows.n_here)
     y = grouped_matmul(act, w_out, group_sizes)
-    back = _permute_rows(y, inverse, order).reshape(tokens, k, -1)
-    # masked BEFORE the gate multiplies it: the gate's gradient is this
-    # product's other factor, and an undefined row times zero is not zero
-    back = jnp.where(
-        held_assignment.reshape(tokens, k, 1), back.astype(jnp.float32), 0.0
-    )
-    return (back * gates[..., None]).sum(axis=1).astype(x.dtype)
+    return _gated_sum_by_token(y, gates, held_rows)
 
 
 def _masked_share(x, gate, w_in, w_out):

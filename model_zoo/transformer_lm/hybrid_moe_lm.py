@@ -601,6 +601,8 @@ class HybridMoELM(nn.Module):
             "conv_layers": self.layer_pattern.count(CONV),
             "attention_layers": self.layer_pattern.count(ATTENTION),
         }
+        if facts["expert_layers"] and self.expert_apply == EXPERT_APPLIES[0]:
+            facts["moe_dispatch_chunk_rows"] = expert.DISPATCH_CHUNK_ROWS
         if self.remat_layers:
             facts["remat_layers"] = 1
             facts["remat_kept_products"] = (

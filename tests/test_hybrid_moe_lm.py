@@ -5,6 +5,7 @@ loaded by path as ``benchmark/spec.load_reference`` loads it): float32,
 toy widths, on the CPU; and one toy job through ``edl train`` whose
 events carry the routing counters."""
 
+import functools
 import importlib.util
 import json
 import os
@@ -183,6 +184,164 @@ def test_the_shares_add_up_to_the_uncut_layer(reference):
     np.testing.assert_allclose(everything, whole, atol=TOL)
 
 
+# ---------------------------------------------------------------------------
+# the dispatch moves the held rows only: the layer against the repo's
+# other exact form of it, every held expert over every token
+# ---------------------------------------------------------------------------
+
+# the tests' chunk: 48 tokens x 2 assignments are six of them
+CHUNK, TOKENS, CHOICES = 16, 48, 2
+
+
+def _routing(held_rows):
+    """(selected, first_expert_held, experts_held) of 16 experts with
+    ``held_rows`` of the 96 assignments routed to held experts;
+    ``"pairs"``: BOTH of every even token's and neither of any odd
+    token's; ``"all"``: every expert held, a router's own top-2."""
+    if held_rows == "all":
+        logits = jax.random.normal(jax.random.PRNGKey(5), (TOKENS, 16))
+        return expert.sigmoid_topk_route(logits, jnp.zeros(16), CHOICES)[0], 0, 16
+    token = np.arange(TOKENS)[:, None]
+    choice = np.arange(CHOICES)[None, :]
+    # absent: 0-3 and 8-15; held: 4-7; distinct within a token
+    absent = np.where(choice == 0, token % 4, 8 + token % 8)
+    held = 4 + (token + choice) % 4
+    if held_rows == "pairs":
+        here = np.broadcast_to(token % 2 == 0, absent.shape)
+    else:
+        here = np.zeros(TOKENS * CHOICES, bool)
+        here[np.random.default_rng(11).permutation(here.size)[:held_rows]] = True
+        here = here.reshape(absent.shape)
+    return jnp.asarray(np.where(here, held, absent), jnp.int32), 4, 4
+
+
+HELD_ROWS = {
+    "no-row": (0, 0),
+    "one-row": (1, 1),
+    "a-chunk": (CHUNK, CHUNK),
+    "a-chunk-and-a-row": (CHUNK + 1, CHUNK + 1),
+    "both-of-a-token-none-of-the-next": ("pairs", TOKENS),
+    "every-row": ("all", TOKENS * CHOICES),
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _both_forms(case):
+    """Output and gradients (x, gates, w_in, w_out) of the dispatching
+    layer and of the masked one, in float32, with the chunk at
+    ``CHUNK`` rows and every buffer a loop fills part of holding NaN
+    first (on the chip: whatever the memory held), so that a pass that
+    read a row it did not write would show."""
+    held_rows, count = HELD_ROWS[case]
+    selected, first, held = _routing(held_rows)
+    local = selected - first
+    assert int(((local >= 0) & (local < held)).sum()) == count
+    keys = jax.random.split(jax.random.PRNGKey(2), 5)
+    x = jax.random.normal(keys[0], (TOKENS, 64))
+    gates = jax.nn.softmax(jax.random.normal(keys[1], (TOKENS, CHOICES)))
+    w_in = jax.random.normal(keys[2], (held, 64, 64)) / 8
+    w_out = jax.random.normal(keys[3], (held, 32, 64)) / 6
+    cotangent = jax.random.normal(keys[4], (TOKENS, 64))
+
+    def both(apply):
+        def objective(x, gates, w_in, w_out):
+            out = apply(x, selected, gates, w_in, w_out, first)
+            return jnp.sum(out * cotangent), out
+
+        (_, out), grads = jax.value_and_grad(
+            objective, argnums=(0, 1, 2, 3), has_aux=True
+        )(x, gates, w_in, w_out)
+        return dict(zip(("out", "x", "gates", "w_in", "w_out"), (out, *grads)))
+
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(expert, "DISPATCH_CHUNK_ROWS", CHUNK)
+        patch.setattr(
+            jax.lax, "empty", lambda shape, dtype: jnp.full(shape, jnp.nan, dtype)
+        )
+        assert expert.dispatch_chunk_rows(TOKENS * CHOICES) == CHUNK
+        # the layer is jitted: a trace of these shapes made with the
+        # program's own chunk must not answer for this one, nor this
+        # one for a later test
+        jax.clear_caches()
+        try:
+            with jax.default_matmul_precision("highest"):
+                return (
+                    both(expert.held_experts_apply),
+                    both(expert.held_experts_apply_masked),
+                )
+        finally:
+            jax.clear_caches()
+
+
+@pytest.mark.parametrize("what", ["out", "x", "gates", "w_in", "w_out"])
+@pytest.mark.parametrize("case", sorted(HELD_ROWS))
+def test_the_dispatch_over_the_held_rows_is_exact_for_any_routing(case, what):
+    """No capacity and no dropped assignment: with no row routed here,
+    one, a chunk of them, a chunk and one, both assignments of some
+    tokens and none of their neighbours', and every row (every expert
+    held: the loops run their whole length), the result and each
+    gradient are those of every held expert over every token."""
+    dispatched, masked = _both_forms(case)
+    assert np.isfinite(np.asarray(dispatched[what])).all()
+    np.testing.assert_allclose(dispatched[what], masked[what], atol=TOL)
+    if what == "out" and case != "no-row":
+        assert float(jnp.abs(masked["out"]).max()) > 0.01
+
+
+@pytest.mark.parametrize(
+    "rows, chunk",
+    [(32768, 1024), (96, 96), (3000, 1000), (2048, 1024), (1024, 1024), (7, 7)],
+)
+def test_a_chunk_divides_its_buffer(rows, chunk):
+    assert expert.dispatch_chunk_rows(rows) == chunk
+    # a multiple of the grouped products' row tile where the buffer is
+    assert rows % 512 or chunk % 512 == 0
+
+
+def test_no_pass_of_the_step_moves_the_whole_buffer(zoo, monkeypatch):
+    """The lowered step of a small model (as the chip would be handed
+    it: kernels not interpreted, lowered for the TPU from here) gathers
+    no array of ``T * k`` rows, and holds the parent's kernels and no
+    other: 4 expert layers x 6 grouped products (attention at 128
+    positions is XLA's), which is what ``tpu_custom_calls`` of a
+    benchmark configuration counts."""
+    import re
+
+    from elasticdl_tpu.ops import flash_attention, grouped_matmul
+
+    monkeypatch.setattr(flash_attention, "kernel_interpret_mode", lambda: False)
+    monkeypatch.setattr(grouped_matmul, "kernel_interpret_mode", lambda: False)
+    monkeypatch.setattr(expert, "DISPATCH_CHUNK_ROWS", 128)
+    jax.clear_caches()  # the jitted layer's traces at another chunk
+    model = zoo.custom_model(
+        **dict(TOY, embed_dim=128, head_dim=64, expert_dim=128, dtype="bfloat16")
+    )
+    tokens = jnp.zeros((2, 128), jnp.int32)
+    buffer_rows = tokens.size * TOY["num_experts_per_tok"]
+    state = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), {"tokens": tokens})
+    )
+    params = state.pop("params")
+
+    def objective(params, state):
+        logits = model.apply(
+            dict(state, params=params), {"tokens": tokens}, training=True
+        )
+        return zoo.loss(logits, tokens)
+
+    text = (
+        jax.jit(jax.value_and_grad(objective))
+        .trace(params, state)
+        .lower(lowering_platforms=("tpu",))
+        .as_text()
+    )
+    assert text.count("@tpu_custom_call") == 4 * 6
+    gathered = re.findall(r"stablehlo\.gather.*-> tensor<(\d+)x(\d+)x", text)
+    assert ("%d" % tokens.size, "128") in gathered  # a token's sum, brought out
+    assert ("128", "128") in gathered  # a chunk
+    assert not [shape for shape in gathered if int(shape[0]) >= buffer_rows]
+
+
 def test_short_convolution_is_causal_and_matches_the_reference(reference, zoo):
     conv = zoo.ShortConv(kernel_size=3, dtype=jnp.float32)
     h = jax.random.normal(jax.random.PRNGKey(0), (2, 32, 64))
@@ -359,6 +518,7 @@ COUNTERS = (
 FACTS = {
     "expert_layers": 4, "experts_held": 4, "experts_routed": 16,
     "first_expert_held": 4, "conv_layers": 4, "attention_layers": 1,
+    "moe_dispatch_chunk_rows": 1024,
 }  # fmt: skip
 
 

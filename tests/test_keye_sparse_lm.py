@@ -778,6 +778,19 @@ def test_step_built_carries_the_models_fact(job, fact):
     assert built[fact] == FACTS[fact]
 
 
+def test_a_masked_share_names_no_dispatch_chunk(zoo, job):
+    """``moe_dispatch_chunk_rows`` is the chunk of the dispatching
+    layer's loops (``expert_apply=grouped``): every held expert over
+    every token has no dispatch, and its ``step_built`` no such
+    field; nor has a model without an expert layer."""
+    _, built = job
+    assert "moe_dispatch_chunk_rows" not in built
+    grouped = zoo.custom_model(**dict(TOY, expert_apply="grouped")).step_facts()
+    assert grouped["moe_dispatch_chunk_rows"] == expert.DISPATCH_CHUNK_ROWS == 1024
+    dense = dict(TOY, expert_apply="grouped", num_dense_layers=len(TOY["layer_pattern"]))
+    assert "moe_dispatch_chunk_rows" not in zoo.custom_model(**dense).step_facts()
+
+
 def test_the_job_ran_the_selecting_attention_it_was_asked_for(job):
     _, built = job
     # 64 positions: under the policy's 1,024, so XLA's masked attention;
