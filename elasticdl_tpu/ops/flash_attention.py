@@ -88,6 +88,28 @@ whose test takes the diagonal's place in :func:`_scores` and
 (:data:`SELECTED`). A call without a selection is built exactly as
 before: same names, operands and block specs.
 
+Under a WINDOW (PR 39, ``flash_attention(..., causal=True, window=W)``):
+query t reads keys ``t - W < s <= t``, and the same three bodies compute
+exactly the band, with no input more. What a square tile holds follows
+from its distance under the diagonal alone, so :func:`_walk_tile` tells
+the tiles apart statically (:func:`_band_strips`): a tile wholly outside
+the band does nothing, a tile wholly inside runs every sub-block with no
+mask, and the diagonal's tile and the one or two the window's lower edge
+crosses run their sub-blocks trimmed to the columns their rows can see,
+masked where an edge passes and only there (the lower edge's kept corner
+is the complement of the diagonal's). :func:`_plan`'s index maps clamp
+the blocks that follow a grid's inner axis from BOTH sides, so a skipped
+step, before the band as after it, fetches nothing. At L = 16,384 in
+1,024-tiles under W = 4,096 a q tile has work in 5 of its 16 k tiles
+(three whole and two of 0.625: :func:`causal_work_ratio` 1.062 over the
+58.7M pairs :func:`window_pairs` counts of the 134.2M causal ones). A
+row whose keys in a tile are all masked (the lower edge's tile holds
+such rows) runs on a maximum of ``NEG_INF`` and what it adds there is
+rescaled to nothing by the first tile that holds a key of its own: the
+diagonal's always does. The calls carry names of their own
+(:data:`WINDOWED`); ``W >= L`` is causal attention and is built as that,
+and a call with no window is built exactly as before.
+
 On a TPU backend the kernels compile through Mosaic. They run in Pallas
 interpret mode only in a process that was explicitly put on the CPU
 (tests, rehearsals); a CPU backend JAX fell back to, or any other
@@ -121,6 +143,14 @@ SELECTED = {
     BWD_DKV_KERNEL: "edl_flash_sel_bwd_dkv",
 }
 
+# and under a window (``flash_attention(..., window=W)``): the global
+# layers of a model that mixes both keep the plain names
+WINDOWED = {
+    FWD_KERNEL: "edl_flash_win_fwd",
+    BWD_DQ_KERNEL: "edl_flash_win_bwd_dq",
+    BWD_DKV_KERNEL: "edl_flash_win_bwd_dkv",
+}
+
 # every grid is (batch*heads, outer tile, inner tile) and accumulates
 # over the innermost axis only. No vmem_limit_bytes: on v5e / libtpu
 # 0.0.34 the default 1024x1024 tiles compile under Mosaic's default
@@ -152,7 +182,9 @@ def _higher(a, b):
     return jnp.maximum(a, b)
 
 
-def _walk_tile(qi, kj, block_q, block_k, w, causal, step, when=pl.when):
+def _walk_tile(
+    qi, kj, block_q, block_k, w, causal, step, when=pl.when, window=None
+):
     """Call ``step(strips)`` with the sub-blocks of tile ``(qi, kj)`` in
     which the causal mask leaves work: ``strips`` is a static list of
     ``(rows, cols, off)``.
@@ -160,7 +192,11 @@ def _walk_tile(qi, kj, block_q, block_k, w, causal, step, when=pl.when):
     ``rows`` and ``cols`` are static slices of the tile. ``off`` is None
     where every score of the sub-block is kept (no mask is built), else
     the sub-block's first q position minus its first k position: the
-    score at local ``(r, c)`` is kept when ``r + off >= c``.
+    score at local ``(r, c)`` is kept when ``r + off >= c``. Under a
+    ``window`` it is a pair ``(off, low)`` instead, either of them None
+    where that edge does not pass through the sub-block: the score is
+    kept when ``r + off >= c`` (the diagonal) and ``c >= r + low`` (the
+    window's lower edge, ``low = off - window + 1``).
 
     The tile is cut along q into ``block_q // w`` sub-blocks of ``w``
     rows, in ascending order; a ``w`` that does not divide ``block_q``
@@ -173,7 +209,17 @@ def _walk_tile(qi, kj, block_q, block_k, w, causal, step, when=pl.when):
       with each sub-block trimmed to the columns its rows can see: a
       static slice, and a static ``off``;
     - causal, other tiles (explicit sizes): nothing is cut. The tile
-      is skipped, masked or whole by a predicate on the program ids.
+      is skipped, masked or whole by a predicate on the program ids;
+    - under a ``window`` (causal, query t reads keys ``t - window < s
+      <= t``), square tiles: what a tile holds follows from ``qi - kj``
+      alone, so the tiles are told apart by it, statically
+      (:func:`_band_strips`): a run of distances whose tiles lie wholly
+      inside the band and step whole, and at most three whose tile an
+      edge crosses, each with its own trimmed and masked sub-blocks (the
+      diagonal's, and the one or two the lower edge passes through:
+      their kept corner is the complement of the diagonal's). Every
+      other tile does nothing. Other tiles: skipped, masked or whole by
+      a predicate, as above, with both edges in the mask.
     """
     if block_q % w:
         w = block_q
@@ -184,9 +230,33 @@ def _walk_tile(qi, kj, block_q, block_k, w, causal, step, when=pl.when):
             cols = slice(0, lo + w) if trimmed else all_cols
             yield slice(lo, lo + w), cols, lo if trimmed else None
 
-    whole = functools.partial(step, list(strips(False)))
+    untrimmed = list(strips(False))
+    whole = functools.partial(step, untrimmed)
     if not causal:
         whole()
+    elif window is not None and block_q == block_k:
+        distance, inside = qi - kj, []
+        for d in range((window + block_q - 2) // block_q + 1):
+            found = _band_strips(d, block_q, w, window)
+            if found == untrimmed:
+                inside.append(d)
+            elif found:
+                when(distance == d)(functools.partial(step, found))
+        if inside:  # one run: the band is convex
+            when((distance >= inside[0]) & (distance <= inside[-1]))(whole)
+    elif window is not None:
+        q_lo, k_lo = qi * block_q, kj * block_k
+        q_hi, k_hi = q_lo + block_q - 1, k_lo + block_k - 1
+        touched = (q_hi >= k_lo) & (q_lo - window < k_hi)
+        when((q_lo >= k_hi) & (q_hi - window < k_lo))(
+            functools.partial(step, [(all_rows, all_cols, None)])
+        )
+        off = q_lo - k_lo
+        when(touched & ((q_lo < k_hi) | (q_hi - window >= k_lo)))(
+            functools.partial(
+                step, [(all_rows, all_cols, (off, off - window + 1))]
+            )
+        )
     elif block_q == block_k:
         when(kj < qi)(whole)
         when(kj == qi)(functools.partial(step, list(strips(True))))
@@ -199,6 +269,41 @@ def _walk_tile(qi, kj, block_q, block_k, w, causal, step, when=pl.when):
         when((q_hi >= k_lo) & (q_lo < k_hi))(
             functools.partial(step, [(all_rows, all_cols, q_lo - k_lo)])
         )
+
+
+def _band_strips(distance, block, w, window):
+    """The sub-blocks with work of a square tile ``distance`` tiles
+    under the diagonal (0: on it), under a window: a list of ``(rows,
+    cols, off)`` as :func:`_walk_tile` hands them out, ``off`` None or a
+    pair. A sub-block is ``w`` rows against the columns between the
+    lowest key its first row reads and the highest its last row reads,
+    widened to whole multiples of ``w``; one no row of which reads a
+    column of the tile is left out."""
+    found = []
+    for lo in range(0, block, w):
+        # the strip's first row, counted from the tile's first key
+        first = lo + distance * block
+        c_min = max(first - window + 1, 0) // w * w
+        c_max = min(first + w, block)
+        if c_min >= c_max:
+            continue
+        off = first - c_min
+        low = off - window + 1
+        span = c_max - c_min
+        # the diagonal passes where some column lies past some row's own;
+        # the lower edge where some row's lowest key lies past column 0
+        mask = (
+            off if span - 1 > off else None,
+            low if w - 1 + low > 0 else None,
+        )
+        found.append(
+            (
+                slice(lo, lo + w),
+                slice(c_min, c_max),
+                None if mask == (None, None) else mask,
+            )
+        )
+    return found
 
 
 def _each(strip):
@@ -226,6 +331,20 @@ def _kept(sel_ref, down, along):
     return sel_ref[0, down, along].astype(jnp.int32) != 0
 
 
+def _inside(row, col, off):
+    """Where q row ``row`` reads k column ``col`` (local indices of a
+    sub-block): under the diagonal, ``row + off >= col``; under a
+    window ``off`` is :func:`_walk_tile`'s pair and the lower edge,
+    ``col >= row + low``, is tested too, each edge only where it
+    passes."""
+    off, low = off if isinstance(off, tuple) else (off, None)
+    if low is None:
+        return row + off >= col
+    if off is None:
+        return col >= row + low
+    return (row + off >= col) & (col >= row + low)
+
+
 def _scores(q, k, off, keep=None):
     """q k^T for one sub-block (q carries the softmax scale), masked to
     NEG_INF above the diagonal where ``off`` says it passes, or
@@ -243,7 +362,7 @@ def _scores(q, k, off, keep=None):
         return s
     row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
     col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    return jnp.where(row + off >= col, s, NEG_INF)
+    return jnp.where(_inside(row, col, off), s, NEG_INF)
 
 
 def _lane_max(x):
@@ -274,6 +393,7 @@ def _fwd_kernel(
     scale,
     w,
     sel_ref=None,
+    window=None,
 ):
     qi = pl.program_id(1)
     kj = pl.program_id(2)
@@ -317,7 +437,10 @@ def _fwd_kernel(
             )
         m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
 
-    _walk_tile(qi, kj, q_ref.shape[1], k_ref.shape[1], w, causal, step)
+    _walk_tile(
+        qi, kj, q_ref.shape[1], k_ref.shape[1], w, causal, step,
+        window=window,
+    )  # fmt: skip
 
     @pl.when(kj == nk - 1)
     def _finish():
@@ -350,7 +473,7 @@ def _scores_t(k, q, off, keep_t=None):
         return s_t
     col = jax.lax.broadcasted_iota(jnp.int32, s_t.shape, 0)
     row = jax.lax.broadcasted_iota(jnp.int32, s_t.shape, 1)
-    return jnp.where(row + off >= col, s_t, NEG_INF)
+    return jnp.where(_inside(row, col, off), s_t, NEG_INF)
 
 
 def _p_and_ds_t(
@@ -390,6 +513,7 @@ def _bwd_dq_kernel(
     scale,
     w,
     sel_ref=None,
+    window=None,
 ):
     qi = pl.program_id(1)
     kj = pl.program_id(2)
@@ -415,8 +539,9 @@ def _bwd_dq_kernel(
         )  # (ds^T)^T k
 
     _walk_tile(
-        qi, kj, q_ref.shape[1], k_ref.shape[1], w, causal, _each(strip)
-    )
+        qi, kj, q_ref.shape[1], k_ref.shape[1], w, causal, _each(strip),
+        window=window,
+    )  # fmt: skip
 
     @pl.when(kj == nk - 1)
     def _finish():
@@ -440,6 +565,7 @@ def _bwd_dkv_kernel(
     scale,
     w,
     sel_ref=None,
+    window=None,
 ):
     kj = pl.program_id(1)
     qi = pl.program_id(2)
@@ -467,8 +593,9 @@ def _bwd_dkv_kernel(
         )  # ds^T (q scale)
 
     _walk_tile(
-        qi, kj, q_ref.shape[1], k_ref.shape[1], w, causal, _each(strip)
-    )
+        qi, kj, q_ref.shape[1], k_ref.shape[1], w, causal, _each(strip),
+        window=window,
+    )  # fmt: skip
 
     @pl.when(qi == nq - 1)
     def _finish():
@@ -552,7 +679,7 @@ def sub_block(d):
     return 256
 
 
-def causal_work_ratio(lq, lk, block_q, block_k, w, causal=True):
+def causal_work_ratio(lq, lk, block_q, block_k, w, causal=True, window=None):
     """Score elements the kernels perform over score elements the mask
     keeps: 1.0 means no score is computed only to be masked away.
 
@@ -560,9 +687,19 @@ def causal_work_ratio(lq, lk, block_q, block_k, w, causal=True):
     :func:`_walk_tile` and adds up the sub-blocks' areas, so one number
     serves all three kernels. L = 2,048 in 1,024-tiles:
     1.5 with the tile whole, 1.125 at w = 256; L = 1,024: 2.0 and 1.25;
-    L = 4,096: 1.25 and 1.06."""
+    L = 4,096: 1.25 and 1.06. Under a ``window`` the mask keeps
+    ``t - window < s <= t``: L = 16,384, window 4,096, w = 256: 1.062
+    (a q tile from the fifth on performs 4.25 tiles for the 4 it keeps;
+    :func:`window_pairs` has the pairs)."""
     block_q, block_k = _block_sizes(lq, lk, block_q, block_k)
-    kept = sum(min(r + 1, lk) for r in range(lq)) if causal else lq * lk
+    if not causal:
+        kept = lq * lk
+    elif window is None:
+        kept = sum(min(r + 1, lk) for r in range(lq))
+    else:
+        kept = sum(
+            max(min(r + 1, lk) - max(r - window + 1, 0), 0) for r in range(lq)
+        )
     done = []
 
     def step(strips):
@@ -574,9 +711,22 @@ def causal_work_ratio(lq, lk, block_q, block_k, w, causal=True):
     for qi in range(lq // block_q):
         for kj in range(lk // block_k):
             _walk_tile(
-                qi, kj, block_q, block_k, w, causal, step, when=_run_if
-            )
+                qi, kj, block_q, block_k, w, causal, step, when=_run_if,
+                window=window,
+            )  # fmt: skip
     return sum(done) / kept
+
+
+def window_pairs(length, window):
+    """``(kept, causal)``: the (query, key) pairs a sequence of
+    ``length`` keeps under a window, query t reading ``min(t + 1,
+    window)`` keys, and the causal pairs. 16,384 under 4,096:
+    58,722,304 of 134,225,920 (43.7%)."""
+    window = min(window, length)
+    return (
+        window * (window + 1) // 2 + (length - window) * window,
+        length * (length + 1) // 2,
+    )
 
 
 def _block_sizes(lq, lk, block_q, block_k):
@@ -593,7 +743,9 @@ def _block_sizes(lq, lk, block_q, block_k):
 _STATISTICS = ("lse", "delta")
 
 
-def _plan(kernel, bh, lq, lk, d, block_q, block_k, causal, heads=None):
+def _plan(
+    kernel, bh, lq, lk, d, block_q, block_k, causal, heads=None, window=None
+):
     """``(grid, inputs, outputs)`` of one kernel's ``pallas_call``, the
     last two as lists of ``(name, BlockSpec)``: what the call is built
     from, and what :func:`hbm_traffic` walks.
@@ -616,6 +768,15 @@ def _plan(kernel, bh, lq, lk, d, block_q, block_k, causal, heads=None):
     pipeline issues no copy for it. Blocks that follow the outer axis,
     every output among them, are left alone.
 
+    Under a ``window`` the clamp is from BOTH sides: a q tile's first
+    row reads keys from ``qi block_q - window + 1`` on, so its first k
+    tile with work is that position's (0 where it is negative), and k,
+    v take the higher of it and the above; a k tile's last key is read
+    by queries up to ``(kj + 1) block_k + window - 2``, and q, dO and
+    the statistics take the lower of that position's tile and the
+    above. At L = 16,384 in 1,024-tiles with a window of 4,096 a q tile
+    moves 5 of 16 k tiles, and a k tile 5 of 16 q tiles.
+
     ``heads``, where given, says the call has a selection, one for each
     run of ``heads`` rows of the grid's first axis (a sequence's
     heads): the last input, int8, in tiles that follow BOTH tile axes,
@@ -628,12 +789,19 @@ def _plan(kernel, bh, lq, lk, d, block_q, block_k, causal, heads=None):
     def k_tile(qi, kj):
         if not causal:
             return kj
-        return _lower(kj, ((qi + 1) * block_q - 1) // block_k)
+        last = _lower(kj, ((qi + 1) * block_q - 1) // block_k)
+        if window is None:
+            return last
+        first = _higher(qi * block_q - window + 1, 0) // block_k
+        return _higher(last, _lower(first, nk - 1))
 
     def q_tile(kj, qi):
         if not causal:
             return qi
-        return _lower(_higher(qi, kj * block_k // block_q), nq - 1)
+        first = _lower(_higher(qi, kj * block_k // block_q), nq - 1)
+        if window is None:
+            return first
+        return _lower(first, ((kj + 1) * block_k + window - 2) // block_q)
 
     if kernel == BWD_DKV_KERNEL:
         grid = (bh, nk, nq)
@@ -666,7 +834,9 @@ def _plan(kernel, bh, lq, lk, d, block_q, block_k, causal, heads=None):
     return grid, inputs, [("dk", by_cols), ("dv", by_cols)]
 
 
-def hbm_traffic(bh, lq, lk, d, block_q, block_k, causal=True, itemsize=2):
+def hbm_traffic(
+    bh, lq, lk, d, block_q, block_k, causal=True, itemsize=2, window=None
+):
     """Bytes each kernel moves between HBM and VMEM in one call, split
     into ``tensors`` (q, k, v, o, dO and the gradients, ``itemsize``
     bytes an element) and ``statistics`` (lse and delta, f32), with the
@@ -680,12 +850,16 @@ def hbm_traffic(bh, lq, lk, d, block_q, block_k, causal=True, itemsize=2):
     kernels (704.6 MB as ``(bh, L, 128)`` blocks that dkv fetched at
     every step, until PR 30), tensors 377.5 MB (528.5), k and v tiles a
     head in the forward and dq 2 (4), q, dO and statistics tiles a head
-    in dkv 2 (4). Not causal nothing is clamped: 4 and 4."""
+    in dkv 2 (4). Not causal nothing is clamped: 4 and 4. Under a
+    ``window`` the clamp is from both sides: at 16,384 in 1,024-tiles
+    under 4,096, k and v tiles a head in the forward and dq 69 of the
+    135 the causal call moves (in 256 grid steps), and so q, dO and
+    the statistics in dkv."""
     block_q, block_k = _block_sizes(lq, lk, block_q, block_k)
     traffic = {}
     for kernel in (FWD_KERNEL, BWD_DQ_KERNEL, BWD_DKV_KERNEL):
         grid, inputs, outputs = _plan(
-            kernel, bh, lq, lk, d, block_q, block_k, causal
+            kernel, bh, lq, lk, d, block_q, block_k, causal, window=window
         )
         moved = {"tensors": 0, "statistics": 0, "blocks": {}}
         at = {}
@@ -714,7 +888,7 @@ def hbm_traffic(bh, lq, lk, d, block_q, block_k, causal=True, itemsize=2):
 # custom calls a layer. The sub-blocked bodies are four times the
 # equations of the whole-tile ones; traced and lowered per layer they
 # cost `lm125m-l2048` 9 s of set-up on the chip (PR 29).
-_STATIC = ("causal", "block_q", "block_k", "interpret", "w")
+_STATIC = ("causal", "block_q", "block_k", "interpret", "w", "window")
 
 
 def _selecting(body, at):
@@ -735,15 +909,20 @@ def _call(
     scratch_shapes,
     interpret,
     heads=None,
+    window=None,
     **static
 ):
     """The ``pallas_call`` of ``kernel``: its grid and block specs are
     :func:`_plan`'s for ``shapes``, ``static`` are the body's keywords.
     With ``heads`` (a selection is the last input, :func:`_plan`) the
-    call goes under its :data:`SELECTED` name."""
-    grid, inputs, outputs = _plan(kernel, *shapes, heads=heads)
+    call goes under its :data:`SELECTED` name, with ``window`` (one
+    more keyword of the body) under its :data:`WINDOWED` name."""
+    grid, inputs, outputs = _plan(kernel, *shapes, heads=heads, window=window)
     if heads:
         body, kernel = _selecting(body, len(inputs) - 1), SELECTED[kernel]
+    if window is not None:
+        static["window"] = window
+        kernel = WINDOWED[kernel]
     return pl.pallas_call(
         functools.partial(body, **static),
         out_shape=out_shape,
@@ -759,10 +938,13 @@ def _call(
 
 @functools.partial(jax.jit, static_argnames=_STATIC, inline=True)
 def _flash_fwd(
-    q, k, v, causal, block_q, block_k, interpret, w=None, selection=None
-):
+    q, k, v, causal, block_q, block_k, interpret, w=None, selection=None,
+    window=None,
+):  # fmt: skip
     """``selection``, where given: (b, lq, lk) int8, non-zero where a
-    query reads a key, the same for every head of a sequence."""
+    query reads a key, the same for every head of a sequence.
+    ``window``, where given (causal, no selection): query t reads keys
+    ``t - window < s <= t``."""
     q, k, v = jnp.asarray(q), jnp.asarray(k), jnp.asarray(v)
     b, lq, h, d = q.shape
     lk = k.shape[1]
@@ -788,6 +970,7 @@ def _flash_fwd(
         ],
         interpret,
         heads=None if selection is None else h,
+        window=window,
         causal=causal,
         scale=scale,
         w=w,
@@ -810,9 +993,10 @@ def _flash_bwd(
     g_lse=None,
     w=None,
     selection_t=None,
+    window=None,
 ):
     """``selection_t``, where given: the forward's selection
-    transposed, (b, lk, lq) int8."""
+    transposed, (b, lk, lq) int8; ``window`` as the forward's."""
     b, lq, h, d = q.shape
     lk = k.shape[1]
     block_q, block_k = _block_sizes(lq, lk, block_q, block_k)
@@ -841,7 +1025,7 @@ def _flash_bwd(
         delta.reshape(b * h, 1, lq),
     )
     shapes = b * h, lq, lk, d, block_q, block_k, causal
-    static = dict(causal=causal, scale=scale, w=w)
+    static = dict(causal=causal, scale=scale, w=w, window=window)
     if selection_t is not None:
         operands += (selection_t,)
         static["heads"] = h
@@ -987,10 +1171,58 @@ def flash_attention_with_lse(
     return _flash_with_lse(q, k, v, causal, block_q, block_k)
 
 
-def flash_attention(q, k, v, causal=False, block_q=None, block_k=None):
-    """(B, L, H, D) fused attention; trains with the blockwise backward."""
-    out, _ = flash_attention_with_lse(q, k, v, causal, block_q, block_k)
-    return out
+def flash_attention(
+    q, k, v, causal=False, block_q=None, block_k=None, window=None
+):
+    """(B, L, H, D) fused attention; trains with the blockwise backward.
+
+    ``window`` (causal only): query t reads keys ``t - window < s <= t``,
+
+        o_t = sum_{t - W < s <= t} softmax(q_t k_s / sqrt(D)) v_s
+
+    by the same three bodies under names of their own
+    (:data:`WINDOWED`). They COMPUTE the band and no more: a tile wholly
+    outside it does nothing and fetches nothing, a tile wholly inside
+    runs unmasked, and the diagonal's tile and the lower edge's are
+    trimmed and masked by sub-block (:func:`_walk_tile`). A window that
+    reaches every key (``window >= L``) is causal attention and is
+    built as that."""
+    if window is None or (causal and window >= k.shape[1]):
+        out, _ = flash_attention_with_lse(q, k, v, causal, block_q, block_k)
+        return out
+    if not causal or window < 1:
+        raise ValueError(
+            "a window of %r keys: a positive number, under causal=True"
+            % (window,)
+        )
+    block_q, block_k = auto_blocks(
+        q.shape[1], k.shape[1], block_q, block_k
+    )
+    return _flash_windowed(q, k, v, int(window), block_q, block_k)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _flash_windowed(q, k, v, window, block_q, block_k):
+    return _windowed_fwd_rule(q, k, v, window, block_q, block_k)[0]
+
+
+def _windowed_fwd_rule(q, k, v, window, block_q, block_k):
+    out, lse = _flash_fwd(
+        q, k, v, True, block_q, block_k, kernel_interpret_mode(),
+        window=window,
+    )  # fmt: skip
+    return out, (q, k, v, out, lse)
+
+
+def _windowed_bwd_rule(window, block_q, block_k, residuals, g):
+    q, k, v, out, lse = residuals
+    return _flash_bwd(
+        q, k, v, out, lse, g, True, block_q, block_k, kernel_interpret_mode(),
+        window=window,
+    )  # fmt: skip
+
+
+_flash_windowed.defvjp(_windowed_fwd_rule, _windowed_bwd_rule)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
@@ -1054,7 +1286,9 @@ def attention_in_step(step_facts):
     a process put on the CPU by request) or ``"xla"`` (the reference
     attention :func:`pick_causal_attention` hands short or untileable
     lengths)."""
-    for flash in (set(SELECTED), set(SELECTED.values())):
+    for flash in (
+        set(SELECTED), set(SELECTED.values()), set(WINDOWED.values())
+    ):
         if flash <= set(step_facts["mosaic_kernels"]):
             return "pallas"
         if flash <= set(step_facts["pallas_kernels"]):
@@ -1093,8 +1327,23 @@ def pick_selected_attention(seq_len, use_flash=True, min_flash_len=1024):
     return selected_reference_attention
 
 
-def pick_causal_attention(seq_len, use_flash=True, min_flash_len=1024):
-    """Causal attention fn for a model at this sequence length.
+def windowed_reference_attention(q, k, v, window):
+    """``flash_attention(..., causal=True, window=window)`` in plain
+    XLA, the (L, L) scores whole: what short or untileable lengths get,
+    and the tests' yardstick."""
+    lq, lk = q.shape[1], k.shape[1]
+    distance = jnp.arange(lq)[:, None] - jnp.arange(lk)[None, :]
+    return selected_reference_attention(
+        q, k, v, ((distance >= 0) & (distance < window))[None]
+    )
+
+
+def pick_causal_attention(
+    seq_len, use_flash=True, min_flash_len=1024, window=None
+):
+    """Causal attention fn for a model at this sequence length; with
+    ``window``, over the ``window`` nearest keys (itself among them),
+    by the same policy.
 
     One home for the policy: 1,024 is the length from which the cells
     use the kernels; ``lm125m-l512`` is the cell on the other side; the
@@ -1102,7 +1351,9 @@ def pick_causal_attention(seq_len, use_flash=True, min_flash_len=1024):
     The kernels need 128-divisible lengths to tile. Both transformer
     builds call this so the threshold lives in exactly one place."""
     if _takes_the_kernels(seq_len, use_flash, min_flash_len):
-        return lambda q, k, v: flash_attention(q, k, v, True)
+        return lambda q, k, v: flash_attention(q, k, v, True, window=window)
+    if window is not None and window < seq_len:
+        return functools.partial(windowed_reference_attention, window=window)
     from elasticdl_tpu.parallel.ring_attention import reference_attention
 
     return functools.partial(reference_attention, causal=True)

@@ -1688,6 +1688,12 @@ class ElasticDPTrainer:
         self._step_fn = entry.step_fn
         return hit
 
+    def example_features(self):
+        """The features of the batch the built step takes (its shapes
+        are what matter), or None before any batch was seen."""
+        example = self._spec_example or self._last_local
+        return example[0] if example else None
+
     def describe_step(self):
         """What the step this establish built contains, read off its
         own trace and lowering rather than off the flags that asked for

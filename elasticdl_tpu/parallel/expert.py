@@ -504,41 +504,47 @@ _gated_sum_by_token.defvjp(
 )
 
 
-def _swiglu(up):
+# what an expert's gate goes through: ``act(x W_1) * (x W_3)``, a
+# SwiGLU with ``silu`` (the default) and a ReGLU with ``relu``
+EXPERT_ACTS = ("silu", "relu")
+
+
+def _glu(up, act):
     width = up.shape[1] // 2
-    return jax.nn.silu(up[:, :width]) * up[:, width:]
+    return getattr(jax.nn, act)(up[:, :width]) * up[:, width:]
 
 
-@jax.custom_vjp
-def _swiglu_held_rows(up, n_here):
-    """``silu(up[:, :f]) * up[:, f:]`` over the chunks that hold a row
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _glu_held_rows(up, n_here, act):
+    """``act(up[:, :f]) * up[:, f:]`` over the chunks that hold a row
     under ``n_here``, and so its cotangent."""
     rows, width = up.shape
     return _over_held_chunks(
         n_here,
         (rows, width // 2),
         up.dtype,
-        lambda start, chunk: _swiglu(
-            jax.lax.dynamic_slice_in_dim(up, start, chunk)
+        lambda start, chunk: _glu(
+            jax.lax.dynamic_slice_in_dim(up, start, chunk), act
         ),
     )
 
 
-def _swiglu_bwd(residuals, g):
+def _glu_bwd(act, residuals, g):
     up, n_here = residuals
 
     def body(start, chunk):
         _, pull = jax.vjp(
-            _swiglu, jax.lax.dynamic_slice_in_dim(up, start, chunk)
+            functools.partial(_glu, act=act),
+            jax.lax.dynamic_slice_in_dim(up, start, chunk),
         )
         return pull(jax.lax.dynamic_slice_in_dim(g, start, chunk))[0]
 
     return _over_held_chunks(n_here, up.shape, up.dtype, body), None
 
 
-_swiglu_held_rows.defvjp(
-    lambda up, n_here: (_swiglu_held_rows(up, n_here), (up, n_here)),
-    _swiglu_bwd,
+_glu_held_rows.defvjp(
+    lambda up, n_here, act: (_glu_held_rows(up, n_here, act), (up, n_here)),
+    _glu_bwd,
 )
 
 
@@ -547,10 +553,13 @@ _swiglu_held_rows.defvjp(
 # every call and cost establish seconds; inside a step it is inlined
 # and changes nothing
 @functools.partial(
-    jax.jit, static_argnames=("first_expert_held",), inline=True
+    jax.jit, static_argnames=("first_expert_held", "act"), inline=True
 )
-def held_experts_apply(x, selected, gates, w_in, w_out, first_expert_held):
-    """This device's share of a SwiGLU expert layer's result.
+def held_experts_apply(
+    x, selected, gates, w_in, w_out, first_expert_held, act=EXPERT_ACTS[0]
+):
+    """This device's share of a gated expert layer's result (a SwiGLU's;
+    with ``act="relu"`` a ReGLU's: ``relu`` where ``silu`` stands below).
 
     ``x`` (T, d); ``selected``, ``gates`` (T, k) over ALL the layer's
     experts (:func:`sigmoid_topk_route`); ``w_in`` (G, d, 2f): the held
@@ -595,14 +604,15 @@ def held_experts_apply(x, selected, gates, w_in, w_out, first_expert_held):
 
     rows = _rows_for_assignments(x, held_rows)
     up = grouped_matmul(rows, w_in, group_sizes)
-    act = _swiglu_held_rows(up, held_rows.n_here)
-    y = grouped_matmul(act, w_out, group_sizes)
+    hidden = _glu_held_rows(up, held_rows.n_here, act)
+    y = grouped_matmul(hidden, w_out, group_sizes)
     return _gated_sum_by_token(y, gates, held_rows)
 
 
-def _masked_share(x, gate, w_in, w_out):
-    """``sum_g gate[:, g] * W_2g (silu(x W_1g) * (x W_3g))`` as three
-    plain ``(T, d) x (d, G * f)`` products; ``gate`` (T, G) float32."""
+def _masked_share(x, gate, w_in, w_out, act):
+    """``sum_g gate[:, g] * W_2g (act(x W_1g) * (x W_3g))`` as three
+    plain ``(T, d) x (d, G * f)`` products; ``gate`` (T, G) float32,
+    ``act`` one of :data:`EXPERT_ACTS`."""
     held, d, _ = w_in.shape
     width = w_out.shape[1]
 
@@ -613,10 +623,10 @@ def _masked_share(x, gate, w_in, w_out):
     # matrix, and not behind a product, where it would move (T, G, 2f)
     up = checkpoint_name(x @ side_by_side(w_in[..., :width]), "held_up")
     across = checkpoint_name(x @ side_by_side(w_in[..., width:]), "held_up")
-    hidden = jax.nn.silu(up) * across
+    hidden = getattr(jax.nn, act)(up) * across
     # an expert's gate over its own columns, column block by block: a
     # (T, G, f) view of ``hidden`` is another tiling, a copy each way
-    act = jnp.concatenate(
+    gated = jnp.concatenate(
         [
             hidden[:, g * width : (g + 1) * width].astype(jnp.float32)
             * gate[:, g : g + 1]
@@ -624,11 +634,11 @@ def _masked_share(x, gate, w_in, w_out):
         ],
         axis=1,
     ).astype(x.dtype)
-    return (act @ w_out.reshape(held * width, d)).astype(x.dtype)
+    return (gated @ w_out.reshape(held * width, d)).astype(x.dtype)
 
 
 def held_experts_apply_masked(
-    x, selected, gates, w_in, w_out, first_expert_held
+    x, selected, gates, w_in, w_out, first_expert_held, act=EXPERT_ACTS[0]
 ):
     """:func:`held_experts_apply`'s result by shapes alone: EVERY held
     expert over every token, its gate zero where the token did not
@@ -656,7 +666,7 @@ def held_experts_apply_masked(
         axis=1,
     )  # (T, G): an expert is selected at most once a token
     return jax.checkpoint(
-        _masked_share,
+        functools.partial(_masked_share, act=act),
         policy=jax.checkpoint_policies.save_only_these_names("held_up"),
     )(x, gate, w_in, w_out)
 

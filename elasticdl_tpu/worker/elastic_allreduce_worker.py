@@ -978,9 +978,13 @@ class ElasticAllReduceWorker:
             (k, facts[k]) for k in step_ops.STEP_BUILT_FIELDS if k in facts
         )
         # what the model says of its own layout (a zoo module's
-        # ``step_facts``: layers by kind, experts held and routed over);
-        # absent on a model that has nothing to say
-        self._model_facts = getattr(self._model, "step_facts", dict)()
+        # ``step_facts``: layers by kind, experts held and routed over,
+        # and what follows from the length of the batch the step was
+        # built for); absent on a model that has nothing to say
+        step_facts = getattr(self._model, "step_facts", None)
+        self._model_facts = (
+            step_facts(self.trainer.example_features()) if step_facts else {}
+        )
         report.update(self._model_facts)
         logger.info(
             "step built: %s",

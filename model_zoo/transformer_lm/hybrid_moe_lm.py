@@ -1,13 +1,16 @@
 """Hybrid sparse decoder LM: gated short convolutions and Mamba-2
-state-space layers beside grouped-head attention, full or over a
-learned selection of keys, a dense SwiGLU MLP in the leading layers and
-a mixture of experts in the rest, of which this device holds a share.
+state-space layers beside grouped-head attention, full, within a window
+or over a learned selection of keys, a dense SwiGLU MLP in the leading
+layers and a mixture of experts in the rest, of which this device holds
+a share.
 
 The layer stack is built from one pattern string, a letter a layer:
 ``c`` a gated short convolution, ``m`` a Mamba-2 state-space layer,
-``a`` full causal attention, ``s`` attention over the ``select_topk``
-keys an indexer picks for each query (``layer_pattern=caccc``: no
-comma, it travels in ``--model_params``). The first
+``a`` full causal attention, ``w`` causal attention within the
+``attention_window`` nearest keys, ``s`` attention over the
+``select_topk`` keys an indexer picks for each query
+(``layer_pattern=caccc``: no comma, it travels in ``--model_params``).
+The first
 ``num_dense_layers`` layers (none is fine, and so is all of them: a
 stack with no expert layer keeps no routing state) have the dense MLP,
 the others the expert layer. With ``h = RMSNorm(x)`` (weight only) and
@@ -61,6 +64,14 @@ forward, which does not lower the peak.
   default); KV head ``j`` serves query heads ``j*g .. j*g+g-1``. The KV
   heads are repeated in front of ops/flash_attention.py (grouped heads
   inside the kernel are not built).
+- ``w``: ``a`` with ``o_t = sum_{t - W < s <= t} softmax(q_t k_s /
+  sqrt(hd)) v_s``, ``W = attention_window`` keys, the query's own among
+  them: the kernels compute the band and skip what lies outside it
+  (``flash_attention(..., window=W)``), lengths under the policy's get
+  the same mask in XLA. Positions are by layer kind: ``rope`` is the
+  switch of the ``a`` and ``s`` layers, ``window_rope`` (on by default)
+  that of the ``w`` layers, so a model may rotate inside its windows and
+  give its global layers no positions at all (``rope=False``).
 - ``s``: ``a`` with ``o_t = sum_{s in S_t} softmax_{s in S_t}(q_t k_s /
   sqrt(hd)) v_s``. The indexer, float32, on ``hb = stop_gradient(h)``:
   ``qI = hb W_qI`` (``indexer_heads`` x ``indexer_dim``), ``kI = hb
@@ -78,13 +89,21 @@ forward, which does not lower the peak.
   over ``num_experts`` in float32, ``num_experts_per_tok`` selected,
   gates normalised over the selected; this device computes the part
   experts ``first_expert_held .. first_expert_held + experts_held - 1``
-  give, and that partial result goes on to the next layer. ``routing``
+  give, and that partial result goes on to the next layer. An expert
+  is ``W_2 (act(u W_1) * (u W_3))`` with ``act`` ``expert_act``:
+  ``silu`` (the default, a SwiGLU) or ``relu`` (a ReGLU). The router
+  reads ``router_input``: ``ffn_norm`` (the default), the expert
+  layer's own normed input ``u``, or ``operator_norm``, the normed
+  input ``h`` of the operator in front of it (a router placed before
+  the attention), while the experts read ``u`` either way. ``routing``
   names the scores: ``sigmoid_bias`` (the default), a sigmoid of each
   logit, selected with a bias added that only steers the selection and
   follows the load (``expert_bias_rate`` a step; no auxiliary loss),
   gates ``score_e / (sum of the scores selected + 1e-6)``;
   ``softmax``, ``p = softmax(h W_r)``, the largest selected, no bias
-  and no bias state, gates ``p_e / sum of the p selected``.
+  and no bias state, gates ``p_e / sum of the p selected`` (which is
+  the softmax over the selected logits alone: the k largest first and
+  then a softmax over them is the same selection and the same gates).
   ``expert_apply`` names how the share is computed: ``grouped`` (the
   default), the assignments sorted by expert and three grouped
   products over the rows routed here, whose time follows those rows;
@@ -122,6 +141,7 @@ from elasticdl_tpu.ops import sparse_select, ssd
 from elasticdl_tpu.ops.flash_attention import (
     pick_causal_attention,
     pick_selected_attention,
+    window_pairs,
 )
 from elasticdl_tpu.parallel import expert
 
@@ -134,13 +154,23 @@ loss = _lm.loss
 dataset_fn = _lm.dataset_fn
 eval_metrics_fn = _lm.eval_metrics_fn
 
-CONV, ATTENTION, SELECTING, MAMBA = "c", "a", "s", "m"
+CONV, ATTENTION, SELECTING, MAMBA, WINDOW = "c", "a", "s", "m", "w"
+LETTERS = {
+    CONV: "a short convolution",
+    MAMBA: "a Mamba-2 state-space layer",
+    ATTENTION: "full attention",
+    SELECTING: "attention over selected keys",
+    WINDOW: "attention within a window",
+}
 ROUTINGS = ("sigmoid_bias", "softmax")
 EXPERT_APPLIES = ("grouped", "masked")
+# the norm whose output the router reads: the expert layer's own, or
+# the operator's in front of it (the router "placed before attention")
+ROUTER_INPUTS = ("ffn_norm", "operator_norm")
 # the name of what ``remat_layers`` keeps for the backward pass, and how
 # many results a layer's operator gives that name (a dense FF: two)
 KEPT = "weight_product"
-KEPT_OF_OPERATOR = {CONV: 1, MAMBA: 1, ATTENTION: 4, SELECTING: 4}
+KEPT_OF_OPERATOR = {CONV: 1, MAMBA: 1, ATTENTION: 4, SELECTING: 4, WINDOW: 4}
 KEPT_OF_DENSE_FF = 2
 # every parameter of an indexer lies under a module of this name
 INDEXER = "indexer"
@@ -344,7 +374,8 @@ class Indexer(nn.Module):
 
 class GroupedAttention(nn.Module):
     """The attention operator over grouped KV heads: over every earlier
-    key, or, with ``select_topk``, over those its indexer selects."""
+    key, or, with ``select_topk``, over those its indexer selects, or,
+    with ``window``, over the ``window`` nearest (its own among them)."""
 
     num_heads: int
     num_kv_heads: int
@@ -359,6 +390,7 @@ class GroupedAttention(nn.Module):
     rope: bool = True
     qk_norm: bool = True
     attention_scale: float = 0.0
+    window: int = 0
 
     def _selection(self, h):
         """The indexer's selection, counted into the module's state
@@ -422,7 +454,9 @@ class GroupedAttention(nn.Module):
                 )
             )
         else:
-            attn = pick_causal_attention(h.shape[1], self.use_flash)(q, k, v)
+            attn = pick_causal_attention(
+                h.shape[1], self.use_flash, window=self.window or None
+            )(q, k, v)
         return _kept(
             nn.DenseGeneral(
                 features=h.shape[-1],
@@ -464,9 +498,12 @@ class HeldExperts(nn.Module):
     dtype: Any
     routing: str = ROUTINGS[0]
     apply: str = EXPERT_APPLIES[0]
+    act: str = expert.EXPERT_ACTS[0]
 
     @nn.compact
-    def __call__(self, h):
+    def __call__(self, h, router_input=None):
+        """``h`` is what the experts read; the router reads
+        ``router_input`` where one is given, else ``h`` too."""
         d = h.shape[-1]
         router = self.param(
             "router", nn.initializers.lecun_normal(), (d, self.num_experts)
@@ -484,8 +521,9 @@ class HeldExperts(nn.Module):
         tokens = h.reshape(-1, d)
         # logits and scores in float32: the selection is discrete, and
         # a bf16 logit would change some percent of fourth choices
+        routed = tokens if router_input is None else router_input.reshape(-1, d)
         logits = jnp.dot(
-            tokens.astype(jnp.float32),
+            routed.astype(jnp.float32),
             router.astype(jnp.float32),
             precision=jax.lax.Precision.HIGHEST,
         )
@@ -542,8 +580,13 @@ class HeldExperts(nn.Module):
             w_in.astype(self.dtype),
             w_out.astype(self.dtype),
             self.first_expert_held,
+            act=self.act,
         )
         return out.reshape(h.shape)
+
+
+def _tokens_of(features):
+    return features["tokens"] if isinstance(features, dict) else features
 
 
 class HybridMoELM(nn.Module):
@@ -580,6 +623,10 @@ class HybridMoELM(nn.Module):
     rope: bool = True
     qk_norm: bool = True
     attention_scale: float = 0.0
+    attention_window: int = 0
+    window_rope: bool = True
+    router_input: str = ROUTER_INPUTS[0]
+    expert_act: str = expert.EXPERT_ACTS[0]
     embedding_multiplier: float = 1.0
     residual_multiplier: float = 1.0
     logits_scaling: float = 1.0
@@ -587,9 +634,11 @@ class HybridMoELM(nn.Module):
     dtype: Any = jnp.float32
     use_flash: bool = True
 
-    def step_facts(self):
+    def step_facts(self, features=None):
         """What the worker's ``step_built`` event says of this model's
-        layout (scalars), and what its window counters are read with."""
+        layout (scalars), and what its window counters are read with.
+        ``features``, where given, is a batch the step was built for:
+        what follows from its sequence length is stated too."""
         facts = {
             "expert_layers": len(self.layer_pattern) - self.num_dense_layers,
             "experts_held": self.experts_held,
@@ -623,14 +672,30 @@ class HybridMoELM(nn.Module):
                 select_topk=self.select_topk,
                 indexer_heads=self.indexer_heads,
             )
+        if WINDOW in self.layer_pattern:
+            facts.update(
+                window_layers=self.layer_pattern.count(WINDOW),
+                attention_window=self.attention_window,
+            )
+            if features is not None:
+                # a sequence's (query, key) pairs in ONE window layer
+                # (not times the heads), and the causal pairs
+                kept, causal = window_pairs(
+                    _tokens_of(features).shape[1], self.attention_window
+                )
+                facts.update(
+                    window_pairs_kept=kept, window_pairs_causal=causal
+                )
+        if facts["expert_layers"]:
+            if self.router_input != ROUTER_INPUTS[0]:
+                facts["router_input"] = self.router_input
+            if self.expert_act != expert.EXPERT_ACTS[0]:
+                facts["expert_act"] = self.expert_act
         return facts
 
     @nn.compact
     def __call__(self, features, training=False):
-        tokens = (
-            features["tokens"] if isinstance(features, dict) else features
-        )
-        tokens = tokens.astype(jnp.int32)
+        tokens = _tokens_of(features).astype(jnp.int32)
         b, l = tokens.shape
         positions = jnp.broadcast_to(jnp.arange(l, dtype=jnp.int32), (b, l))
 
@@ -674,7 +739,8 @@ class HybridMoELM(nn.Module):
                     name=name + "mamba",
                 )(h)
             else:
-                out = GroupedAttention(
+                windowed = kind == WINDOW
+                attention = GroupedAttention(
                     num_heads=self.num_heads,
                     num_kv_heads=self.num_kv_heads,
                     head_dim=self.head_dim,
@@ -685,13 +751,19 @@ class HybridMoELM(nn.Module):
                     select_topk=self.select_topk if kind == SELECTING else 0,
                     indexer_heads=self.indexer_heads,
                     indexer_dim=self.indexer_dim,
-                    rope=self.rope,
+                    rope=self.window_rope if windowed else self.rope,
                     qk_norm=self.qk_norm,
                     attention_scale=self.attention_scale,
+                    window=self.attention_window if windowed else 0,
                     name=name + "attention",
-                )(h, positions)
+                )
+                if windowed:
+                    with jax.named_scope("edl/window_attention"):
+                        out = attention(h, positions)
+                else:
+                    out = attention(h, positions)
             x = joined(x, out)
-            h = norm(name + "ffn_norm")(x)
+            operator_h, h = h, norm(name + "ffn_norm")(x)
             if i < self.num_dense_layers:
                 out = SwiGLU(self.mlp_dim, self.dtype, name=name + "mlp")(h)
             else:
@@ -707,8 +779,14 @@ class HybridMoELM(nn.Module):
                         dtype=self.dtype,
                         routing=self.routing,
                         apply=self.expert_apply,
+                        act=self.expert_act,
                         name=name + "moe",
-                    )(h)
+                    )(
+                        h,
+                        operator_h
+                        if self.router_input == ROUTER_INPUTS[1]
+                        else None,
+                    )
             return joined(x, out)
 
         if self.remat_layers and not self.is_initializing():
@@ -737,15 +815,13 @@ def custom_model(dtype="float32", **sizes):
     """``HybridMoELM(**sizes)``; every size has the toy default of the
     class, and a name it does not know is refused."""
     pattern = str(sizes.get("layer_pattern", HybridMoELM.layer_pattern))
-    unknown = sorted(set(pattern) - {CONV, ATTENTION, SELECTING, MAMBA})
+    unknown = sorted(set(pattern) - set(LETTERS))
     if not pattern or unknown:
         raise ValueError(
-            "layer_pattern %r holds %s: a letter a layer, %r a short "
-            "convolution, %r a Mamba-2 state-space layer, %r full attention, "
-            "%r attention over selected keys"
+            "layer_pattern %r holds %s: a letter a layer, %s"
             % (
                 pattern, unknown or "no layer",
-                CONV, MAMBA, ATTENTION, SELECTING,
+                ", ".join("%r %s" % item for item in LETTERS.items()),
             )
         )  # fmt: skip
     model = HybridMoELM(dtype=jnp.dtype(dtype), **sizes)
@@ -772,12 +848,28 @@ def custom_model(dtype="float32", **sizes):
             "layer_pattern %r holds no state-space layer (%r), so %s say "
             "nothing" % (pattern, MAMBA, sorted(k for k, v in ssm_sizes.items() if v))
         )
-    for name in ("rope", "qk_norm", "remat_layers"):
+    for name in ("rope", "qk_norm", "remat_layers", "window_rope"):
         if not isinstance(getattr(model, name), bool):
             raise ValueError(
                 "%s=%r is neither True nor False" % (name, getattr(model, name))
             )
-    attends = ATTENTION in pattern or SELECTING in pattern
+    if WINDOW in pattern:
+        if not (
+            isinstance(model.attention_window, int)
+            and model.attention_window > 0
+        ):
+            raise ValueError(
+                "layer_pattern %r holds a window layer: attention_window=%r "
+                "has to be a positive whole number"
+                % (pattern, model.attention_window)
+            )
+    elif model.attention_window or not model.window_rope:
+        raise ValueError(
+            "layer_pattern %r holds no window layer (%r), so "
+            "attention_window=%r and window_rope=%r say nothing"
+            % (pattern, WINDOW, model.attention_window, model.window_rope)
+        )
+    attends = bool({ATTENTION, SELECTING, WINDOW} & set(pattern))
     if not attends and not (
         model.rope and model.qk_norm and not model.attention_scale
     ):
@@ -797,6 +889,24 @@ def custom_model(dtype="float32", **sizes):
     if model.routing not in ROUTINGS:
         raise ValueError(
             "routing %r is not one of %s" % (model.routing, ", ".join(ROUTINGS))
+        )
+    if model.router_input not in ROUTER_INPUTS:
+        raise ValueError(
+            "router_input %r is not one of %s"
+            % (model.router_input, ", ".join(ROUTER_INPUTS))
+        )
+    if model.expert_act not in expert.EXPERT_ACTS:
+        raise ValueError(
+            "expert_act %r is not one of %s"
+            % (model.expert_act, ", ".join(expert.EXPERT_ACTS))
+        )
+    if model.num_dense_layers == len(pattern) and (
+        model.router_input != ROUTER_INPUTS[0]
+        or model.expert_act != expert.EXPERT_ACTS[0]
+    ):
+        raise ValueError(
+            "layer_pattern %r holds no expert layer, so router_input and "
+            "expert_act say nothing" % pattern
         )
     if model.expert_apply not in EXPERT_APPLIES:
         raise ValueError(

@@ -361,10 +361,13 @@ def test_the_width_is_derived_and_no_caller_can_set_it():
     from elasticdl_tpu.ops import flash_attention as fa
 
     assert fa.sub_block(64) == fa.sub_block(128) == 256  # both swept
-    for fn in (fa.flash_attention, fa.flash_attention_with_lse):
-        assert list(inspect.signature(fn).parameters) == [
-            "q", "k", "v", "causal", "block_q", "block_k",
-        ]
+    plain = ["q", "k", "v", "causal", "block_q", "block_k"]
+    # PR 39: a caller may name a window, which is the model's; not a width
+    for fn, extra in (
+        (fa.flash_attention, ["window"]),
+        (fa.flash_attention_with_lse, []),
+    ):
+        assert list(inspect.signature(fn).parameters) == plain + extra
 
 
 def test_three_kernels_a_layer_under_their_names():
@@ -528,3 +531,257 @@ def test_divisible_on_a_tpu_wants_block_q_in_whole_lanes(
         # interpret mode keeps no constraint but division
         monkeypatch.setattr(fa, "kernel_interpret_mode", lambda: True)
         assert fa.divisible(lq, lk, block_q, block_k)
+
+
+# ---------------------------------------------------------------------------
+# under a window (PR 39): query t reads keys t - W < s <= t. Tiles wholly
+# outside the band do nothing and fetch nothing, tiles wholly inside run
+# unmasked, the diagonal's and the lower edge's are trimmed and masked
+# ---------------------------------------------------------------------------
+
+WINDOWS = [
+    # lq, lk, block_q, block_k, w, window
+    pytest.param(64, 64, 16, 16, 4, 20, id="w-over-a-tile-not-a-multiple"),
+    pytest.param(64, 64, 16, 16, 4, 16, id="w-one-tile"),
+    pytest.param(64, 64, 16, 16, 4, 32, id="w-two-tiles"),
+    pytest.param(64, 64, 16, 16, 4, 5, id="w-under-a-tile"),
+    pytest.param(64, 64, 16, 16, 8, 1, id="w-the-key-itself"),
+    pytest.param(64, 64, 16, 16, 16, 24, id="tile-left-whole"),
+    pytest.param(64, 64, 16, 16, 4, 63, id="w-all-but-one-key"),
+    pytest.param(128, 128, 32, 32, 8, 50, id="larger-tiles"),
+    pytest.param(64, 64, 32, 16, 8, 20, id="tiles-32x16"),
+    pytest.param(64, 64, 16, 32, 8, 20, id="tiles-16x32"),
+    pytest.param(64, 64, 64, 64, 16, 20, id="one-tile-a-head"),
+]
+
+
+def _banded(q, k, v, window):
+    """(out, lse) the long way, in f32, over t - window < s <= t."""
+    import jax.numpy as jnp
+
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * q.shape[-1] ** -0.5
+    behind = jnp.arange(q.shape[1])[:, None] - jnp.arange(k.shape[1])
+    s = jnp.where((behind >= 0) & (behind < window), s, -jnp.inf)
+    lse = jax.scipy.special.logsumexp(s, axis=-1)
+    out = jnp.einsum("bhqk,bkhd->bqhd", jnp.exp(s - lse[..., None]), v)
+    return out, lse
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("lq, lk, block_q, block_k, w, window", WINDOWS)
+def test_windowed_forward_matches_a_masked_softmax(
+    lq, lk, block_q, block_k, w, window, dtype
+):
+    from elasticdl_tpu.ops.flash_attention import _flash_fwd
+
+    (q, k, v), exact = _geometry_inputs(lq, lk, dtype)
+    out, lse = _flash_fwd(
+        q, k, v, True, block_q, block_k, True, w=w, window=window
+    )
+    want_out, want_lse = _banded(*exact, window)
+    np.testing.assert_allclose(
+        np.asarray(out, np.float32), np.asarray(want_out), **TOLERANCE[dtype]
+    )
+    np.testing.assert_allclose(
+        np.asarray(lse), np.asarray(want_lse), rtol=2e-4, atol=2e-5
+    )
+
+
+@pytest.mark.parametrize("lq, lk, block_q, block_k, w, window", WINDOWS)
+def test_windowed_gradients_match_a_masked_softmax(
+    lq, lk, block_q, block_k, w, window
+):
+    """dq, dk and dv, each against the dense computation's."""
+    from elasticdl_tpu.ops.flash_attention import _flash_bwd, _flash_fwd
+
+    (q, k, v), exact = _geometry_inputs(lq, lk)
+    g = np.random.default_rng(7).standard_normal(q.shape).astype(np.float32)
+    want = jax.grad(
+        lambda q, k, v: (_banded(q, k, v, window)[0] * g).sum(),
+        argnums=(0, 1, 2),
+    )(*exact)
+    out, lse = _flash_fwd(
+        q, k, v, True, block_q, block_k, True, w=w, window=window
+    )
+    got = _flash_bwd(
+        q, k, v, out, lse, g, True, block_q, block_k, True, w=w, window=window
+    )
+    for name, a, b in zip(("dq", "dk", "dv"), got, want):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), err_msg=name,
+            **GRAD_TOLERANCE["float32"],
+        )  # fmt: skip
+
+
+@pytest.mark.parametrize("window", [64, 65, 1000])
+def test_a_window_that_reaches_every_key_is_causal_attention(window):
+    """W >= L: built as the plain causal call, under the plain names,
+    and so the same numbers bit for bit."""
+    from elasticdl_tpu.ops import flash_attention as fa
+
+    q, k, v = _qkv()
+    plain = jax.make_jaxpr(lambda *a: fa.flash_attention(*a, True, 16, 16))
+    windowed = jax.make_jaxpr(
+        lambda *a: fa.flash_attention(*a, True, 16, 16, window=window)
+    )
+    assert str(plain(q, k, v)) == str(windowed(q, k, v))
+    assert "edl_flash_win" not in str(windowed(q, k, v))
+    np.testing.assert_array_equal(
+        np.asarray(fa.flash_attention(q, k, v, True, 16, 16, window=window)),
+        np.asarray(fa.flash_attention(q, k, v, True, 16, 16)),
+    )
+
+
+def test_a_window_goes_under_names_of_its_own_and_trains():
+    """Three calls a layer, none of which a reader of the plain
+    kernels' prefix catches; a call with no window is built as before."""
+    from elasticdl_tpu.ops import flash_attention as fa
+
+    q, k, v = _qkv()
+
+    def loss(window):
+        return lambda q, k, v: (
+            fa.flash_attention(q, k, v, True, 16, 16, window=window) ** 2
+        ).sum()
+
+    text = str(jax.make_jaxpr(jax.grad(loss(20), argnums=(0, 1, 2)))(q, k, v))
+    assert text.count("pallas_call") == 3
+    for plain, name in fa.WINDOWED.items():
+        assert text.count("name=%s\n" % name) == 1, name
+        assert "name=%s\n" % plain not in text
+        assert not name.startswith(plain + "_")
+    plain = str(jax.make_jaxpr(jax.grad(loss(None), argnums=(0, 1, 2)))(q, k, v))
+    assert "edl_flash_win" not in plain and "window" not in plain
+    got = jax.grad(loss(20), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(
+        lambda q, k, v: (fa.windowed_reference_attention(q, k, v, 20) ** 2).sum(),
+        argnums=(0, 1, 2),
+    )(q, k, v)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=3e-4, atol=3e-4
+        )
+    for bad in (dict(causal=False, window=8), dict(causal=True, window=0)):
+        with pytest.raises(ValueError, match="a window of"):
+            fa.flash_attention(q, k, v, block_q=16, block_k=16, **bad)
+
+
+def test_the_policy_hands_short_lengths_the_window_in_xla():
+    from elasticdl_tpu.ops import flash_attention as fa
+
+    q, k, v = _qkv()
+    short = fa.pick_causal_attention(64, window=20)
+    np.testing.assert_allclose(
+        np.asarray(short(q, k, v)),
+        np.asarray(_banded(q, k, v, 20)[0]),
+        rtol=2e-4, atol=2e-5,
+    )  # fmt: skip
+    # a window that reaches every key is the plain causal reference
+    whole = fa.pick_causal_attention(64, window=64)
+    np.testing.assert_array_equal(
+        np.asarray(whole(q, k, v)),
+        np.asarray(reference_attention(q, k, v, causal=True)),
+    )
+    long = fa.pick_causal_attention(1024, window=256)
+    text = str(
+        jax.make_jaxpr(long)(*(np.zeros((1, 1024, 1, 16), np.float32),) * 3)
+    )
+    assert "name=edl_flash_win_fwd" in text
+
+
+def test_work_and_traffic_under_the_cells_window():
+    """L = 16,384 in 1,024-tiles under a window of 4,096: a q tile has
+    work in 5 of its 16 k tiles (three whole, the diagonal's and the
+    lower edge's, each 0.625 of a tile at w = 256), the pairs are
+    :func:`window_pairs`, and a skipped step fetches nothing."""
+    from elasticdl_tpu.ops import flash_attention as fa
+
+    length, window, tile = 16384, 4096, 1024
+    kept, causal = fa.window_pairs(length, window)
+    assert (kept, causal) == (58_722_304, 134_225_920)
+    assert kept == window * (window + 1) // 2 + (length - window) * window
+    worked = {}
+    for qi in range(16):
+        for kj in range(16):
+            done = []
+            fa._walk_tile(
+                qi, kj, tile, tile, 256, True, done.append,
+                when=fa._run_if, window=window,
+            )  # fmt: skip
+            if done:
+                worked[qi, kj] = sum(
+                    (r.stop - r.start) * (c.stop - c.start)
+                    for r, c, _ in done[0]
+                ) / tile**2
+    for qi in range(16):
+        mine = {kj: area for (q, kj), area in worked.items() if q == qi}
+        assert sorted(mine) == list(range(max(qi - 4, 0), qi + 1))
+        assert mine[qi] == 0.625  # the diagonal's tile
+        if qi >= 4:
+            assert len(mine) == 5 and mine[qi - 4] == 0.625  # the lower edge's
+        assert all(mine[kj] == 1.0 for kj in mine if qi - 4 < kj < qi)
+    performed = sum(worked.values())
+    assert performed == 16 * 0.625 + 12 * 0.625 + (1 + 2 + 3 * 13)
+    assert fa.causal_work_ratio(
+        length, length, tile, tile, 256, window=window
+    ) == pytest.approx(performed * tile**2 / kept)
+    # the static account of blocks moved: a q tile moves the k tiles it
+    # works on and no other (the first step of a q tile names the tile
+    # the last step of the one before left in place)
+    steps = sum(min(qi, 4) + 1 for qi in range(16))
+    assert steps == len(worked) == 70
+    blocks = {
+        kernel: moved["blocks"]
+        for kernel, moved in fa.hbm_traffic(
+            1, length, length, 128, tile, tile, window=window
+        ).items()
+    }
+    for kernel in (FWD, DQ):
+        assert blocks[kernel]["k"] == blocks[kernel]["v"] == steps - 1
+        assert blocks[kernel]["q"] == 16
+    for name in ("q", "dO", "lse", "delta"):
+        assert blocks[DKV][name] == steps - 1
+    assert blocks[DKV]["k"] == blocks[DKV]["dk"] == 16
+    causal_blocks = fa.hbm_traffic(1, length, length, 128, tile, tile)
+    assert causal_blocks[FWD]["blocks"]["k"] == 16 * 17 // 2 - 1
+
+
+@pytest.mark.parametrize("lq, lk, block_q, block_k, w, window", WINDOWS)
+def test_under_a_window_a_tile_with_work_is_handed_its_own_blocks(
+    lq, lk, block_q, block_k, w, window
+):
+    """The clamp from both sides may only touch steps that do nothing."""
+    from elasticdl_tpu.ops import flash_attention as fa
+
+    nq, nk = lq // block_q, lk // block_k
+    skipped = 0
+    for kernel in (FWD, DQ, DKV):
+        grid, inputs, outputs = fa._plan(
+            kernel, 2, lq, lk, 16, block_q, block_k, True, window=window
+        )
+        for i, a, b in np.ndindex(*grid):
+            qi, kj = (b, a) if kernel == DKV else (a, b)
+            worked = []
+            fa._walk_tile(
+                qi, kj, block_q, block_k, w, True, worked.append,
+                when=fa._run_if, window=window,
+            )  # fmt: skip
+            skipped += not worked
+            # a tile does work exactly where the band passes through it
+            behind = (
+                qi * block_q + np.arange(block_q)[:, None]
+                - kj * block_k - np.arange(block_k)
+            )  # fmt: skip
+            assert bool(worked) == bool(
+                ((behind >= 0) & (behind < window)).any()
+            ), (qi, kj)
+            for name, spec in inputs + outputs:
+                index = spec.index_map(int(i), int(a), int(b))
+                along = index[2] if name in ("lse", "delta") else index[1]
+                own = kj if name in ("k", "v", "dk", "dv") else qi
+                limit = nk if name in ("k", "v", "dk", "dv") else nq
+                assert index[0] == i and 0 <= along < limit
+                if worked:
+                    assert along == own, (kernel, name, qi, kj)
+    if nq > 1:
+        assert skipped

@@ -303,6 +303,48 @@ def test_flash_kernels_under_a_selection_compile_for_the_v5e_at_the_cells_shape(
         assert name + '"' not in text
 
 
+@pytest.mark.parametrize(
+    "length, heads, window, tiles",
+    [
+        pytest.param(16384, 28, 4096, None, id="smallthinker-ep8-l16384"),
+        # an edge that is no multiple of the tile, nor of the sub-block
+        pytest.param(4096, 4, 1000, None, id="a-ragged-window"),
+        pytest.param(2048, 4, 300, (512, 1024), id="tiles-512x1024"),
+    ],
+)
+def test_flash_kernels_under_a_window_compile_for_the_v5e_at_the_cells_shape(
+    one_chip, length, heads, window, tiles
+):
+    """`smallthinker-ep8-l16384`: one sequence of 16,384, 28 heads of 128,
+    a window of 4,096. A third variant of each body (the lower edge's
+    trimmed sub-blocks, with a mask of their own) and the index maps
+    clamped from both sides are Mosaic's to refuse; interpret mode
+    refuses none of them. The calls go under their own names."""
+    from elasticdl_tpu.ops import flash_attention as fa
+
+    x = jax.ShapeDtypeStruct(
+        (1, length, heads, 128), jnp.bfloat16, sharding=one_chip
+    )
+    tiles = tiles or fa.auto_blocks(length, length)
+
+    def fwd_and_bwd(q, k, v, g):
+        out, lse = fa._flash_fwd(q, k, v, True, *tiles, False, window=window)
+        return out, fa._flash_bwd(
+            q, k, v, out, lse, g, True, *tiles, False, window=window
+        )
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        text = jax.jit(fwd_and_bwd).lower(x, x, x, x).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+    assert text.count("tpu_custom_call") >= 3
+    for name in fa.WINDOWED.values():
+        assert name in text
+    for name in fa.WINDOWED:
+        assert name + '"' not in text
+
+
 def test_the_key_selection_compiles_for_the_v5e_as_loops_that_carry_int8_blocks(
     one_chip,
 ):
