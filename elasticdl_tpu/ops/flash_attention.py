@@ -2,10 +2,12 @@
 
 The hot op of the transformer family: softmax(QK^T)V computed blockwise
 with the online-softmax recurrence, so neither the (L, L) score matrix nor
-full-length K/V ever sit in VMEM. The grid is (batch*heads, q_blocks,
-k_blocks): Pallas streams one (block_k, D) K/V tile from HBM per step
-while the running max / normalizer / accumulator persist in VMEM scratch
-across the innermost k axis — the standard TPU flash pipeline.
+full-length K/V ever sit in VMEM. The grid is (batch*heads, steps), a
+step for each (q tile, k tile) pair the mask leaves work in, the k tiles
+of a q tile one after the other (:func:`_walk`): Pallas streams one
+(block_k, D) K/V tile from HBM per step while the running max /
+normalizer / accumulator persist in VMEM scratch across a q tile's
+steps — the standard TPU flash pipeline, over a list of tiles.
 Accumulation is float32 while inputs may be bfloat16 (MXU native).
 
 Inside a grid step the tile is not computed whole (PR 29). The body
@@ -72,13 +74,29 @@ broadcasts nothing. Each kernel reads or writes them its own way:
   contracted over the left operand's rows; ``dq += (ds^T)^T k`` is now
   the one that does.
 
-And a grid step the causal mask skips fetches nothing (PR 31): the
-index maps of the blocks that follow a grid's inner axis (k and v in
-the forward and dq, q, dO and both statistics in dkv) clamp it to the
-nearest tile with work (:func:`_plan`), so the skipped step names the
-block its neighbour computes with and the pipeline issues no copy. At
-L = 2,048 that is one step in four. :func:`hbm_traffic` walks the grids
-with the kernels' own index maps and counts the bytes.
+And a tile the mask leaves no work in is no grid step at all (PR 41).
+Which pairs hold work is known from the shapes, so :func:`_walk` lists
+them, an outer tile's one after the other (q tiles outside in the
+forward and dq, k tiles in dkv), and the call takes the list as two
+int32 tables in scalar memory (``PrefetchScalarGridSpec``, as
+``ops/grouped_matmul.py`` takes its visits): the index maps and the
+body read a step's tiles from them (:func:`_here`), and an accumulator
+starts and is written out where the outer tile changes. Until then the
+grid was the rectangle (batch*heads, outer tiles, inner tiles). A step
+without work fetched nothing since PR 31 (its index maps named the
+block its neighbour computed with), but it was still a step, and it
+still ran the body's prologue (the forward's scaled q and its
+``[v | 1]`` scratch, the backward kernels' q and dO tiles converted
+whole): 0.40-0.44 us each at head size 128 (chip runs, PR 41), 186 of
+256 steps a head under `smallthinker-ep8-l16384`'s window, 120 under
+its causal mask, one in four at L = 2,048. The prologue now sits under
+the tile's ``when``, a sub-block's rows at a time, which a step WITH
+work gains from too (0.12-0.20 us). Kernel-only, fwd + dq + dkv in ms a
+call, parent -> as committed: (28, 16384, 128) under a window of 4,096
+33.55 -> 26.02, causal 60.09 -> 55.09; (32, 8192, 128) under a
+selection 18.89 -> 16.41; (96, 2048, 64) 3.490 -> 3.280.
+:func:`hbm_traffic` walks the grids with the kernels' own index maps and
+counts the bytes: what the rectangle moved, block for block.
 
 Under a SELECTION (PR 32, :func:`flash_attention_selected`): the same
 three bodies take one more input, a per-sequence ``(L, L)`` int8 mask
@@ -97,9 +115,9 @@ the band does nothing, a tile wholly inside runs every sub-block with no
 mask, and the diagonal's tile and the one or two the window's lower edge
 crosses run their sub-blocks trimmed to the columns their rows can see,
 masked where an edge passes and only there (the lower edge's kept corner
-is the complement of the diagonal's). :func:`_plan`'s index maps clamp
-the blocks that follow a grid's inner axis from BOTH sides, so a skipped
-step, before the band as after it, fetches nothing. At L = 16,384 in
+is the complement of the diagonal's). :func:`_walk` lists the band's
+tiles and no other, so nothing is stepped through, before the band or
+after it. At L = 16,384 in
 1,024-tiles under W = 4,096 a q tile has work in 5 of its 16 k tiles
 (three whole and two of 0.625: :func:`causal_work_ratio` 1.062 over the
 58.7M pairs :func:`window_pairs` counts of the 134.2M causal ones). A
@@ -151,13 +169,23 @@ WINDOWED = {
     BWD_DKV_KERNEL: "edl_flash_win_bwd_dkv",
 }
 
-# every grid is (batch*heads, outer tile, inner tile) and accumulates
-# over the innermost axis only. No vmem_limit_bytes: on v5e / libtpu
-# 0.0.34 the default 1024x1024 tiles compile under Mosaic's default
-# scoped-VMEM limit (chip runs, PR 21 and PR 29; 2048x2048 tiles do
-# not: the dq kernel runs out of VMEM, PR 29).
+# what ``step_built`` says of a step's flash calls (:func:`grid_steps_in`)
+STEP_BUILT_FIELDS = ("flash_grid_steps", "flash_grid_steps_empty")
+# the grid steps with no tile to compute of each call built so far
+# (:func:`_call`), by what a jaxpr shows of a call: its name, its grid and
+# its two lengths. Not in the call's ``metadata``: jax writes that into
+# the compiled module's text as JSON over several lines, which
+# ``utils/step_ops.py`` and every other reader of that text a line an
+# instruction would have to learn
+_EMPTY_STEPS = {}
+
+# every grid is (batch*heads, steps of :func:`_walk`) and accumulates
+# over the steps of one outer tile, which are consecutive. No
+# vmem_limit_bytes: on v5e / libtpu 0.0.34 the default 1024x1024 tiles
+# compile under Mosaic's default scoped-VMEM limit (chip runs, PR 21 and
+# PR 29; 2048x2048 tiles do not: the dq kernel runs out of VMEM, PR 29).
 _COMPILER_PARAMS = pltpu.CompilerParams(
-    dimension_semantics=("parallel", "parallel", "arbitrary")
+    dimension_semantics=("parallel", "arbitrary")
 )
 
 
@@ -165,21 +193,6 @@ def _run_if(pred):
     """``pl.when`` for Python booleans: what :func:`causal_work_ratio`
     walks a tile with."""
     return lambda fn: fn() if pred else None
-
-
-def _lower(a, b):
-    """``min`` for program ids and for Python ints alike: an index map
-    runs on the former inside ``pallas_call`` and on the latter under
-    :func:`hbm_traffic`'s walk."""
-    if isinstance(a, int) and isinstance(b, int):
-        return min(a, b)
-    return jnp.minimum(a, b)
-
-
-def _higher(a, b):
-    if isinstance(a, int) and isinstance(b, int):
-        return max(a, b)
-    return jnp.maximum(a, b)
 
 
 def _walk_tile(
@@ -306,6 +319,20 @@ def _band_strips(distance, block, w, window):
     return found
 
 
+def _here(outer_ref, inner_ref):
+    """``(outer, inner, first, last)`` of this grid step: the tiles
+    :func:`_walk`'s two tables name for it, and whether it is the first
+    / the last step of its outer tile, whose steps are consecutive: an
+    accumulator starts at the one and is written out at the other."""
+    step, steps = pl.program_id(1), pl.num_programs(1)
+    outer = outer_ref[step]
+    first = (step == 0) | (outer_ref[jnp.maximum(step - 1, 0)] != outer)
+    last = (step == steps - 1) | (
+        outer_ref[jnp.minimum(step + 1, steps - 1)] != outer
+    )
+    return outer, inner_ref[step], first, last
+
+
 def _each(strip):
     """A step that treats its sub-blocks one by one."""
 
@@ -316,10 +343,10 @@ def _each(strip):
     return step
 
 
-def _scaled_q(q_ref, scale):
-    """The q tile with the softmax scale folded in: once an element of
-    q, not once a score."""
-    return q_ref[0].astype(jnp.float32) * scale
+def _scaled_q(q_ref, scale, rows):
+    """``rows`` of the q tile with the softmax scale folded in: once an
+    element of q, not once a score."""
+    return q_ref[0, rows, :].astype(jnp.float32) * scale
 
 
 def _kept(sel_ref, down, along):
@@ -380,6 +407,8 @@ def _lane_max(x):
 
 
 def _fwd_kernel(
+    q_tile_ref,
+    k_tile_ref,
     q_ref,
     k_ref,
     v_ref,
@@ -395,25 +424,23 @@ def _fwd_kernel(
     sel_ref=None,
     window=None,
 ):
-    qi = pl.program_id(1)
-    kj = pl.program_id(2)
-    nk = pl.num_programs(2)
+    qi, kj, first, last = _here(q_tile_ref, k_tile_ref)
     d = q_ref.shape[2]
 
-    @pl.when(kj == 0)
+    @pl.when(first)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
 
-    q = _scaled_q(q_ref, scale)
-    # v beside columns of ones: p @ [v | 1] leaves the row sums of p in
-    # the lanes the head size leaves empty, so the matrix unit takes
-    # the normalizer's row reduction and acc_ref[:, d:] carries l
-    # through the same rescale as the accumulator
-    v1_ref[:, :d] = v_ref[0].astype(jnp.float32)
-    v1_ref[:, d:] = jnp.ones((v1_ref.shape[0], v1_ref.shape[1] - d))
-
     def step(strips):
+        # v beside columns of ones: p @ [v | 1] leaves the row sums of p
+        # in the lanes the head size leaves empty, so the matrix unit
+        # takes the normalizer's row reduction and acc_ref[:, d:]
+        # carries l through the same rescale as the accumulator.
+        # Filled here and not above: a grid step with no tile to
+        # compute never gets here
+        v1_ref[:, :d] = v_ref[0].astype(jnp.float32)
+        v1_ref[:, d:] = jnp.ones((v1_ref.shape[0], v1_ref.shape[1] - d))
         # ONE step of the online-softmax recurrence for the tile, its
         # products and exponentials taken sub-block by sub-block.
         # m_ref holds the running maximum in every lane; between the
@@ -423,7 +450,10 @@ def _fwd_kernel(
         scores = []
         for rows, cols, off in strips:
             s = _scores(
-                q[rows], k_ref[0, cols, :], off, _kept(sel_ref, rows, cols)
+                _scaled_q(q_ref, scale, rows),
+                k_ref[0, cols, :],
+                off,
+                _kept(sel_ref, rows, cols),
             )
             m_ref[rows, :] = jnp.maximum(m_ref[rows, :], _lane_max(s))
             scores.append(s)
@@ -442,7 +472,7 @@ def _fwd_kernel(
         window=window,
     )  # fmt: skip
 
-    @pl.when(kj == nk - 1)
+    @pl.when(last)
     def _finish():
         l_fin = acc_ref[:, d:d + 1]
         o_ref[0] = (acc_ref[:, :d] / l_fin).astype(o_ref.dtype)
@@ -481,18 +511,17 @@ def _p_and_ds_t(
 ):
     """One sub-block of both backward passes, transposed: ``p^T =
     exp(k q^T - lse)`` recomputed and ``ds^T = p^T * (V dO^T - delta)``,
-    both (columns, rows). ``lse`` and ``delta`` arrive as (1, rows)
+    both (columns, rows). ``q`` (scaled) and ``do`` are the sub-block's
+    ``rows`` in f32. ``lse`` and ``delta`` arrive as (1, rows)
     and broadcast down the sublanes: no relayout. ``sel_ref`` is the
     TRANSPOSED selection's block, (columns, rows) as the scores."""
     p_t = jnp.exp(
-        _scores_t(
-            k_ref[0, cols, :], q[rows], off, _kept(sel_ref, cols, rows)
-        )
+        _scores_t(k_ref[0, cols, :], q, off, _kept(sel_ref, cols, rows))
         - lse_ref[0, :, rows]
     )
     dp_t = jax.lax.dot_general(
         v_ref[0, cols, :].astype(jnp.float32),
-        do[rows],
+        do,
         (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
@@ -500,6 +529,8 @@ def _p_and_ds_t(
 
 
 def _bwd_dq_kernel(
+    q_tile_ref,
+    k_tile_ref,
     q_ref,
     k_ref,
     v_ref,
@@ -515,19 +546,19 @@ def _bwd_dq_kernel(
     sel_ref=None,
     window=None,
 ):
-    qi = pl.program_id(1)
-    kj = pl.program_id(2)
-    nk = pl.num_programs(2)
+    qi, kj, first, last = _here(q_tile_ref, k_tile_ref)
 
-    @pl.when(kj == 0)
+    @pl.when(first)
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    q = _scaled_q(q_ref, scale)
-    do = do_ref[0].astype(jnp.float32)
     lhs_t = (((0,), (0,)), ((), ()))
 
     def strip(rows, cols, off):
+        # q and dO are read here, a sub-block's rows at a time, and not
+        # above: a grid step with no tile to compute reads nothing
+        q = _scaled_q(q_ref, scale, rows)
+        do = do_ref[0, rows, :].astype(jnp.float32)
         _, ds_t = _p_and_ds_t(
             q, k_ref, v_ref, do, lse_ref, delta_ref, rows, cols, off, sel_ref
         )
@@ -543,13 +574,15 @@ def _bwd_dq_kernel(
         window=window,
     )  # fmt: skip
 
-    @pl.when(kj == nk - 1)
+    @pl.when(last)
     def _finish():
         # ds k is the gradient of (q scale): the scale comes back once
         dq_ref[0] = (dq_acc[:] * scale).astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(
+    q_tile_ref,
+    k_tile_ref,
     q_ref,
     k_ref,
     v_ref,
@@ -567,29 +600,27 @@ def _bwd_dkv_kernel(
     sel_ref=None,
     window=None,
 ):
-    kj = pl.program_id(1)
-    qi = pl.program_id(2)
-    nq = pl.num_programs(2)
+    kj, qi, first, last = _here(k_tile_ref, q_tile_ref)
 
-    @pl.when(qi == 0)
+    @pl.when(first)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    # the scaled q serves both products: s^T = k (q scale)^T, and
-    # dk = ds^T (q scale) needs no scale of its own
-    q = _scaled_q(q_ref, scale)
-    do = do_ref[0].astype(jnp.float32)
-
     def strip(rows, cols, off):
+        # the scaled q serves both products: s^T = k (q scale)^T, and
+        # dk = ds^T (q scale) needs no scale of its own; read here, as
+        # in dq
+        q = _scaled_q(q_ref, scale, rows)
+        do = do_ref[0, rows, :].astype(jnp.float32)
         p_t, ds_t = _p_and_ds_t(
             q, k_ref, v_ref, do, lse_ref, delta_ref, rows, cols, off, sel_ref
         )
         dv_acc[cols, :] += jax.lax.dot(
-            p_t, do[rows], preferred_element_type=jnp.float32
+            p_t, do, preferred_element_type=jnp.float32
         )  # p^T dO
         dk_acc[cols, :] += jax.lax.dot(
-            ds_t, q[rows], preferred_element_type=jnp.float32
+            ds_t, q, preferred_element_type=jnp.float32
         )  # ds^T (q scale)
 
     _walk_tile(
@@ -597,7 +628,7 @@ def _bwd_dkv_kernel(
         window=window,
     )  # fmt: skip
 
-    @pl.when(qi == nq - 1)
+    @pl.when(last)
     def _finish():
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
@@ -743,79 +774,85 @@ def _block_sizes(lq, lk, block_q, block_k):
 _STATISTICS = ("lse", "delta")
 
 
+def _has_work(qi, kj, block_q, block_k, causal, window=None):
+    """Whether the mask leaves tile ``(qi, kj)`` a pair to compute, by
+    positions: some query of the tile reads some key of it. What
+    :func:`_walk_tile` decides by its predicates, tile by tile."""
+    if not causal:
+        return True
+    q_lo, k_lo = qi * block_q, kj * block_k
+    q_hi, k_hi = q_lo + block_q - 1, k_lo + block_k - 1
+    return q_hi >= k_lo and (window is None or q_lo - window < k_hi)
+
+
+def _walk(kernel, lq, lk, block_q, block_k, causal, window=None):
+    """``(q_tiles, k_tiles)``: the tiles a kernel's grid steps through,
+    as two lists with an entry a step.
+
+    The forward and dq run k tiles inside a q tile (the q tile is the
+    OUTER one), dkv q tiles inside a k tile. An outer tile's steps are
+    consecutive and list, in ascending order, the inner tiles in which
+    the mask leaves work (:func:`_has_work`: by positions, so square
+    and other tiles alike). At L = 16,384 in 1,024-tiles a q tile has
+    ``qi + 1`` steps under the causal mask (136 a head) and at most 5
+    under a window of 4,096 (70 a head, of the rectangle's 256), and so
+    a k tile. Not causal, every pair is listed.
+
+    Every outer tile has at least one step, where its result is
+    written. One with no work at all (lengths that differ: a k tile no
+    query reads, a q tile whose window begins past the last key) keeps
+    ONE step, on the last inner tile, to zero its result and write it:
+    the only steps without work a grid has."""
+    nq, nk = lq // block_q, lk // block_k
+    by_k = kernel == BWD_DKV_KERNEL
+    q_tiles, k_tiles = [], []
+    for a in range(nk if by_k else nq):
+        tiles = [(b, a) if by_k else (a, b) for b in range(nq if by_k else nk)]
+        found = [
+            tile
+            for tile in tiles
+            if _has_work(*tile, block_q, block_k, causal, window)
+        ]
+        found = found or tiles[-1:]
+        q_tiles += [qi for qi, _ in found]
+        k_tiles += [kj for _, kj in found]
+    return q_tiles, k_tiles
+
+
 def _plan(
     kernel, bh, lq, lk, d, block_q, block_k, causal, heads=None, window=None
 ):
-    """``(grid, inputs, outputs)`` of one kernel's ``pallas_call``, the
-    last two as lists of ``(name, BlockSpec)``: what the call is built
-    from, and what :func:`hbm_traffic` walks.
+    """``(grid, tables, inputs, outputs)`` of one kernel's
+    ``pallas_call``, the last two as lists of ``(name, BlockSpec)``:
+    what the call is built from, and what :func:`hbm_traffic` walks.
+
+    The grid is ``(bh, steps)``, a step for each tile with work and no
+    other, and ``tables`` are :func:`_walk`'s ``(q_tiles, k_tiles)``:
+    the call's two scalar-prefetch operands, which every index map is
+    handed after the grid's two indices and the body reads its tiles
+    from (:func:`_here`).
 
     q, dO, o, dq and the statistics move in tiles of ``block_q`` rows,
     k, v, dk and dv in tiles of ``block_k``; a statistic is
     ``(bh, 1, L)`` f32 and its tile ``(1, 1, block_q)``, L along the
-    lanes. The forward and dq run k tiles inside a q tile, dkv q tiles
-    inside a k tile.
-
-    Under ``causal`` the blocks that follow the inner axis are clamped
-    to the nearest tile with work, by positions, so square and other
-    tiles alike: a q tile's last row sees k positions up to its own, so
-    its last k tile with work is ``((qi + 1) block_q - 1) // block_k``
-    and k, v take the lower of that and ``kj``; a k tile is first seen
-    by q tile ``kj block_k // block_q`` and q, dO and the statistics
-    take the higher of that and ``qi`` (square tiles: ``min(kj, qi)``
-    and ``max(qi, kj)``). A grid step :func:`_walk_tile` skips then
-    names the block its neighbour in the walk computes with, and the
-    pipeline issues no copy for it. Blocks that follow the outer axis,
-    every output among them, are left alone.
-
-    Under a ``window`` the clamp is from BOTH sides: a q tile's first
-    row reads keys from ``qi block_q - window + 1`` on, so its first k
-    tile with work is that position's (0 where it is negative), and k,
-    v take the higher of it and the above; a k tile's last key is read
-    by queries up to ``(kj + 1) block_k + window - 2``, and q, dO and
-    the statistics take the lower of that position's tile and the
-    above. At L = 16,384 in 1,024-tiles with a window of 4,096 a q tile
-    moves 5 of 16 k tiles, and a k tile 5 of 16 q tiles.
+    lanes.
 
     ``heads``, where given, says the call has a selection, one for each
     run of ``heads`` rows of the grid's first axis (a sequence's
-    heads): the last input, int8, in tiles that follow BOTH tile axes,
-    clamped as the operands of each axis are. The forward reads it as
-    ``(bh // heads, lq, lk)`` in ``(1, block_q, block_k)`` tiles; both
-    backward kernels form transposed scores and read its transpose,
-    ``(bh // heads, lk, lq)`` in ``(1, block_k, block_q)`` tiles."""
-    nq, nk = lq // block_q, lk // block_k
-
-    def k_tile(qi, kj):
-        if not causal:
-            return kj
-        last = _lower(kj, ((qi + 1) * block_q - 1) // block_k)
-        if window is None:
-            return last
-        first = _higher(qi * block_q - window + 1, 0) // block_k
-        return _higher(last, _lower(first, nk - 1))
-
-    def q_tile(kj, qi):
-        if not causal:
-            return qi
-        first = _lower(_higher(qi, kj * block_k // block_q), nq - 1)
-        if window is None:
-            return first
-        return _lower(first, ((kj + 1) * block_k + window - 2) // block_q)
-
-    if kernel == BWD_DKV_KERNEL:
-        grid = (bh, nk, nq)
-        rows = lambda i, kj, qi: (i, q_tile(kj, qi), 0)
-        cols = lambda i, kj, qi: (i, kj, 0)
-        stat = lambda i, kj, qi: (i, 0, q_tile(kj, qi))
-        sel_t = lambda i, kj, qi: (i // heads, kj, q_tile(kj, qi))
-    else:
-        grid = (bh, nq, nk)
-        rows = lambda i, qi, kj: (i, qi, 0)
-        cols = lambda i, qi, kj: (i, k_tile(qi, kj), 0)
-        stat = lambda i, qi, kj: (i, 0, qi)
-        sel = lambda i, qi, kj: (i // heads, qi, k_tile(qi, kj))
-        sel_t = lambda i, qi, kj: (i // heads, k_tile(qi, kj), qi)
+    heads): the last input, int8, in tiles that follow BOTH tile axes.
+    The forward reads it as ``(bh // heads, lq, lk)`` in
+    ``(1, block_q, block_k)`` tiles; both backward kernels form
+    transposed scores and read its transpose, ``(bh // heads, lk, lq)``
+    in ``(1, block_k, block_q)`` tiles."""
+    tables = _walk(kernel, lq, lk, block_q, block_k, causal, window)
+    grid = (bh, len(tables[0]))
+    rows = lambda i, s, q_tiles, k_tiles: (i, q_tiles[s], 0)
+    cols = lambda i, s, q_tiles, k_tiles: (i, k_tiles[s], 0)
+    stat = lambda i, s, q_tiles, k_tiles: (i, 0, q_tiles[s])
+    sel = lambda i, s, q_tiles, k_tiles: (i // heads, q_tiles[s], k_tiles[s])
+    sel_t = lambda i, s, q_tiles, k_tiles: (
+        i // heads, k_tiles[s], q_tiles[s]
+    )
     by_rows = pl.BlockSpec((1, block_q, d), rows)
     by_cols = pl.BlockSpec((1, block_k, d), cols)
     statistic = pl.BlockSpec((1, 1, block_q), stat)
@@ -823,15 +860,15 @@ def _plan(
     if kernel == FWD_KERNEL:
         if heads:
             qkv.append(("sel", pl.BlockSpec((1, block_q, block_k), sel)))
-        return grid, qkv, [("o", by_rows), ("lse", statistic)]
+        return grid, tables, qkv, [("o", by_rows), ("lse", statistic)]
     inputs = qkv + [
         ("dO", by_rows), ("lse", statistic), ("delta", statistic)
     ]
     if heads:
         inputs.append(("sel_t", pl.BlockSpec((1, block_k, block_q), sel_t)))
     if kernel == BWD_DQ_KERNEL:
-        return grid, inputs, [("dq", by_rows)]
-    return grid, inputs, [("dk", by_cols), ("dv", by_cols)]
+        return grid, tables, inputs, [("dq", by_rows)]
+    return grid, tables, inputs, [("dk", by_cols), ("dv", by_cols)]
 
 
 def hbm_traffic(
@@ -858,14 +895,14 @@ def hbm_traffic(
     block_q, block_k = _block_sizes(lq, lk, block_q, block_k)
     traffic = {}
     for kernel in (FWD_KERNEL, BWD_DQ_KERNEL, BWD_DKV_KERNEL):
-        grid, inputs, outputs = _plan(
+        grid, tables, inputs, outputs = _plan(
             kernel, bh, lq, lk, d, block_q, block_k, causal, window=window
         )
         moved = {"tensors": 0, "statistics": 0, "blocks": {}}
         at = {}
         for step in itertools.product(*map(range, grid)):
             for name, spec in inputs + outputs:
-                index = spec.index_map(*step)
+                index = spec.index_map(*step, *tables)
                 if at.get(name) == index:
                     continue
                 at[name] = index
@@ -892,8 +929,8 @@ _STATIC = ("causal", "block_q", "block_k", "interpret", "w", "window")
 
 
 def _selecting(body, at):
-    """``body`` for a call whose input number ``at``, its last, is the
-    selection: the refs come as inputs, outputs, scratch."""
+    """``body`` for a call whose ref number ``at``, its last input, is
+    the selection: the refs come as tables, inputs, outputs, scratch."""
 
     def kernel(*refs, **static):
         return body(*refs[:at], *refs[at + 1 :], sel_ref=refs[at], **static)
@@ -912,28 +949,44 @@ def _call(
     window=None,
     **static
 ):
-    """The ``pallas_call`` of ``kernel``: its grid and block specs are
-    :func:`_plan`'s for ``shapes``, ``static`` are the body's keywords.
-    With ``heads`` (a selection is the last input, :func:`_plan`) the
-    call goes under its :data:`SELECTED` name, with ``window`` (one
-    more keyword of the body) under its :data:`WINDOWED` name."""
-    grid, inputs, outputs = _plan(kernel, *shapes, heads=heads, window=window)
+    """The ``pallas_call`` of ``kernel``, as a function of its array
+    operands: its grid, tables and block specs are :func:`_plan`'s for
+    ``shapes`` (the tables go in front of the operands, as scalar
+    prefetch), ``static`` are the body's keywords. With ``heads`` (a
+    selection is the last input, :func:`_plan`) the call goes under its
+    :data:`SELECTED` name, with ``window`` (one more keyword of the
+    body) under its :data:`WINDOWED` name. How many of the grid's steps
+    have no tile to compute is noted for :func:`grid_steps_in`."""
+    grid, tables, inputs, outputs = _plan(
+        kernel, *shapes, heads=heads, window=window
+    )
+    bh, lq, lk, _, block_q, block_k, causal = shapes
     if heads:
-        body, kernel = _selecting(body, len(inputs) - 1), SELECTED[kernel]
+        body = _selecting(body, len(tables) + len(inputs) - 1)
+        kernel = SELECTED[kernel]
     if window is not None:
         static["window"] = window
         kernel = WINDOWED[kernel]
-    return pl.pallas_call(
+    call = pl.pallas_call(
         functools.partial(body, **static),
         out_shape=out_shape,
-        grid=grid,
-        in_specs=[spec for _, spec in inputs],
-        out_specs=[spec for _, spec in outputs],
-        scratch_shapes=scratch_shapes,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(tables),
+            grid=grid,
+            in_specs=[spec for _, spec in inputs],
+            out_specs=[spec for _, spec in outputs],
+            scratch_shapes=scratch_shapes,
+        ),
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
         name=kernel,
     )
+    _EMPTY_STEPS[kernel, grid, lq, lk] = bh * sum(
+        not _has_work(qi, kj, block_q, block_k, causal, window)
+        for qi, kj in zip(*tables)
+    )
+    tables = [np.asarray(table, np.int32) for table in tables]
+    return lambda *operands: call(*tables, *operands)
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC, inline=True)
@@ -1294,6 +1347,30 @@ def attention_in_step(step_facts):
         if flash <= set(step_facts["pallas_kernels"]):
             return "pallas-interpret"
     return "xla"
+
+
+def grid_steps_in(jaxpr):
+    """:data:`STEP_BUILT_FIELDS` of a traced program: the grid steps of
+    every flash call in ``jaxpr`` and in the jaxprs nested in it, and
+    how many of them have no tile to compute, each call counted as
+    often as the program holds it (a layer's three, a recomputed
+    forward's once more); ``{}`` for a program without the kernels.
+    Static: a count of the walk (:func:`_walk`), no device number."""
+    steps = empty = 0
+    flash = {*SELECTED, *SELECTED.values(), *WINDOWED.values()}
+    todo = [getattr(jaxpr, "jaxpr", jaxpr)]
+    while todo:
+        for eqn in todo.pop().eqns:
+            name = eqn.params.get("name")
+            if eqn.primitive.name == "pallas_call" and name in flash:
+                grid = tuple(eqn.params["grid_mapping"].grid)
+                # a flash call's operands: the two tables, q, k, ...
+                lengths = [v.aval.shape[1] for v in eqn.invars[2:4]]
+                steps += math.prod(grid)
+                empty += _EMPTY_STEPS.get((name, grid, *lengths), 0)
+            todo.extend(jax.core.jaxprs_in_params(eqn.params))
+    # a flash call has a step at least
+    return dict(zip(STEP_BUILT_FIELDS, (steps, empty))) if steps else {}
 
 
 def selected_reference_attention(q, k, v, selection):

@@ -61,6 +61,7 @@ from jax.sharding import PartitionSpec as P
 
 from elasticdl_tpu.common.log_utils import default_logger as logger
 from elasticdl_tpu.nn.model_api import apply_model, init_variables, split_variables
+from elasticdl_tpu.ops import flash_attention
 from elasticdl_tpu.parallel import compile_plane, distributed, layout_solver
 from elasticdl_tpu.parallel.sharding import tp_degree_candidates
 from elasticdl_tpu.training.step import (
@@ -1701,7 +1702,10 @@ class ElasticDPTrainer:
         the calls are interpreted; and the Mosaic custom calls in the
         lowered module, by kernel name; and how many of the module's
         inputs it donates (the state's leaves on a process-local mesh,
-        none on one that spans processes: :func:`state_donation`).
+        none on one that spans processes: :func:`state_donation`); and,
+        where it holds the flash kernels, how many steps their grids
+        take and how many of those have no tile to compute
+        (``flash_grid_steps``, ``flash_grid_steps_empty``).
         Asked BEFORE the first step it
         costs about nothing: jax caches the trace and the lowering, and
         the step's own first call reuses both (CPU, 8 layers: 4.4 s
@@ -1734,6 +1738,8 @@ class ElasticDPTrainer:
                 set(re.findall(r'kernel_name = "([^"]+)"', lowered_text))
             ),
             "donated_inputs": count_donated_inputs(lowered_text),
+            # where the step holds the flash kernels: their grids' steps
+            **flash_attention.grid_steps_in(traced.jaxpr),
             **(self._step_ops_facts(lowered, trace_dir) if trace_dir else {}),
         }
 

@@ -935,7 +935,10 @@ class ElasticAllReduceWorker:
         import jax
 
         from elasticdl_tpu.data.recordio import reader_kind
-        from elasticdl_tpu.ops.flash_attention import attention_in_step
+        from elasticdl_tpu.ops.flash_attention import (
+            STEP_BUILT_FIELDS as flash_fields,
+            attention_in_step,
+        )
 
         # the first train_window's clocks start here: describe_step
         # does the step's trace and lowering, which the first step call
@@ -973,9 +976,13 @@ class ElasticAllReduceWorker:
             or "",
         }
         # a traced run's own: the compiled step's ops by class and the
-        # compiler's account of its memory (describe_step)
+        # compiler's account of its memory (describe_step); a step with
+        # the flash kernels: the steps of their grids, and those of them
+        # with no tile to compute (grid_steps_in)
         report.update(
-            (k, facts[k]) for k in step_ops.STEP_BUILT_FIELDS if k in facts
+            (k, facts[k])
+            for k in (*step_ops.STEP_BUILT_FIELDS, *flash_fields)
+            if k in facts
         )
         # what the model says of its own layout (a zoo module's
         # ``step_facts``: layers by kind, experts held and routed over,

@@ -475,35 +475,77 @@ def test_hbm_traffic_against_hand_counts(heads):
 def test_a_tile_with_work_is_handed_its_own_blocks(
     lq, lk, block_q, block_k, w
 ):
-    """The clamp may only touch steps that do nothing: wherever the walk of
-    a tile performs a sub-block, every operand's block is the tile's own;
-    wherever it performs none, the blocks named are inside the arrays."""
+    """Every tile in which the walk of a tile performs a sub-block is a
+    step of the grid, once, and every operand's block there is the
+    tile's own; a step that performs none (an outer tile past the other
+    sequence's end keeps one, to write its zeros) names blocks inside
+    the arrays."""
+    _check_the_walk(lq, lk, block_q, block_k, w)
+
+
+def _check_the_walk(lq, lk, block_q, block_k, w, window=None):
     from elasticdl_tpu.ops import flash_attention as fa
 
     nq, nk = lq // block_q, lk // block_k
+    every_empty = 0
     for kernel in (FWD, DQ, DKV):
-        grid, inputs, outputs = fa._plan(
-            kernel, 2, lq, lk, 16, block_q, block_k, True
+        grid, tables, inputs, outputs = fa._plan(
+            kernel, 2, lq, lk, 16, block_q, block_k, True, window=window
         )
-        for i, a, b in np.ndindex(*grid):
-            qi, kj = (b, a) if kernel == DKV else (a, b)
+        q_tiles, k_tiles = fa._walk(
+            kernel, lq, lk, block_q, block_k, True, window
+        )
+        assert tables == (q_tiles, k_tiles) and grid == (2, len(q_tiles))
+        # dkv runs q tiles inside a k tile, the other two the other way
+        outer, inner = (k_tiles, q_tiles) if kernel == DKV else tables
+        tile_of = lambda a, b: (b, a) if kernel == DKV else (a, b)
+        with_work = set()
+        for a, b in np.ndindex(*((nk, nq) if kernel == DKV else (nq, nk))):
+            qi, kj = tile_of(a, b)
             worked = []
             fa._walk_tile(
                 qi, kj, block_q, block_k, w, True, worked.append,
-                when=fa._run_if,
+                when=fa._run_if, window=window,
+            )  # fmt: skip
+            if window is not None:
+                # a tile does work exactly where the band passes through
+                behind = (
+                    qi * block_q + np.arange(block_q)[:, None]
+                    - kj * block_k - np.arange(block_k)
+                )  # fmt: skip
+                assert bool(worked) == bool(
+                    ((behind >= 0) & (behind < window)).any()
+                ), (qi, kj)
+            assert bool(worked) == fa._has_work(
+                qi, kj, block_q, block_k, True, window
             )
+            if worked:
+                with_work.add((a, b))
+        listed = list(zip(outer, inner))
+        # each tile with work once; an outer tile's steps consecutive,
+        # its inner tiles ascending; every outer tile writes its result
+        assert len(set(listed)) == len(listed) and with_work <= set(listed)
+        assert listed == sorted(listed)
+        assert set(outer) == set(range(nk if kernel == DKV else nq))
+        # a step without work is the only step of its outer tile
+        idle = set(listed) - with_work
+        assert all(outer.count(a) == 1 for a, _ in idle)
+        every_empty += len(idle)
+        for i, s in np.ndindex(*grid):
+            qi, kj = q_tiles[s], k_tiles[s]
             for name, spec in inputs + outputs:
-                index = spec.index_map(int(i), int(a), int(b))
+                index = spec.index_map(int(i), int(s), *tables)
                 along = index[2] if name in ("lse", "delta") else index[1]
                 own = kj if name in ("k", "v", "dk", "dv") else qi
                 limit = nk if name in ("k", "v", "dk", "dv") else nq
                 assert index[0] == i and 0 <= along < limit
-                if worked:
-                    assert along == own, (kernel, name, qi, kj)
-    # and the clamp does engage: some skipped step names another tile
-    assert fa.hbm_traffic(2, lq, lk, 16, block_q, block_k)[DKV]["blocks"][
-        "q"
-    ] <= 2 * nq * nk
+                assert along == own, (kernel, name, qi, kj)
+    # the masks do leave tiles out: fewer steps than the rectangle has
+    if nq > 1 and nk > 1:
+        assert len(listed) < nq * nk
+    if lq == lk:
+        assert every_empty == 0
+    return every_empty
 
 
 @pytest.mark.parametrize(
@@ -750,38 +792,276 @@ def test_work_and_traffic_under_the_cells_window():
 def test_under_a_window_a_tile_with_work_is_handed_its_own_blocks(
     lq, lk, block_q, block_k, w, window
 ):
-    """The clamp from both sides may only touch steps that do nothing."""
+    """The band from both sides: a tile is a step of the grid exactly
+    where the band passes through it."""
+    assert _check_the_walk(lq, lk, block_q, block_k, w, window) == 0
+
+
+# ---------------------------------------------------------------------------
+# a grid step with no tile to compute costs nothing (PR 41): the grid lists
+# only tiles with work, handed to the call as two scalar-prefetch tables
+# ---------------------------------------------------------------------------
+
+# the three ways a call leaves tiles without work, at the cell's
+# proportions in toy tiles: W = 4 tiles, as `smallthinker-ep8-l16384`
+MASKS = ["causal", "windowed", "selected"]
+TOY_LENGTH, TOY_TILE, TOY_W, TOY_WINDOW = 128, 16, 4, 64
+
+
+def _toy_selection(length, seed=3):
+    """(1, L, L) int8 inside the causal triangle, the diagonal kept."""
+    rng = np.random.default_rng(seed)
+    kept = rng.random((1, length, length)) < 0.4
+    kept |= np.eye(length, dtype=bool)
+    return (kept & np.tril(np.ones((length, length), bool))).astype(np.int8)
+
+
+def _toy_call(fa, mask, dtype, length=TOY_LENGTH, tile=TOY_TILE, seed=41):
+    """(out, lse, dq, dk, dv) of one call of ``fa``'s kernels under
+    ``mask``: eight tiles of ``tile`` each way in sub-blocks of 4, one
+    sequence of two heads, inputs from ``seed``."""
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(seed)
+    q, k, v, g = (
+        jnp.asarray(rng.standard_normal((1, length, 2, 16)), dtype)
+        for _ in range(4)
+    )
+    how = {}
+    if mask == "windowed":
+        how = {"window": TOY_WINDOW}
+    selection = _toy_selection(length) if mask == "selected" else None
+    out, lse = fa._flash_fwd(
+        q, k, v, mask != "full", tile, tile, True, w=TOY_W,
+        selection=selection, **how,
+    )  # fmt: skip
+    if selection is not None:
+        how = {"selection_t": selection.transpose(0, 2, 1)}
+    grads = fa._flash_bwd(
+        q, k, v, out, lse, g, mask != "full", tile, tile, True, w=TOY_W, **how
+    )
+    return (q, k, v, g), (out, lse) + tuple(grads)
+
+
+def _digest(fa, mask, dtype):
+    import hashlib
+
+    _, results = _toy_call(fa, mask, dtype)
+    sha = hashlib.sha256()
+    for x in results:
+        sha.update(np.asarray(x).tobytes())
+    return sha.hexdigest()[:16]
+
+
+# out, lse, dq, dk and dv of `_toy_call` as the PARENT's kernels (commit
+# 909f9a5, the 3-axis grid with its prologue on every step) computed them
+# here on the CPU's interpreter. Recorded anew by
+# `PYTHONPATH=. python tests/test_flash_attention.py <a flash_attention.py>`.
+BIT_FOR_BIT = {
+    "causal-float32": "adfdfb1ccf81b0a2",
+    "causal-bfloat16": "c2b11eeaa57cb270",
+    "windowed-float32": "9e1ce5c3c27fff8f",
+    "windowed-bfloat16": "162f925f8be37582",
+    "selected-float32": "992ee4becaa9c301",
+    "selected-bfloat16": "6443fa57784f0188",
+    "full-float32": "a06c2fec720642fc",
+    "full-bfloat16": "107d748b23b31609",
+}
+
+
+@pytest.mark.parametrize("case", sorted(BIT_FOR_BIT))
+def test_a_tile_with_work_computes_what_it_computed(case):
+    """Neither the prologue under the tile's ``when`` nor the grid of
+    tiles with work changes a product, a mask or their order: bit for
+    bit the parent's results."""
     from elasticdl_tpu.ops import flash_attention as fa
 
-    nq, nk = lq // block_q, lk // block_k
-    skipped = 0
-    for kernel in (FWD, DQ, DKV):
-        grid, inputs, outputs = fa._plan(
-            kernel, 2, lq, lk, 16, block_q, block_k, True, window=window
+    assert _digest(fa, *case.split("-")) == BIT_FOR_BIT[case]
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("mask", MASKS)
+def test_every_tile_of_eight_each_way_against_the_reference(mask, dtype):
+    """Forward and the three gradients where the rectangle had 28 (36
+    under the window) of 64 steps a head without work."""
+    import jax.numpy as jnp
+
+    from elasticdl_tpu.ops import flash_attention as fa
+
+    (q, k, v, g), (out, _, *grads) = _toy_call(fa, mask, dtype)
+    if mask == "windowed":
+        reference = lambda q, k, v: fa.windowed_reference_attention(
+            q, k, v, TOY_WINDOW
         )
-        for i, a, b in np.ndindex(*grid):
-            qi, kj = (b, a) if kernel == DKV else (a, b)
-            worked = []
-            fa._walk_tile(
-                qi, kj, block_q, block_k, w, True, worked.append,
-                when=fa._run_if, window=window,
-            )  # fmt: skip
-            skipped += not worked
-            # a tile does work exactly where the band passes through it
-            behind = (
-                qi * block_q + np.arange(block_q)[:, None]
-                - kj * block_k - np.arange(block_k)
-            )  # fmt: skip
-            assert bool(worked) == bool(
-                ((behind >= 0) & (behind < window)).any()
-            ), (qi, kj)
-            for name, spec in inputs + outputs:
-                index = spec.index_map(int(i), int(a), int(b))
-                along = index[2] if name in ("lse", "delta") else index[1]
-                own = kj if name in ("k", "v", "dk", "dv") else qi
-                limit = nk if name in ("k", "v", "dk", "dv") else nq
-                assert index[0] == i and 0 <= along < limit
-                if worked:
-                    assert along == own, (kernel, name, qi, kj)
-    if nq > 1:
-        assert skipped
+    elif mask == "selected":
+        selection = jnp.asarray(_toy_selection(TOY_LENGTH))
+        reference = lambda q, k, v: fa.selected_reference_attention(
+            q, k, v, selection
+        )
+    else:
+        reference = lambda q, k, v: reference_attention(q, k, v, causal=True)
+    exact = [x.astype(jnp.float32) for x in (q, k, v)]
+    want, vjp = jax.vjp(reference, *exact)
+    np.testing.assert_allclose(
+        np.asarray(out, np.float32), np.asarray(want), **TOLERANCE[dtype]
+    )
+    for name, a, b in zip(("dq", "dk", "dv"), grads, vjp(g.astype(jnp.float32))):
+        np.testing.assert_allclose(
+            np.asarray(a, np.float32), np.asarray(b), err_msg=name,
+            **GRAD_TOLERANCE[dtype],
+        )  # fmt: skip
+
+
+@pytest.mark.parametrize("kernel", [FWD, DQ, DKV])
+@pytest.mark.parametrize("mask", MASKS)
+def test_the_grid_lists_the_tiles_with_work_and_no_other(mask, kernel):
+    """`smallthinker-ep8-l16384`'s calls, 16 x 16 tiles of 1,024 a head
+    (and `keyevl2-ep8-l8192`'s selection at the same length): a step
+    for each tile with work, once, an outer tile's steps consecutive,
+    and none without work, where the rectangle had 120 (186 under the
+    window of four tiles) of 256."""
+    from elasticdl_tpu.ops import flash_attention as fa
+
+    length, tile, n = 16384, 1024, 16
+    window = 4096 if mask == "windowed" else None
+    grid, (q_tiles, k_tiles), inputs, _ = fa._plan(
+        kernel, 28, length, length, 128, tile, tile, True,
+        heads=28 if mask == "selected" else None, window=window,
+    )  # fmt: skip
+    outer, inner = (k_tiles, q_tiles) if kernel == DKV else (q_tiles, k_tiles)
+    band = 5 if window else n  # tiles the band crosses, the diagonal's too
+    want = [
+        (a, b)
+        for a in range(n)
+        for b in (
+            range(a, min(a + band, n))
+            if kernel == DKV
+            else range(max(a - band + 1, 0), a + 1)
+        )
+    ]
+    assert list(zip(outer, inner)) == want
+    assert grid == (28, 70 if window else 136)
+    for a, b in want:
+        worked = []
+        fa._walk_tile(
+            *((b, a) if kernel == DKV else (a, b)), tile, tile, 256, True,
+            worked.append, when=fa._run_if, window=window,
+        )  # fmt: skip
+        assert worked
+    # a selection's tile follows both tables: the backward kernels read
+    # the transposed selection
+    if mask == "selected":
+        name, spec = inputs[-1]
+        s = 7
+        at = (q_tiles[s], k_tiles[s])
+        assert spec.index_map(30, s, q_tiles, k_tiles) == (
+            1, *(at if kernel == FWD else at[::-1])
+        ), name  # fmt: skip
+
+
+def test_a_call_that_is_not_causal_lists_every_pair():
+    from elasticdl_tpu.ops import flash_attention as fa
+
+    for kernel in (FWD, DQ, DKV):
+        q_tiles, k_tiles = fa._walk(kernel, 64, 32, 16, 8, False)
+        outer, inner = (k_tiles, q_tiles) if kernel == DKV else (q_tiles, k_tiles)
+        assert list(zip(outer, inner)) == list(np.ndindex(4, 4))
+
+
+# what the parent's rectangle moved, whose steps without work named a
+# neighbour's block and copied nothing (PR 31, PR 39): bytes a kernel as
+# (tensors, statistics) and blocks as (inner-axis operands, the others)
+TRAFFIC = {
+    "lm125m-l2048": (
+        (96, 2048, 64, None),
+        {FWD: (100663296, 786432, 192, 192),
+         DQ: (125829120, 1572864, 192, 192),
+         DKV: (150994944, 1572864, 192, 192)},
+    ),
+    "smallthinker-window": (
+        (28, 16384, 128, 4096),
+        {FWD: (1247805440, 1835008, 1932, 448),
+         DQ: (1365245952, 3670016, 1932, 448),
+         DKV: (1482686464, 15826944, 1932, 448)},
+    ),
+    "smallthinker-global": (
+        (28, 16384, 128, None),
+        {FWD: (2216689664, 1835008, 3780, 448),
+         DQ: (2334130176, 3670016, 3780, 448),
+         DKV: (2451570688, 30965760, 3780, 448)},
+    ),
+}  # fmt: skip
+
+
+@pytest.mark.parametrize("cell", sorted(TRAFFIC))
+def test_the_listed_grid_moves_what_the_rectangle_moved(cell):
+    from elasticdl_tpu.ops import flash_attention as fa
+
+    (heads, length, d, window), want = TRAFFIC[cell]
+    got = fa.hbm_traffic(
+        heads, length, length, d, 1024, 1024, window=window
+    )
+    for kernel, (tensors, statistics, inner, others) in want.items():
+        moved = got[kernel]
+        assert (moved["tensors"], moved["statistics"]) == (tensors, statistics)
+        follows_inner = (
+            ("q", "dO", "lse", "delta") if kernel == DKV else ("k", "v")
+        )
+        for name, blocks in moved["blocks"].items():
+            assert blocks == (inner if name in follows_inner else others), name
+    if cell == "lm125m-l2048":
+        total = lambda kind: sum(m[kind] for m in got.values()) / 1e6
+        assert total("tensors") == pytest.approx(377.5, abs=0.05)
+        assert total("statistics") == pytest.approx(3.93, abs=0.01)
+
+
+def test_a_call_says_how_many_steps_its_grid_takes():
+    """`grid_steps_in` reads the flash calls' grids off a traced program:
+    what `step_built` reports as `flash_grid_steps` and
+    `flash_grid_steps_empty`."""
+    from elasticdl_tpu.ops import flash_attention as fa
+
+    q, k, v = _qkv()  # 2 sequences x 2 heads, 64 positions
+
+    def loss(window):
+        return lambda q, k, v: (
+            fa.flash_attention(q, k, v, True, 16, 16, window=window) ** 2
+        ).sum()
+
+    both = lambda q, k, v: loss(None)(q, k, v) + loss(20)(q, k, v)
+    jaxpr = jax.make_jaxpr(jax.grad(both, argnums=(0, 1, 2)))(q, k, v)
+    # four tiles each way: ten with work under the diagonal, nine of
+    # them under a window of 20 (a query reads into the tile before
+    # the last one); three kernels each, four heads
+    assert fa.grid_steps_in(jaxpr) == {
+        "flash_grid_steps": 4 * 3 * (10 + 9),
+        "flash_grid_steps_empty": 0,
+    }
+    # lengths that differ leave k tiles that no query reads: one step
+    # each, to write their zeros
+    q = q[:, :32]
+    jaxpr = jax.make_jaxpr(jax.grad(loss(None), argnums=(0, 1, 2)))(q, k, v)
+    assert fa.grid_steps_in(jaxpr) == {
+        "flash_grid_steps": 4 * (3 + 3 + (2 + 1 + 2)),
+        "flash_grid_steps_empty": 4 * 2,
+    }
+    assert fa.grid_steps_in(jax.make_jaxpr(lambda x: x * 2)(1.0)) == {}
+
+
+if __name__ == "__main__":
+    import importlib.util
+    import json
+    import sys
+
+    spec = importlib.util.spec_from_file_location("fa_recorded", sys.argv[1])
+    recorded = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(recorded)
+    print(
+        json.dumps(
+            {
+                case: _digest(recorded, *case.split("-"))
+                for case in BIT_FOR_BIT
+            },
+            indent=4,
+        )
+    )

@@ -307,6 +307,9 @@ def test_flash_kernels_under_a_selection_compile_for_the_v5e_at_the_cells_shape(
     "length, heads, window, tiles",
     [
         pytest.param(16384, 28, 4096, None, id="smallthinker-ep8-l16384"),
+        # the cell's global layer: the plain kernels at 16 x 16 tiles of
+        # head size 128, 136 steps a head read off the grid's two tables
+        pytest.param(16384, 28, None, None, id="smallthinker-ep8-l16384-global"),
         # an edge that is no multiple of the tile, nor of the sub-block
         pytest.param(4096, 4, 1000, None, id="a-ragged-window"),
         pytest.param(2048, 4, 300, (512, 1024), id="tiles-512x1024"),
@@ -319,7 +322,9 @@ def test_flash_kernels_under_a_window_compile_for_the_v5e_at_the_cells_shape(
     a window of 4,096. A third variant of each body (the lower edge's
     trimmed sub-blocks, with a mask of their own) and the index maps
     clamped from both sides are Mosaic's to refuse; interpret mode
-    refuses none of them. The calls go under their own names."""
+    refuses none of them. The calls go under their own names. Since
+    PR 41 the grid is (heads, tiles with work) and a step reads its
+    tiles from two int32 tables in scalar memory: those too."""
     from elasticdl_tpu.ops import flash_attention as fa
 
     x = jax.ShapeDtypeStruct(
@@ -339,6 +344,18 @@ def test_flash_kernels_under_a_window_compile_for_the_v5e_at_the_cells_shape(
     finally:
         jax.config.update("jax_enable_compilation_cache", True)
     assert text.count("tpu_custom_call") >= 3
+    # the compiled text stays a line an instruction, which is how
+    # utils/step_ops.py reads a step (a call's `metadata=` is written
+    # as JSON over several lines and ended that: PERF.md section 6, PR 41)
+    from elasticdl_tpu.utils import step_ops
+
+    ops = step_ops.op_classes(text)
+    assert sum(name.startswith("edl_flash") for name in ops) == 3, sorted(ops)
+    if window is None:
+        for name in fa.WINDOWED:
+            assert name + "/pallas_call" in text
+        assert "edl_flash_win" not in text
+        return
     for name in fa.WINDOWED.values():
         assert name in text
     for name in fa.WINDOWED:

@@ -568,8 +568,9 @@ def _kernel_calls(fn, *args):
 
 
 def test_a_call_without_a_selection_is_the_call_it_was():
-    """The three kernels under their old names with their old operands;
-    under a selection three others, one operand more each."""
+    """The three kernels under their old names with their old operands
+    (since PR 41 behind the two tables of the grid's tiles); under a
+    selection three others, one operand more each."""
     q, k, v, g, selection = _attention_inputs(64, 16)
 
     def plain(q, k, v):
@@ -581,17 +582,18 @@ def test_a_call_without_a_selection_is_the_call_it_was():
         )[1](g)
 
     assert _kernel_calls(plain, q, k, v) == [
-        (fa.FWD_KERNEL, 3), (fa.BWD_DQ_KERNEL, 6), (fa.BWD_DKV_KERNEL, 6),
+        (fa.FWD_KERNEL, 2 + 3), (fa.BWD_DQ_KERNEL, 2 + 6),
+        (fa.BWD_DKV_KERNEL, 2 + 6),
     ]  # fmt: skip
     assert _kernel_calls(selected, q, k, v) == [
-        ("edl_flash_sel_fwd", 4), ("edl_flash_sel_bwd_dq", 7),
-        ("edl_flash_sel_bwd_dkv", 7),
+        ("edl_flash_sel_fwd", 2 + 4), ("edl_flash_sel_bwd_dq", 2 + 7),
+        ("edl_flash_sel_bwd_dkv", 2 + 7),
     ]  # fmt: skip
     # what the plan of a plain call holds is what hbm_traffic walks
     for kernel, operands in ((fa.FWD_KERNEL, 3), (fa.BWD_DKV_KERNEL, 6)):
-        _, inputs, _ = fa._plan(kernel, 8, 64, 64, 16, 16, 16, True)
+        _, _, inputs, _ = fa._plan(kernel, 8, 64, 64, 16, 16, 16, True)
         assert len(inputs) == operands
-        _, inputs, _ = fa._plan(kernel, 8, 64, 64, 16, 16, 16, True, heads=4)
+        _, _, inputs, _ = fa._plan(kernel, 8, 64, 64, 16, 16, 16, True, heads=4)
         assert len(inputs) == operands + 1
         assert inputs[-1][1].block_shape == (1, 16, 16)
     for facts in (
@@ -606,19 +608,31 @@ def test_a_call_without_a_selection_is_the_call_it_was():
 
 
 def test_a_selections_tile_follows_both_axes_and_is_clamped_like_its_operands():
-    """dkv's transposed tile goes with its k tile and, under the mask's
-    clamp, with the q tile its q block goes with; the forward's goes
-    with its q tile and the clamped k tile; one selection serves the
-    ``heads`` grid rows of a sequence."""
-    _, inputs, _ = fa._plan(fa.FWD_KERNEL, 8, 64, 64, 16, 16, 16, True, heads=4)
+    """dkv's transposed tile goes with its k tile and the q tile its q
+    block goes with; the forward's goes with its q tile and its k tile;
+    one selection serves the ``heads`` grid rows of a sequence. Since
+    PR 41 nothing is clamped: the grid has a step for each tile under
+    the diagonal and no other, and a step's tiles come from the two
+    tables the call prefetches."""
+    _, tables, inputs, _ = fa._plan(fa.FWD_KERNEL, 8, 64, 64, 16, 16, 16, True, heads=4)
+    q_tiles, k_tiles = tables
+    assert list(zip(q_tiles, k_tiles)) == [(a, b) for a in range(4) for b in range(a + 1)]
     index = dict(inputs)["sel"].index_map
-    assert index(5, 2, 1) == (1, 2, 1) and index(5, 1, 3) == (1, 1, 1)
-    _, inputs, _ = fa._plan(fa.BWD_DKV_KERNEL, 8, 64, 64, 16, 16, 16, True, heads=4)
+    step = list(zip(q_tiles, k_tiles)).index
+    assert index(5, step((2, 1)), *tables) == (1, 2, 1)
+    assert index(5, step((1, 1)), *tables) == (1, 1, 1)
+    _, tables, inputs, _ = fa._plan(fa.BWD_DKV_KERNEL, 8, 64, 64, 16, 16, 16, True, heads=4)
+    q_tiles, k_tiles = tables
+    assert list(zip(k_tiles, q_tiles)) == [(a, b) for a in range(4) for b in range(a, 4)]
     index = dict(inputs)["sel_t"].index_map
-    assert index(3, 2, 3) == (0, 2, 3) and index(3, 2, 0) == (0, 2, 2)
-    _, inputs, _ = fa._plan(fa.BWD_DQ_KERNEL, 8, 64, 64, 16, 16, 16, True, heads=4)
+    step = list(zip(k_tiles, q_tiles)).index
+    assert index(3, step((2, 3)), *tables) == (0, 2, 3)
+    assert index(3, step((2, 2)), *tables) == (0, 2, 2)
+    _, tables, inputs, _ = fa._plan(fa.BWD_DQ_KERNEL, 8, 64, 64, 16, 16, 16, True, heads=4)
     index = dict(inputs)["sel_t"].index_map
-    assert index(4, 2, 1) == (1, 1, 2) and index(4, 1, 3) == (1, 1, 1)
+    step = list(zip(*tables)).index
+    assert index(4, step((2, 1)), *tables) == (1, 1, 2)
+    assert index(4, step((1, 1)), *tables) == (1, 1, 1)
 
 
 def test_the_model_takes_the_kernels_from_the_policys_length(zoo):

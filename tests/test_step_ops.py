@@ -531,3 +531,77 @@ def test_step_split_says_what_it_did_not_find(profile_dir, missing, capsys):
     assert tracetool.main(["--step-split", str(profile_dir)]) == 2
     said = capsys.readouterr().out
     assert {"trace": "no *.xplane.pb", "map": "no edl_step_ops.json", "module": "no execution of module"}[missing] in said
+
+
+# ---------------------------------------------------------------------------
+# a step with the flash kernels says how many steps their grids take (PR 41)
+# ---------------------------------------------------------------------------
+
+
+class Windowed(nn.Module):
+    """A global attention layer and one under a window of 20, in tiles
+    of 16: four tiles each way at 64 positions."""
+
+    @nn.compact
+    def __call__(self, x, training=False):
+        from elasticdl_tpu.ops.flash_attention import flash_attention
+
+        for window in (None, 20):
+            q, k, v = (
+                nn.Dense(32)(x).reshape(*x.shape[:2], 2, 16) for _ in range(3)
+            )
+            x = x + nn.Dense(16)(
+                flash_attention(q, k, v, True, 16, 16, window=window).reshape(
+                    *x.shape[:2], 32
+                )
+            )
+        return nn.Dense(1, name="head")(x.mean(axis=1))
+
+
+def test_step_built_carries_the_flash_grids_steps(monkeypatch):
+    """`describe_step` sums the steps of every flash call in the traced
+    step, and those with no tile to compute; the worker's `step_built`
+    carries both. A step without the kernels says nothing of them
+    (`TODAYS_FACTS` above)."""
+    from elasticdl_tpu.utils import profiling
+    from elasticdl_tpu.worker.elastic_allreduce_worker import (
+        ElasticAllReduceWorker,
+    )
+
+    monkeypatch.delenv("EDL_PROFILE_DIR", raising=False)
+    monkeypatch.setattr(dist_mod, "ensure_world", lambda s, **k: None)
+    monkeypatch.setattr(
+        elastic, "build_world_mesh",
+        lambda mesh_axes_fn=None: Mesh(np.asarray(jax.devices()[:1]), ("data",)),
+    )  # fmt: skip
+    trainer = elastic.ElasticDPTrainer(Windowed(), _loss, optax.sgd(1e-2))
+    trainer.default_minibatch_size = 2
+    batch = np.ones((2, 64, 16), np.float32), np.ones((2,), np.float32)
+    try:
+        trainer.establish(
+            WorldSpec(coordinator="", num_processes=1, process_id=0, epoch=0),
+            example_batch=batch,
+        )
+        facts = trainer.describe_step()
+        # 2 sequences x 2 heads, three kernels a layer; ten tiles under
+        # the diagonal, nine of them under the window
+        assert facts["flash_grid_steps"] == 4 * 3 * (10 + 9)
+        assert facts["flash_grid_steps_empty"] == 0
+        assert set(facts) == TODAYS_FACTS | {
+            "flash_grid_steps", "flash_grid_steps_empty"
+        }  # fmt: skip
+        worker = ElasticAllReduceWorker.__new__(ElasticAllReduceWorker)
+        worker._step_reported, worker._worker_id = False, 0
+        worker.trainer, worker._model = trainer, object()
+        emitted = {}
+        monkeypatch.setattr(
+            profiling.events, "emit",
+            lambda kind, **fields: emitted.update(kind=kind, **fields),
+        )  # fmt: skip
+        worker._report_step_built()
+        assert emitted["kind"] == "step_built"
+        assert emitted["flash_grid_steps"] == 228
+        assert emitted["flash_grid_steps_empty"] == 0
+        assert emitted["attention"] == "pallas-interpret"
+    finally:
+        trainer.close()
