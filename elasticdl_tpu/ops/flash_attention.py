@@ -169,6 +169,20 @@ WINDOWED = {
     BWD_DKV_KERNEL: "edl_flash_win_bwd_dkv",
 }
 
+# and with a value head size that differs from the query's and the key's
+# (``v.shape[-1] != q.shape[-1]``: a latent attention trained in its
+# expanded form reads 192 and writes 128), causal or not
+UNEQUAL = {
+    FWD_KERNEL: "edl_flash_mla_fwd",
+    BWD_DQ_KERNEL: "edl_flash_mla_bwd_dq",
+    BWD_DKV_KERNEL: "edl_flash_mla_bwd_dkv",
+}
+# every name a flash call goes under, a set a kind of call
+_NAMES = tuple(
+    frozenset(names)
+    for names in (SELECTED, SELECTED.values(), WINDOWED.values(), UNEQUAL.values())
+)
+
 # what ``step_built`` says of a step's flash calls (:func:`grid_steps_in`)
 STEP_BUILT_FIELDS = ("flash_grid_steps", "flash_grid_steps_empty")
 # the grid steps with no tile to compute of each call built so far
@@ -425,7 +439,7 @@ def _fwd_kernel(
     window=None,
 ):
     qi, kj, first, last = _here(q_tile_ref, k_tile_ref)
-    d = q_ref.shape[2]
+    d = v_ref.shape[2]  # the value's head size: the result's
 
     @pl.when(first)
     def _init():
@@ -820,8 +834,9 @@ def _walk(kernel, lq, lk, block_q, block_k, causal, window=None):
 
 
 def _plan(
-    kernel, bh, lq, lk, d, block_q, block_k, causal, heads=None, window=None
-):
+    kernel, bh, lq, lk, d, block_q, block_k, causal, heads=None, window=None,
+    d_v=None,
+):  # fmt: skip
     """``(grid, tables, inputs, outputs)`` of one kernel's
     ``pallas_call``, the last two as lists of ``(name, BlockSpec)``:
     what the call is built from, and what :func:`hbm_traffic` walks.
@@ -835,7 +850,8 @@ def _plan(
     q, dO, o, dq and the statistics move in tiles of ``block_q`` rows,
     k, v, dk and dv in tiles of ``block_k``; a statistic is
     ``(bh, 1, L)`` f32 and its tile ``(1, 1, block_q)``, L along the
-    lanes.
+    lanes. ``d`` is the head size of q and k (and of dq and dk);
+    ``d_v``, where given, that of v, o, dO and dv, else ``d`` too.
 
     ``heads``, where given, says the call has a selection, one for each
     run of ``heads`` rows of the grid's first axis (a sequence's
@@ -855,25 +871,31 @@ def _plan(
     )
     by_rows = pl.BlockSpec((1, block_q, d), rows)
     by_cols = pl.BlockSpec((1, block_k, d), cols)
+    if d_v is None or d_v == d:
+        value_rows, value_cols = by_rows, by_cols
+    else:
+        value_rows = pl.BlockSpec((1, block_q, d_v), rows)
+        value_cols = pl.BlockSpec((1, block_k, d_v), cols)
     statistic = pl.BlockSpec((1, 1, block_q), stat)
-    qkv = [("q", by_rows), ("k", by_cols), ("v", by_cols)]
+    qkv = [("q", by_rows), ("k", by_cols), ("v", value_cols)]
     if kernel == FWD_KERNEL:
         if heads:
             qkv.append(("sel", pl.BlockSpec((1, block_q, block_k), sel)))
-        return grid, tables, qkv, [("o", by_rows), ("lse", statistic)]
+        return grid, tables, qkv, [("o", value_rows), ("lse", statistic)]
     inputs = qkv + [
-        ("dO", by_rows), ("lse", statistic), ("delta", statistic)
+        ("dO", value_rows), ("lse", statistic), ("delta", statistic)
     ]
     if heads:
         inputs.append(("sel_t", pl.BlockSpec((1, block_k, block_q), sel_t)))
     if kernel == BWD_DQ_KERNEL:
         return grid, tables, inputs, [("dq", by_rows)]
-    return grid, tables, inputs, [("dk", by_cols), ("dv", by_cols)]
+    return grid, tables, inputs, [("dk", by_cols), ("dv", value_cols)]
 
 
 def hbm_traffic(
-    bh, lq, lk, d, block_q, block_k, causal=True, itemsize=2, window=None
-):
+    bh, lq, lk, d, block_q, block_k, causal=True, itemsize=2, window=None,
+    d_v=None,
+):  # fmt: skip
     """Bytes each kernel moves between HBM and VMEM in one call, split
     into ``tensors`` (q, k, v, o, dO and the gradients, ``itemsize``
     bytes an element) and ``statistics`` (lse and delta, f32), with the
@@ -891,13 +913,16 @@ def hbm_traffic(
     ``window`` the clamp is from both sides: at 16,384 in 1,024-tiles
     under 4,096, k and v tiles a head in the forward and dq 69 of the
     135 the causal call moves (in 256 grid steps), and so q, dO and
-    the statistics in dkv."""
+    the statistics in dkv. ``d_v``, where given, is the head size of v,
+    o, dO and dv beside ``d`` of q, k, dq and dk: at 192 and 128 a
+    block of q is one and a half times a block of o."""
     block_q, block_k = _block_sizes(lq, lk, block_q, block_k)
     traffic = {}
     for kernel in (FWD_KERNEL, BWD_DQ_KERNEL, BWD_DKV_KERNEL):
         grid, tables, inputs, outputs = _plan(
-            kernel, bh, lq, lk, d, block_q, block_k, causal, window=window
-        )
+            kernel, bh, lq, lk, d, block_q, block_k, causal, window=window,
+            d_v=d_v,
+        )  # fmt: skip
         moved = {"tensors": 0, "statistics": 0, "blocks": {}}
         at = {}
         for step in itertools.product(*map(range, grid)):
@@ -947,6 +972,7 @@ def _call(
     interpret,
     heads=None,
     window=None,
+    d_v=None,
     **static
 ):
     """The ``pallas_call`` of ``kernel``, as a function of its array
@@ -955,12 +981,21 @@ def _call(
     prefetch), ``static`` are the body's keywords. With ``heads`` (a
     selection is the last input, :func:`_plan`) the call goes under its
     :data:`SELECTED` name, with ``window`` (one more keyword of the
-    body) under its :data:`WINDOWED` name. How many of the grid's steps
+    body) under its :data:`WINDOWED` name, with a value head size ``d_v``
+    that is not the query's under its :data:`UNEQUAL` name. How many of
+    the grid's steps
     have no tile to compute is noted for :func:`grid_steps_in`."""
     grid, tables, inputs, outputs = _plan(
-        kernel, *shapes, heads=heads, window=window
+        kernel, *shapes, heads=heads, window=window, d_v=d_v
     )
-    bh, lq, lk, _, block_q, block_k, causal = shapes
+    bh, lq, lk, d, block_q, block_k, causal = shapes
+    if d_v not in (None, d):
+        if heads or window is not None:
+            raise ValueError(
+                "head sizes %d and %d under a selection or a window: not "
+                "built" % (d, d_v)
+            )
+        kernel = UNEQUAL[kernel]
     if heads:
         body = _selecting(body, len(tables) + len(inputs) - 1)
         kernel = SELECTED[kernel]
@@ -1000,20 +1035,20 @@ def _flash_fwd(
     ``t - window < s <= t``."""
     q, k, v = jnp.asarray(q), jnp.asarray(k), jnp.asarray(v)
     b, lq, h, d = q.shape
-    lk = k.shape[1]
+    lk, d_v = k.shape[1], v.shape[3]
     block_q, block_k = _block_sizes(lq, lk, block_q, block_k)
     w = w or sub_block(d)
     scale = d ** -0.5
     qf, kf, vf = _fold_heads(q), _fold_heads(k), _fold_heads(v)
 
     # room for v and at least one column of ones, in whole lanes
-    d_ones = -(-(d + 1) // _LANES) * _LANES
+    d_ones = -(-(d_v + 1) // _LANES) * _LANES
     out, lse = _call(
         FWD_KERNEL,
         _fwd_kernel,
         (b * h, lq, lk, d, block_q, block_k, causal),
         [
-            jax.ShapeDtypeStruct(qf.shape, q.dtype),
+            jax.ShapeDtypeStruct((b * h, lq, d_v), q.dtype),
             jax.ShapeDtypeStruct((b * h, 1, lq), jnp.float32),
         ],
         [
@@ -1024,6 +1059,7 @@ def _flash_fwd(
         interpret,
         heads=None if selection is None else h,
         window=window,
+        d_v=d_v,
         causal=causal,
         scale=scale,
         w=w,
@@ -1078,7 +1114,9 @@ def _flash_bwd(
         delta.reshape(b * h, 1, lq),
     )
     shapes = b * h, lq, lk, d, block_q, block_k, causal
-    static = dict(causal=causal, scale=scale, w=w, window=window)
+    static = dict(
+        causal=causal, scale=scale, w=w, window=window, d_v=v.shape[3]
+    )
     if selection_t is not None:
         operands += (selection_t,)
         static["heads"] = h
@@ -1102,7 +1140,7 @@ def _flash_bwd(
         ],
         [
             pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, v.shape[3]), jnp.float32),
         ],
         interpret,
         **static,
@@ -1339,9 +1377,7 @@ def attention_in_step(step_facts):
     a process put on the CPU by request) or ``"xla"`` (the reference
     attention :func:`pick_causal_attention` hands short or untileable
     lengths)."""
-    for flash in (
-        set(SELECTED), set(SELECTED.values()), set(WINDOWED.values())
-    ):
+    for flash in _NAMES:
         if flash <= set(step_facts["mosaic_kernels"]):
             return "pallas"
         if flash <= set(step_facts["pallas_kernels"]):
@@ -1357,7 +1393,7 @@ def grid_steps_in(jaxpr):
     forward's once more); ``{}`` for a program without the kernels.
     Static: a count of the walk (:func:`_walk`), no device number."""
     steps = empty = 0
-    flash = {*SELECTED, *SELECTED.values(), *WINDOWED.values()}
+    flash = frozenset().union(*_NAMES)
     todo = [getattr(jaxpr, "jaxpr", jaxpr)]
     while todo:
         for eqn in todo.pop().eqns:

@@ -21,8 +21,9 @@ routing even.
 
 **The held-share, dropless layer** (:func:`sigmoid_topk_route`,
 :func:`softmax_topk_route`, :func:`held_experts_apply`,
-:func:`held_experts_apply_masked`, :func:`expert_bias_update`; the zoo's
-``hybrid_moe_lm``). A device is told which experts it holds
+:func:`held_experts_apply_masked`, :func:`shared_expert_apply`,
+:func:`expert_bias_update`; the zoo's ``hybrid_moe_lm``). A device is
+told which experts it holds
 (``first_expert_held``, ``experts_held``: MANY a device), routes over
 all ``num_experts`` of the layer, and computes the part of the result
 its own experts give. Scores are sigmoids, selection adds a bias that
@@ -243,17 +244,36 @@ def reference_moe(expert_fn, per_expert_params, x, gate_logits, num_selected=1):
 MOE_STATE_COLLECTION = "moe_state"
 
 
-def sigmoid_topk_route(router_logits, expert_bias, k, scaling=1.0):
+def sigmoid_topk_route(
+    router_logits, expert_bias, k, scaling=1.0, n_group=1, topk_group=1
+):
     """(T, E) router logits -> (selected (T, k) int32, gates (T, k) f32).
 
     ``s = sigmoid(logits)`` in float32; ``selected = top_k(s + bias)``
     (the bias steers the selection and nothing else: it takes no
     gradient and is not in the gates); ``gate_e = s_e / (sum of s over
-    the selected + 1e-6) * scaling``."""
+    the selected + 1e-6) * scaling``.
+
+    With ``n_group > 1`` the selection is group-limited (DeepSeek-V3,
+    arXiv:2412.19437 section 2.1.2): the experts are ``n_group`` runs
+    of ``E / n_group`` consecutive ones, a group's score is the sum of
+    its two largest ``s + bias``, the ``topk_group`` best groups stay
+    (ties to the lower group), and the ``k`` largest ``s + bias`` among
+    their experts are selected. One group of which one stays is the
+    selection above, and is built as that."""
     scores = jax.nn.sigmoid(router_logits.astype(jnp.float32))
-    _, selected = jax.lax.top_k(
-        scores + jax.lax.stop_gradient(expert_bias.astype(jnp.float32)), k
-    )
+    biased = scores + jax.lax.stop_gradient(expert_bias.astype(jnp.float32))
+    if n_group > 1:
+        by_group = biased.reshape(biased.shape[:-1] + (n_group, -1))
+        group_score = jnp.sum(jax.lax.top_k(by_group, 2)[0], axis=-1)
+        _, kept = jax.lax.top_k(group_score, topk_group)
+        stays = jnp.any(
+            kept[..., None] == jnp.arange(n_group, dtype=kept.dtype), axis=-2
+        )
+        biased = jnp.where(stays[..., None], by_group, -jnp.inf).reshape(
+            biased.shape
+        )
+    _, selected = jax.lax.top_k(biased, k)
     picked = jnp.take_along_axis(scores, selected, axis=-1)
     gates = picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-6)
     return selected.astype(jnp.int32), gates * scaling
@@ -635,6 +655,17 @@ def _masked_share(x, gate, w_in, w_out, act):
         axis=1,
     ).astype(x.dtype)
     return (gated @ w_out.reshape(held * width, d)).astype(x.dtype)
+
+
+def shared_expert_apply(x, w_in, w_out, act=EXPERT_ACTS[0]):
+    """The shared expert beside the routed ones: ``W_2 (act(x W_1) * (x
+    W_3))`` of EVERY token, no router and no gate. ``w_in`` (d, 2f):
+    ``W_1 | W_3`` side by side; ``w_out`` (f, d). It is not a share:
+    every chip of an expert-parallel deployment computes it alike for
+    its own tokens, so where the shares of a layer are added up it is
+    counted once. Named scope ``edl/moe/shared``."""
+    with jax.named_scope("edl/moe/shared"):
+        return (_glu(x @ w_in, act) @ w_out).astype(x.dtype)
 
 
 def held_experts_apply_masked(

@@ -1,14 +1,15 @@
-"""Hybrid sparse decoder LM: gated short convolutions and Mamba-2
-state-space layers beside grouped-head attention, full, within a window
-or over a learned selection of keys, a dense SwiGLU MLP in the leading
-layers and a mixture of experts in the rest, of which this device holds
-a share.
+"""Hybrid sparse decoder LM: gated short convolutions, Mamba-2
+state-space layers and Kimi-Delta-Attention layers beside grouped-head
+attention, full, within a window or over a learned selection of keys,
+and latent attention (MLA), a dense SwiGLU MLP in the leading layers and
+a mixture of experts in the rest, of which this device holds a share.
 
 The layer stack is built from one pattern string, a letter a layer:
 ``c`` a gated short convolution, ``m`` a Mamba-2 state-space layer,
-``a`` full causal attention, ``w`` causal attention within the
-``attention_window`` nearest keys, ``s`` attention over the
-``select_topk`` keys an indexer picks for each query
+``k`` a Kimi-Delta-Attention layer, ``a`` full causal attention, ``w``
+causal attention within the ``attention_window`` nearest keys, ``s``
+attention over the ``select_topk`` keys an indexer picks for each
+query, ``l`` full causal attention through a low-rank latent
 (``layer_pattern=caccc``: no comma, it travels in ``--model_params``).
 The first
 ``num_dense_layers`` layers (none is fine, and so is all of them: a
@@ -28,7 +29,9 @@ results of its products against weight matrices, but for an operator's
 input projection, and recomputes the rest (``jax.checkpoint`` a layer
 that keeps what is named ``KEPT``; selective recomputation, Korthikanti
 et al., arXiv:2205.05198, section 5). Kept: ``out_proj`` of a ``c`` or
-``m`` layer, ``q``, ``k``, ``v`` and ``o`` of an attention layer,
+``m`` layer, ``out`` of a ``k`` layer (its five input projections are
+recomputed, as ``in_proj`` is), ``q``, ``k``, ``v`` and ``o`` of an
+attention layer, the four products of an ``l`` layer,
 ``W_1`` and ``W_3`` of the dense FF (``W_2``'s result is needed by
 nothing): ``2 (embed_dim + 2 mlp_dim)`` bytes a token a ``c`` or ``m``
 layer in bf16 (37 KB at granite-4.0-h-micro's widths). Recomputed:
@@ -56,6 +59,33 @@ forward, which does not lower the peak.
   (one norm over all ``H P``). Initial ``A_log = log(1..H)``, ``D =
   1``, ``dt_bias`` the inverse softplus of a log-uniform draw in
   [0.001, 0.1] (Mamba-2's published initialisation).
+- ``k`` (ops/kda.py has the recurrence), with ``H = kda_heads``, ``D =
+  kda_head_dim`` for keys and values alike:
+  ``q = l2norm(silu(conv(h W_q)))``, ``k = l2norm(silu(conv(h W_k)))``,
+  ``v = silu(conv(h W_v))`` (each ``H D`` wide, a causal depthwise
+  convolution of ``kda_conv_kernel`` taps each, the norm over a head,
+  ``x / sqrt(sum x^2 + 1e-6)``);
+  ``g = kda_gate_lower_bound * sigmoid(exp(A_log) * (h W_f +
+  dt_bias))``, the log-decay a CHANNEL, float32, between the bound and
+  0 (``A_log`` a head, ``dt_bias`` a channel);
+  ``beta = sigmoid(h W_beta)`` a head;
+  ``S_t = (I - beta_t k_t k_t^T) Diag(exp(g_t)) S_{t-1} + beta_t k_t
+  v_t^T``, ``o_t = D ** -0.5 * S_t^T q_t``;
+  ``out = (sigmoid(h W_g) * RMSNorm(o)) W_o`` (one gate a head, one
+  learned norm weight of ``D`` shared by the heads). Initial ``A_log``
+  the log of a uniform draw in [1, 16], ``dt_bias`` as Mamba-2's.
+- ``l``: ``q = h W_q`` (``num_heads`` x ``[mla_nope_dim |
+  mla_rope_dim]``); ``[c | k_rope] = h W_kva`` (``mla_kv_rank |
+  mla_rope_dim``), ``c <- RMSNorm(c)``; ``[k_nope | v] = c W_kvb``
+  (``num_heads`` x ``[mla_nope_dim | mla_v_dim]``); ``q_rope`` and
+  ``k_rope`` rotated with base ``rope_theta``, ``k_rope`` ONE head that
+  every query head reads; causal softmax attention of ``q . [k_nope |
+  k_rope] / sqrt(mla_nope_dim + mla_rope_dim)`` over ``v``; ``out =
+  concat(heads) W_o``. The expanded form a latent attention is TRAINED
+  in (DeepSeek-V2, arXiv:2405.04434 section 2.1): the kernels of
+  ops/flash_attention.py take q and k of one head size and v of
+  another. The absorbed form and a cache of latents belong to a
+  serving path, which this model has none of.
 - ``a``: ``num_heads`` query heads over ``num_kv_heads`` key/value
   heads, a learned RMSNorm over each head of q and of k (``qk_norm``,
   on by default), rotary positions of base ``rope_theta`` (``rope``, on
@@ -112,6 +142,12 @@ forward, which does not lower the peak.
   follows from the shapes alone, at ``experts_held`` experts a token
   instead of the ``num_experts_per_tok * experts_held / num_experts``
   routed here in expectation. Both give the same result.
+  ``num_expert_groups`` and ``expert_groups_per_tok`` (1 and 1 by
+  default: no limit) limit the selection of ``sigmoid_bias`` routing
+  to the best groups of consecutive experts
+  (``expert.sigmoid_topk_route``). ``shared_expert_dim``, where set,
+  adds ``Shared(u)``, one more expert of that width that EVERY token
+  passes with no gate: not a share, every chip computes it alike.
 
 ``expert_bias`` (of ``sigmoid_bias`` routing only), the ``assignments``
 counters and a selecting attention's ``sel_pairs_kept`` /
@@ -137,7 +173,7 @@ import optax
 from jax.ad_checkpoint import checkpoint_name
 
 from elasticdl_tpu.common.model_utils import load_module
-from elasticdl_tpu.ops import sparse_select, ssd
+from elasticdl_tpu.ops import kda, sparse_select, ssd
 from elasticdl_tpu.ops.flash_attention import (
     pick_causal_attention,
     pick_selected_attention,
@@ -155,12 +191,15 @@ dataset_fn = _lm.dataset_fn
 eval_metrics_fn = _lm.eval_metrics_fn
 
 CONV, ATTENTION, SELECTING, MAMBA, WINDOW = "c", "a", "s", "m", "w"
+KDA, MLA = "k", "l"
 LETTERS = {
     CONV: "a short convolution",
     MAMBA: "a Mamba-2 state-space layer",
     ATTENTION: "full attention",
     SELECTING: "attention over selected keys",
     WINDOW: "attention within a window",
+    KDA: "a Kimi-Delta-Attention layer",
+    MLA: "latent attention",
 }
 ROUTINGS = ("sigmoid_bias", "softmax")
 EXPERT_APPLIES = ("grouped", "masked")
@@ -170,7 +209,9 @@ ROUTER_INPUTS = ("ffn_norm", "operator_norm")
 # the name of what ``remat_layers`` keeps for the backward pass, and how
 # many results a layer's operator gives that name (a dense FF: two)
 KEPT = "weight_product"
-KEPT_OF_OPERATOR = {CONV: 1, MAMBA: 1, ATTENTION: 4, SELECTING: 4, WINDOW: 4}
+KEPT_OF_OPERATOR = {
+    CONV: 1, MAMBA: 1, ATTENTION: 4, SELECTING: 4, WINDOW: 4, KDA: 1, MLA: 4,
+}  # fmt: skip
 KEPT_OF_DENSE_FF = 2
 # every parameter of an indexer lies under a module of this name
 INDEXER = "indexer"
@@ -339,6 +380,198 @@ class Mamba2(nn.Module):
         )
 
 
+@jax.custom_vjp
+def _read_together(x, *others):
+    """``(x, *others)`` as they are. In the backward pass ``x``'s
+    gradient READS the others': zero times their sums is added to it (a
+    float's product with zero is not folded away), so it cannot be
+    handed on before they exist. ``others`` are parameters. Left to
+    itself the compiler schedules a parameter's gradient last, behind
+    whatever reads it (an optimizer's update, a norm), and keeps what it
+    is computed from until then: for a projection's kernel the product's
+    two operands, for a parameter a channel or a head (a convolution's
+    taps, ``A_log``, ``dt_bias``) the arrays its reduction runs over;
+    the cotangents of a KDA layer's five projections and its gates, 0.8
+    GB a layer at 2 x 4,096 positions, of all six layers at once
+    (compiled for a described v5e, PERF.md section 6, PR 42). What it
+    buys is the comparison of this model with its reference at the
+    published widths, which holds the reference's pass beside this one:
+    14.27 GiB at the peak with it, 17.85 of the chip's 15.75 without
+    (``tests/test_ling_linear_lm.py``, the slow case). The training
+    step alone does not need it (13.96 GB with it, 13.81 without). An
+    ``optimization_barrier`` over the pair does not help: it binds the
+    optimizer's passes, not the scheduler."""
+    return (x,) + others
+
+
+def _read_together_bwd(_, gradients):
+    d_x, *d_others = gradients
+    read = 0.0 * sum(jnp.sum(g, dtype=jnp.float32) for g in d_others)
+    return (d_x + read.astype(d_x.dtype),) + tuple(d_others)
+
+
+_read_together.defvjp(
+    lambda x, *others: ((x,) + others, None), _read_together_bwd
+)
+
+
+class PromptDense(nn.Module):
+    """``nn.Dense`` without a bias (the same ``kernel``, the same
+    initial value) whose input's gradient waits for the kernel's
+    (:func:`_read_together`)."""
+
+    features: int
+    dtype: Any
+
+    @nn.compact
+    def __call__(self, x):
+        kernel = self.param(
+            "kernel",
+            nn.initializers.lecun_normal(),
+            (x.shape[-1], self.features),
+        )
+        return jnp.dot(
+            *_read_together(x.astype(self.dtype), kernel.astype(self.dtype))
+        )
+
+
+class KimiDeltaAttention(nn.Module):
+    """The Kimi-Delta-Attention operator: a gated delta rule whose
+    decay is a vector over the key's channels."""
+
+    heads: int
+    head_dim: int
+    conv_kernel: int
+    gate_lower_bound: float
+    chunk: int
+    norm_eps: float
+    dtype: Any
+
+    @nn.compact
+    def __call__(self, h):
+        inner = self.heads * self.head_dim
+        by_head = h.shape[:2] + (self.heads, self.head_dim)
+
+        def dense(features, name):
+            return PromptDense(features, self.dtype, name=name)(h)
+
+        def mixed(name):
+            taps = self.param(
+                name + "_conv",
+                nn.initializers.normal(self.conv_kernel**-0.5),
+                (self.conv_kernel, inner),
+            ).astype(self.dtype)
+            projected, taps = _read_together(dense(inner, name), taps)
+            return nn.silu(_causal_depthwise_conv(projected, taps)).reshape(
+                by_head
+            )
+
+        def unit(x):
+            x32 = x.astype(jnp.float32)
+            return (
+                x32
+                * jax.lax.rsqrt(jnp.sum(x32 * x32, axis=-1, keepdims=True) + 1e-6)
+            ).astype(x.dtype)
+
+        q, k, v = unit(mixed("query")), unit(mixed("key")), mixed("value")
+        a_log = self.param(
+            "A_log",
+            lambda key, shape: jnp.log(
+                jax.random.uniform(key, shape, minval=1.0, maxval=16.0)
+            ),
+            (self.heads,),
+        )
+        dt_bias = self.param("dt_bias", _dt_bias_init, (inner,))
+        raw, a_log, dt_bias = _read_together(
+            dense(inner, "decay"), a_log, dt_bias
+        )
+        # the log-decay a channel, between the bound and 0, float32
+        log_decay = self.gate_lower_bound * jax.nn.sigmoid(
+            jnp.exp(a_log)[:, None]
+            * (raw.astype(jnp.float32) + dt_bias).reshape(by_head)
+        )
+        beta = jax.nn.sigmoid(dense(self.heads, "beta").astype(jnp.float32))
+        if self.is_initializing():
+            # the variables are made, and no shape depends on the
+            # state: an eager init (the trainer's) is spared the
+            # recurrence's loops
+            o = v
+        else:
+            o = kda.kda(q, k, v, log_decay, beta, self.chunk)
+        normed = nn.RMSNorm(
+            epsilon=self.norm_eps, dtype=self.dtype, name="norm"
+        )(o)
+        gate = nn.sigmoid(dense(self.heads, "gate"))[..., None]
+        return _kept(
+            PromptDense(h.shape[-1], self.dtype, name="out")(
+                (gate * normed).reshape(h.shape[:2] + (inner,))
+            )
+        )
+
+
+class LatentAttention(nn.Module):
+    """Latent attention (MLA) in its expanded form: keys and values
+    come up out of one normed low-rank latent, the rotated part of the
+    key is one head that every query head reads, and q and k are wider
+    than v."""
+
+    num_heads: int
+    kv_rank: int
+    nope_dim: int
+    rope_dim: int
+    v_dim: int
+    rope_theta: float
+    norm_eps: float
+    dtype: Any
+    use_flash: bool
+
+    @nn.compact
+    def __call__(self, h, positions):
+        def heads(width, name, x):
+            return _kept(
+                nn.DenseGeneral(
+                    features=(self.num_heads, width),
+                    use_bias=False,
+                    dtype=self.dtype,
+                    name=name,
+                )(x)
+            )
+
+        q = heads(self.nope_dim + self.rope_dim, "query", h)
+        down = _kept(
+            nn.Dense(
+                self.kv_rank + self.rope_dim,
+                use_bias=False,
+                dtype=self.dtype,
+                name="kv_down",
+            )(h)
+        )
+        latent = nn.RMSNorm(
+            epsilon=self.norm_eps, dtype=self.dtype, name="kv_norm"
+        )(down[..., : self.kv_rank])
+        up = heads(self.nope_dim + self.v_dim, "kv_up", latent)
+        k_nope, v = up[..., : self.nope_dim], up[..., self.nope_dim :]
+        rotated = lambda x: _lm._rotary(x, positions, self.rope_theta)
+        k_rope = rotated(down[..., None, self.kv_rank :])
+        q = jnp.concatenate(
+            [q[..., : self.nope_dim], rotated(q[..., self.nope_dim :])], axis=-1
+        )
+        k = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(k_rope, k_nope.shape[:3] + k_rope.shape[3:])],
+            axis=-1,
+        )
+        attn = pick_causal_attention(h.shape[1], self.use_flash)(q, k, v)
+        return _kept(
+            nn.DenseGeneral(
+                features=h.shape[-1],
+                axis=(-2, -1),
+                use_bias=False,
+                dtype=self.dtype,
+                name="out",
+            )(attn)
+        )
+
+
 class Indexer(nn.Module):
     """Which keys each query reads: (B, L, L) int8, float32 inside."""
 
@@ -499,6 +732,9 @@ class HeldExperts(nn.Module):
     routing: str = ROUTINGS[0]
     apply: str = EXPERT_APPLIES[0]
     act: str = expert.EXPERT_ACTS[0]
+    shared_expert_dim: int = 0
+    num_expert_groups: int = 1
+    expert_groups_per_tok: int = 1
 
     @nn.compact
     def __call__(self, h, router_input=None):
@@ -550,6 +786,8 @@ class HeldExperts(nn.Module):
                 bias,
                 self.num_experts_per_tok,
                 self.routed_scaling_factor,
+                self.num_expert_groups,
+                self.expert_groups_per_tok,
             )
         else:
             selected, gates = expert.softmax_topk_route(
@@ -582,6 +820,21 @@ class HeldExperts(nn.Module):
             self.first_expert_held,
             act=self.act,
         )
+        if self.shared_expert_dim:
+            out = out + expert.shared_expert_apply(
+                tokens,
+                self.param(
+                    "shared_w13",
+                    nn.initializers.lecun_normal(),
+                    (d, 2 * self.shared_expert_dim),
+                ).astype(self.dtype),
+                self.param(
+                    "shared_w2",
+                    nn.initializers.lecun_normal(),
+                    (self.shared_expert_dim, d),
+                ).astype(self.dtype),
+                act=self.act,
+            )
         return out.reshape(h.shape)
 
 
@@ -627,6 +880,18 @@ class HybridMoELM(nn.Module):
     window_rope: bool = True
     router_input: str = ROUTER_INPUTS[0]
     expert_act: str = expert.EXPERT_ACTS[0]
+    kda_heads: int = 0
+    kda_head_dim: int = 0
+    kda_conv_kernel: int = 0
+    kda_gate_lower_bound: float = 0.0
+    kda_chunk: int = 0
+    mla_kv_rank: int = 0
+    mla_nope_dim: int = 0
+    mla_rope_dim: int = 0
+    mla_v_dim: int = 0
+    shared_expert_dim: int = 0
+    num_expert_groups: int = 1
+    expert_groups_per_tok: int = 1
     embedding_multiplier: float = 1.0
     residual_multiplier: float = 1.0
     logits_scaling: float = 1.0
@@ -672,6 +937,19 @@ class HybridMoELM(nn.Module):
                 select_topk=self.select_topk,
                 indexer_heads=self.indexer_heads,
             )
+        if KDA in self.layer_pattern:
+            facts.update(
+                kda_layers=self.layer_pattern.count(KDA),
+                kda_heads=self.kda_heads,
+                kda_head_dim=self.kda_head_dim,
+                kda_chunk=self.kda_chunk,
+            )
+        if MLA in self.layer_pattern:
+            facts.update(
+                mla_layers=self.layer_pattern.count(MLA),
+                mla_qk_dim=self.mla_nope_dim + self.mla_rope_dim,
+                mla_v_dim=self.mla_v_dim,
+            )
         if WINDOW in self.layer_pattern:
             facts.update(
                 window_layers=self.layer_pattern.count(WINDOW),
@@ -691,6 +969,13 @@ class HybridMoELM(nn.Module):
                 facts["router_input"] = self.router_input
             if self.expert_act != expert.EXPERT_ACTS[0]:
                 facts["expert_act"] = self.expert_act
+            if self.shared_expert_dim:
+                facts["shared_expert_dim"] = self.shared_expert_dim
+            if self.num_expert_groups > 1:
+                facts.update(
+                    expert_groups=self.num_expert_groups,
+                    expert_groups_per_tok=self.expert_groups_per_tok,
+                )
         return facts
 
     @nn.compact
@@ -738,6 +1023,31 @@ class HybridMoELM(nn.Module):
                     dtype=self.dtype,
                     name=name + "mamba",
                 )(h)
+            elif kind == KDA:
+                out = KimiDeltaAttention(
+                    heads=self.kda_heads,
+                    head_dim=self.kda_head_dim,
+                    conv_kernel=self.kda_conv_kernel,
+                    gate_lower_bound=self.kda_gate_lower_bound,
+                    chunk=self.kda_chunk,
+                    norm_eps=self.norm_eps,
+                    dtype=self.dtype,
+                    name=name + "kda",
+                )(h)
+            elif kind == MLA:
+                with jax.named_scope("edl/mla"):
+                    out = LatentAttention(
+                        num_heads=self.num_heads,
+                        kv_rank=self.mla_kv_rank,
+                        nope_dim=self.mla_nope_dim,
+                        rope_dim=self.mla_rope_dim,
+                        v_dim=self.mla_v_dim,
+                        rope_theta=self.rope_theta,
+                        norm_eps=self.norm_eps,
+                        dtype=self.dtype,
+                        use_flash=self.use_flash,
+                        name=name + "mla",
+                    )(h, positions)
             else:
                 windowed = kind == WINDOW
                 attention = GroupedAttention(
@@ -780,6 +1090,9 @@ class HybridMoELM(nn.Module):
                         routing=self.routing,
                         apply=self.expert_apply,
                         act=self.expert_act,
+                        shared_expert_dim=self.shared_expert_dim,
+                        num_expert_groups=self.num_expert_groups,
+                        expert_groups_per_tok=self.expert_groups_per_tok,
                         name=name + "moe",
                     )(
                         h,
@@ -848,6 +1161,72 @@ def custom_model(dtype="float32", **sizes):
             "layer_pattern %r holds no state-space layer (%r), so %s say "
             "nothing" % (pattern, MAMBA, sorted(k for k, v in ssm_sizes.items() if v))
         )
+    for letter, sizes_of in (
+        (
+            KDA,
+            ("kda_heads", "kda_head_dim", "kda_conv_kernel", "kda_chunk",
+             "kda_gate_lower_bound"),
+        ),
+        (MLA, ("mla_kv_rank", "mla_nope_dim", "mla_rope_dim", "mla_v_dim")),
+    ):  # fmt: skip
+        given = {name: getattr(model, name) for name in sizes_of}
+        whole = {
+            k: v for k, v in given.items() if k != "kda_gate_lower_bound"
+        }
+        if letter not in pattern:
+            if any(given.values()):
+                raise ValueError(
+                    "layer_pattern %r holds no %s (%r), so %s say nothing"
+                    % (pattern, LETTERS[letter], letter,
+                       sorted(k for k, v in given.items() if v))
+                )  # fmt: skip
+        elif not all(isinstance(v, int) and v > 0 for v in whole.values()):
+            raise ValueError(
+                "layer_pattern %r holds %s: %s all have to be positive whole "
+                "numbers" % (pattern, LETTERS[letter], whole)
+            )
+    if KDA in pattern and not (
+        model.kda_gate_lower_bound < 0
+        and math.isfinite(model.kda_gate_lower_bound)
+        # the exponents of a sub-block of the recurrence (ops/kda.py)
+        and -model.kda_gate_lower_bound * kda.SUB_BLOCK / 2 <= 80
+    ):
+        raise ValueError(
+            "kda_gate_lower_bound=%r: a log-decay a position, under 0 and "
+            "no lower than %g" % (model.kda_gate_lower_bound, -160 / kda.SUB_BLOCK)
+        )
+    if MLA in pattern and model.mla_rope_dim % 2:
+        raise ValueError("mla_rope_dim=%r is odd" % model.mla_rope_dim)
+    groups, kept_groups = model.num_expert_groups, model.expert_groups_per_tok
+    if not (
+        isinstance(groups, int)
+        and isinstance(kept_groups, int)
+        and 1 <= kept_groups <= groups
+        and model.num_experts % groups == 0
+    ):
+        raise ValueError(
+            "num_expert_groups=%r and expert_groups_per_tok=%r: num_experts=%r "
+            "in groups of equal size, of which between one and all stay"
+            % (groups, kept_groups, model.num_experts)
+        )
+    if groups > 1 and not (
+        model.routing == ROUTINGS[0]
+        and model.num_experts // groups >= 2
+        and model.num_experts_per_tok
+        <= kept_groups * (model.num_experts // groups)
+    ):
+        raise ValueError(
+            "a group limit (%d groups, %d kept) is %s routing's, over groups "
+            "of two experts or more that hold num_experts_per_tok=%r between "
+            "them" % (groups, kept_groups, ROUTINGS[0], model.num_experts_per_tok)
+        )
+    if not (
+        isinstance(model.shared_expert_dim, int) and model.shared_expert_dim >= 0
+    ):
+        raise ValueError(
+            "shared_expert_dim=%r: a width, or 0 for none"
+            % model.shared_expert_dim
+        )
     for name in ("rope", "qk_norm", "remat_layers", "window_rope"):
         if not isinstance(getattr(model, name), bool):
             raise ValueError(
@@ -907,6 +1286,13 @@ def custom_model(dtype="float32", **sizes):
         raise ValueError(
             "layer_pattern %r holds no expert layer, so router_input and "
             "expert_act say nothing" % pattern
+        )
+    if model.num_dense_layers == len(pattern) and (
+        model.shared_expert_dim or groups > 1
+    ):
+        raise ValueError(
+            "layer_pattern %r holds no expert layer, so shared_expert_dim "
+            "and the expert groups say nothing" % pattern
         )
     if model.expert_apply not in EXPERT_APPLIES:
         raise ValueError(

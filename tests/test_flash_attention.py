@@ -1048,6 +1048,137 @@ def test_a_call_says_how_many_steps_its_grid_takes():
     assert fa.grid_steps_in(jax.make_jaxpr(lambda x: x * 2)(1.0)) == {}
 
 
+# ---------------------------------------------------------------------------
+# a value head size that is not the query's and the key's (PR 42): a
+# latent attention trained in its expanded form reads 192 and writes 128
+# ---------------------------------------------------------------------------
+
+UNEQUAL_SIZES = [(192, 128), (128, 64)]
+
+
+def _unequal_qkv(d_qk, d_v, dtype="float32", b=2, l=64, h=2, seed=5):
+    rng = np.random.default_rng(seed)
+    draw = lambda d: rng.standard_normal((b, l, h, d)).astype(dtype)
+    return draw(d_qk), draw(d_qk), draw(d_v), draw(d_v)
+
+
+@pytest.mark.parametrize("what", ["forward", "dq", "dk", "dv"])
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+@pytest.mark.parametrize("d_qk, d_v", UNEQUAL_SIZES)
+def test_unequal_head_sizes_match_the_reference(d_qk, d_v, causal, what):
+    """``softmax(q k^T / sqrt(d_qk)) v`` with q and k of one head size
+    and v of another, four tiles each way: the result and each gradient
+    against ``reference_attention``."""
+    q, k, v, weights = _unequal_qkv(d_qk, d_v)
+    if what == "forward":
+        got = flash_attention(q, k, v, causal, 16, 16)
+        assert got.shape == v.shape
+        np.testing.assert_allclose(
+            got,
+            reference_attention(q, k, v, causal=causal),
+            rtol=2e-4,
+            atol=2e-5,
+        )
+        return
+    argnum = ["dq", "dk", "dv"].index(what)
+    grad = lambda fn: jax.grad(
+        lambda *qkv: (fn(*qkv) * weights).sum(), argnums=argnum
+    )(q, k, v)
+    got = grad(lambda q, k, v: flash_attention(q, k, v, causal, 16, 16))
+    want = grad(lambda q, k, v: reference_attention(q, k, v, causal=causal))
+    assert got.shape == (q, k, v)[argnum].shape
+    np.testing.assert_allclose(got, want, rtol=3e-4, atol=3e-5)
+
+
+def test_unequal_head_sizes_go_under_names_of_their_own_and_nothing_else_does():
+    from elasticdl_tpu.ops import flash_attention as fa
+
+    def names(q, k, v):
+        loss = lambda q, k, v: (fa.flash_attention(q, k, v, True, 16, 16) ** 2).sum()
+        jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
+        return sorted(
+            eqn.params["name"]
+            for eqn in _every_eqn(jaxpr.jaxpr)
+            if eqn.primitive.name == "pallas_call"
+        )
+
+    q, k, v, _ = _unequal_qkv(24, 16)
+    assert names(q, k, v) == sorted(fa.UNEQUAL.values())
+    assert names(q, k, v[..., :8]) == sorted(fa.UNEQUAL.values())
+    assert names(q, k, k) == sorted(fa.UNEQUAL)
+    assert fa.attention_in_step(
+        {"mosaic_kernels": sorted(fa.UNEQUAL.values()), "pallas_kernels": []}
+    ) == "pallas"
+    with pytest.raises(ValueError, match="not built"):
+        fa.flash_attention(q, k, v, True, 16, 16, window=20)
+
+
+def _every_eqn(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for inner in jax.core.jaxprs_in_params(eqn.params):
+            yield from _every_eqn(getattr(inner, "jaxpr", inner))
+
+
+@pytest.mark.parametrize("d_qk, d_v", UNEQUAL_SIZES + [(64, 64)])
+def test_grid_steps_and_traffic_at_both_head_sizes(d_qk, d_v):
+    """The walk does not read a head size, so the steps are those of
+    equal sizes; the traffic counts q, k, dq and dk at one width and v,
+    o, dO and dv at the other. bf16, causal, 64 x 4,096 in 1,024-tiles
+    (`ling3flash-ep64-l4096` at (192, 128)): 4 q tiles and 10 steps a
+    head in the forward and dq, the mirror image in dkv."""
+    from elasticdl_tpu.ops import flash_attention as fa
+
+    q, k, v, _ = _unequal_qkv(d_qk, d_v)
+    loss = lambda q, k, v: (fa.flash_attention(q, k, v, True, 16, 16) ** 2).sum()
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
+    assert fa.grid_steps_in(jaxpr) == {
+        "flash_grid_steps": 4 * 3 * 10,
+        "flash_grid_steps_empty": 0,
+    }
+
+    heads, tile = 64, 1024 * 2
+    got = fa.hbm_traffic(heads, 4096, 4096, d_qk, 1024, 1024, d_v=d_v)
+    # blocks a head: an outer tile's operand once a tile (4), an inner
+    # tile's once a step that changes it (9 of the 10 steps: an outer
+    # tile's first inner tile is its neighbour's first, or its last)
+    want = {
+        FWD: 4 * d_qk + 9 * d_qk + 9 * d_v + 4 * d_v,  # q k v o
+        DQ: (4 + 9 + 4) * d_qk + (9 + 4) * d_v,  # q k dq | v dO
+        DKV: (9 + 4 + 4) * d_qk + (4 + 9 + 4) * d_v,  # q k dk | v dO dv
+    }
+    for kernel, columns in want.items():
+        assert got[kernel]["tensors"] == heads * tile * columns, kernel
+    if d_qk == d_v:
+        assert got == fa.hbm_traffic(heads, 4096, 4096, d_qk, 1024, 1024)
+    assert fa.causal_work_ratio(
+        4096, 4096, 1024, 1024, fa.sub_block(d_qk)
+    ) == pytest.approx(1.0615, abs=1e-3)
+
+
+def test_unequal_head_sizes_in_bfloat16():
+    q, k, v, weights = _unequal_qkv(192, 128, "float32", l=32)
+    low = [x.astype(jax.numpy.bfloat16) for x in (q, k, v)]
+    got = flash_attention(*low, True, 16, 16)
+    assert got.dtype == jax.numpy.bfloat16
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32),
+        reference_attention(*[np.asarray(x, np.float32) for x in low], causal=True),
+        rtol=0.03,
+        atol=0.03,
+    )
+
+
+def test_the_policy_hands_short_lengths_unequal_head_sizes_in_xla():
+    from elasticdl_tpu.ops.flash_attention import pick_causal_attention
+
+    q, k, v, _ = _unequal_qkv(24, 16)
+    got = pick_causal_attention(q.shape[1])(q, k, v)
+    np.testing.assert_allclose(
+        got, flash_attention(q, k, v, True, 16, 16), rtol=2e-4, atol=2e-5
+    )
+
+
 if __name__ == "__main__":
     import importlib.util
     import json

@@ -304,6 +304,59 @@ def test_flash_kernels_under_a_selection_compile_for_the_v5e_at_the_cells_shape(
 
 
 @pytest.mark.parametrize(
+    "batch, length, heads, d_qk, d_v, causal",
+    [
+        pytest.param(2, 4096, 32, 192, 128, True, id="ling3flash-ep64-l4096"),
+        pytest.param(2, 2048, 4, 128, 64, True, id="a-value-half-the-key"),
+        pytest.param(2, 2048, 4, 192, 128, False, id="not-causal"),
+    ],
+)
+def test_flash_kernels_of_unequal_head_sizes_compile_for_the_v5e_at_the_cells_shape(
+    one_chip, batch, length, heads, d_qk, d_v, causal
+):
+    """`ling3flash-ep64-l4096`'s latent attention in its expanded form:
+    2 sequences of 4,096, 32 heads whose q and k are 192 wide (128
+    without positions, 64 rotated) and whose v is 128. A block whose
+    last dimension is one and a half lane tiles, a contraction over 192
+    and accumulators of two widths are Mosaic's to refuse; interpret
+    mode refuses none of them. The calls go under their own names."""
+    from elasticdl_tpu.ops import flash_attention as fa
+
+    def shape(d):
+        return jax.ShapeDtypeStruct(
+            (batch, length, heads, d), jnp.bfloat16, sharding=one_chip
+        )
+
+    tiles = fa.auto_blocks(length, length)
+
+    def fwd_and_bwd(q, k, v, g):
+        out, lse = fa._flash_fwd(q, k, v, causal, *tiles, False)
+        return out, fa._flash_bwd(q, k, v, out, lse, g, causal, *tiles, False)
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        compiled = (
+            jax.jit(fwd_and_bwd)
+            .lower(shape(d_qk), shape(d_qk), shape(d_v), shape(d_v))
+            .compile()
+        )
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+    text = compiled.as_text()
+    from elasticdl_tpu.utils import step_ops
+
+    ops = step_ops.op_classes(text)
+    assert sum(name.startswith("edl_flash") for name in ops) == 3, sorted(ops)
+    for name in fa.UNEQUAL.values():
+        assert name in text
+    for name in fa.UNEQUAL:
+        assert name + '"' not in text
+    out, (dq, dk, dv) = compiled.out_info
+    assert out.shape == dv.shape == (batch, length, heads, d_v)
+    assert dq.shape == dk.shape == (batch, length, heads, d_qk)
+
+
+@pytest.mark.parametrize(
     "length, heads, window, tiles",
     [
         pytest.param(16384, 28, 4096, None, id="smallthinker-ep8-l16384"),

@@ -1,0 +1,162 @@
+"""ops/kda.py: the chunked gated delta rule against the recurrence
+written out position by position, in float32 on the CPU."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from elasticdl_tpu.ops import kda
+
+LOWER_BOUND = -5.0
+
+
+def recurrence(q, k, v, g, beta):
+    """``o`` position by position: the (d_k, d_v) state of every
+    (sequence, head) decayed a channel, read, then written."""
+
+    def step(state, at):
+        q_t, k_t, v_t, g_t, beta_t = at  # (B, H, ...)
+        state = jnp.exp(g_t)[..., None] * state
+        read = jnp.einsum("bhk,bhkv->bhv", k_t, state)
+        state = state + jnp.einsum(
+            "bhk,bhv->bhkv", k_t, beta_t[..., None] * (v_t - read)
+        )
+        return state, jnp.einsum("bhk,bhkv->bhv", q_t, state)
+
+    b, _, h, d_k = k.shape
+    with jax.default_matmul_precision("highest"):
+        _, out = jax.lax.scan(
+            step,
+            jnp.zeros((b, h, d_k, v.shape[-1]), jnp.float32),
+            tuple(jnp.moveaxis(t, 1, 0) for t in (q, k, v, g, beta)),
+        )
+    return jnp.moveaxis(out, 0, 1) * d_k**-0.5
+
+
+def operands(length, gates="random", floor=(0, 0), seed=0, heads=2, d_k=8, d_v=4):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 5)
+    shape = (2, length, heads)
+    normed = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+    q = normed(jax.random.normal(keys[0], shape + (d_k,)))
+    k = normed(jax.random.normal(keys[1], shape + (d_k,)))
+    v = jax.random.normal(keys[2], shape + (d_v,))
+    g = LOWER_BOUND * jax.nn.sigmoid(
+        2.0 * jax.random.normal(keys[3], shape + (d_k,))
+    )
+    beta = jax.nn.sigmoid(jax.random.normal(keys[4], shape))
+    if gates == "floor":
+        # every gate at the bound for a whole chunk: exp(-5 * 64) is 0
+        # in float32
+        g = g.at[:, slice(*floor)].set(LOWER_BOUND)
+    elif gates == "no_write":
+        beta = jnp.zeros_like(beta)
+    elif gates == "plain_delta_rule":
+        g, beta = jnp.zeros_like(g), jnp.ones_like(beta)
+    return q, k, v, g, beta
+
+
+def weighted(fn, weights):
+    return lambda *args: jnp.sum(fn(*args) * weights)
+
+
+@pytest.mark.parametrize("gates", ["random", "floor"])
+@pytest.mark.parametrize("chunks", [1, 4, 5])
+@pytest.mark.parametrize("chunk", [16, 64])
+def test_chunks_agree_with_the_recurrence(chunk, chunks, gates):
+    """Forward and every gradient (q, k, v, g, beta), with random gates
+    and with every gate at -5 for a whole chunk: nothing overflows, and
+    the tolerance is the same."""
+    length = chunk * chunks
+    # the second chunk where there is one, else the only one
+    floor = (chunk, 2 * chunk) if chunks > 1 else (0, chunk)
+    args = operands(length, gates, floor)
+    weights = jax.random.normal(jax.random.PRNGKey(9), args[2].shape)
+    want, want_grads = jax.value_and_grad(
+        weighted(recurrence, weights), argnums=(0, 1, 2, 3, 4)
+    )(*args)
+    got, got_grads = jax.value_and_grad(
+        weighted(lambda *a: kda.kda(*a, chunk=chunk), weights),
+        argnums=(0, 1, 2, 3, 4),
+    )(*args)
+    assert np.isfinite(got)
+    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(
+        kda.kda(*args, chunk=chunk), recurrence(*args), rtol=1e-4, atol=1e-5
+    )
+    for name, g_got, g_want in zip("qkvgb", got_grads, want_grads):
+        assert np.all(np.isfinite(g_got)), name
+        np.testing.assert_allclose(
+            g_got, g_want, rtol=2e-4, atol=2e-5, err_msg="d" + name
+        )
+
+
+def test_a_length_that_is_no_multiple_of_the_chunk_is_padded_behind():
+    args = operands(40)
+    np.testing.assert_allclose(
+        kda.kda(*args, chunk=16), recurrence(*args), rtol=1e-4, atol=1e-5
+    )
+
+
+def test_nothing_written_leaves_nothing_to_read():
+    """``beta = 0``: the state only decays, and it starts at zero."""
+    args = operands(64, "no_write")
+    np.testing.assert_array_equal(kda.kda(*args, chunk=16), 0.0)
+
+
+def test_no_write_into_a_state_that_holds_something_is_a_pure_decay():
+    """``beta = 0`` from position 16 on: ``o_t = d_k ** -0.5 * q_t .
+    (prod of alpha after 15) S_15``, whatever k and v say there."""
+    q, k, v, g, beta = operands(48)
+    beta = beta.at[:, 16:].set(0.0)
+    got = kda.kda(q, k, v, g, beta, chunk=16)
+    other = kda.kda(
+        q, k.at[:, 16:].multiply(-3.0), v.at[:, 16:].add(7.0), g, beta, chunk=16
+    )
+    np.testing.assert_allclose(got[:, 16:], other[:, 16:], rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(
+        got, recurrence(q, k, v, g, beta), rtol=1e-4, atol=1e-5
+    )
+
+
+def test_alpha_one_and_beta_one_is_the_plain_delta_rule():
+    """``S_t = (I - k_t k_t^T) S_{t-1} + k_t v_t^T``: with unit keys,
+    reading the state with the key just written gives back its value."""
+    q, k, v, g, beta = operands(32, "plain_delta_rule")
+    got = kda.kda(k, k, v, g, beta, chunk=16)
+    np.testing.assert_allclose(
+        got, v * k.shape[-1] ** -0.5, rtol=1e-4, atol=1e-5
+    )
+
+
+def test_a_chunk_between_sixteen_and_its_multiples_is_refused():
+    with pytest.raises(ValueError, match="multiple"):
+        kda.kda(*operands(48), chunk=24)
+
+
+def test_keys_that_repeat_are_solved_as_accurately_as_any():
+    """Sixty-four equal unit keys written at full strength with no
+    decay: ``I + Diag(beta) A`` is one plus the strictly lower triangle
+    of ones, whose inverse by powers cancels numbers of seventeen
+    digits; a back-substitution does not notice."""
+    q, k, v, g, beta = operands(64, "plain_delta_rule")
+    k = jnp.broadcast_to(k[:, :1], k.shape)
+    np.testing.assert_allclose(
+        kda.kda(q, k, v, g, beta, chunk=64),
+        recurrence(q, k, v, g, beta),
+        rtol=1e-4,
+        atol=1e-5,
+    )
+
+
+def test_operands_of_bfloat16_keep_a_float32_state():
+    """bf16 operands: the result is bf16 and within bf16's reach of the
+    float32 recurrence."""
+    args = operands(128)
+    low = [a.astype(jnp.bfloat16) for a in args[:3]] + list(args[3:])
+    got = kda.kda(*low, chunk=64)
+    assert got.dtype == jnp.bfloat16
+    want = recurrence(*[a.astype(jnp.float32) for a in low])
+    np.testing.assert_allclose(
+        got.astype(jnp.float32), want, rtol=0.05, atol=0.02
+    )
