@@ -55,9 +55,19 @@ like binomial coefficients and cancel), and an inverse by blocks in
 
 Gates, cumulative sums and the solve are float32; the products take
 operands of ``v``'s dtype and accumulate in float32; the carried state
-is float32. The loop over the groups keeps the state it handed each
-group and recomputes the rest in the backward pass (``jax.checkpoint``
-of the body).
+is float32. The loop over the groups has a backward pass of its own
+(``jax.custom_vjp``): the forward emits beside each group's ``o`` the
+state the group was handed and keeps those and the grouped operands;
+the backward is a loop over the groups last to first that carries the
+state's cotangent, rebuilds a group from its kept state and
+differentiates it (``jax.vjp`` of the group's ``jax.checkpoint``ed
+step), so nothing inside a group outlives it: what ``lax.scan`` of a
+``jax.checkpoint``ed step gave, bit for bit. The kept states and
+``kda``'s result go through ``checkpoint_name`` as :data:`KEPT_STATES`
+and :data:`KEPT_OUTPUT`: a layer rematerialised under ``save_only_these_names`` that lists them
+(``remat_layers`` of the model) keeps both from its forward pass, and
+its recomputation does not run the recurrence; one that does not list
+them recomputes it, as any other op.
 
 Named scope: ``edl/kda`` (docs/observability.md). A device trace
 carries no scope; there the recurrence is the ``while`` loops that
@@ -70,8 +80,15 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 SCOPE = "edl/kda"
+# what a rematerialised layer keeps of the recurrence (the names of a
+# ``save_only_these_names`` policy): ``kda``'s result, and the states
+# the loop over the groups handed its groups
+KEPT_OUTPUT = "kda_output"
+KEPT_STATES = "kda_group_states"
+KEPT_NAMES = (KEPT_OUTPUT, KEPT_STATES)
 # rows of a sub-block of the chunk's two decay matrices; with
 # ``SUB_BLOCK / 2 * max|g|`` under 80 nothing overflows float32
 SUB_BLOCK = 16
@@ -187,6 +204,59 @@ def _group_step(state, inputs, sub):
     return state, jnp.moveaxis(out, 0, 2)  # (B, H, n, C, d_v)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _over_the_groups(inputs, sub):
+    """The loop over the groups, from a state of zeros: ``o`` of every
+    group, (groups, B, H, n, C, d_v) float32. ``inputs``: what
+    :func:`_group_step` takes, a leading axis over the groups."""
+    return _over_the_groups_fwd(inputs, sub)[0]
+
+
+def _over_the_groups_fwd(inputs, sub):
+    """The same loop, emitting beside a group's ``o`` the state the
+    group was HANDED (the first is zeros): the backward pass starts
+    each group from it."""
+    k, v = inputs[1:3]
+
+    def step(state, group):
+        after, out = _group_step(state, group, sub)
+        return after, (out, state)
+
+    _, (out, handed) = jax.lax.scan(
+        step,
+        jnp.zeros(v.shape[1:3] + (k.shape[-1], v.shape[-1]), jnp.float32),
+        inputs,
+    )
+    return out, (inputs, checkpoint_name(handed, KEPT_STATES))
+
+
+def _over_the_groups_bwd(sub, kept, d_out):
+    """The groups last to first, the carry the state's cotangent: a
+    group is rebuilt from the state it was handed and differentiated,
+    nothing of it kept for the next."""
+    inputs, handed = kept
+
+    # checkpointed, so that the group's forward runs inside ``back``
+    # under jax's name for a recomputation (``rematted_computation``:
+    # utils/step_ops.py counts it as one) and ``jax.vjp``'s own is dead
+    rebuilt = jax.checkpoint(
+        functools.partial(_group_step, sub=sub), prevent_cse=False
+    )
+
+    def step(d_state, group):
+        group_inputs, state, d_group_out = group
+        _, back = jax.vjp(rebuilt, state, group_inputs)
+        return back((d_state, d_group_out))
+
+    _, d_inputs = jax.lax.scan(
+        step, jnp.zeros_like(handed[0]), (inputs, handed, d_out), reverse=True
+    )
+    return (d_inputs,)
+
+
+_over_the_groups.defvjp(_over_the_groups_fwd, _over_the_groups_bwd)
+
+
 def kda(q, k, v, g, beta, chunk=64):
     """``o`` of the recurrence above, (B, L, H, d_v) in ``v``'s dtype.
 
@@ -224,21 +294,17 @@ def kda(q, k, v, g, beta, chunk=64):
 
     with jax.named_scope(SCOPE):
         f32 = jnp.float32
-        inputs = (
-            grouped(q),
-            grouped(k),
-            grouped(v),
-            grouped(g.astype(f32)),
-            grouped(beta.astype(f32))[..., None],
-        )
-        _, out = jax.lax.scan(
-            jax.checkpoint(
-                functools.partial(_group_step, sub=sub), prevent_cse=False
+        out = _over_the_groups(
+            (
+                grouped(q),
+                grouped(k),
+                grouped(v),
+                grouped(g.astype(f32)),
+                grouped(beta.astype(f32))[..., None],
             ),
-            jnp.zeros((batch, heads, d_k, d_v), f32),
-            inputs,
+            sub,
         )  # (groups, B, H, n, C, d_v)
         out = jnp.moveaxis(out, (0, 2), (1, 4)).reshape(
             batch, length + padded, heads, d_v
         )
-    return out[:, :length].astype(dtype)
+        return checkpoint_name(out[:, :length].astype(dtype), KEPT_OUTPUT)

@@ -41,6 +41,14 @@ costs more than the product does: PERF.md section 6, PR 36), the
 residual scalings, ``silu`` and the gates, the depthwise convolution,
 ``softplus``, the scan (ops/ssd.py), the gated norm, the attention
 itself (the kernel's forward runs twice) and an expert FF whole. A
+``k`` layer's recurrence is NOT recomputed: the policy also keeps what
+ops/kda.py names (``kda.KEPT_NAMES``: the recurrence's output in the
+model's dtype and the float32 states at its group boundaries, 101 MB a
+layer at 2 x 4,096 positions of 32 heads of 128), so the recomputed
+layer runs the five projections, convolutions and gates in front of it
+and the gated norm behind it, and the recurrence's backward pass
+rebuilds each group from its kept state: two passes forward a step
+where there were three (PERF.md section 6, PR 43). A
 model parameter, since the worker's ``--remat`` wraps the whole
 forward, which does not lower the peak.
 
@@ -923,6 +931,10 @@ class HybridMoELM(nn.Module):
                 sum(KEPT_OF_OPERATOR[kind] for kind in self.layer_pattern)
                 + self.num_dense_layers * KEPT_OF_DENSE_FF
             )
+            if KDA in self.layer_pattern:
+                # beside ``out``'s product a ``k`` layer keeps what
+                # ops/kda.py names: its recurrence is not run again
+                facts["remat_kept_recurrences"] = self.layer_pattern.count(KDA)
         if MAMBA in self.layer_pattern:
             facts.update(
                 mamba_layers=self.layer_pattern.count(MAMBA),
@@ -1107,7 +1119,9 @@ class HybridMoELM(nn.Module):
             layer = nn.remat(
                 layer,
                 static_argnums=(2,),
-                policy=jax.checkpoint_policies.save_only_these_names(KEPT),
+                policy=jax.checkpoint_policies.save_only_these_names(
+                    KEPT, *kda.KEPT_NAMES
+                ),
             )
         for i in range(len(self.layer_pattern)):
             x = layer(self, x, i)

@@ -1,6 +1,8 @@
 """ops/kda.py: the chunked gated delta rule against the recurrence
 written out position by position, in float32 on the CPU."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -61,12 +63,15 @@ def weighted(fn, weights):
 
 
 @pytest.mark.parametrize("gates", ["random", "floor"])
-@pytest.mark.parametrize("chunks", [1, 4, 5])
+# 1, 4 and 5 chunks are one group; 10 are two groups of five, 16 two of
+# eight, 24 three of eight, 13 thirteen of one
+@pytest.mark.parametrize("chunks", [1, 4, 5, 10, 13, 16, 24])
 @pytest.mark.parametrize("chunk", [16, 64])
 def test_chunks_agree_with_the_recurrence(chunk, chunks, gates):
-    """Forward and every gradient (q, k, v, g, beta), with random gates
-    and with every gate at -5 for a whole chunk: nothing overflows, and
-    the tolerance is the same."""
+    """Forward and every gradient (q, k, v, g, beta; ``kda``'s own
+    backward pass against plain differentiation of the recurrence),
+    with random gates and with every gate at -5 for a whole chunk:
+    nothing overflows, and the tolerance is the same."""
     length = chunk * chunks
     # the second chunk where there is one, else the only one
     floor = (chunk, 2 * chunk) if chunks > 1 else (0, chunk)
@@ -96,6 +101,86 @@ def test_a_length_that_is_no_multiple_of_the_chunk_is_padded_behind():
     np.testing.assert_allclose(
         kda.kda(*args, chunk=16), recurrence(*args), rtol=1e-4, atol=1e-5
     )
+
+
+@pytest.mark.parametrize("length", [40, 136, 200])
+def test_a_padded_lengths_gradients_agree_with_the_recurrence(length):
+    """3, 9 and 13 chunks of 16 with the last one part padding: one
+    group, three groups of three, thirteen of one."""
+    args = operands(length, seed=3)
+    weights = jax.random.normal(jax.random.PRNGKey(9), args[2].shape)
+    want = jax.grad(weighted(recurrence, weights), argnums=(0, 1, 2, 3, 4))(*args)
+    got = jax.grad(
+        weighted(lambda *a: kda.kda(*a, chunk=16), weights),
+        argnums=(0, 1, 2, 3, 4),
+    )(*args)
+    for name, g_got, g_want in zip("qkvgb", got, want):
+        assert g_got.shape == g_want.shape
+        np.testing.assert_allclose(
+            g_got, g_want, rtol=2e-4, atol=2e-5, err_msg="d" + name
+        )
+
+
+def scan_of_checkpoint(q, k, v, g, beta, chunk):
+    """``kda`` as it was before it had a backward pass of its own (PR
+    42): ``lax.scan`` over the groups of ``jax.checkpoint`` of the
+    group's step, differentiated by jax. A length that is a multiple of
+    the chunk."""
+    batch, length, heads, d_k = k.shape
+    chunks = length // chunk
+    group = next(n for n in range(kda.GROUP_CHUNKS, 0, -1) if chunks % n == 0)
+
+    def grouped(t):
+        split = t.reshape(
+            (batch, chunks // group, group, chunk, heads) + t.shape[3:]
+        )
+        return jnp.moveaxis(split, (1, 4), (0, 2))
+
+    f32 = jnp.float32
+    _, out = jax.lax.scan(
+        jax.checkpoint(
+            functools.partial(kda._group_step, sub=min(chunk, kda.SUB_BLOCK)),
+            prevent_cse=False,
+        ),
+        jnp.zeros((batch, heads, d_k, v.shape[-1]), f32),
+        (
+            grouped(q),
+            grouped(k),
+            grouped(v),
+            grouped(g.astype(f32)),
+            grouped(beta.astype(f32))[..., None],
+        ),
+    )
+    out = jnp.moveaxis(out, (0, 2), (1, 4)).reshape(v.shape)
+    return out.astype(v.dtype)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("chunk, chunks", [(16, 4), (16, 16), (64, 24), (16, 13)])
+def test_the_backward_pass_is_what_differentiating_the_loop_gave(chunk, chunks, dtype):
+    """The op's own backward pass (a reverse loop over the groups, each
+    rebuilt from the state it was handed) against jax's differentiation
+    of the loop of checkpointed groups it replaced: the same
+    arithmetic group by group, so the result and the five gradients
+    are equal bit for bit, in the dtypes they had (``g`` and ``beta``
+    float32 under operands of bfloat16)."""
+    args = operands(chunk * chunks, seed=5)
+    args = tuple(a.astype(dtype) for a in args[:3]) + args[3:]
+    weights = jax.random.normal(jax.random.PRNGKey(9), args[2].shape)
+    both = [
+        jax.jit(
+            jax.value_and_grad(
+                lambda *a: jnp.sum(fn(*a, chunk).astype(jnp.float32) * weights),
+                argnums=(0, 1, 2, 3, 4),
+            )
+        )(*args)
+        for fn in (kda.kda, scan_of_checkpoint)
+    ]
+    got, want = map(jax.tree_util.tree_leaves, both)
+    for name, a, b in zip(("loss",) + tuple("qkvgb"), got, want):
+        assert a.dtype == b.dtype, name
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b), err_msg=name)
+    assert [a.dtype for a in got[1:]] == [jnp.dtype(dtype)] * 3 + [jnp.float32] * 2
 
 
 def test_nothing_written_leaves_nothing_to_read():
@@ -160,3 +245,24 @@ def test_operands_of_bfloat16_keep_a_float32_state():
     np.testing.assert_allclose(
         got.astype(jnp.float32), want, rtol=0.05, atol=0.02
     )
+
+
+@pytest.mark.parametrize("chunks", [2, 16])
+def test_gradients_under_operands_of_bfloat16(chunks):
+    """The cotangents of q, k and v come back bf16, those of ``g`` and
+    ``beta`` float32, each within bf16's reach of the float32
+    recurrence's (one group of two chunks; two groups of eight)."""
+    args = operands(64 * chunks, seed=7)
+    low = tuple(a.astype(jnp.bfloat16) for a in args[:3]) + args[3:]
+    weights = jax.random.normal(jax.random.PRNGKey(9), args[2].shape)
+    got = jax.grad(
+        lambda *a: jnp.sum(kda.kda(*a, chunk=64).astype(jnp.float32) * weights),
+        argnums=(0, 1, 2, 3, 4),
+    )(*low)
+    want = jax.grad(weighted(recurrence, weights), argnums=(0, 1, 2, 3, 4))(
+        *[a.astype(jnp.float32) for a in low]
+    )
+    assert [a.dtype for a in got] == [jnp.bfloat16] * 3 + [jnp.float32] * 2
+    for name, g_got, g_want in zip("qkvgb", got, want):
+        error = jnp.linalg.norm((g_got.astype(jnp.float32) - g_want).ravel())
+        assert error < 0.03 * jnp.linalg.norm(g_want.ravel()), name

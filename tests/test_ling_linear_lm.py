@@ -22,6 +22,7 @@ import pytest
 
 from elasticdl_tpu.common import model_utils
 from elasticdl_tpu.ops import flash_attention as fa
+from elasticdl_tpu.ops import kda
 from elasticdl_tpu.parallel import expert
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -88,7 +89,7 @@ GROUPS = {
 }
 
 
-def _loss_and_grads(zoo, sizes, tokens):
+def _loss_and_grads(zoo, sizes, tokens, jit=False):
     model = zoo.custom_model(**sizes)
     params = model.init(jax.random.PRNGKey(0), {"tokens": tokens})["params"]
 
@@ -96,8 +97,9 @@ def _loss_and_grads(zoo, sizes, tokens):
         logits = model.apply({"params": params}, {"tokens": tokens}, training=True)
         return zoo.loss(logits, tokens)
 
+    loss_and_grads = jax.value_and_grad(objective)
     with jax.default_matmul_precision("highest"):
-        return params, jax.value_and_grad(objective)(params)
+        return params, (jax.jit(loss_and_grads) if jit else loss_and_grads)(params)
 
 
 @pytest.fixture(scope="module")
@@ -403,14 +405,109 @@ def test_step_facts_cover_the_recurrence_the_latent_and_the_router(zoo):
     # a KDA layer keeps its output projection, the MLA layer its four
     # products, the dense FF two
     assert facts["remat_kept_products"] == 3 * 1 + 4 + 2
+    # and each KDA layer its recurrence, which is no weight product
+    assert facts["remat_kept_recurrences"] == 3
+    assert "remat_kept_recurrences" not in zoo.custom_model(**TOY).step_facts()
+    no_k = zoo.custom_model(layer_pattern="caccc", remat_layers=True).step_facts()
+    assert "remat_kept_products" in no_k and "remat_kept_recurrences" not in no_k
     plain = zoo.custom_model(layer_pattern="caccc").step_facts()
     assert not {"kda_layers", "mla_layers", "shared_expert_dim", "expert_groups"} & set(plain)
+
+
+# ---------------------------------------------------------------------------
+# ``remat_layers`` keeps a ``k`` layer's recurrence
+# ---------------------------------------------------------------------------
+
+# 16 chunks of 16: two groups of eight, so a kept state that is not zeros
+REMAT_LENGTH = 256
+
+
+@pytest.fixture(scope="module")
+def kept_and_not(reference, zoo):
+    """Loss and gradients of the toy model on 2 x 256 tokens with
+    ``remat_layers`` off and on, by the reference's names."""
+    tokens = jax.random.randint(jax.random.PRNGKey(4), (2, REMAT_LENGTH), 0, 256)
+    sides = []
+    for remat in (False, True):
+        _, (loss, grads) = _loss_and_grads(
+            zoo, {**TOY, "remat_layers": remat}, tokens, jit=True
+        )
+        sides.append((loss, reference.from_program(grads, TOY)))
+    return sides
+
+
+@pytest.mark.parametrize("group", ["loss"] + sorted(GROUPS))
+def test_keeping_the_recurrence_changes_no_number(kept_and_not, group):
+    """A rematerialised ``k`` layer hands its backward pass the kept
+    output and group states in place of recomputed ones: the same
+    arrays, so the loss and every leaf's gradient are what they are
+    with ``remat_layers`` off, up to how the compiler fuses two
+    programs' float32 sums: the limit of the comparison with the
+    reference above (the worst leaf read 1.8e-5, an ``A_log``, whose
+    gradient is the smallest)."""
+    (loss, grads), (kept_loss, kept_grads) = kept_and_not
+    if group == "loss":
+        assert abs(float(kept_loss) - float(loss)) <= 1e-6 * float(loss)
+        return
+    for leaf in GROUPS[group]:
+        norm = float(jnp.linalg.norm(grads[leaf].ravel()))
+        error = float(jnp.linalg.norm((kept_grads[leaf] - grads[leaf]).ravel()))
+        assert norm > 0 and error / norm <= 10 * TOL, (leaf, error / norm)
+
+
+def _state_loops(zoo, remat_layers, kept_names=None):
+    """How many ``while`` loops of the compiled gradient of ONE ``k``
+    layer (2 x 256 tokens: two groups of eight chunks) carry the
+    recurrence's state or its cotangent, by the regular expression the
+    benchmark's reader finds them with in a device trace."""
+    sys.path[:0] = [os.path.join(REPO, "benchmark"), os.path.join(REPO, "benchmark", "layer_metrics")]
+    try:
+        import _kda
+    finally:
+        del sys.path[:2]
+    kda_sizes = {name: size for name, size in TOY.items() if name.startswith("kda_")}
+    model = zoo.custom_model(
+        vocab_size=256, layer_pattern="k", num_dense_layers=1, embed_dim=64,
+        num_heads=4, mlp_dim=96, tie_head=False, remat_layers=remat_layers,
+        **kda_sizes,
+    )  # fmt: skip
+    tokens = jnp.zeros((2, REMAT_LENGTH), jnp.int32)
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), {"tokens": tokens})["params"]
+    )
+
+    def objective(params):
+        logits = model.apply({"params": params}, {"tokens": tokens}, training=True)
+        return zoo.loss(logits, tokens)
+
+    with pytest.MonkeyPatch.context() as patch:
+        if kept_names is not None:
+            patch.setattr(kda, "KEPT_NAMES", kept_names)
+        # value_and_grad: under grad alone the forward's value is unused
+        # and a pass is dropped whatever is kept
+        text = jax.jit(jax.value_and_grad(objective)).lower(params).compile().as_text()
+    loop = _kda.state_loop(model.step_facts(), 2)
+    return sum(bool(loop.match(line.strip())) for line in text.splitlines())
+
+
+def test_a_rematerialised_layer_runs_the_recurrence_forward_twice_not_three_times(zoo):
+    """A pass forward is two such loops (the groups, and in a group its
+    chunks); a pass backward three (the groups last to first, and in a
+    group its chunks forward again, then backward). With what
+    ``ops/kda.py`` names kept, a rematerialised layer's gradient holds
+    the five loops of a layer that is not rematerialised; with nothing
+    of the recurrence kept (the names taken out of the policy: PR 42's
+    program) it holds a third pass forward, seven. Should jax stop
+    honouring a ``checkpoint_name`` inside a ``custom_vjp``'s forward
+    rule, this reads seven."""
+    assert _state_loops(zoo, remat_layers=False) == 2 + 3
+    assert _state_loops(zoo, remat_layers=True) == 2 + 3
+    assert _state_loops(zoo, remat_layers=True, kept_names=()) == 2 + 2 + 3
 
 
 def test_an_eager_init_runs_no_loop_of_the_recurrence(zoo, monkeypatch, tokens):
     """The trainer's init is an eager forward pass: the op hands back
     ``v`` there, as the selecting attention hands back ``q``."""
-    from elasticdl_tpu.ops import kda
 
     def refuse(*args, **kwargs):
         raise AssertionError("the recurrence ran while initialising")
@@ -557,7 +654,7 @@ FACTS = {
     "mla_layers": 1, "mla_qk_dim": 24, "mla_v_dim": 16,
     "shared_expert_dim": 24, "expert_groups": 4, "expert_groups_per_tok": 2,
     "routing": "sigmoid_bias", "tie_head": 0, "expert_apply": "grouped",
-    "remat_layers": 1,
+    "remat_layers": 1, "remat_kept_recurrences": 3,
 }  # fmt: skip
 
 
