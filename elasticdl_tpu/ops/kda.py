@@ -47,27 +47,43 @@ lie within ``exp(+-SUB_BLOCK / 2 * max|g|)`` (``exp(40)`` at 16 rows
 and -5), a column behind the sub-block only decays further, and a
 column past it, which the mask drops, gets the exponent 0. ``U`` and
 ``Y`` come out of one triangular solve a chunk
-(``lax.linalg.triangular_solve``, float32 on float32 right-hand sides):
-back-substitution stays accurate where keys repeat, which an explicit
-inverse by powers of the strictly lower part does not (its terms grow
-like binomial coefficients and cancel), and an inverse by blocks in
-``highest`` precision took three quarters of this op's compile time.
+(``lax.linalg.triangular_solve`` of the identity, then ``M^-1 R`` as a
+float32 product at ``highest``): back-substitution stays accurate where
+keys repeat, which an explicit inverse by powers of the strictly lower
+part does not (its terms grow like binomial coefficients and cancel),
+and an inverse by blocks in ``highest`` precision took three quarters
+of this op's compile time. Inverse times right-hand side is what the
+chip did with the solve of ``R`` itself: XLA's TPU lowering of a
+triangular solve is the custom call ``InvertDiagBlocksLowerTriangular``
+(an explicit inversion of diagonal blocks of up to 128 rows: the whole
+``C x C`` matrix here) and a ``dot`` at ``highest`` with the right-hand
+side, and the inversion was the largest single device operation of a
+step (PERF.md section 6, PR 44). So the inverse is made once, in the
+forward pass, and kept: the backward pass needs no other solve
+(:func:`_solved_by`: ``X = M^-1 R`` to rebuild, ``dR = M^-T dX``,
+``dM = -dR X^T`` under the diagonal), where differentiating the solve
+inverted the same matrix twice more.
 
 Gates, cumulative sums and the solve are float32; the products take
 operands of ``v``'s dtype and accumulate in float32; the carried state
 is float32. The loop over the groups has a backward pass of its own
 (``jax.custom_vjp``): the forward emits beside each group's ``o`` the
-state the group was handed and keeps those and the grouped operands;
-the backward is a loop over the groups last to first that carries the
-state's cotangent, rebuilds a group from its kept state and
-differentiates it (``jax.vjp`` of the group's ``jax.checkpoint``ed
-step), so nothing inside a group outlives it: what ``lax.scan`` of a
-``jax.checkpoint``ed step gave, bit for bit. The kept states and
-``kda``'s result go through ``checkpoint_name`` as :data:`KEPT_STATES`
-and :data:`KEPT_OUTPUT`: a layer rematerialised under ``save_only_these_names`` that lists them
-(``remat_layers`` of the model) keeps both from its forward pass, and
-its recomputation does not run the recurrence; one that does not list
-them recomputes it, as any other op.
+state the group was handed and its chunks' inverses and keeps those and
+the grouped operands; the backward is a loop over the groups last to
+first that carries the state's cotangent, rebuilds a group from its
+kept state and inverses and differentiates it (``jax.vjp`` of the
+group's ``jax.checkpoint``ed step), so nothing else inside a group
+outlives it: what ``lax.scan`` of a ``jax.checkpoint``ed step gives,
+bit for bit. The kept states, the kept inverses and ``kda``'s result go
+through ``checkpoint_name`` as :data:`KEPT_STATES`,
+:data:`KEPT_INVERSES` and :data:`KEPT_OUTPUT`: a layer rematerialised
+under ``save_only_these_names`` that lists them (``remat_layers`` of
+the model) keeps all three from its forward pass (at 2 x 4,096
+positions of 32 heads of 128 in chunks of 64: 34 MB of states, 67 MB
+of output in bf16, 67 MB of inverses, which the TPU's tiles of 128
+lanes lay out as 134), and its recomputation does not run the
+recurrence; one that does not list them recomputes it, as any
+other op.
 
 Named scope: ``edl/kda`` (docs/observability.md). A device trace
 carries no scope; there the recurrence is the ``while`` loops that
@@ -84,11 +100,12 @@ from jax.ad_checkpoint import checkpoint_name
 
 SCOPE = "edl/kda"
 # what a rematerialised layer keeps of the recurrence (the names of a
-# ``save_only_these_names`` policy): ``kda``'s result, and the states
-# the loop over the groups handed its groups
+# ``save_only_these_names`` policy): ``kda``'s result, the states the
+# loop over the groups handed its groups, and every chunk's inverse
 KEPT_OUTPUT = "kda_output"
 KEPT_STATES = "kda_group_states"
-KEPT_NAMES = (KEPT_OUTPUT, KEPT_STATES)
+KEPT_INVERSES = "kda_chunk_inverses"
+KEPT_NAMES = (KEPT_OUTPUT, KEPT_STATES, KEPT_INVERSES)
 # rows of a sub-block of the chunk's two decay matrices; with
 # ``SUB_BLOCK / 2 * max|g|`` under 80 nothing overflows float32
 SUB_BLOCK = 16
@@ -139,6 +156,35 @@ def _decay_matrices(q, k, cum, sub, dtype):
     )
 
 
+@jax.custom_vjp
+def _solved_by(inverse, matrix, rhs):
+    """``X`` of ``matrix X = rhs`` from the INVERSE of the unit lower
+    triangular ``matrix``, (..., C, C) float32 both: a float32 product
+    at ``highest``. ``matrix`` is here for its cotangent; ``inverse``
+    is a function of it and gets none."""
+    return jnp.matmul(inverse, rhs, precision=jax.lax.Precision.HIGHEST)
+
+
+def _solved_by_fwd(inverse, matrix, rhs):
+    solved = _solved_by(inverse, matrix, rhs)
+    return solved, (inverse, solved)
+
+
+def _solved_by_bwd(kept, d_solved):
+    """``d_rhs = M^-T dX``; ``d_matrix = -d_rhs X^T`` strictly under
+    the diagonal (a unit triangular solve reads nothing else)."""
+    inverse, solved = kept
+    highest = jax.lax.Precision.HIGHEST
+    d_rhs = jnp.einsum("...ji,...jv->...iv", inverse, d_solved, precision=highest)
+    d_matrix = -jnp.tril(
+        jnp.einsum("...iv,...jv->...ij", d_rhs, solved, precision=highest), -1
+    )
+    return None, d_matrix, d_rhs
+
+
+_solved_by.defvjp(_solved_by_fwd, _solved_by_bwd)
+
+
 def _chunk_step(state, inputs):
     """One chunk: (B, H, d_k, d_v) float32 state in, the chunk's ``o``
     (B, H, C, d_v) float32 and the state after it out. ``inputs``: U
@@ -167,12 +213,15 @@ def _chunk_step(state, inputs):
     return after, out
 
 
-def _group_step(state, inputs, sub):
+def _group_step(state, inputs, sub, inverse=None):
     """A group of chunks: what does not read the state (the decay
     matrices, ``U`` and ``Y``) for all of them at once,
     then the loop over them. ``inputs``: q, k (B, H, n, C, d_k); v
     (B, H, n, C, d_v); g (B, H, n, C, d_k) float32; beta (B, H, n, C,
-    1) float32."""
+    1) float32. ``inverse``: ``(I + Diag(beta) A)^-1`` of every chunk,
+    (B, H, n, C, C) float32, where an earlier pass over the group kept
+    it; computed here where not. Returns the state after the group and
+    its ``o``, (B, H, n, C, d_v) float32, and beside them the inverse."""
     q, k, v, g, beta = inputs
     dtype, f32 = v.dtype, jnp.float32
     q, k = q.astype(f32) * q.shape[-1] ** -0.5, k.astype(f32)
@@ -181,12 +230,20 @@ def _group_step(state, inputs, sub):
     a_matrix, b_matrix = _decay_matrices(q, k, cum, sub, dtype)
     decay = jnp.exp(cum)
     # U and Y at once: (I + Diag(beta) A) [U | Y] = Diag(beta) [V | exp(G) K]
-    solved = jax.lax.linalg.triangular_solve(
-        jnp.eye(k.shape[-2], dtype=f32) + beta * a_matrix,
+    eye = jnp.eye(k.shape[-2], dtype=f32)
+    matrix = eye + beta * a_matrix
+    if inverse is None:
+        inverse = jax.lax.linalg.triangular_solve(
+            matrix,
+            jnp.broadcast_to(eye, matrix.shape),
+            left_side=True,
+            lower=True,
+            unit_diagonal=True,
+        )
+    solved = _solved_by(
+        inverse,
+        matrix,
         beta * jnp.concatenate([v.astype(f32), decay * k], axis=-1),
-        left_side=True,
-        lower=True,
-        unit_diagonal=True,
     ).astype(dtype)
     by_chunk = (
         solved[..., : v.shape[-1]],
@@ -201,7 +258,7 @@ def _group_step(state, inputs, sub):
         state,
         jax.tree_util.tree_map(lambda t: jnp.moveaxis(t, 2, 0), by_chunk),
     )
-    return state, jnp.moveaxis(out, 0, 2)  # (B, H, n, C, d_v)
+    return (state, jnp.moveaxis(out, 0, 2)), inverse
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
@@ -214,27 +271,33 @@ def _over_the_groups(inputs, sub):
 
 def _over_the_groups_fwd(inputs, sub):
     """The same loop, emitting beside a group's ``o`` the state the
-    group was HANDED (the first is zeros): the backward pass starts
-    each group from it."""
+    group was HANDED (the first is zeros) and its chunks' inverses:
+    the backward pass starts each group from the one and solves with
+    the other."""
     k, v = inputs[1:3]
 
     def step(state, group):
-        after, out = _group_step(state, group, sub)
-        return after, (out, state)
+        (after, out), inverse = _group_step(state, group, sub)
+        return after, (out, state, inverse)
 
-    _, (out, handed) = jax.lax.scan(
+    _, (out, handed, inverses) = jax.lax.scan(
         step,
         jnp.zeros(v.shape[1:3] + (k.shape[-1], v.shape[-1]), jnp.float32),
         inputs,
     )
-    return out, (inputs, checkpoint_name(handed, KEPT_STATES))
+    kept = (
+        inputs,
+        checkpoint_name(handed, KEPT_STATES),
+        checkpoint_name(inverses, KEPT_INVERSES),
+    )
+    return out, kept
 
 
 def _over_the_groups_bwd(sub, kept, d_out):
     """The groups last to first, the carry the state's cotangent: a
-    group is rebuilt from the state it was handed and differentiated,
-    nothing of it kept for the next."""
-    inputs, handed = kept
+    group is rebuilt from the state it was handed and its kept
+    inverses and differentiated, nothing of it kept for the next."""
+    inputs, handed, inverses = kept
 
     # checkpointed, so that the group's forward runs inside ``back``
     # under jax's name for a recomputation (``rematted_computation``:
@@ -244,12 +307,20 @@ def _over_the_groups_bwd(sub, kept, d_out):
     )
 
     def step(d_state, group):
-        group_inputs, state, d_group_out = group
-        _, back = jax.vjp(rebuilt, state, group_inputs)
+        group_inputs, state, inverse, d_group_out = group
+        _, back, _ = jax.vjp(
+            functools.partial(rebuilt, inverse=inverse),
+            state,
+            group_inputs,
+            has_aux=True,
+        )
         return back((d_state, d_group_out))
 
     _, d_inputs = jax.lax.scan(
-        step, jnp.zeros_like(handed[0]), (inputs, handed, d_out), reverse=True
+        step,
+        jnp.zeros_like(handed[0]),
+        (inputs, handed, inverses, d_out),
+        reverse=True,
     )
     return (d_inputs,)
 

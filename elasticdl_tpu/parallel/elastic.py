@@ -1700,8 +1700,10 @@ class ElasticDPTrainer:
         own trace and lowering rather than off the flags that asked for
         it: the Pallas kernels in the jaxpr, by name, and how many of
         the calls are interpreted; and the Mosaic custom calls in the
-        lowered module, by kernel name; and how many of the module's
-        inputs it donates (the state's leaves on a process-local mesh,
+        lowered module, by kernel name, and its triangular solves (on
+        the chip an explicit inversion each: ops/kda.py); and how many
+        of the module's inputs it donates (the state's leaves on a
+        process-local mesh,
         none on one that spans processes: :func:`state_donation`); and,
         where it holds the flash kernels, how many steps their grids
         take and how many of those have no tile to compute
@@ -1734,6 +1736,14 @@ class ElasticDPTrainer:
                 set(re.findall(r"name=(\S+)\n\s+out_avals=", jaxpr_text))
             ),
             "tpu_custom_calls": lowered_text.count("@tpu_custom_call"),
+            # lax.linalg.triangular_solve as lowered: XLA's op on the
+            # chip, LAPACK's trsm on the CPU
+            "triangular_solves": len(
+                re.findall(
+                    r"stablehlo\.triangular_solve|@lapack_[sdcz]trsm",
+                    lowered_text,
+                )
+            ),
             "mosaic_kernels": sorted(
                 set(re.findall(r'kernel_name = "([^"]+)"', lowered_text))
             ),

@@ -969,6 +969,7 @@ class ElasticAllReduceWorker:
             "pallas_calls": facts["pallas_calls"],
             "pallas_interpreted": facts["pallas_interpreted"],
             "tpu_custom_calls": facts["tpu_custom_calls"],
+            "triangular_solves": facts["triangular_solves"],
             "mosaic_kernels": ",".join(facts["mosaic_kernels"]),
             "donated_inputs": facts["donated_inputs"],
             "record_reader": reader_kind(),

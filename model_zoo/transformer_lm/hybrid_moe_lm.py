@@ -43,12 +43,14 @@ residual scalings, ``silu`` and the gates, the depthwise convolution,
 itself (the kernel's forward runs twice) and an expert FF whole. A
 ``k`` layer's recurrence is NOT recomputed: the policy also keeps what
 ops/kda.py names (``kda.KEPT_NAMES``: the recurrence's output in the
-model's dtype and the float32 states at its group boundaries, 101 MB a
-layer at 2 x 4,096 positions of 32 heads of 128), so the recomputed
-layer runs the five projections, convolutions and gates in front of it
-and the gated norm behind it, and the recurrence's backward pass
-rebuilds each group from its kept state: two passes forward a step
-where there were three (PERF.md section 6, PR 43). A
+model's dtype, the float32 states at its group boundaries and the
+float32 inverse of every chunk's triangular matrix, 168 MB a layer at
+2 x 4,096 positions of 32 heads of 128 in chunks of 64), so the
+recomputed layer runs the five projections, convolutions and gates in
+front of it and the gated norm behind it, and the recurrence's backward
+pass rebuilds each group from its kept state and inverses: two passes
+forward a step where there were three (PERF.md section 6, PR 43), and
+one inversion of a chunk's matrix where there were three (PR 44). A
 model parameter, since the worker's ``--remat`` wraps the whole
 forward, which does not lower the peak.
 

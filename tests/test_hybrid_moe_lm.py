@@ -602,11 +602,12 @@ def test_an_untraced_jobs_step_built_is_what_it_was(job):
         "kind", "id", "ts", "src_id", "src_ts", "worker",
         "platform", "device_kind", "device_count", "mesh", "attention",
         "pallas_calls", "pallas_interpreted", "tpu_custom_calls",
-        "mosaic_kernels", "donated_inputs", "record_reader",
+        "triangular_solves", "mosaic_kernels", "donated_inputs", "record_reader",
         "compile_cache_dir",
         # the model's own (``step_facts``)
         "routing", "expert_apply", "tie_head", *FACTS,
     }  # fmt: skip
+    assert built["triangular_solves"] == 0  # a ``k`` layer's alone
 
 
 def test_step_built_names_the_grouped_matmul_kernels(job):

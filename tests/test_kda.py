@@ -137,9 +137,11 @@ def scan_of_checkpoint(q, k, v, g, beta, chunk):
         return jnp.moveaxis(split, (1, 4), (0, 2))
 
     f32 = jnp.float32
+    sub = min(chunk, kda.SUB_BLOCK)
     _, out = jax.lax.scan(
+        # [0]: the state and the group's ``o``, without the inverse
         jax.checkpoint(
-            functools.partial(kda._group_step, sub=min(chunk, kda.SUB_BLOCK)),
+            lambda state, group: kda._group_step(state, group, sub)[0],
             prevent_cse=False,
         ),
         jnp.zeros((batch, heads, d_k, v.shape[-1]), f32),
@@ -181,6 +183,75 @@ def test_the_backward_pass_is_what_differentiating_the_loop_gave(chunk, chunks, 
         assert a.dtype == b.dtype, name
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b), err_msg=name)
     assert [a.dtype for a in got[1:]] == [jnp.dtype(dtype)] * 3 + [jnp.float32] * 2
+
+
+@pytest.mark.parametrize("matrix", ["random", "keys_that_repeat"])
+def test_the_applied_inverses_backward_rule_is_the_solves(matrix):
+    """``_solved_by``'s own backward rule (``d_rhs = M^-T dX``,
+    ``d_matrix = -d_rhs X^T`` under the diagonal) against jax's
+    differentiation of ``lax.linalg.triangular_solve``, which groups
+    the second as ``M^-T (dX X^T)``: the result and both gradients
+    within float32 rounding, on random unit lower matrices of the size
+    of ``Diag(beta) A``'s entries and on the matrix of
+    ``test_keys_that_repeat_are_solved_as_accurately_as_any`` (one plus
+    the strictly lower triangle of ones)."""
+    size, width = 64, 24
+    keys = jax.random.split(jax.random.PRNGKey(11), 3)
+    under = {
+        "random": 0.1 * jax.random.normal(keys[0], (2, 3, size, size)),
+        "keys_that_repeat": jnp.ones((2, 3, size, size)),
+    }[matrix]
+    unit_lower = jnp.eye(size) + jnp.tril(under, -1)
+    rhs = jax.random.normal(keys[1], (2, 3, size, width))
+    weights = jax.random.normal(keys[2], rhs.shape)
+    solve = functools.partial(
+        jax.lax.linalg.triangular_solve,
+        left_side=True,
+        lower=True,
+        unit_diagonal=True,
+    )
+
+    def applied(m, r):
+        inverse = solve(m, jnp.broadcast_to(jnp.eye(size), m.shape))
+        return kda._solved_by(inverse, m, r)
+
+    want, want_grads = jax.value_and_grad(weighted(solve, weights), (0, 1))(
+        unit_lower, rhs
+    )
+    got, got_grads = jax.value_and_grad(weighted(applied, weights), (0, 1))(
+        unit_lower, rhs
+    )
+    # float32's 6e-8 through 64 rows: the worst of them read 3e-7
+    np.testing.assert_allclose(got, want, rtol=2e-6)
+    for name, g_got, g_want in zip(("d_matrix", "d_rhs"), got_grads, want_grads):
+        np.testing.assert_allclose(
+            g_got,
+            g_want,
+            rtol=2e-6,
+            atol=2e-6 * float(jnp.max(jnp.abs(g_want))),
+            err_msg=name,
+        )
+    # a unit triangular solve reads nothing on or over the diagonal
+    np.testing.assert_array_equal(jnp.triu(got_grads[0]), 0.0)
+
+
+# 4 chunks are one group; 16 two groups of eight, 13 thirteen of one
+@pytest.mark.parametrize("chunks", [4, 13, 16])
+def test_the_backward_pass_solves_nothing(chunks):
+    """The lowered gradient holds the ONE ``triangular_solve`` of the
+    lowered forward: the backward pass applies the inverses the forward
+    kept (PR 43's gradient held four: the rebuilt group's, and one for
+    either cotangent of the solve). Lowered for the TPU, where the op
+    keeps XLA's name (on the CPU it is LAPACK's ``trsm``)."""
+    args = operands(16 * chunks)
+
+    def solves(fn):
+        lowered = jax.jit(fn).trace(*args).lower(lowering_platforms=("tpu",))
+        return lowered.as_text().count("stablehlo.triangular_solve")
+
+    objective = lambda *a: jnp.sum(kda.kda(*a, chunk=16))
+    assert solves(objective) == 1
+    assert solves(jax.value_and_grad(objective, argnums=(0, 1, 2, 3, 4))) == 1
 
 
 def test_nothing_written_leaves_nothing_to_read():

@@ -455,11 +455,13 @@ def test_keeping_the_recurrence_changes_no_number(kept_and_not, group):
         assert norm > 0 and error / norm <= 10 * TOL, (leaf, error / norm)
 
 
-def _state_loops(zoo, remat_layers, kept_names=None):
-    """How many ``while`` loops of the compiled gradient of ONE ``k``
-    layer (2 x 256 tokens: two groups of eight chunks) carry the
+def _loops_and_solves(zoo, remat_layers, kept_names=None):
+    """Of the compiled gradient of ONE ``k`` layer (2 x 256 tokens: two
+    groups of eight chunks): how many ``while`` loops carry the
     recurrence's state or its cotangent, by the regular expression the
-    benchmark's reader finds them with in a device trace."""
+    benchmark's reader finds them with in a device trace, and how many
+    triangular solves it holds (LAPACK's ``trsm`` on the CPU; on the
+    chip each is an inversion)."""
     sys.path[:0] = [os.path.join(REPO, "benchmark"), os.path.join(REPO, "benchmark", "layer_metrics")]
     try:
         import _kda
@@ -487,7 +489,10 @@ def _state_loops(zoo, remat_layers, kept_names=None):
         # and a pass is dropped whatever is kept
         text = jax.jit(jax.value_and_grad(objective)).lower(params).compile().as_text()
     loop = _kda.state_loop(model.step_facts(), 2)
-    return sum(bool(loop.match(line.strip())) for line in text.splitlines())
+    return (
+        sum(bool(loop.match(line.strip())) for line in text.splitlines()),
+        text.count('custom_call_target="lapack_strsm_ffi"'),
+    )
 
 
 def test_a_rematerialised_layer_runs_the_recurrence_forward_twice_not_three_times(zoo):
@@ -499,10 +504,20 @@ def test_a_rematerialised_layer_runs_the_recurrence_forward_twice_not_three_time
     of the recurrence kept (the names taken out of the policy: PR 42's
     program) it holds a third pass forward, seven. Should jax stop
     honouring a ``checkpoint_name`` inside a ``custom_vjp``'s forward
-    rule, this reads seven."""
-    assert _state_loops(zoo, remat_layers=False) == 2 + 3
-    assert _state_loops(zoo, remat_layers=True) == 2 + 3
-    assert _state_loops(zoo, remat_layers=True, kept_names=()) == 2 + 2 + 3
+    rule, this reads seven.
+
+    And it inverts a chunk's matrix ONCE, in the forward pass, which
+    keeps the inverse for the backward pass under a name of its own:
+    with that name alone out of the policy (PR 43's two) the
+    recomputed layer inverts again to hand the backward pass what it
+    reads (in loops that carry no state: nothing reads it), as it does
+    with nothing kept."""
+    assert _loops_and_solves(zoo, remat_layers=False) == (2 + 3, 1)
+    assert _loops_and_solves(zoo, remat_layers=True) == (2 + 3, 1)
+    assert _loops_and_solves(
+        zoo, remat_layers=True, kept_names=(kda.KEPT_OUTPUT, kda.KEPT_STATES)
+    ) == (2 + 3, 2)
+    assert _loops_and_solves(zoo, remat_layers=True, kept_names=()) == (2 + 2 + 3, 2)
 
 
 def test_an_eager_init_runs_no_loop_of_the_recurrence(zoo, monkeypatch, tokens):
@@ -655,6 +670,8 @@ FACTS = {
     "shared_expert_dim": 24, "expert_groups": 4, "expert_groups_per_tok": 2,
     "routing": "sigmoid_bias", "tie_head": 0, "expert_apply": "grouped",
     "remat_layers": 1, "remat_kept_recurrences": 3,
+    # one a ``k`` layer: its forward pass's, and none in the backward
+    "triangular_solves": 3,
 }  # fmt: skip
 
 

@@ -357,7 +357,7 @@ def test_the_scopes_change_no_cache_key(lowered, monkeypatch):
 
 TODAYS_FACTS = {
     "pallas_calls", "pallas_interpreted", "pallas_kernels",
-    "tpu_custom_calls", "mosaic_kernels", "donated_inputs",
+    "tpu_custom_calls", "triangular_solves", "mosaic_kernels", "donated_inputs",
 }  # fmt: skip
 ROWS = 8
 
