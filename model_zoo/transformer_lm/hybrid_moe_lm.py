@@ -166,15 +166,24 @@ counters and a selecting attention's ``sel_pairs_kept`` /
 training step), and with the collection absent the bias is zero, its
 initial value.
 
+All the model knows of a kind of layer is one record: ``KINDS`` holds
+one a letter, ``DENSE_FF`` and ``EXPERT_FF`` are the feed-forward
+halves', and ``Kind`` says how a kind is added. ``custom_model`` applies
+one rule to every record: where a layer of the kind is in the pattern
+each of the kind's own sizes passes its check, and where none is, a
+size other than the class's default says nothing and is refused.
+
 What the two LMs of this directory share comes from the sibling module
 (a zoo module is loaded by path, not as a package): ``_rotary``,
 ``loss``, ``optimizer``, ``dataset_fn``, ``eval_metrics_fn``, and the
 one attention policy ``pick_causal_attention``.
 """
 
+import dataclasses
 import math
 import os
-from typing import Any
+from contextlib import nullcontext
+from typing import Any, Callable, NamedTuple, Optional
 
 import flax.linen as nn
 import jax
@@ -200,29 +209,13 @@ loss = _lm.loss
 dataset_fn = _lm.dataset_fn
 eval_metrics_fn = _lm.eval_metrics_fn
 
-CONV, ATTENTION, SELECTING, MAMBA, WINDOW = "c", "a", "s", "m", "w"
-KDA, MLA = "k", "l"
-LETTERS = {
-    CONV: "a short convolution",
-    MAMBA: "a Mamba-2 state-space layer",
-    ATTENTION: "full attention",
-    SELECTING: "attention over selected keys",
-    WINDOW: "attention within a window",
-    KDA: "a Kimi-Delta-Attention layer",
-    MLA: "latent attention",
-}
 ROUTINGS = ("sigmoid_bias", "softmax")
 EXPERT_APPLIES = ("grouped", "masked")
 # the norm whose output the router reads: the expert layer's own, or
 # the operator's in front of it (the router "placed before attention")
 ROUTER_INPUTS = ("ffn_norm", "operator_norm")
-# the name of what ``remat_layers`` keeps for the backward pass, and how
-# many results a layer's operator gives that name (a dense FF: two)
+# the name of what ``remat_layers`` keeps for the backward pass
 KEPT = "weight_product"
-KEPT_OF_OPERATOR = {
-    CONV: 1, MAMBA: 1, ATTENTION: 4, SELECTING: 4, WINDOW: 4, KDA: 1, MLA: 4,
-}  # fmt: skip
-KEPT_OF_DENSE_FF = 2
 # every parameter of an indexer lies under a module of this name
 INDEXER = "indexer"
 
@@ -852,6 +845,384 @@ def _tokens_of(features):
     return features["tokens"] if isinstance(features, dict) else features
 
 
+def _check(admits, text):
+    """One check of a size: ``admits(value, model)``, and what is said
+    of a value it does not admit (``name``, ``value`` and ``model``
+    filled in). Checks add up with ``+``: the first to fail speaks."""
+    return ((admits, text),)
+
+
+_WHOLE = _check(
+    lambda v, m: isinstance(v, int) and v > 0,
+    "{name}={value!r} where its sizes all have to be positive whole numbers",
+)
+_SWITCH = _check(
+    lambda v, m: isinstance(v, bool), "{name}={value!r} is neither True nor False"
+)
+_POSITIVE = _check(
+    lambda v, m: v > 0 and math.isfinite(v),
+    "{name}={value!r} is not a positive number",
+)
+_EVEN = _check(lambda v, m: v % 2 == 0, "{name}={value!r} is odd")
+_DIVIDES_SSM_HEADS = _check(
+    lambda v, m: m.ssm_heads % v == 0,
+    "ssm_heads={model.ssm_heads!r} is not a multiple of {name}={value!r}",
+)
+_LOG_DECAY = _check(
+    # the exponents of a sub-block of the recurrence (ops/kda.py)
+    lambda v, m: v < 0 and math.isfinite(v) and -v * kda.SUB_BLOCK / 2 <= 80,
+    "{name}={value!r}: a log-decay a position, under 0 and no lower than %g"
+    % (-160 / kda.SUB_BLOCK),
+)
+_SCALE = _check(
+    lambda v, m: v >= 0 and math.isfinite(v),
+    "{name}={value!r}: a positive number, or 0 for head_dim ** -0.5",
+)
+_WIDTH_OR_NONE = _check(
+    lambda v, m: isinstance(v, int) and v >= 0,
+    "{name}={value!r}: a width, or 0 for none",
+)
+_EQUAL_GROUPS = _check(
+    lambda v, m: isinstance(v, int)
+    and isinstance(m.expert_groups_per_tok, int)
+    and 1 <= m.expert_groups_per_tok <= v
+    and m.num_experts % v == 0,
+    "{name}={value!r} and expert_groups_per_tok={model.expert_groups_per_tok!r}: "
+    "num_experts={model.num_experts!r} in groups of equal size, of which "
+    "between one and all stay",
+)
+_INSIDE_PATTERN = _check(
+    lambda v, m: 0 <= v <= len(m.layer_pattern),
+    "num_dense_layers outside the pattern",
+)
+_SERVES_KV_HEADS = _check(
+    lambda v, m: v % m.num_kv_heads == 0,
+    "num_heads is not a multiple of num_kv_heads",
+)
+_AMONG_EXPERTS = _check(
+    lambda v, m: 0 < v <= m.num_experts,
+    "{name}={value!r} is not between 1 and num_experts={model.num_experts!r}",
+)
+_HELD_AMONG_EXPERTS = _check(
+    lambda v, m: 0 < v and 0 <= m.first_expert_held <= m.num_experts - v,
+    "the experts held are not among num_experts",
+)
+
+
+def _one_of(choices):
+    return _check(
+        lambda v, m: v in choices,
+        "{name} {value!r} is not one of " + ", ".join(choices),
+    )
+
+
+def _within_the_groups(kept, model):
+    groups = model.num_expert_groups
+    return groups == 1 or (
+        model.routing == ROUTINGS[0]
+        and model.num_experts // groups >= 2
+        and model.num_experts_per_tok <= kept * (model.num_experts // groups)
+    )
+
+
+_GROUP_LIMIT = _check(
+    _within_the_groups,
+    "a group limit ({model.num_expert_groups} groups, {value} kept) is "
+    + ROUTINGS[0]
+    + " routing's, over groups of two experts or more that hold "
+    "num_experts_per_tok={model.num_experts_per_tok!r} between them",
+)
+
+
+class Size(NamedTuple):
+    """A keyword of ``custom_model`` that one kind of layer owns."""
+
+    name: str
+    check: tuple = ()
+    # the operator's keyword: "" the same name, None not the operator's
+    to: Optional[str] = ""
+
+    def wrong(self, model):
+        """What is wrong with ``model``'s value, or None."""
+        value = getattr(model, self.name)
+        for admits, text in self.check:
+            if not admits(value, model):
+                return text.format(name=self.name, value=value, model=model)
+
+
+def _where(layers, **facts):
+    return facts if layers else {}
+
+
+def _stated(counted, *names):
+    """``facts`` of a kind that states, where a layer of it is in the
+    pattern, how many are and some of its sizes as they are."""
+    return lambda model, layers, features: _where(
+        layers, **{counted: layers}, **{n: getattr(model, n) for n in names}
+    )
+
+
+@dataclasses.dataclass(frozen=True)
+class Kind:
+    """All the model knows of one kind of layer: ``HybridMoELM`` builds
+    it, ``step_facts`` states it and ``custom_model`` checks it from
+    this record alone. A kind is added in four steps: (1) its sizes as
+    fields of ``HybridMoELM``, each with a default that means "none";
+    (2) its operator, an ``nn.Module`` over ``h`` (and the positions,
+    with ``call=_with_positions``) that names its weight products
+    ``_kept``; (3) one record in ``KINDS`` under its letter; (4) its
+    paragraph in the module's docstring."""
+
+    operator: type
+    name: str  # of its variables, behind ``layer_<i>_``
+    # its own: each passes its check where a layer of the kind is in the
+    # pattern, and says nothing (has to be the default) where none is,
+    # unless another kind that is there owns it too
+    sizes: tuple = ()
+    # the shared sizes the operator is handed under their own names
+    reads: tuple = ()
+    says: str = ""  # of the letter, where a pattern is refused
+    noun: str = ""  # "holds a ..." and "holds no ...", where not ``says``
+    # operator, h, what else the model has (an operator: the positions;
+    # a feed-forward half: the operator's normed input) -> the result
+    call: Callable = lambda model, operator, h, other: operator(h)
+    scope: str = ""  # the named scope the model wraps it in
+    kept: int = 1  # how many of its results are named ``KEPT``
+    keeps_recurrence: bool = False  # ``kda.KEPT_NAMES`` beside them
+    # (model, how many such layers, a batch or None) -> its step_facts
+    facts: Callable = lambda model, layers, features: {}
+
+    def held(self, layers):
+        if not self.noun:
+            return self.says if layers else "no " + self.says
+        return ("a " if layers else "no ") + self.noun
+
+    def apply(self, model, name, h, other):
+        given = {shared: getattr(model, shared) for shared in self.reads}
+        given.update(
+            (size.to or size.name, getattr(model, size.name))
+            for size in self.sizes
+            if size.to is not None
+        )
+        operator = self.operator(**given, name=name + self.name)
+        with jax.named_scope(self.scope) if self.scope else nullcontext():
+            return self.call(model, operator, h, other)
+
+
+def _with_positions(model, operator, h, positions):
+    return operator(h, positions)
+
+
+def _window_facts(model, layers, features):
+    facts = _stated("window_layers", "attention_window")(model, layers, features)
+    if layers and features is not None:
+        # a sequence's (query, key) pairs in ONE window layer (not
+        # times the heads), and the causal pairs
+        kept, causal = window_pairs(
+            _tokens_of(features).shape[1], model.attention_window
+        )
+        facts.update(window_pairs_kept=kept, window_pairs_causal=causal)
+    return facts
+
+
+def _expert_facts(model, layers, features):
+    # the share is stated with no expert layer too: the worker reads it
+    facts = {
+        "expert_layers": layers,
+        "experts_held": model.experts_held,
+        "experts_routed": model.num_experts,
+        "first_expert_held": model.first_expert_held,
+        "routing": model.routing,
+        "expert_apply": model.expert_apply,
+    }
+    if not layers:
+        return facts
+    if model.expert_apply == EXPERT_APPLIES[0]:
+        facts["moe_dispatch_chunk_rows"] = expert.DISPATCH_CHUNK_ROWS
+    for name in ("router_input", "expert_act", "shared_expert_dim"):
+        if getattr(model, name) != getattr(HybridMoELM, name):
+            facts[name] = getattr(model, name)
+    if model.num_expert_groups > 1:
+        facts.update(
+            expert_groups=model.num_expert_groups,
+            expert_groups_per_tok=model.expert_groups_per_tok,
+        )
+    return facts
+
+
+# one module serves the three kinds of grouped-head attention
+_ATTENDS = dict(
+    operator=GroupedAttention,
+    name="attention",
+    reads=(
+        "num_heads", "num_kv_heads", "head_dim", "rope_theta", "norm_eps",
+        "dtype", "use_flash",
+    ),
+    call=_with_positions,
+    kept=4,
+)  # fmt: skip
+_SCALED = (Size("qk_norm", _SWITCH), Size("attention_scale", _SCALE))
+KINDS = {
+    "c": Kind(
+        ShortConv,
+        "conv",
+        says="a short convolution",
+        sizes=(Size("conv_kernel", _WHOLE, "kernel_size"),),
+        reads=("dtype",),
+        scope="edl/short_conv",
+        # stated at none too, as ``attention_layers`` is
+        facts=lambda model, layers, features: {"conv_layers": layers},
+    ),
+    "m": Kind(
+        Mamba2,
+        "mamba",
+        says="a Mamba-2 state-space layer",
+        noun="state-space layer",
+        sizes=(
+            Size("ssm_heads", _WHOLE, "heads"),
+            Size("ssm_head_dim", _WHOLE, "head_dim"),
+            Size("ssm_state", _WHOLE, "state"),
+            Size("ssm_groups", _WHOLE + _DIVIDES_SSM_HEADS, "groups"),
+            Size("ssm_conv_kernel", _WHOLE, "conv_kernel"),
+            Size("ssm_chunk", _WHOLE, "chunk"),
+        ),
+        reads=("norm_eps", "dtype"),
+        facts=_stated(
+            "mamba_layers", "ssm_heads", "ssm_head_dim", "ssm_state", "ssm_chunk"
+        ),
+    ),
+    "a": Kind(
+        says="full attention",
+        noun="attention layer",
+        sizes=(Size("rope", _SWITCH),) + _SCALED,
+        facts=lambda model, layers, features: {"attention_layers": layers},
+        **_ATTENDS,
+    ),
+    "s": Kind(
+        says="attention over selected keys",
+        sizes=(
+            Size("rope", _SWITCH),
+            Size("select_topk", _WHOLE),
+            Size("indexer_heads", _WHOLE),
+            Size("indexer_dim", _WHOLE),
+        )
+        + _SCALED,
+        facts=_stated("sparse_layers", "select_topk", "indexer_heads"),
+        **_ATTENDS,
+    ),
+    "w": Kind(
+        says="attention within a window",
+        noun="window layer",
+        sizes=(
+            Size("attention_window", _WHOLE, "window"),
+            Size("window_rope", _SWITCH, "rope"),
+        )
+        + _SCALED,
+        scope="edl/window_attention",
+        facts=_window_facts,
+        **_ATTENDS,
+    ),
+    "k": Kind(
+        KimiDeltaAttention,
+        "kda",
+        says="a Kimi-Delta-Attention layer",
+        sizes=(
+            Size("kda_heads", _WHOLE, "heads"),
+            Size("kda_head_dim", _WHOLE, "head_dim"),
+            Size("kda_conv_kernel", _WHOLE, "conv_kernel"),
+            Size("kda_gate_lower_bound", _LOG_DECAY, "gate_lower_bound"),
+            Size("kda_chunk", _WHOLE, "chunk"),
+        ),
+        reads=("norm_eps", "dtype"),
+        # beside ``out``'s product it keeps what ops/kda.py names: its
+        # recurrence is not run again
+        keeps_recurrence=True,
+        facts=_stated("kda_layers", "kda_heads", "kda_head_dim", "kda_chunk"),
+    ),
+    "l": Kind(
+        LatentAttention,
+        "mla",
+        says="latent attention",
+        sizes=(
+            Size("mla_kv_rank", _WHOLE, "kv_rank"),
+            Size("mla_nope_dim", _WHOLE, "nope_dim"),
+            Size("mla_rope_dim", _WHOLE + _EVEN, "rope_dim"),
+            Size("mla_v_dim", _WHOLE, "v_dim"),
+        ),
+        reads=("num_heads", "rope_theta", "norm_eps", "dtype", "use_flash"),
+        call=_with_positions,
+        scope="edl/mla",
+        kept=4,
+        facts=lambda model, layers, features: _where(
+            layers,
+            mla_layers=layers,
+            mla_qk_dim=model.mla_nope_dim + model.mla_rope_dim,
+            mla_v_dim=model.mla_v_dim,
+        ),
+    ),
+}
+LETTERS = {letter: kind.says for letter, kind in KINDS.items()}
+# the feed-forward half of a layer: of the first ``num_dense_layers``
+# the dense MLP, of the others this device's share of the experts
+DENSE_FF = Kind(
+    SwiGLU,
+    "mlp",
+    noun="dense MLP",
+    sizes=(Size("mlp_dim", _WHOLE, "width"),),
+    reads=("dtype",),
+    kept=2,
+)
+EXPERT_FF = Kind(
+    HeldExperts,
+    "moe",
+    noun="expert layer",
+    sizes=(
+        Size("router_input", _one_of(ROUTER_INPUTS), None),
+        Size("expert_act", _one_of(expert.EXPERT_ACTS), "act"),
+        Size("expert_apply", _one_of(EXPERT_APPLIES), "apply"),
+        Size("shared_expert_dim", _WIDTH_OR_NONE),
+        Size("num_expert_groups", _EQUAL_GROUPS),
+        Size("expert_groups_per_tok", _GROUP_LIMIT),
+        Size("expert_bias_rate"),
+    ),
+    # the tests of a stack with no expert layer give these, so they are
+    # shared: none is refused as saying nothing (their checks: SHARED)
+    reads=(
+        "routing", "routed_scaling_factor", "num_experts", "experts_held",
+        "first_expert_held", "num_experts_per_tok", "expert_dim", "dtype",
+    ),
+    # the router reads the operator's normed input where so placed
+    call=lambda model, operator, h, operator_h: operator(
+        h, operator_h if model.router_input == ROUTER_INPUTS[1] else None
+    ),
+    scope="edl/moe",
+    kept=0,
+    facts=_expert_facts,
+)  # fmt: skip
+# what no kind owns and has a check, whatever the pattern holds
+SHARED = (
+    Size("num_dense_layers", _INSIDE_PATTERN),
+    Size("remat_layers", _SWITCH),
+    Size("embedding_multiplier", _POSITIVE),
+    Size("residual_multiplier", _POSITIVE),
+    Size("logits_scaling", _POSITIVE),
+    Size("num_heads", _SERVES_KV_HEADS),
+    Size("routing", _one_of(ROUTINGS)),
+    Size("num_experts_per_tok", _AMONG_EXPERTS),
+    Size("experts_held", _HELD_AMONG_EXPERTS),
+)
+
+
+def _layers(model):
+    """(letter, record, how many layers of ``model`` are of it) of
+    every record; the feed-forward halves have no letter."""
+    dense = model.num_dense_layers
+    return [
+        (letter, kind, model.layer_pattern.count(letter))
+        for letter, kind in KINDS.items()
+    ] + [("", DENSE_FF, dense), ("", EXPERT_FF, len(model.layer_pattern) - dense)]
+
+
 class HybridMoELM(nn.Module):
     vocab_size: int = 1024
     layer_pattern: str = "caccc"
@@ -914,82 +1285,20 @@ class HybridMoELM(nn.Module):
         layout (scalars), and what its window counters are read with.
         ``features``, where given, is a batch the step was built for:
         what follows from its sequence length is stated too."""
-        facts = {
-            "expert_layers": len(self.layer_pattern) - self.num_dense_layers,
-            "experts_held": self.experts_held,
-            "experts_routed": self.num_experts,
-            "first_expert_held": self.first_expert_held,
-            "routing": self.routing,
-            "tie_head": int(self.tie_head),
-            "expert_apply": self.expert_apply,
-            "conv_layers": self.layer_pattern.count(CONV),
-            "attention_layers": self.layer_pattern.count(ATTENTION),
-        }
-        if facts["expert_layers"] and self.expert_apply == EXPERT_APPLIES[0]:
-            facts["moe_dispatch_chunk_rows"] = expert.DISPATCH_CHUNK_ROWS
+        facts = {"tie_head": int(self.tie_head)}
+        held = _layers(self)
+        for _, record, layers in held:
+            facts.update(record.facts(self, layers, features))
         if self.remat_layers:
             facts["remat_layers"] = 1
-            facts["remat_kept_products"] = (
-                sum(KEPT_OF_OPERATOR[kind] for kind in self.layer_pattern)
-                + self.num_dense_layers * KEPT_OF_DENSE_FF
+            facts["remat_kept_products"] = sum(
+                record.kept * layers for _, record, layers in held
             )
-            if KDA in self.layer_pattern:
-                # beside ``out``'s product a ``k`` layer keeps what
-                # ops/kda.py names: its recurrence is not run again
-                facts["remat_kept_recurrences"] = self.layer_pattern.count(KDA)
-        if MAMBA in self.layer_pattern:
-            facts.update(
-                mamba_layers=self.layer_pattern.count(MAMBA),
-                ssm_heads=self.ssm_heads,
-                ssm_head_dim=self.ssm_head_dim,
-                ssm_state=self.ssm_state,
-                ssm_chunk=self.ssm_chunk,
+            recurrences = sum(
+                layers for _, record, layers in held if record.keeps_recurrence
             )
-        if SELECTING in self.layer_pattern:
-            facts.update(
-                sparse_layers=self.layer_pattern.count(SELECTING),
-                select_topk=self.select_topk,
-                indexer_heads=self.indexer_heads,
-            )
-        if KDA in self.layer_pattern:
-            facts.update(
-                kda_layers=self.layer_pattern.count(KDA),
-                kda_heads=self.kda_heads,
-                kda_head_dim=self.kda_head_dim,
-                kda_chunk=self.kda_chunk,
-            )
-        if MLA in self.layer_pattern:
-            facts.update(
-                mla_layers=self.layer_pattern.count(MLA),
-                mla_qk_dim=self.mla_nope_dim + self.mla_rope_dim,
-                mla_v_dim=self.mla_v_dim,
-            )
-        if WINDOW in self.layer_pattern:
-            facts.update(
-                window_layers=self.layer_pattern.count(WINDOW),
-                attention_window=self.attention_window,
-            )
-            if features is not None:
-                # a sequence's (query, key) pairs in ONE window layer
-                # (not times the heads), and the causal pairs
-                kept, causal = window_pairs(
-                    _tokens_of(features).shape[1], self.attention_window
-                )
-                facts.update(
-                    window_pairs_kept=kept, window_pairs_causal=causal
-                )
-        if facts["expert_layers"]:
-            if self.router_input != ROUTER_INPUTS[0]:
-                facts["router_input"] = self.router_input
-            if self.expert_act != expert.EXPERT_ACTS[0]:
-                facts["expert_act"] = self.expert_act
-            if self.shared_expert_dim:
-                facts["shared_expert_dim"] = self.shared_expert_dim
-            if self.num_expert_groups > 1:
-                facts.update(
-                    expert_groups=self.num_expert_groups,
-                    expert_groups_per_tok=self.expert_groups_per_tok,
-                )
+            if recurrences:
+                facts["remat_kept_recurrences"] = recurrences
         return facts
 
     @nn.compact
@@ -1018,103 +1327,13 @@ class HybridMoELM(nn.Module):
         def layer(self, x, i):
             """Layer ``i`` of the pattern, its variables under this
             module by the layer's own names."""
-            kind, name = self.layer_pattern[i], "layer_%d_" % i
+            name = "layer_%d_" % i
             h = norm(name + "operator_norm")(x)
-            if kind == CONV:
-                with jax.named_scope("edl/short_conv"):
-                    out = ShortConv(
-                        self.conv_kernel, self.dtype, name=name + "conv"
-                    )(h)
-            elif kind == MAMBA:
-                out = Mamba2(
-                    heads=self.ssm_heads,
-                    head_dim=self.ssm_head_dim,
-                    state=self.ssm_state,
-                    groups=self.ssm_groups,
-                    conv_kernel=self.ssm_conv_kernel,
-                    chunk=self.ssm_chunk,
-                    norm_eps=self.norm_eps,
-                    dtype=self.dtype,
-                    name=name + "mamba",
-                )(h)
-            elif kind == KDA:
-                out = KimiDeltaAttention(
-                    heads=self.kda_heads,
-                    head_dim=self.kda_head_dim,
-                    conv_kernel=self.kda_conv_kernel,
-                    gate_lower_bound=self.kda_gate_lower_bound,
-                    chunk=self.kda_chunk,
-                    norm_eps=self.norm_eps,
-                    dtype=self.dtype,
-                    name=name + "kda",
-                )(h)
-            elif kind == MLA:
-                with jax.named_scope("edl/mla"):
-                    out = LatentAttention(
-                        num_heads=self.num_heads,
-                        kv_rank=self.mla_kv_rank,
-                        nope_dim=self.mla_nope_dim,
-                        rope_dim=self.mla_rope_dim,
-                        v_dim=self.mla_v_dim,
-                        rope_theta=self.rope_theta,
-                        norm_eps=self.norm_eps,
-                        dtype=self.dtype,
-                        use_flash=self.use_flash,
-                        name=name + "mla",
-                    )(h, positions)
-            else:
-                windowed = kind == WINDOW
-                attention = GroupedAttention(
-                    num_heads=self.num_heads,
-                    num_kv_heads=self.num_kv_heads,
-                    head_dim=self.head_dim,
-                    rope_theta=self.rope_theta,
-                    norm_eps=self.norm_eps,
-                    dtype=self.dtype,
-                    use_flash=self.use_flash,
-                    select_topk=self.select_topk if kind == SELECTING else 0,
-                    indexer_heads=self.indexer_heads,
-                    indexer_dim=self.indexer_dim,
-                    rope=self.window_rope if windowed else self.rope,
-                    qk_norm=self.qk_norm,
-                    attention_scale=self.attention_scale,
-                    window=self.attention_window if windowed else 0,
-                    name=name + "attention",
-                )
-                if windowed:
-                    with jax.named_scope("edl/window_attention"):
-                        out = attention(h, positions)
-                else:
-                    out = attention(h, positions)
-            x = joined(x, out)
+            operator = KINDS[self.layer_pattern[i]]
+            x = joined(x, operator.apply(self, name, h, positions))
             operator_h, h = h, norm(name + "ffn_norm")(x)
-            if i < self.num_dense_layers:
-                out = SwiGLU(self.mlp_dim, self.dtype, name=name + "mlp")(h)
-            else:
-                with jax.named_scope("edl/moe"):
-                    out = HeldExperts(
-                        num_experts=self.num_experts,
-                        experts_held=self.experts_held,
-                        first_expert_held=self.first_expert_held,
-                        num_experts_per_tok=self.num_experts_per_tok,
-                        expert_dim=self.expert_dim,
-                        routed_scaling_factor=self.routed_scaling_factor,
-                        expert_bias_rate=self.expert_bias_rate,
-                        dtype=self.dtype,
-                        routing=self.routing,
-                        apply=self.expert_apply,
-                        act=self.expert_act,
-                        shared_expert_dim=self.shared_expert_dim,
-                        num_expert_groups=self.num_expert_groups,
-                        expert_groups_per_tok=self.expert_groups_per_tok,
-                        name=name + "moe",
-                    )(
-                        h,
-                        operator_h
-                        if self.router_input == ROUTER_INPUTS[1]
-                        else None,
-                    )
-            return joined(x, out)
+            ff = DENSE_FF if i < self.num_dense_layers else EXPERT_FF
+            return joined(x, ff.apply(self, name, h, operator_h))
 
         if self.remat_layers and not self.is_initializing():
             # static_argnums counts the module: the layer's index
@@ -1140,9 +1359,16 @@ class HybridMoELM(nn.Module):
         return logits
 
 
+def _listed(items):
+    return " and ".join(filter(None, (", ".join(items[:-1]), items[-1])))
+
+
 def custom_model(dtype="float32", **sizes):
     """``HybridMoELM(**sizes)``; every size has the toy default of the
-    class, and a name it does not know is refused."""
+    class, and a name it does not know is refused. One rule for every
+    record: where a layer of the kind is in the pattern each of its own
+    sizes passes its check, and where none is, a size other than the
+    default says nothing and is refused."""
     pattern = str(sizes.get("layer_pattern", HybridMoELM.layer_pattern))
     unknown = sorted(set(pattern) - set(LETTERS))
     if not pattern or unknown:
@@ -1154,188 +1380,34 @@ def custom_model(dtype="float32", **sizes):
             )
         )  # fmt: skip
     model = HybridMoELM(dtype=jnp.dtype(dtype), **sizes)
-    ssm_sizes = {
-        name: getattr(model, name)
-        for name in (
-            "ssm_heads", "ssm_head_dim", "ssm_state", "ssm_groups",
-            "ssm_conv_kernel", "ssm_chunk",
-        )
-    }  # fmt: skip
-    if MAMBA in pattern:
-        if not all(isinstance(v, int) and v > 0 for v in ssm_sizes.values()):
+
+    def checked(rows, holding=""):
+        for size in rows:
+            said = size.wrong(model)
+            if said:
+                raise ValueError(holding + said)
+
+    checked(SHARED)
+    held = _layers(model)
+    owned = {
+        size.name for _, record, layers in held if layers for size in record.sizes
+    }
+    for letter, record, layers in held:
+        named = "layer_pattern %r holds %s" % (pattern, record.held(layers))
+        if layers:
+            checked(record.sizes, named + ": ")
+            continue
+        # a number or a switch with its value, a choice by its name (the
+        # texts the earlier refusals had, which tests/ match)
+        idle = [
+            size.name if isinstance(value, str) else "%s=%r" % (size.name, value)
+            for size in record.sizes
+            for value in (getattr(model, size.name),)
+            if size.name not in owned and value != getattr(HybridMoELM, size.name)
+        ]
+        if idle:
             raise ValueError(
-                "layer_pattern %r holds a state-space layer: %s all have to "
-                "be positive whole numbers" % (pattern, ssm_sizes)
+                "%s%s, so %s say nothing"
+                % (named, letter and " (%r)" % letter, _listed(idle))
             )
-        if model.ssm_heads % model.ssm_groups:
-            raise ValueError(
-                "ssm_heads=%r is not a multiple of ssm_groups=%r"
-                % (model.ssm_heads, model.ssm_groups)
-            )
-    elif any(ssm_sizes.values()):
-        raise ValueError(
-            "layer_pattern %r holds no state-space layer (%r), so %s say "
-            "nothing" % (pattern, MAMBA, sorted(k for k, v in ssm_sizes.items() if v))
-        )
-    for letter, sizes_of in (
-        (
-            KDA,
-            ("kda_heads", "kda_head_dim", "kda_conv_kernel", "kda_chunk",
-             "kda_gate_lower_bound"),
-        ),
-        (MLA, ("mla_kv_rank", "mla_nope_dim", "mla_rope_dim", "mla_v_dim")),
-    ):  # fmt: skip
-        given = {name: getattr(model, name) for name in sizes_of}
-        whole = {
-            k: v for k, v in given.items() if k != "kda_gate_lower_bound"
-        }
-        if letter not in pattern:
-            if any(given.values()):
-                raise ValueError(
-                    "layer_pattern %r holds no %s (%r), so %s say nothing"
-                    % (pattern, LETTERS[letter], letter,
-                       sorted(k for k, v in given.items() if v))
-                )  # fmt: skip
-        elif not all(isinstance(v, int) and v > 0 for v in whole.values()):
-            raise ValueError(
-                "layer_pattern %r holds %s: %s all have to be positive whole "
-                "numbers" % (pattern, LETTERS[letter], whole)
-            )
-    if KDA in pattern and not (
-        model.kda_gate_lower_bound < 0
-        and math.isfinite(model.kda_gate_lower_bound)
-        # the exponents of a sub-block of the recurrence (ops/kda.py)
-        and -model.kda_gate_lower_bound * kda.SUB_BLOCK / 2 <= 80
-    ):
-        raise ValueError(
-            "kda_gate_lower_bound=%r: a log-decay a position, under 0 and "
-            "no lower than %g" % (model.kda_gate_lower_bound, -160 / kda.SUB_BLOCK)
-        )
-    if MLA in pattern and model.mla_rope_dim % 2:
-        raise ValueError("mla_rope_dim=%r is odd" % model.mla_rope_dim)
-    groups, kept_groups = model.num_expert_groups, model.expert_groups_per_tok
-    if not (
-        isinstance(groups, int)
-        and isinstance(kept_groups, int)
-        and 1 <= kept_groups <= groups
-        and model.num_experts % groups == 0
-    ):
-        raise ValueError(
-            "num_expert_groups=%r and expert_groups_per_tok=%r: num_experts=%r "
-            "in groups of equal size, of which between one and all stay"
-            % (groups, kept_groups, model.num_experts)
-        )
-    if groups > 1 and not (
-        model.routing == ROUTINGS[0]
-        and model.num_experts // groups >= 2
-        and model.num_experts_per_tok
-        <= kept_groups * (model.num_experts // groups)
-    ):
-        raise ValueError(
-            "a group limit (%d groups, %d kept) is %s routing's, over groups "
-            "of two experts or more that hold num_experts_per_tok=%r between "
-            "them" % (groups, kept_groups, ROUTINGS[0], model.num_experts_per_tok)
-        )
-    if not (
-        isinstance(model.shared_expert_dim, int) and model.shared_expert_dim >= 0
-    ):
-        raise ValueError(
-            "shared_expert_dim=%r: a width, or 0 for none"
-            % model.shared_expert_dim
-        )
-    for name in ("rope", "qk_norm", "remat_layers", "window_rope"):
-        if not isinstance(getattr(model, name), bool):
-            raise ValueError(
-                "%s=%r is neither True nor False" % (name, getattr(model, name))
-            )
-    if WINDOW in pattern:
-        if not (
-            isinstance(model.attention_window, int)
-            and model.attention_window > 0
-        ):
-            raise ValueError(
-                "layer_pattern %r holds a window layer: attention_window=%r "
-                "has to be a positive whole number"
-                % (pattern, model.attention_window)
-            )
-    elif model.attention_window or not model.window_rope:
-        raise ValueError(
-            "layer_pattern %r holds no window layer (%r), so "
-            "attention_window=%r and window_rope=%r say nothing"
-            % (pattern, WINDOW, model.attention_window, model.window_rope)
-        )
-    attends = bool({ATTENTION, SELECTING, WINDOW} & set(pattern))
-    if not attends and not (
-        model.rope and model.qk_norm and not model.attention_scale
-    ):
-        raise ValueError(
-            "layer_pattern %r holds no attention layer, so rope, qk_norm "
-            "and attention_scale say nothing" % pattern
-        )
-    if model.attention_scale < 0 or not math.isfinite(model.attention_scale):
-        raise ValueError(
-            "attention_scale=%r: a positive number, or 0 for head_dim ** -0.5"
-            % model.attention_scale
-        )
-    for name in ("embedding_multiplier", "residual_multiplier", "logits_scaling"):
-        value = getattr(model, name)
-        if not (value > 0 and math.isfinite(value)):
-            raise ValueError("%s=%r is not a positive number" % (name, value))
-    if model.routing not in ROUTINGS:
-        raise ValueError(
-            "routing %r is not one of %s" % (model.routing, ", ".join(ROUTINGS))
-        )
-    if model.router_input not in ROUTER_INPUTS:
-        raise ValueError(
-            "router_input %r is not one of %s"
-            % (model.router_input, ", ".join(ROUTER_INPUTS))
-        )
-    if model.expert_act not in expert.EXPERT_ACTS:
-        raise ValueError(
-            "expert_act %r is not one of %s"
-            % (model.expert_act, ", ".join(expert.EXPERT_ACTS))
-        )
-    if model.num_dense_layers == len(pattern) and (
-        model.router_input != ROUTER_INPUTS[0]
-        or model.expert_act != expert.EXPERT_ACTS[0]
-    ):
-        raise ValueError(
-            "layer_pattern %r holds no expert layer, so router_input and "
-            "expert_act say nothing" % pattern
-        )
-    if model.num_dense_layers == len(pattern) and (
-        model.shared_expert_dim or groups > 1
-    ):
-        raise ValueError(
-            "layer_pattern %r holds no expert layer, so shared_expert_dim "
-            "and the expert groups say nothing" % pattern
-        )
-    if model.expert_apply not in EXPERT_APPLIES:
-        raise ValueError(
-            "expert_apply %r is not one of %s"
-            % (model.expert_apply, ", ".join(EXPERT_APPLIES))
-        )
-    if SELECTING in pattern and not (
-        model.select_topk > 0 and model.indexer_heads > 0 and model.indexer_dim > 0
-    ):
-        raise ValueError(
-            "layer_pattern %r selects keys: select_topk=%r, indexer_heads=%r "
-            "and indexer_dim=%r all have to be positive"
-            % (pattern, model.select_topk, model.indexer_heads, model.indexer_dim)
-        )
-    if not 0 < model.num_experts_per_tok <= model.num_experts:
-        raise ValueError(
-            "num_experts_per_tok=%r is not between 1 and num_experts=%r"
-            % (model.num_experts_per_tok, model.num_experts)
-        )
-    if not 0 <= model.num_dense_layers <= len(pattern):
-        raise ValueError("num_dense_layers outside the pattern")
-    if model.num_heads % model.num_kv_heads:
-        raise ValueError("num_heads is not a multiple of num_kv_heads")
-    if not (
-        0 < model.experts_held
-        and 0 <= model.first_expert_held
-        and model.first_expert_held + model.experts_held <= model.num_experts
-    ):
-        raise ValueError("the experts held are not among num_experts")
     return model

@@ -5,7 +5,7 @@ three multipliers, per-layer recomputation, a stack with no expert
 layer) against the plain reference the benchmark keeps
 (benchmark/reference/granite_hybrid_reference.py, loaded by path as
 ``benchmark/spec.load_reference`` loads it): float32, toy widths, on the
-CPU. And the two models the module ran before, held to what the parent
+CPU. And the five models the module runs, held to what the parent
 commit computed for them, bit for bit."""
 
 import hashlib
@@ -495,6 +495,27 @@ BEFORE = {
         routing="softmax", select_topk=16, indexer_heads=4, indexer_dim=8,
         tie_head=False, expert_apply="masked", rope_theta=1e7, norm_eps=1e-6,
     ),
+    # the toy sizes of this file, of tests/test_smallthinker_lm.py and of
+    # tests/test_ling_linear_lm.py (the vocabulary the tokens are drawn from)
+    "granite": dict(TOY, vocab_size=256, remat_layers=True),
+    "smallthinker": dict(
+        vocab_size=256, layer_pattern="awww", num_dense_layers=0, embed_dim=64,
+        num_heads=4, num_kv_heads=2, head_dim=16, expert_dim=32, num_experts=16,
+        experts_held=4, first_expert_held=4, num_experts_per_tok=3,
+        routing="softmax", router_input="operator_norm", expert_act="relu",
+        expert_apply="masked", attention_window=12, rope=False, window_rope=True,
+        qk_norm=False, tie_head=False, rope_theta=1.5e6, norm_eps=1e-6,
+    ),
+    "ling": dict(
+        vocab_size=256, layer_pattern="kkkl", num_dense_layers=1, embed_dim=64,
+        num_heads=4, mlp_dim=96, expert_dim=32, num_experts=32, experts_held=4,
+        first_expert_held=8, num_experts_per_tok=4, num_expert_groups=4,
+        expert_groups_per_tok=2, shared_expert_dim=24, routing="sigmoid_bias",
+        routed_scaling_factor=2.5, kda_heads=4, kda_head_dim=16,
+        kda_conv_kernel=4, kda_gate_lower_bound=-5.0, kda_chunk=16,
+        mla_kv_rank=24, mla_nope_dim=16, mla_rope_dim=8, mla_v_dim=16,
+        tie_head=False, rope_theta=6e6, norm_eps=1e-6, remat_layers=True,
+    ),
 }  # fmt: skip
 
 
@@ -516,10 +537,11 @@ def _canary():
 
 def outputs_of(zoo_file):
     """The initial table, the logits, the loss and each gradient
-    leaf's bytes of the two toy models, from the module at
-    ``zoo_file``. ``python tests/test_granite_hybrid_lm.py <checkout of
-    the parent>`` wrote tests/data/hybrid_lm_parent_outputs.json with
-    it."""
+    leaf's bytes of the five toy models, and what each says of its
+    layout (``step_facts``, without a batch and with one), from the
+    module at ``zoo_file``. ``python tests/test_granite_hybrid_lm.py
+    <checkout of the parent>`` wrote
+    tests/data/hybrid_lm_parent_outputs.json with it."""
     zoo = model_utils.load_module(zoo_file)
     held = {"canary": _canary()}
     for name, sizes in BEFORE.items():
@@ -541,6 +563,9 @@ def outputs_of(zoo_file):
         held[name + ".logits"] = _digest(logits)
         for path, leaf in jax.tree_util.tree_leaves_with_path(grads):
             held[name + ".grad" + jax.tree_util.keystr(path)] = _digest(leaf)
+        for key, batch in ((".step_facts", None), (".step_facts_of_a_batch", tokens)):
+            facts = json.dumps(model.step_facts(batch), sort_keys=True)
+            held[name + key] = hashlib.sha256(facts.encode()).hexdigest()
     return held
 
 
@@ -564,9 +589,9 @@ def outputs_now():
 
 @pytest.mark.parametrize("name", sorted(k for k in PARENT if k != "canary"))
 def test_the_models_before_compute_what_the_parent_computed(outputs_now, name):
-    """The new switches at their defaults: ``lfm2``- and ``keye``-shaped
-    toy models give the parent commit's initial values, logits, loss and
-    gradients, bit for bit."""
+    """Every model the module runs, toy-sized: the parent commit's
+    initial values, logits, loss, gradients and ``step_facts``, bit for
+    bit."""
     assert outputs_now[name] == PARENT[name]
 
 

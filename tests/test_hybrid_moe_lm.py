@@ -5,6 +5,7 @@ loaded by path as ``benchmark/spec.load_reference`` loads it): float32,
 toy widths, on the CPU; and one toy job through ``edl train`` whose
 events carry the routing counters."""
 
+import dataclasses
 import functools
 import importlib.util
 import json
@@ -475,6 +476,97 @@ def test_the_dense_lm_keeps_its_rotary_base(zoo):
 def test_a_layout_that_cannot_be_built_is_refused(zoo, sizes, message):
     with pytest.raises(ValueError, match=message):
         zoo.custom_model(**dict(TOY, **sizes))
+
+
+# ---------------------------------------------------------------------------
+# the records: every kind of layer, walked by the one rule
+# ---------------------------------------------------------------------------
+
+SMALL = dict(vocab_size=64, embed_dim=32, num_heads=4, num_kv_heads=2, head_dim=8)
+# a stack that holds the kind (its sizes admissible), and what no other
+# stack of these shares a size with: one that holds none of it
+WITH = {
+    "c": dict(layer_pattern="c"),
+    "m": dict(
+        layer_pattern="m", ssm_heads=4, ssm_head_dim=8, ssm_state=8, ssm_groups=2,
+        ssm_conv_kernel=4, ssm_chunk=8,
+    ),
+    "a": dict(layer_pattern="a"),
+    "s": dict(layer_pattern="s", select_topk=4),
+    "w": dict(layer_pattern="w", attention_window=4),
+    "k": dict(
+        layer_pattern="k", kda_heads=2, kda_head_dim=16, kda_conv_kernel=4,
+        kda_gate_lower_bound=-5.0, kda_chunk=16,
+    ),
+    "l": dict(
+        layer_pattern="l", mla_kv_rank=8, mla_nope_dim=8, mla_rope_dim=4, mla_v_dim=8
+    ),
+    "dense": dict(layer_pattern="c", num_dense_layers=1),
+    "expert": dict(layer_pattern="c", num_dense_layers=0),
+}  # fmt: skip
+WITHOUT = dict(
+    WITH, c=WITH["a"], a=WITH["c"], s=WITH["c"], w=WITH["c"], m=WITH["c"],
+    k=WITH["c"], l=WITH["c"], dense=WITH["expert"], expert=WITH["dense"],
+)  # fmt: skip
+
+
+def _record(zoo, kind):
+    return {"dense": zoo.DENSE_FF, "expert": zoo.EXPERT_FF}.get(kind) or zoo.KINDS[kind]
+
+
+def _another(value):
+    """A value other than the default ``value``, of its type."""
+    if isinstance(value, bool):
+        return not value
+    return "another" if isinstance(value, str) else value + 1
+
+
+def _inadmissible(value):
+    if isinstance(value, (bool, str)):
+        return "neither"
+    return -1 if isinstance(value, int) else float("nan")
+
+
+def test_every_letter_and_every_field_has_its_place(zoo):
+    """A record a letter, and of the 55 values ``custom_model`` takes
+    each is one record's own or shared: by the three kinds of grouped
+    attention, or by everything."""
+    assert list(WITH) == list(zoo.KINDS) + ["dense", "expert"]
+    assert zoo.LETTERS == {letter: kind.says for letter, kind in zoo.KINDS.items()}
+    assert all(zoo.LETTERS.values())
+    fields = {f.name for f in dataclasses.fields(zoo.HybridMoELM)} - {"parent", "name"}
+    assert len(fields) == 55
+    owners = {}
+    for kind in WITH:
+        for size in _record(zoo, kind).sizes:
+            owners.setdefault(size.name, []).append(kind)
+    assert set(owners) <= fields
+    assert {name: kinds for name, kinds in owners.items() if len(kinds) > 1} == {
+        "rope": ["a", "s"], "qk_norm": ["a", "s", "w"], "attention_scale": ["a", "s", "w"],
+    }  # fmt: skip
+    shared = {size.name for size in zoo.SHARED}
+    for kind in WITH:
+        shared |= set(_record(zoo, kind).reads)
+    assert shared <= fields and not shared & set(owners)
+
+
+@pytest.mark.parametrize("kind", list(WITH))
+def test_a_kind_builds_is_checked_and_says_nothing_where_it_is_not(zoo, kind):
+    record = _record(zoo, kind)
+    model = zoo.custom_model(**SMALL, **WITH[kind])
+    tokens = jnp.zeros((1, 16), jnp.int32)
+    variables = model.init(jax.random.PRNGKey(0), {"tokens": tokens})
+    assert any(key.endswith("_" + record.name) for key in variables["params"])
+    assert model.apply(variables, {"tokens": tokens}).shape == (1, 16, 64)
+    assert record.sizes
+    for size in record.sizes:
+        default = getattr(zoo.HybridMoELM, size.name)
+        with pytest.raises(ValueError, match=size.name + ".* say nothing"):
+            zoo.custom_model(**SMALL, **WITHOUT[kind], **{size.name: _another(default)})
+        if size.check:
+            given = dict(WITH[kind], **{size.name: _inadmissible(default)})
+            with pytest.raises(ValueError, match="holds .*: .*" + size.name):
+                zoo.custom_model(**SMALL, **given)
 
 
 # ---------------------------------------------------------------------------

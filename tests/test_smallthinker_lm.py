@@ -345,7 +345,7 @@ def test_step_facts_cover_the_window_the_router_and_the_activation(zoo):
     # an earlier model says nothing new
     plain = zoo.custom_model(vocab_size=64).step_facts({"tokens": np.zeros((2, 16))})
     assert not {"window_layers", "router_input", "expert_act"} & set(plain)
-    assert zoo.KEPT_OF_OPERATOR[zoo.WINDOW] == 4
+    assert zoo.KINDS["w"].kept == 4
     kept_products = zoo.custom_model(**dict(TOY, remat_layers=True)).step_facts()
     assert kept_products["remat_kept_products"] == 16
 
