@@ -67,6 +67,7 @@ from elasticdl_tpu.parallel.sharding import tp_degree_candidates
 from elasticdl_tpu.training.step import (
     TrainState,
     accumulate_gradients,
+    AUX_LOSS_COLLECTION,
     aux_loss_total,
     block_device_losses,
 )
@@ -1258,11 +1259,31 @@ class ElasticDPTrainer:
         the same ``fetch`` phase: it waits for the window's last step."""
         from elasticdl_tpu.parallel.expert import MOE_STATE_COLLECTION
 
+        return self._host_copy_of(MOE_STATE_COLLECTION)
+
+    def _host_copy_of(self, collection):
+        """A host copy of one collection of the model's state, charged
+        to the ``fetch`` phase; None between worlds and for a model
+        that keeps none."""
         state = None if self._ts is None else self._ts.state
-        if not isinstance(state, dict) or MOE_STATE_COLLECTION not in state:
+        if not isinstance(state, dict) or collection not in state:
             return None
         with profiling.phases.measure("fetch"):
-            return host_copy(state[MOE_STATE_COLLECTION])
+            return host_copy(state[collection])
+
+    def aux_losses(self):
+        """A host copy of what the model wrote to its ``aux_loss``
+        collection in the last step (``training/step.py``: every step
+        builder adds the collection to the loss), ``{leaf's own name:
+        value}``, leaves of one name summed; nothing between worlds and
+        for a model that writes none. Asked once a window beside
+        :meth:`routing_state`, under the same ``fetch`` phase."""
+        parts = {}
+        held = self._host_copy_of(AUX_LOSS_COLLECTION)
+        for path, leaf in jax.tree_util.tree_leaves_with_path(held or {}):
+            name = str(getattr(path[-1], "key", path[-1]))
+            parts[name] = parts.get(name, 0.0) + float(np.sum(leaf))
+        return parts
 
     def _most_on_a_device(self, stat):
         """The largest ``memory_stats()[stat]`` over the mesh's local

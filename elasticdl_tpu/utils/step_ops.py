@@ -18,6 +18,10 @@ is tested on the CPU at no cost):
   that runs as an op of its own (entry, loop bodies and conditions,
   branches, called computations) with the classes it holds; a fusion
   holds those of every instruction fused into it;
+- :func:`ops_under`: the same walk for one ``jax.named_scope`` of a
+  model's (``edl/mtp``): the instructions that lie under it whole
+  (``in``) and those the compiler fused with work from outside it
+  (``in+out``);
 - :func:`split_by_class`: the trace's ops of the train-step module +
   that map -> self time by class and by op.
 
@@ -154,7 +158,44 @@ def _computations(hlo_text):
     return entry, computations
 
 
-def op_classes(hlo_text):
+def inside(scope):
+    """A rule like :func:`classify` for ONE named scope: a name stack
+    is ``in`` where ``scope`` is a whole part of its path and ``out``
+    where it is not; an instruction without a name says nothing."""
+    part = re.compile(r"(^|[/(])" + re.escape(scope) + r"($|[/)])")
+
+    def membership(op_name):
+        return frozenset(
+            "in" if part.search(stack) else "out"
+            for stack in op_name.split(";")
+            if stack
+        )
+
+    return membership
+
+
+def model_scopes(hlo_text):
+    """The named scopes of ours a module's text holds but for the two
+    that are classes (``edl/mtp``, ``edl/mla``, ...), sorted."""
+    found = set(re.findall(r"[/(](edl/[a-z_0-9]+)[/)\"]", hlo_text))
+    return sorted(found - {OPTIMIZER_SCOPE, REDUCE_SCOPE})
+
+
+def ops_under(hlo_text, scope, parsed=None):
+    """``{instruction: "in" | "in+out"}`` of the instructions that run
+    as ops of their own and hold work under ``scope``: ``in`` where
+    every name stack of theirs (a fusion's: of everything fused into
+    it) lies under it, ``in+out`` where the compiler fused work from
+    outside it in. A reader sums the first and never divides the
+    second."""
+    return {
+        name: held
+        for name, held in op_classes(hlo_text, inside(scope), parsed).items()
+        if "in" in held.split("+")
+    }
+
+
+def op_classes(hlo_text, classify=classify, parsed=None):
     """``{instruction: classes}`` of an optimized module's text, the
     classes joined (:func:`joined`; ``""`` where the rule classes
     nothing), for every instruction that runs as an op of its own: those
@@ -165,8 +206,9 @@ def op_classes(hlo_text):
     their own (a fusion's ``calls=``, a reduction's ``to_apply=``,
     followed to any depth), so no fused computation's inner instruction
     is a key. Instruction names are unique in a module, so one flat
-    dict serves loops too."""
-    entry, computations = _computations(hlo_text)
+    dict serves loops too. ``parsed``, where given, is
+    ``_computations(hlo_text)``, read once for several rules."""
+    entry, computations = parsed or _computations(hlo_text)
     part_classes = {}  # computation -> classes of everything inside it
 
     def classes_inside(computation):
@@ -203,12 +245,22 @@ def op_classes(hlo_text):
 
 def step_ops_map(hlo_text):
     """What a traced process writes beside its trace (:data:`FILE_NAME`),
-    ``{"module": name, "ops": {instruction: classes}}`` with only the
-    instructions the rule classes, and how many instructions run in
-    all (``step_built`` says both counts)."""
-    classes = op_classes(hlo_text)
+    ``{"module": name, "ops": {instruction: classes}, "scopes": {scope:
+    {instruction: "in" | "in+out"}}}`` with only the instructions the
+    rule classes, the model's named scopes the text holds
+    (:func:`model_scopes`, :func:`ops_under`), and how many
+    instructions run in all (``step_built`` says both counts)."""
+    parsed = _computations(hlo_text)
+    classes = op_classes(hlo_text, parsed=parsed)
     ops = {name: c for name, c in classes.items() if c}
-    return {"module": module_name(hlo_text), "ops": ops}, len(classes)
+    scopes = {
+        scope: ops_under(hlo_text, scope, parsed)
+        for scope in model_scopes(hlo_text)
+    }
+    return (
+        {"module": module_name(hlo_text), "ops": ops, "scopes": scopes},
+        len(classes),
+    )
 
 
 def instruction_name(event_name):

@@ -1012,8 +1012,12 @@ class ElasticAllReduceWorker:
         worker's phases"): ``<phase>_s`` for each of
         ``profiling.STEP_PHASES``, the single slowest call, the loop
         thread's CPU seconds, the devices' peak memory where the
-        backend reports it, and, of a model with held-share expert
-        layers, the window's routing counters (:meth:`_window_routing`).
+        backend reports it, of a model with held-share expert
+        layers the window's routing counters (:meth:`_window_routing`),
+        and, of a model that adds to its loss through the ``aux_loss``
+        collection, the window's last loss apart: ``lm_loss`` and each
+        part the model wrote (``mtp_loss``), which add up to
+        ``last_loss``.
         The same fields go to the span plane as one
         ``train/window`` span, so ``/trace`` and the flight recorder
         hold the last windows of a worker that died. Called at sync
@@ -1031,6 +1035,13 @@ class ElasticAllReduceWorker:
         # with the loss drain, inside the window it closes: its wait
         # for the window's last step is this window's ``fetch``
         routing = self._window_routing()
+        # the last step's loss apart, of a model that adds to it
+        # through ``aux_loss``: each part under the name the model
+        # wrote it by (``mtp_loss``), and ``lm_loss``, what is left
+        # (every zoo model that writes one is a language model)
+        parts = self.trainer.aux_losses()
+        if parts:
+            parts["lm_loss"] = float(window[-1]) - sum(parts.values())
         now, cpu = time.time(), time.thread_time()
         if self._window_t0 is None:  # no step was ever built
             self._window_t0, self._window_cpu0 = now, cpu
@@ -1052,6 +1063,7 @@ class ElasticAllReduceWorker:
                 state_on_devices=self.trainer.state_device_coverage(),
                 **account,
                 **routing,
+                **parts,
             )
             profiling.events.emit("train_window", **fields)
             if profiling.metrics_enabled():

@@ -2,7 +2,9 @@
 state-space layers and Kimi-Delta-Attention layers beside grouped-head
 attention, full, within a window or over a learned selection of keys,
 and latent attention (MLA), a dense SwiGLU MLP in the leading layers and
-a mixture of experts in the rest, of which this device holds a share.
+a mixture of experts in the rest, of which this device holds a share,
+and, where asked for, one multi-token-prediction module behind the last
+layer.
 
 The layer stack is built from one pattern string, a letter a layer:
 ``c`` a gated short convolution, ``m`` a Mamba-2 state-space layer,
@@ -24,6 +26,43 @@ the others the expert layer. With ``h = RMSNorm(x)`` (weight only) and
 
 The three multipliers are 1 by default and then multiply nothing.
 Embedding and head are over the slice of the vocabulary held here.
+
+``mtp_layers=1`` adds the multi-token-prediction module of DeepSeek-V3
+(arXiv:2412.19437 section 2.2) at depth 1. With ``x`` the last layer's
+output IN FRONT of the final norm, ``tok`` the input ids and ``L`` the
+length:
+
+    u_i = [RMSNorm_h(x_i) ; RMSNorm_e(E[tok_{i+1}])] W_M     (W_M: 2 embed_dim x embed_dim, no bias)
+    y = Layer(u)         one more layer of the kind of the pattern's LAST
+                         (operator and feed-forward half), weights,
+                         router, selection bias and counters its own
+    logits1_i = RMSNorm_m(y_i) W_head / logits_scaling    (the trunk's own head; tied: E^T)
+    L_mtp = mean over i = 0..L-3 of CE(logits1_i, tok_{i+2})
+
+and a training step descends ``L_lm + mtp_loss_weight * L_mtp``. The
+embedding and the head are SHARED with the trunk and nothing is
+stopped: the module's loss reaches the trunk, the table and the head.
+The module runs only where a loss is asked for (``training=True``, and
+when the variables are made); a prediction or evaluation forward
+returns the trunk's logits and computes nothing of it. Its weighted
+loss goes where a step builder looks for it, the ``aux_loss``
+collection (``training/step.py``: every builder adds the collection to
+the loss it differentiates), under the name ``mtp_loss``; a training
+forward that was handed no such collection (``model.apply({"params":
+...}, ..., training=True)``, the comparison's and the tests') returns
+``LogitsAndLoss(logits, mtp_loss)`` instead, which this module's
+``loss`` adds up. The module's layer runs all ``L`` positions, so that
+the attention kernels tile (``L - 1`` does not divide by 128): the last
+position reads the embedding of the sequence's FIRST token where there
+is no next one, causal attention and a per-token feed-forward keep it
+from every other position, no loss reads it, and its assignments are
+counted in the module's routing counters (one token in ``L``). The
+module's logits are float32 and never exist whole: the head and the
+softmax go ``2,048`` positions at a time (``_mean_cross_entropy``).
+Under ``remat_layers`` the module is one more rematerialised unit, its
+layer's products kept as any layer's, its projection and its pass
+through the head recomputed. Modules chained one behind the other
+(depth 2 and more) are not built.
 ``remat_layers``: the backward pass keeps a layer's input and the
 results of its products against weight matrices, but for an operator's
 input projection, and recomputes the rest (``jax.checkpoint`` a layer
@@ -31,7 +70,10 @@ that keeps what is named ``KEPT``; selective recomputation, Korthikanti
 et al., arXiv:2205.05198, section 5). Kept: ``out_proj`` of a ``c`` or
 ``m`` layer, ``out`` of a ``k`` layer (its five input projections are
 recomputed, as ``in_proj`` is), ``q``, ``k``, ``v`` and ``o`` of an
-attention layer, the four products of an ``l`` layer,
+attention layer, the four products of an ``l`` layer (five with the
+low-rank query: ``q_down``'s is kept like the others, 12.6 MB a layer
+at 8,192 positions of 768, where recomputing it is a product of 26
+GFLOP: the rule stays "a product against a weight matrix is kept"),
 ``W_1`` and ``W_3`` of the dense FF (``W_2``'s result is needed by
 nothing): ``2 (embed_dim + 2 mlp_dim)`` bytes a token a ``c`` or ``m``
 layer in bf16 (37 KB at granite-4.0-h-micro's widths). Recomputed:
@@ -85,7 +127,9 @@ forward, which does not lower the peak.
   learned norm weight of ``D`` shared by the heads). Initial ``A_log``
   the log of a uniform draw in [1, 16], ``dt_bias`` as Mamba-2's.
 - ``l``: ``q = h W_q`` (``num_heads`` x ``[mla_nope_dim |
-  mla_rope_dim]``); ``[c | k_rope] = h W_kva`` (``mla_kv_rank |
+  mla_rope_dim]``), or, with ``mla_q_rank`` R > 0, through a latent of
+  the query's own: ``c_q = RMSNorm(h W_qa)`` (R wide, a learned
+  weight), ``q = c_q W_qb``; ``[c | k_rope] = h W_kva`` (``mla_kv_rank |
   mla_rope_dim``), ``c <- RMSNorm(c)``; ``[k_nope | v] = c W_kvb``
   (``num_heads`` x ``[mla_nope_dim | mla_v_dim]``); ``q_rope`` and
   ``k_rope`` rotated with base ``rope_theta``, ``k_rope`` ONE head that
@@ -94,8 +138,9 @@ forward, which does not lower the peak.
   concat(heads) W_o``. The expanded form a latent attention is TRAINED
   in (DeepSeek-V2, arXiv:2405.04434 section 2.1): the kernels of
   ops/flash_attention.py take q and k of one head size and v of
-  another. The absorbed form and a cache of latents belong to a
-  serving path, which this model has none of.
+  another; where the three are equally wide (192 + 64 and 256) the
+  call is the plain kernels'. The absorbed form and a cache of latents
+  belong to a serving path, which this model has none of.
 - ``a``: ``num_heads`` query heads over ``num_kv_heads`` key/value
   heads, a learned RMSNorm over each head of q and of k (``qk_norm``,
   on by default), rotary positions of base ``rope_theta`` (``rope``, on
@@ -199,13 +244,13 @@ from elasticdl_tpu.ops.flash_attention import (
     window_pairs,
 )
 from elasticdl_tpu.parallel import expert
+from elasticdl_tpu.training.step import AUX_LOSS_COLLECTION
 
 _lm = load_module(
     os.path.join(
         os.path.dirname(os.path.abspath(__file__)), "transformer_lm.py"
     )
 )
-loss = _lm.loss
 dataset_fn = _lm.dataset_fn
 eval_metrics_fn = _lm.eval_metrics_fn
 
@@ -218,6 +263,26 @@ ROUTER_INPUTS = ("ffn_norm", "operator_norm")
 KEPT = "weight_product"
 # every parameter of an indexer lies under a module of this name
 INDEXER = "indexer"
+
+
+class LogitsAndLoss(NamedTuple):
+    """What a training forward of a model with a prediction module
+    returns where it was handed no ``aux_loss`` collection to write
+    (``model.apply({"params": ...}, ..., training=True)``, as the
+    comparison with the plain reference and the tests make it)."""
+
+    logits: jax.Array
+    mtp_loss: jax.Array  # ``mtp_loss_weight`` times the module's loss
+
+
+def loss(output, labels):
+    """The sibling LM's next-token cross entropy, and with it what a
+    prediction module's loss came back as beside the logits. (Inside a
+    training step it comes through the ``aux_loss`` collection instead,
+    and the step builder adds it.)"""
+    if isinstance(output, LogitsAndLoss):
+        return _lm.loss(output.logits, labels) + output.mtp_loss
+    return _lm.loss(output, labels)
 
 
 def optimizer(lr=3e-3):
@@ -264,6 +329,34 @@ def _per_expert_init():
 
 def _kept(product):
     return checkpoint_name(product, KEPT)
+
+
+def _mean_cross_entropy(y, head, scaling, targets, counted, rows=2048):
+    """The mean, over the positions ``counted``, of the cross entropy of
+    ``y head / scaling`` against ``targets``: ``y`` (B, L, d) in the
+    model's dtype, ``head`` (d, V) a parameter as it is held. ``rows``
+    positions at a time, each block rematerialised, so that of the
+    (B L, V) logits one block is alive at a time, in the forward pass
+    and in the backward (a length that ``rows`` does not divide, the
+    tests', goes whole). The head is cast inside the block: its
+    gradient adds up over the blocks in the parameter's own float32.
+    The logits and the softmax are float32."""
+    d = y.shape[-1]
+    block = rows if (y.shape[0] * y.shape[1]) % rows == 0 else y.shape[0] * y.shape[1]
+
+    @jax.checkpoint
+    def summed(y, targets, counted):
+        logits = jnp.dot(y, head.astype(y.dtype)).astype(jnp.float32)
+        if scaling != 1.0:
+            logits = logits / scaling
+        each = optax.softmax_cross_entropy_with_integer_labels(logits, targets)
+        return jnp.sum(jnp.where(counted, each, 0.0))
+
+    sums = jax.lax.map(
+        lambda block_of: summed(*block_of),
+        (y.reshape(-1, block, d), targets.reshape(-1, block), counted.reshape(-1, block)),
+    )
+    return jnp.sum(sums) / jnp.sum(counted)
 
 
 def _causal_depthwise_conv(u, taps):
@@ -401,7 +494,12 @@ def _read_together(x, *others):
     published widths, which holds the reference's pass beside this one:
     14.27 GiB at the peak with it, 17.85 of the chip's 15.75 without
     (``tests/test_ling_linear_lm.py``, the slow case). The training
-    step alone does not need it (13.96 GB with it, 13.81 without). An
+    step alone does not need it (13.96 GB with it, 13.81 without). A
+    latent attention's products read their gradients together too
+    (``_prompt_dot_general``, PR 46): six such layers at 2 x 8,192
+    positions and their reference compile to 14.44 GiB with it and are
+    refused without (16.03 GB of 15.75 GiB, with every other saving in
+    place; ``tests/test_glm_mla_mtp_lm.py``, the slow case). An
     ``optimization_barrier`` over the pair does not help: it binds the
     optimizer's passes, not the scheduler."""
     return (x,) + others
@@ -416,6 +514,16 @@ def _read_together_bwd(_, gradients):
 _read_together.defvjp(
     lambda x, *others: ((x,) + others, None), _read_together_bwd
 )
+
+
+def _prompt_dot_general(lhs, rhs, dimension_numbers, precision=None, **more):
+    """``lax.dot_general`` for a flax ``Dense`` or ``DenseGeneral``
+    (their ``dot_general``) whose input's gradient waits for the
+    kernel's (:func:`_read_together`)."""
+    lhs, rhs = _read_together(lhs, rhs)
+    return jax.lax.dot_general(
+        lhs, rhs, dimension_numbers, precision=precision, **more
+    )
 
 
 class PromptDense(nn.Module):
@@ -515,8 +623,10 @@ class KimiDeltaAttention(nn.Module):
 class LatentAttention(nn.Module):
     """Latent attention (MLA) in its expanded form: keys and values
     come up out of one normed low-rank latent, the rotated part of the
-    key is one head that every query head reads, and q and k are wider
-    than v."""
+    key is one head that every query head reads, q and k are as wide
+    as v or wider, and with ``q_rank`` the query comes up out of a
+    normed latent of its own. Every product's input hands its gradient
+    on after the kernel's (``_prompt_dot_general``)."""
 
     num_heads: int
     kv_rank: int
@@ -527,6 +637,7 @@ class LatentAttention(nn.Module):
     norm_eps: float
     dtype: Any
     use_flash: bool
+    q_rank: int = 0
 
     @nn.compact
     def __call__(self, h, positions):
@@ -536,22 +647,31 @@ class LatentAttention(nn.Module):
                     features=(self.num_heads, width),
                     use_bias=False,
                     dtype=self.dtype,
+                    dot_general=_prompt_dot_general,
                     name=name,
                 )(x)
             )
 
-        q = heads(self.nope_dim + self.rope_dim, "query", h)
-        down = _kept(
-            nn.Dense(
-                self.kv_rank + self.rope_dim,
-                use_bias=False,
-                dtype=self.dtype,
-                name="kv_down",
-            )(h)
-        )
-        latent = nn.RMSNorm(
-            epsilon=self.norm_eps, dtype=self.dtype, name="kv_norm"
-        )(down[..., : self.kv_rank])
+        def down_to(width, name):
+            return _kept(
+                nn.Dense(
+                    width, use_bias=False, dtype=self.dtype,
+                    dot_general=_prompt_dot_general, name=name,
+                )(h)
+            )
+
+        def normed(name, x):
+            return nn.RMSNorm(
+                epsilon=self.norm_eps, dtype=self.dtype, name=name
+            )(x)
+
+        q_from = h
+        if self.q_rank:
+            # the query through a latent of its own
+            q_from = normed("q_norm", down_to(self.q_rank, "q_down"))
+        q = heads(self.nope_dim + self.rope_dim, "query", q_from)
+        down = down_to(self.kv_rank + self.rope_dim, "kv_down")
+        latent = normed("kv_norm", down[..., : self.kv_rank])
         up = heads(self.nope_dim + self.v_dim, "kv_up", latent)
         k_nope, v = up[..., : self.nope_dim], up[..., self.nope_dim :]
         rotated = lambda x: _lm._rotary(x, positions, self.rope_theta)
@@ -570,6 +690,7 @@ class LatentAttention(nn.Module):
                 axis=(-2, -1),
                 use_bias=False,
                 dtype=self.dtype,
+                dot_general=_prompt_dot_general,
                 name="out",
             )(attn)
         )
@@ -891,6 +1012,18 @@ _EQUAL_GROUPS = _check(
     "num_experts={model.num_experts!r} in groups of equal size, of which "
     "between one and all stay",
 )
+_NONE_OR_ONE = _check(
+    lambda v, m: v in (0, 1) and not isinstance(v, bool),
+    "{name}={value!r}: 0 for no prediction module, or 1 (modules chained "
+    "one behind the other are not built)",
+)
+_WEIGHS_A_MODULE = _check(
+    lambda v, m: v >= 0 and math.isfinite(v),
+    "{name}={value!r} is not a weight: a number, 0 or more",
+) + _check(
+    lambda v, m: m.mtp_layers or v == HybridMoELM.mtp_loss_weight,
+    "{name}={value!r} says nothing: mtp_layers=0, no prediction module",
+)
 _INSIDE_PATTERN = _check(
     lambda v, m: 0 <= v <= len(m.layer_pattern),
     "num_dense_layers outside the pattern",
@@ -987,10 +1120,15 @@ class Kind:
     # a feed-forward half: the operator's normed input) -> the result
     call: Callable = lambda model, operator, h, other: operator(h)
     scope: str = ""  # the named scope the model wraps it in
-    kept: int = 1  # how many of its results are named ``KEPT``
+    # how many of its results are named ``KEPT``: a number, or a
+    # function of the model where that follows from a size
+    kept: Any = 1
     keeps_recurrence: bool = False  # ``kda.KEPT_NAMES`` beside them
     # (model, how many such layers, a batch or None) -> its step_facts
     facts: Callable = lambda model, layers, features: {}
+
+    def kept_by(self, model):
+        return self.kept(model) if callable(self.kept) else self.kept
 
     def held(self, layers):
         if not self.noun:
@@ -1148,16 +1286,19 @@ KINDS = {
             Size("mla_nope_dim", _WHOLE, "nope_dim"),
             Size("mla_rope_dim", _WHOLE + _EVEN, "rope_dim"),
             Size("mla_v_dim", _WHOLE, "v_dim"),
+            Size("mla_q_rank", _WIDTH_OR_NONE, "q_rank"),
         ),
         reads=("num_heads", "rope_theta", "norm_eps", "dtype", "use_flash"),
         call=_with_positions,
         scope="edl/mla",
-        kept=4,
+        # the low-rank query's down projection beside the four
+        kept=lambda model: 5 if model.mla_q_rank else 4,
         facts=lambda model, layers, features: _where(
             layers,
             mla_layers=layers,
             mla_qk_dim=model.mla_nope_dim + model.mla_rope_dim,
             mla_v_dim=model.mla_v_dim,
+            **_where(model.mla_q_rank, mla_q_rank=model.mla_q_rank),
         ),
     ),
 }
@@ -1210,17 +1351,22 @@ SHARED = (
     Size("routing", _one_of(ROUTINGS)),
     Size("num_experts_per_tok", _AMONG_EXPERTS),
     Size("experts_held", _HELD_AMONG_EXPERTS),
+    Size("mtp_layers", _NONE_OR_ONE),
+    Size("mtp_loss_weight", _WEIGHS_A_MODULE),
 )
 
 
 def _layers(model):
     """(letter, record, how many layers of ``model`` are of it) of
-    every record; the feed-forward halves have no letter."""
+    every record; the feed-forward halves have no letter. A prediction
+    module's layer is one more of the pattern's last."""
+    pattern = model.layer_pattern + model.layer_pattern[-1:] * model.mtp_layers
     dense = model.num_dense_layers
+    if dense == len(model.layer_pattern):  # the last layer's half is dense
+        dense += model.mtp_layers
     return [
-        (letter, kind, model.layer_pattern.count(letter))
-        for letter, kind in KINDS.items()
-    ] + [("", DENSE_FF, dense), ("", EXPERT_FF, len(model.layer_pattern) - dense)]
+        (letter, kind, pattern.count(letter)) for letter, kind in KINDS.items()
+    ] + [("", DENSE_FF, dense), ("", EXPERT_FF, len(pattern) - dense)]
 
 
 class HybridMoELM(nn.Module):
@@ -1277,6 +1423,9 @@ class HybridMoELM(nn.Module):
     residual_multiplier: float = 1.0
     logits_scaling: float = 1.0
     remat_layers: bool = False
+    mla_q_rank: int = 0
+    mtp_layers: int = 0
+    mtp_loss_weight: float = 0.3
     dtype: Any = jnp.float32
     use_flash: bool = True
 
@@ -1286,13 +1435,17 @@ class HybridMoELM(nn.Module):
         ``features``, where given, is a batch the step was built for:
         what follows from its sequence length is stated too."""
         facts = {"tie_head": int(self.tie_head)}
+        if self.mtp_layers:
+            facts.update(
+                mtp_layers=self.mtp_layers, mtp_loss_weight=self.mtp_loss_weight
+            )
         held = _layers(self)
         for _, record, layers in held:
             facts.update(record.facts(self, layers, features))
         if self.remat_layers:
             facts["remat_layers"] = 1
             facts["remat_kept_products"] = sum(
-                record.kept * layers for _, record, layers in held
+                record.kept_by(self) * layers for _, record, layers in held
             )
             recurrences = sum(
                 layers for _, record, layers in held if record.keeps_recurrence
@@ -1324,10 +1477,10 @@ class HybridMoELM(nn.Module):
                 out = out * jnp.asarray(self.residual_multiplier, out.dtype)
             return x + out
 
-        def layer(self, x, i):
-            """Layer ``i`` of the pattern, its variables under this
-            module by the layer's own names."""
-            name = "layer_%d_" % i
+        def built(self, x, i, name):
+            """A layer of the kind of the pattern's ``i``-th, its
+            variables under this module as ``name`` and the part's
+            own."""
             h = norm(name + "operator_norm")(x)
             operator = KINDS[self.layer_pattern[i]]
             x = joined(x, operator.apply(self, name, h, positions))
@@ -1335,28 +1488,81 @@ class HybridMoELM(nn.Module):
             ff = DENSE_FF if i < self.num_dense_layers else EXPERT_FF
             return joined(x, ff.apply(self, name, h, operator_h))
 
+        def layer(self, x, i):
+            return built(self, x, i, "layer_%d_" % i)
+
+        def predicted(self, x, after, head):
+            """The prediction module's loss (the module's docstring has
+            the equations): ``x`` the trunk's output in front of its
+            final norm, ``after`` the embedding of the token behind
+            each position, ``head`` (embed_dim, vocab_size). All ``L``
+            positions run, so that the kernels tile; the last one reads
+            a token that does not exist and nothing reads its result."""
+            name = "mtp_0_"
+            u = nn.Dense(
+                self.embed_dim, use_bias=False, dtype=self.dtype,
+                name=name + "proj",
+            )(
+                jnp.concatenate(
+                    [norm(name + "hidden_norm")(x), norm(name + "embed_norm")(after)],
+                    axis=-1,
+                )
+            )  # fmt: skip
+            y = built(self, u, len(self.layer_pattern) - 1, name)
+            y = norm(name + "final_norm")(y).astype(self.dtype)
+            # the last two positions have no token two places on
+            counted = positions < l - 2
+            return _mean_cross_entropy(
+                y, head, self.logits_scaling, jnp.roll(tokens, -2, axis=1), counted
+            )
+
         if self.remat_layers and not self.is_initializing():
-            # static_argnums counts the module: the layer's index
-            layer = nn.remat(
-                layer,
-                static_argnums=(2,),
+            keeps = dict(
                 policy=jax.checkpoint_policies.save_only_these_names(
                     KEPT, *kda.KEPT_NAMES
-                ),
+                )
             )
+            # static_argnums counts the module: the layer's index
+            layer = nn.remat(layer, static_argnums=(2,), **keeps)
+            predicted = nn.remat(predicted, **keeps)
         for i in range(len(self.layer_pattern)):
             x = layer(self, x, i)
-        x = norm("final_norm")(x)
+        trunk, x = x, norm("final_norm")(x)
         # the head over the slice of the vocabulary held here
         if self.tie_head:
             logits = embed_layer.attend(x.astype(jnp.float32))
         else:
-            logits = nn.Dense(
+            head = nn.Dense(
                 self.vocab_size, use_bias=False, dtype=self.dtype, name="head"
-            )(x)
+            )
+            logits = head(x)
         if self.logits_scaling != 1.0:
             logits = logits / jnp.asarray(self.logits_scaling, logits.dtype)
-        return logits
+        if not self.mtp_layers or not (training or self.is_initializing()):
+            return logits
+        with jax.named_scope("edl/mtp"):
+            after = embed_layer(jnp.roll(tokens, -1, axis=1))
+            if self.embedding_multiplier != 1.0:
+                after = after * jnp.asarray(self.embedding_multiplier, after.dtype)
+            weighed = self.mtp_loss_weight * predicted(
+                self,
+                trunk,
+                after,
+                embed_layer.embedding.T
+                if self.tie_head
+                else head.variables["params"]["kernel"],
+            )
+        if self.is_mutable_collection(AUX_LOSS_COLLECTION):
+            # a training step holds it: every step builder adds the
+            # collection to the loss it differentiates
+            held = self.variable(
+                AUX_LOSS_COLLECTION, "mtp_loss", lambda: jnp.zeros((), jnp.float32)
+            )
+            if not self.is_initializing():
+                held.value = weighed
+            return logits
+        # a training forward that was handed no state: ``loss`` adds it
+        return LogitsAndLoss(logits, weighed)
 
 
 def _listed(items):

@@ -1179,6 +1179,60 @@ def test_the_policy_hands_short_lengths_unequal_head_sizes_in_xla():
     )
 
 
+# head size 256: q, k and v of a latent attention equally wide
+# (glm-4.7-flash-ep8), so the call is the plain bodies', at a size no
+# cell ran before PR 46
+WIDE = 256
+WIDE_LENGTH = 2048
+
+
+@pytest.fixture(scope="module")
+def wide_both_ways():
+    """Forward and the three gradients at head size 256, causal, at the
+    tiles the policy picks for the length (``auto_blocks``: two 1,024
+    tiles each way, three with work, the diagonal's cut into sub-blocks
+    of ``sub_block(256)``), interpreted, and the same of XLA's path."""
+    rng = np.random.default_rng(17)
+    draw = lambda: rng.standard_normal((1, WIDE_LENGTH, 2, WIDE)).astype("float32")
+    q, k, v, weights = draw(), draw(), draw(), draw()
+
+    def both(fn):
+        out, pull = jax.vjp(fn, q, k, v)
+        return (out,) + pull(weights)
+
+    return (
+        both(lambda q, k, v: flash_attention(q, k, v, True)),
+        both(lambda q, k, v: reference_attention(q, k, v, causal=True)),
+    )
+
+
+@pytest.mark.parametrize("what", ["forward", "dq", "dk", "dv"])
+def test_head_size_256_matches_the_reference(wide_both_ways, what):
+    at = ["forward", "dq", "dk", "dv"].index(what)
+    got, want = wide_both_ways[0][at], wide_both_ways[1][at]
+    assert got.shape == (1, WIDE_LENGTH, 2, WIDE)
+    np.testing.assert_allclose(got, want, rtol=3e-4, atol=3e-5)
+
+
+def test_head_size_256_takes_the_plain_bodies_at_the_policys_tiles():
+    """Equal head sizes go under the plain names (the unequal bodies'
+    are a latent attention's with q and k wider than v), in tiles of
+    1,024 cut into sub-blocks of 256, as every head size measured."""
+    from elasticdl_tpu.ops import flash_attention as fa
+
+    assert fa.auto_blocks(WIDE_LENGTH, WIDE_LENGTH) == (1024, 1024)
+    assert fa.sub_block(WIDE) == 256
+    q = jax.ShapeDtypeStruct((1, WIDE_LENGTH, 2, WIDE), jax.numpy.bfloat16)
+    jaxpr = jax.make_jaxpr(
+        jax.grad(lambda q, k, v: flash_attention(q, k, v, True).sum(), argnums=(0, 1, 2))
+    )(q, q, q)
+    names = [
+        eqn.params["name"] for eqn in _every_eqn(jaxpr.jaxpr)
+        if eqn.primitive.name == "pallas_call"
+    ]  # fmt: skip
+    assert sorted(names) == sorted([FWD, DQ, DKV])
+
+
 if __name__ == "__main__":
     import importlib.util
     import json

@@ -304,6 +304,48 @@ def test_flash_kernels_under_a_selection_compile_for_the_v5e_at_the_cells_shape(
 
 
 @pytest.mark.parametrize(
+    "batch, tiles",
+    [
+        pytest.param(1, None, id="glm47flash-ep8-l8192"),
+        pytest.param(2, None, id="its-comparison"),
+        pytest.param(1, (512, 512), id="tiles-512x512"),
+    ],
+)
+def test_flash_kernels_at_head_size_256_compile_for_the_v5e_at_the_cells_shape(
+    one_chip, batch, tiles
+):
+    """`glm47flash-ep8-l8192`'s latent attention: 8,192 positions of 20
+    heads whose q, k and v are all 256 wide, so the call is the plain
+    bodies' at a head size none had run before PR 46: a q tile of 1,024
+    x 256, dkv's two f32 (1,024, 256) accumulators and the forward's
+    `[v | 1]` scratch of 384 lanes are Mosaic's to refuse (scoped VMEM),
+    and interpret mode refuses none of them."""
+    from elasticdl_tpu.ops import flash_attention as fa
+
+    x = jax.ShapeDtypeStruct((batch, 8192, 20, 256), jnp.bfloat16, sharding=one_chip)
+    tiles = tiles or fa.auto_blocks(8192, 8192)
+    assert fa.sub_block(256) == 256
+
+    def fwd_and_bwd(q, k, v, g):
+        out, lse = fa._flash_fwd(q, k, v, True, *tiles, False)
+        return out, fa._flash_bwd(q, k, v, out, lse, g, True, *tiles, False)
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        text = jax.jit(fwd_and_bwd).lower(x, x, x, x).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+    from elasticdl_tpu.utils import step_ops
+
+    ops = step_ops.op_classes(text)
+    assert sum(name.startswith("edl_flash") for name in ops) == 3, sorted(ops)
+    for name in (fa.FWD_KERNEL, fa.BWD_DQ_KERNEL, fa.BWD_DKV_KERNEL):
+        assert name in text
+    for name in fa.UNEQUAL.values():
+        assert name not in text
+
+
+@pytest.mark.parametrize(
     "batch, length, heads, d_qk, d_v, causal",
     [
         pytest.param(2, 4096, 32, 192, 128, True, id="ling3flash-ep64-l4096"),

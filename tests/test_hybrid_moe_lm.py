@@ -528,14 +528,16 @@ def _inadmissible(value):
 
 
 def test_every_letter_and_every_field_has_its_place(zoo):
-    """A record a letter, and of the 55 values ``custom_model`` takes
-    each is one record's own or shared: by the three kinds of grouped
-    attention, or by everything."""
+    """A record a letter, and of the 58 values ``custom_model`` takes
+    (PR 46: ``mla_q_rank``, the ``l`` record's own, and the prediction
+    module's ``mtp_layers`` and ``mtp_loss_weight``, shared) each is one
+    record's own or shared: by the three kinds of grouped attention, or
+    by everything."""
     assert list(WITH) == list(zoo.KINDS) + ["dense", "expert"]
     assert zoo.LETTERS == {letter: kind.says for letter, kind in zoo.KINDS.items()}
     assert all(zoo.LETTERS.values())
     fields = {f.name for f in dataclasses.fields(zoo.HybridMoELM)} - {"parent", "name"}
-    assert len(fields) == 55
+    assert len(fields) == 58
     owners = {}
     for kind in WITH:
         for size in _record(zoo, kind).sizes:

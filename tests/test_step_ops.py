@@ -282,6 +282,71 @@ def test_op_classes_of_a_written_module():
     assert "copy.8" not in ops_map["ops"] and ops_map["ops"]["fusion.12"] == "bwd+optimizer"
 
 
+@pytest.mark.parametrize(
+    "op_name, held",
+    [
+        ("jit(step)/jvp(M)/edl/mtp/M.predicted/dot_general", {"in"}),
+        ("jit(step)/transpose(jvp(M))/edl/mtp/embed/scatter-add", {"in"}),
+        ("jit(step)/transpose(jvp(edl/mtp))/mul", {"in"}),
+        ("jit(step)/jvp(M)/edl/mtp/M.predicted/edl/mla/mtp_0_mla/dot_general", {"in"}),
+        ("jit(step)/jvp(M)/M.layer/edl/mla/layer_0_mla/dot_general", {"out"}),
+        # a scope whose name only starts alike is another scope
+        ("jit(step)/jvp(M)/edl/mtp_other/add", {"out"}),
+        ("a/edl/mtp/x;a/edl/optimizer/y", {"in", "out"}),
+        ("", set()),
+    ],
+)
+def test_a_name_stack_is_inside_a_scope_or_outside_it(op_name, held):
+    assert step_ops.inside("edl/mtp")(op_name) == held
+
+
+# a fusion wholly under a model's scope, one the compiler fused with
+# the optimizer's work, and ops outside it
+SCOPED = """HloModule jit_per_device, is_scheduled=true
+
+%fused_module (p0: f32[8]) -> f32[8] {
+  %p0 = f32[8]{0} parameter(0)
+  %exp.1 = f32[8]{0} exponential(%p0), metadata={op_name="jit(per_device)/jvp(M)/edl/mtp/M.predicted/exp"}
+  ROOT %tanh.2 = f32[8]{0} tanh(%exp.1), metadata={op_name="jit(per_device)/jvp(M)/edl/mtp/M.predicted/edl/mla/mtp_0_mla/tanh"}
+}
+
+%fused_both (p0.1: f32[8]) -> f32[8] {
+  %p0.1 = f32[8]{0} parameter(0)
+  %neg.3 = f32[8]{0} negate(%p0.1), metadata={op_name="jit(per_device)/transpose(jvp(M))/edl/mtp/M.predicted/neg"}
+  ROOT %sqrt.4 = f32[8]{0} sqrt(%neg.3), metadata={op_name="jit(per_device)/edl/optimizer/sqrt"}
+}
+
+ENTRY %main (arg: f32[8]) -> f32[8] {
+  %arg = f32[8]{0} parameter(0)
+  %fusion.5 = f32[8]{0} fusion(%arg), kind=kLoop, calls=%fused_module
+  %fusion.6 = f32[8]{0} fusion(%fusion.5), kind=kLoop, calls=%fused_both
+  %abs.7 = f32[8]{0} abs(%fusion.6), metadata={op_name="jit(per_device)/jvp(M)/M.layer/edl/mla/layer_0_mla/abs"}
+  ROOT %copy.8 = f32[8]{0} copy(%abs.7)
+}
+"""
+
+
+def test_the_map_says_which_ops_lie_under_a_models_scope():
+    """``scopes`` of the map a traced worker writes: a scope of the
+    model's by name, its ops whole (``in``) or fused with work from
+    outside it (``in+out``); the two class scopes are not among them;
+    an op with no name says nothing."""
+    assert step_ops.model_scopes(SCOPED) == ["edl/mla", "edl/mtp"]
+    assert step_ops.ops_under(SCOPED, "edl/mtp") == {
+        "fusion.5": "in", "fusion.6": "in+out",
+    }  # fmt: skip
+    # the module's own latent attention is under both; the trunk's under one
+    assert step_ops.ops_under(SCOPED, "edl/mla") == {"fusion.5": "in+out", "abs.7": "in"}
+    ops_map, total = step_ops.step_ops_map(SCOPED)
+    assert total == 4 and ops_map["ops"]["fusion.6"] == "bwd+optimizer"
+    assert ops_map["scopes"] == {
+        "edl/mla": {"fusion.5": "in+out", "abs.7": "in"},
+        "edl/mtp": {"fusion.5": "in", "fusion.6": "in+out"},
+    }
+    # a step with no scope of a model's: the key is there and empty
+    assert step_ops.step_ops_map(WRITTEN)[0]["scopes"] == {}
+
+
 def _computations(text):
     """{computation: [instruction names]} and the names a ``fusion``
     calls, by a reading of the text that shares nothing with the parser
