@@ -27,10 +27,30 @@ slower: not worth a second order).
 
 The forward keeps ONE online-softmax step a tile however many
 sub-blocks it has: the row maximum is collected lane by lane across the
-sub-blocks and reduced across lanes once, and the normalizer's row sum
-is taken by the matrix unit (``p @ [v | 1]``: at head size 64 half of
-the 128 result lanes are idle). Per-sub-block recurrences doubled the
-forward's time on the chip; cross-lane reductions and (rows, 128)
+sub-blocks and reduced across lanes once. The normalizer's row sum goes
+one of two ways, by the value's head size alone (:func:`_fwd_scratch`).
+Where v leaves lanes spare in its last group of 128 (head size 64: half
+of the 128 result lanes are idle) it is taken by the matrix unit,
+``p @ [v | 1]``, ones in the spare lanes, in a pass the unit makes
+anyway. Where v fills its lanes (128, 256) a column of ones would cost
+one more 128-wide pass for ONE useful column (384 lanes of matrix work
+a tile where the roofline counts 256 at head size 128, 640 for 512 at
+256), so there the sums are kept lane by lane as the maximum is
+(:func:`_lane_sum`: elementwise adds over a sub-block's groups of 128
+columns, rescaled with the accumulator) and reduced across lanes once a
+FINISHED q tile, and the product is ``p @ v``, v read at the product
+(chip runs, PR 47, the forward alone in ms a call, ones column -> lane
+sums: (28, 16384, 128) under a window of 4,096 8.195 -> 6.519, causal
+17.117 -> 13.518; (32, 8192, 128) under a selection 5.210 -> 4.191;
+(20, 8192, 256) 5.095 -> 4.342; (32, 4096, 192 / 128) 1.728 -> 1.429;
+(96, 2048, 64) 0.903 and 0.903, bit for bit the same results; an f32
+copy of v in a scratch of its own, filled once a tile, read 1.5-2.3%
+SLOWER than v converted at each sub-block's product: 6.655, 13.774,
+4.290, 4.443, 1.451). The sums are f32 adds of p where the matrix unit
+contracted p in one bf16 pass, so the normalizer is the more exact:
+against f32 attention at head size 256 the logsumexp is off by 6e-6
+where the ones column's was off by 1e-3. Per-sub-block recurrences
+doubled the forward's time on the chip; cross-lane reductions and (rows, 128)
 statistics are what a tile's forward is made of, not its area. The
 softmax scale is folded into q once a tile. Operands reach the matrix
 unit as float32 (Mosaic contracts them in one bf16 pass on v5e);
@@ -178,13 +198,19 @@ UNEQUAL = {
     BWD_DKV_KERNEL: "edl_flash_mla_bwd_dkv",
 }
 # every name a flash call goes under, a set a kind of call
-_NAMES = tuple(
-    frozenset(names)
-    for names in (SELECTED, SELECTED.values(), WINDOWED.values(), UNEQUAL.values())
+_KINDS = (SELECTED, WINDOWED, UNEQUAL)
+_NAMES = (frozenset(SELECTED),) + tuple(
+    frozenset(names.values()) for names in _KINDS
 )
+# and those of them that are forwards
+_FORWARDS = frozenset([FWD_KERNEL] + [names[FWD_KERNEL] for names in _KINDS])
 
 # what ``step_built`` says of a step's flash calls (:func:`grid_steps_in`)
-STEP_BUILT_FIELDS = ("flash_grid_steps", "flash_grid_steps_empty")
+STEP_BUILT_FIELDS = (
+    "flash_grid_steps",
+    "flash_grid_steps_empty",
+    "flash_fwd_lane_sums",
+)
 # the grid steps with no tile to compute of each call built so far
 # (:func:`_call`), by what a jaxpr shows of a call: its name, its grid and
 # its two lengths. Not in the call's ``metadata``: jax writes that into
@@ -420,6 +446,42 @@ def _lane_max(x):
     )
 
 
+def _lane_sum(x):
+    """(rows, cols) -> (rows, 128): the sum over the groups of 128
+    columns, lane by lane. The elementwise half of a row sum, as
+    :func:`_lane_max` is of a row maximum; the cross-lane half is taken
+    once a FINISHED q tile."""
+    cols = x.shape[1]
+    if cols % _LANES:  # narrow sub-block: the whole sum, in lane 0
+        lane = jax.lax.broadcasted_iota(jnp.int32, (x.shape[0], _LANES), 1)
+        return jnp.where(lane == 0, jnp.sum(x, axis=1, keepdims=True), 0.0)
+    return functools.reduce(
+        jnp.add, [x[:, c:c + _LANES] for c in range(0, cols, _LANES)]
+    )
+
+
+def _fwd_scratch(block_q, block_k, d_v):
+    """The forward's scratch: the accumulator, the running maximum in
+    every lane, and what the normalizer's row sums l take, which
+    follows from the value's head size and nothing else.
+
+    Where v leaves lanes spare in its last group of 128 (``d_v`` 64, the
+    tests' 16) the third is ``[v | 1]``, (block_k, lanes of v): v beside
+    columns of ones, so that ``p @ [v | 1]`` leaves l in the
+    accumulator's spare lanes, a row reduction the matrix unit takes in
+    a pass it makes anyway. Where v fills its lanes (128, 256) a column
+    of ones would cost one more 128-wide pass for ONE useful column, and
+    the third is l itself, (block_q, 128), summed lane by lane
+    (:func:`_lane_sum`) beside an accumulator of ``d_v`` lanes."""
+    lanes = -(-d_v // _LANES) * _LANES
+    side = (block_q, _LANES) if lanes == d_v else (block_k, lanes)
+    return [
+        pltpu.VMEM((block_q, lanes), jnp.float32),
+        pltpu.VMEM((block_q, _LANES), jnp.float32),
+        pltpu.VMEM(side, jnp.float32),
+    ]
+
+
 def _fwd_kernel(
     q_tile_ref,
     k_tile_ref,
@@ -430,7 +492,7 @@ def _fwd_kernel(
     lse_ref,
     acc_ref,
     m_ref,
-    v1_ref,
+    side_ref,
     *,
     causal,
     scale,
@@ -440,21 +502,28 @@ def _fwd_kernel(
 ):
     qi, kj, first, last = _here(q_tile_ref, k_tile_ref)
     d = v_ref.shape[2]  # the value's head size: the result's
+    # where the normalizer's row sums live (:func:`_fwd_scratch`): in
+    # the accumulator's lanes past d, side_ref being [v | 1], or lane by
+    # lane in side_ref
+    v1_ref, l_ref = (side_ref, None) if acc_ref.shape[1] > d else (None, side_ref)
 
     @pl.when(first)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+        if l_ref is not None:
+            l_ref[:] = jnp.zeros_like(l_ref)
 
     def step(strips):
-        # v beside columns of ones: p @ [v | 1] leaves the row sums of p
-        # in the lanes the head size leaves empty, so the matrix unit
-        # takes the normalizer's row reduction and acc_ref[:, d:]
-        # carries l through the same rescale as the accumulator.
-        # Filled here and not above: a grid step with no tile to
-        # compute never gets here
-        v1_ref[:, :d] = v_ref[0].astype(jnp.float32)
-        v1_ref[:, d:] = jnp.ones((v1_ref.shape[0], v1_ref.shape[1] - d))
+        if v1_ref is not None:
+            # v beside columns of ones: p @ [v | 1] leaves the row sums
+            # of p in the lanes the head size leaves empty, so the
+            # matrix unit takes the normalizer's row reduction and
+            # acc_ref[:, d:] carries l through the same rescale as the
+            # accumulator. Filled here and not above: a grid step with
+            # no tile to compute never gets here
+            v1_ref[:, :d] = v_ref[0].astype(jnp.float32)
+            v1_ref[:, d:] = jnp.ones((v1_ref.shape[0], v1_ref.shape[1] - d))
         # ONE step of the online-softmax recurrence for the tile, its
         # products and exponentials taken sub-block by sub-block.
         # m_ref holds the running maximum in every lane; between the
@@ -472,12 +541,19 @@ def _fwd_kernel(
             m_ref[rows, :] = jnp.maximum(m_ref[rows, :], _lane_max(s))
             scores.append(s)
         m_new = jnp.max(m_ref[:], axis=1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * jnp.exp(m_prev - m_new)
+        rescale = jnp.exp(m_prev - m_new)
+        acc_ref[:] = acc_ref[:] * rescale
+        if l_ref is not None:
+            l_ref[:] = l_ref[:] * rescale
         for (rows, cols, _), s in zip(strips, scores):
+            p = jnp.exp(s - m_new[rows])
+            if l_ref is None:
+                values = v1_ref[cols, :]
+            else:
+                values = v_ref[0, cols, :].astype(jnp.float32)
+                l_ref[rows, :] += _lane_sum(p)
             acc_ref[rows, :] += jax.lax.dot(
-                jnp.exp(s - m_new[rows]),
-                v1_ref[cols, :],
-                preferred_element_type=jnp.float32,
+                p, values, preferred_element_type=jnp.float32
             )
         m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
 
@@ -488,7 +564,10 @@ def _fwd_kernel(
 
     @pl.when(last)
     def _finish():
-        l_fin = acc_ref[:, d:d + 1]
+        if l_ref is None:
+            l_fin = acc_ref[:, d:d + 1]
+        else:  # the one cross-lane reduction of l, a finished q tile
+            l_fin = jnp.sum(l_ref[:], axis=1, keepdims=True)
         o_ref[0] = (acc_ref[:, :d] / l_fin).astype(o_ref.dtype)
         # the statistics leave with L along the lanes: m_ref holds the
         # maximum in every lane, so one transpose of the (rows, 128)
@@ -1041,8 +1120,6 @@ def _flash_fwd(
     scale = d ** -0.5
     qf, kf, vf = _fold_heads(q), _fold_heads(k), _fold_heads(v)
 
-    # room for v and at least one column of ones, in whole lanes
-    d_ones = -(-(d_v + 1) // _LANES) * _LANES
     out, lse = _call(
         FWD_KERNEL,
         _fwd_kernel,
@@ -1051,11 +1128,7 @@ def _flash_fwd(
             jax.ShapeDtypeStruct((b * h, lq, d_v), q.dtype),
             jax.ShapeDtypeStruct((b * h, 1, lq), jnp.float32),
         ],
-        [
-            pltpu.VMEM((block_q, d_ones), jnp.float32),
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_k, d_ones), jnp.float32),
-        ],
+        _fwd_scratch(block_q, block_k, d_v),
         interpret,
         heads=None if selection is None else h,
         window=window,
@@ -1387,12 +1460,15 @@ def attention_in_step(step_facts):
 
 def grid_steps_in(jaxpr):
     """:data:`STEP_BUILT_FIELDS` of a traced program: the grid steps of
-    every flash call in ``jaxpr`` and in the jaxprs nested in it, and
-    how many of them have no tile to compute, each call counted as
-    often as the program holds it (a layer's three, a recomputed
-    forward's once more); ``{}`` for a program without the kernels.
-    Static: a count of the walk (:func:`_walk`), no device number."""
-    steps = empty = 0
+    every flash call in ``jaxpr`` and in the jaxprs nested in it, how
+    many of them have no tile to compute, and how many of the calls are
+    forwards that keep the normalizer's row sums lane by lane (a
+    forward whose accumulator is no wider than v: :func:`_fwd_scratch`),
+    each call counted as often as the program holds it (a layer's
+    three, a recomputed forward's once more); ``{}`` for a program
+    without the kernels. Static: a count of the walk (:func:`_walk`)
+    and of the calls' scratch, no device number."""
+    steps = empty = lane_sums = 0
     flash = frozenset().union(*_NAMES)
     todo = [getattr(jaxpr, "jaxpr", jaxpr)]
     while todo:
@@ -1404,9 +1480,14 @@ def grid_steps_in(jaxpr):
                 lengths = [v.aval.shape[1] for v in eqn.invars[2:4]]
                 steps += math.prod(grid)
                 empty += _EMPTY_STEPS.get((name, grid, *lengths), 0)
+                if name in _FORWARDS:
+                    acc = eqn.params["grid_mapping"].scratch_avals[0]
+                    lane_sums += acc.shape[1] == eqn.invars[4].aval.shape[2]
             todo.extend(jax.core.jaxprs_in_params(eqn.params))
     # a flash call has a step at least
-    return dict(zip(STEP_BUILT_FIELDS, (steps, empty))) if steps else {}
+    if not steps:
+        return {}
+    return dict(zip(STEP_BUILT_FIELDS, (steps, empty, lane_sums)))
 
 
 def selected_reference_attention(q, k, v, selection):

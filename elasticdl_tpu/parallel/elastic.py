@@ -1727,8 +1727,10 @@ class ElasticDPTrainer:
         process-local mesh,
         none on one that spans processes: :func:`state_donation`); and,
         where it holds the flash kernels, how many steps their grids
-        take and how many of those have no tile to compute
-        (``flash_grid_steps``, ``flash_grid_steps_empty``).
+        take, how many of those have no tile to compute and how many
+        of the forward calls keep their row sums by lanes
+        (``flash_grid_steps``, ``flash_grid_steps_empty``,
+        ``flash_fwd_lane_sums``).
         Asked BEFORE the first step it
         costs about nothing: jax caches the trace and the lowering, and
         the step's own first call reuses both (CPU, 8 layers: 4.4 s
@@ -1770,6 +1772,7 @@ class ElasticDPTrainer:
             ),
             "donated_inputs": count_donated_inputs(lowered_text),
             # where the step holds the flash kernels: their grids' steps
+            # and which forward body they were built with
             **flash_attention.grid_steps_in(traced.jaxpr),
             **(self._step_ops_facts(lowered, trace_dir) if trace_dir else {}),
         }

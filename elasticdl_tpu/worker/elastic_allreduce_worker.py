@@ -978,8 +978,9 @@ class ElasticAllReduceWorker:
         }
         # a traced run's own: the compiled step's ops by class and the
         # compiler's account of its memory (describe_step); a step with
-        # the flash kernels: the steps of their grids, and those of them
-        # with no tile to compute (grid_steps_in)
+        # the flash kernels: the steps of their grids, those of them
+        # with no tile to compute, and the forwards whose row sums are
+        # kept by lanes (grid_steps_in)
         report.update(
             (k, facts[k])
             for k in (*step_ops.STEP_BUILT_FIELDS, *flash_fields)

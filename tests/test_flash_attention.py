@@ -1,5 +1,7 @@
 """Pallas flash-attention kernel vs the XLA reference (interpret mode)."""
 
+import functools
+
 import jax
 import numpy as np
 import pytest
@@ -816,37 +818,41 @@ def _toy_selection(length, seed=3):
     return (kept & np.tril(np.ones((length, length), bool))).astype(np.int8)
 
 
-def _toy_call(fa, mask, dtype, length=TOY_LENGTH, tile=TOY_TILE, seed=41):
+def _toy_call(
+    fa, mask, dtype, length=TOY_LENGTH, tile=TOY_TILE, seed=41,
+    sizes=(16, 16), w=TOY_W, window=TOY_WINDOW,
+):  # fmt: skip
     """(out, lse, dq, dk, dv) of one call of ``fa``'s kernels under
     ``mask``: eight tiles of ``tile`` each way in sub-blocks of 4, one
-    sequence of two heads, inputs from ``seed``."""
+    sequence of two heads of ``sizes`` (q and k's, v's), inputs from
+    ``seed``."""
     import jax.numpy as jnp
 
     rng = np.random.default_rng(seed)
     q, k, v, g = (
-        jnp.asarray(rng.standard_normal((1, length, 2, 16)), dtype)
-        for _ in range(4)
+        jnp.asarray(rng.standard_normal((1, length, 2, d)), dtype)
+        for d in (sizes[0], sizes[0], sizes[1], sizes[1])
     )
     how = {}
     if mask == "windowed":
-        how = {"window": TOY_WINDOW}
+        how = {"window": window}
     selection = _toy_selection(length) if mask == "selected" else None
     out, lse = fa._flash_fwd(
-        q, k, v, mask != "full", tile, tile, True, w=TOY_W,
+        q, k, v, mask != "full", tile, tile, True, w=w,
         selection=selection, **how,
     )  # fmt: skip
     if selection is not None:
         how = {"selection_t": selection.transpose(0, 2, 1)}
     grads = fa._flash_bwd(
-        q, k, v, out, lse, g, mask != "full", tile, tile, True, w=TOY_W, **how
+        q, k, v, out, lse, g, mask != "full", tile, tile, True, w=w, **how
     )
     return (q, k, v, g), (out, lse) + tuple(grads)
 
 
-def _digest(fa, mask, dtype):
+def _digest(fa, mask, dtype, d="16"):
     import hashlib
 
-    _, results = _toy_call(fa, mask, dtype)
+    _, results = _toy_call(fa, mask, dtype, sizes=(int(d), int(d)))
     sha = hashlib.sha256()
     for x in results:
         sha.update(np.asarray(x).tobytes())
@@ -866,6 +872,16 @@ BIT_FOR_BIT = {
     "selected-bfloat16": "6443fa57784f0188",
     "full-float32": "a06c2fec720642fc",
     "full-bfloat16": "107d748b23b31609",
+    # head size 64, every cell's whose v leaves lanes spare: the forward
+    # PR 47 left as it was, recorded from ITS parent (commit f6a1c34)
+    "causal-float32-64": "015a1756b50fb7f3",
+    "causal-bfloat16-64": "08c077b0d527bdf5",
+    "windowed-float32-64": "3fc559d316d0e79f",
+    "windowed-bfloat16-64": "208aab076997c6ee",
+    "selected-float32-64": "db7c87d3d497f57d",
+    "selected-bfloat16-64": "325cf58eef962190",
+    "full-float32-64": "6be03b909ba0abba",
+    "full-bfloat16-64": "9f3093a1f016f279",
 }
 
 
@@ -1036,6 +1052,7 @@ def test_a_call_says_how_many_steps_its_grid_takes():
     assert fa.grid_steps_in(jaxpr) == {
         "flash_grid_steps": 4 * 3 * (10 + 9),
         "flash_grid_steps_empty": 0,
+        "flash_fwd_lane_sums": 0,  # head size 16: ones in the spare lanes
     }
     # lengths that differ leave k tiles that no query reads: one step
     # each, to write their zeros
@@ -1044,6 +1061,7 @@ def test_a_call_says_how_many_steps_its_grid_takes():
     assert fa.grid_steps_in(jaxpr) == {
         "flash_grid_steps": 4 * (3 + 3 + (2 + 1 + 2)),
         "flash_grid_steps_empty": 4 * 2,
+        "flash_fwd_lane_sums": 0,
     }
     assert fa.grid_steps_in(jax.make_jaxpr(lambda x: x * 2)(1.0)) == {}
 
@@ -1135,6 +1153,8 @@ def test_grid_steps_and_traffic_at_both_head_sizes(d_qk, d_v):
     assert fa.grid_steps_in(jaxpr) == {
         "flash_grid_steps": 4 * 3 * 10,
         "flash_grid_steps_empty": 0,
+        # the one forward: v of 128 fills its lanes, v of 64 does not
+        "flash_fwd_lane_sums": int(d_v == 128),
     }
 
     heads, tile = 64, 1024 * 2
@@ -1231,6 +1251,206 @@ def test_head_size_256_takes_the_plain_bodies_at_the_policys_tiles():
         if eqn.primitive.name == "pallas_call"
     ]  # fmt: skip
     assert sorted(names) == sorted([FWD, DQ, DKV])
+
+
+# ---------------------------------------------------------------------------
+# the normalizer's row sums (PR 47): where the value's head size fills its
+# lanes (128, 256) the forward sums p lane by lane and reduces across lanes
+# once a finished q tile; where it leaves lanes spare (64, 16) the ones ride
+# beside v through the matrix unit, as before
+# ---------------------------------------------------------------------------
+
+# three tiles of 256 each way in sub-blocks of 128: a whole sub-block is
+# two groups of 128 columns (the lane sums add across groups), a q tile has
+# up to three k tiles (they are rescaled across tiles), and the window's
+# lower edge passes through the middle of a tile
+LANE_LENGTH, LANE_TILE, LANE_W, LANE_WINDOW = 768, 256, 128, 384
+# q and k's head size, v's: the cells' whose v fills its lanes
+# (`smallthinker-ep8-l16384` and `keyevl2-ep8-l8192`,
+# `glm47flash-ep8-l8192`, `ling3flash-ep64-l4096`)
+LANE_SIZES = [(128, 128), (256, 256), (192, 128)]
+LANE_CASES = [
+    pytest.param(mask, *sizes, id="%s-%d-%d" % (mask, *sizes))
+    for sizes in LANE_SIZES
+    for mask in MASKS + ["full"]
+    # unequal sizes under a selection or a window are not built
+    if sizes[0] == sizes[1] or mask in ("causal", "full")
+]
+RESULTS = ["forward", "lse", "dq", "dk", "dv"]
+
+
+# one call serves its five results, which run one after the other
+@functools.lru_cache(maxsize=1)
+def _lane_sums_both_ways(mask, d_qk, d_v, dtype):
+    """``{result: (got, want)}`` of one call whose row sums are kept by
+    lanes: the kernels' ``(out, lse, dq, dk, dv)`` beside the plain
+    reference's in f32 on the same (rounded) inputs, ``lse`` beside the
+    ``logsumexp`` of the reference's masked scores."""
+    import jax.numpy as jnp
+
+    from elasticdl_tpu.ops import flash_attention as fa
+
+    (q, k, v, g), got = _toy_call(
+        fa, mask, dtype, LANE_LENGTH, LANE_TILE, seed=47, sizes=(d_qk, d_v),
+        w=LANE_W, window=LANE_WINDOW,
+    )  # fmt: skip
+    distance = np.arange(LANE_LENGTH)[:, None] - np.arange(LANE_LENGTH)
+    if mask == "windowed":
+        keep = ((distance >= 0) & (distance < LANE_WINDOW))[None]
+        reference = lambda q, k, v: fa.windowed_reference_attention(
+            q, k, v, LANE_WINDOW
+        )
+    elif mask == "selected":
+        keep = _toy_selection(LANE_LENGTH) != 0
+        reference = lambda q, k, v: fa.selected_reference_attention(
+            q, k, v, jnp.asarray(keep)
+        )
+    else:
+        keep = (distance >= 0 if mask == "causal" else distance < np.inf)[None]
+        reference = lambda q, k, v: reference_attention(
+            q, k, v, causal=mask == "causal"
+        )
+    exact = [x.astype(jnp.float32) for x in (q, k, v)]
+    want, vjp = jax.vjp(reference, *exact)
+    scores = jnp.einsum("bqhd,bkhd->bhqk", *exact[:2]) * d_qk ** -0.5
+    lse = jax.scipy.special.logsumexp(
+        jnp.where(keep[:, None], scores, -jnp.inf), axis=-1
+    )
+    wanted = (want, lse) + tuple(vjp(g.astype(jnp.float32)))
+    return {
+        name: (np.asarray(a, np.float32), np.asarray(b))
+        for name, a, b in zip(RESULTS, got, wanted)
+    }
+
+
+@pytest.mark.parametrize("what", RESULTS)
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("mask, d_qk, d_v", LANE_CASES)
+def test_row_sums_by_lanes_match_the_reference(mask, d_qk, d_v, dtype, what):
+    """Forward, logsumexp and the three gradients at the head sizes
+    whose row sums are kept by lanes, under every mask. The statistics
+    are f32 whatever the operands are, so ``lse`` is held to f32's
+    tolerance in bf16 too."""
+    got, want = _lane_sums_both_ways(mask, d_qk, d_v, dtype)[what]
+    assert got.shape == want.shape
+    if what == "lse":
+        tolerance = dict(rtol=2e-5, atol=2e-5)
+    else:
+        tolerance = (TOLERANCE if what == "forward" else GRAD_TOLERANCE)[dtype]
+    np.testing.assert_allclose(got, want, **tolerance)
+
+
+def _forward_calls(jaxpr):
+    """``(name, scratch shapes)`` of each flash forward in ``jaxpr``."""
+    from elasticdl_tpu.ops import flash_attention as fa
+
+    return [
+        (
+            eqn.params["name"],
+            [a.shape for a in eqn.params["grid_mapping"].scratch_avals],
+        )
+        for eqn in _every_eqn(getattr(jaxpr, "jaxpr", jaxpr))
+        if eqn.primitive.name == "pallas_call"
+        and eqn.params["name"] in fa._FORWARDS
+    ]
+
+
+# q and k's head size, v's, the lanes v takes, whether the row sums are
+# kept by lanes
+ARMS = [
+    pytest.param(*arm, mask, id="%d-%d-%s" % (*arm[:2], mask))
+    for arm in [
+        (16, 16, 128, False),  # the tests'
+        (64, 64, 128, False),  # the dense cells', granite's, lfm2moe's
+        (128, 64, 128, False),
+        (192, 192, 256, False),
+        (128, 128, 128, True),
+        (192, 128, 128, True),  # a latent attention's
+        (256, 256, 256, True),
+    ]
+    for mask in MASKS
+    # unequal sizes under a selection or a window are not built
+    if arm[0] == arm[1] or mask == "causal"
+]
+
+
+@pytest.mark.parametrize("d_qk, d_v, lanes, by_lanes, mask", ARMS)
+def test_the_row_sums_live_where_the_values_head_size_puts_them(
+    d_qk, d_v, lanes, by_lanes, mask
+):
+    """Which body a forward gets is read off ``v.shape[-1]`` and
+    nothing else: its scratch says which, and `grid_steps_in` counts the
+    calls that keep their row sums by lanes (``step_built``'s
+    ``flash_fwd_lane_sums``). Tiles of 1,024 x 512 tell the two third
+    scratches apart: ``[v | 1]`` has a k tile's rows, ``l`` a q tile's.
+    Where v leaves lanes spare the scratch is the parent's, shape for
+    shape."""
+    import jax.numpy as jnp
+
+    from elasticdl_tpu.ops import flash_attention as fa
+
+    length, block_q, block_k = 2048, 1024, 512
+    q, v = (
+        jax.ShapeDtypeStruct((1, length, 2, d), jnp.bfloat16)
+        for d in (d_qk, d_v)
+    )
+    how = {"window": 700} if mask == "windowed" else {}
+    if mask == "selected":
+        how = {"selection": jnp.asarray(_toy_selection(length))}
+    jaxpr = jax.make_jaxpr(
+        lambda q, k, v: fa._flash_fwd(
+            q, k, v, True, block_q, block_k, True, **how
+        )
+    )(q, q, v)
+    ((name, scratch),) = _forward_calls(jaxpr)
+    side = (block_q, 128) if by_lanes else (block_k, lanes)
+    assert scratch == [(block_q, lanes), (block_q, 128), side], name
+    assert fa.grid_steps_in(jaxpr)["flash_fwd_lane_sums"] == int(by_lanes)
+    if not by_lanes:  # the parent's: room for v and a column of ones
+        parents = -(-(d_v + 1) // 128) * 128
+        assert scratch == [(block_q, parents), (block_q, 128), (block_k, parents)]
+
+
+@pytest.mark.parametrize("d, forwards_by_lanes", [(128, 3), (16, 0)])
+def test_a_recomputed_forward_counts_once_more(d, forwards_by_lanes):
+    """`flash_fwd_lane_sums` counts a call as often as the program holds
+    it: a layer under ``jax.checkpoint`` runs its forward twice, and the
+    backward kernels, which read ``(out, lse)`` alone, never count."""
+    from elasticdl_tpu.ops import flash_attention as fa
+
+    q, k, v = _qkv(d=d)
+    layer = lambda q, k, v: fa.flash_attention(q, k, v, True, 16, 16)
+
+    def loss(q, k, v):
+        once = layer(q, k, v)
+        return (jax.checkpoint(layer)(once, k, v) ** 2).sum()
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
+    assert len(_forward_calls(jaxpr)) == 3
+    assert fa.grid_steps_in(jaxpr) == {
+        "flash_grid_steps": 4 * 10 * (3 + 2 + 2),
+        "flash_grid_steps_empty": 0,
+        "flash_fwd_lane_sums": forwards_by_lanes,
+    }
+
+
+@pytest.mark.parametrize("cols", [16, 100, 128, 384])
+def test_lane_sum_is_a_row_sum_once_reduced_across_lanes(cols):
+    """`_lane_sum` keeps (rows, 128) whose cross-lane sum is the row
+    sum: groups of 128 columns added lane by lane, and a narrow
+    sub-block's whole sum in lane 0."""
+    from elasticdl_tpu.ops import flash_attention as fa
+
+    x = np.random.default_rng(cols).random((8, cols)).astype("float32")
+    by_lanes = np.asarray(fa._lane_sum(jax.numpy.asarray(x)))
+    assert by_lanes.shape == (8, 128)
+    np.testing.assert_allclose(by_lanes.sum(axis=1), x.sum(axis=1), rtol=1e-6)
+    if cols % 128 == 0:
+        np.testing.assert_allclose(
+            by_lanes, x.reshape(8, -1, 128).sum(axis=1), rtol=1e-6
+        )
+    else:
+        assert not by_lanes[:, 1:].any()
 
 
 if __name__ == "__main__":

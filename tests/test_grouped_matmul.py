@@ -318,8 +318,10 @@ def test_flash_kernels_at_head_size_256_compile_for_the_v5e_at_the_cells_shape(
     heads whose q, k and v are all 256 wide, so the call is the plain
     bodies' at a head size none had run before PR 46: a q tile of 1,024
     x 256, dkv's two f32 (1,024, 256) accumulators and the forward's
-    `[v | 1]` scratch of 384 lanes are Mosaic's to refuse (scoped VMEM),
-    and interpret mode refuses none of them."""
+    scratch (since PR 47 a (1,024, 256) accumulator beside two (1,024,
+    128) statistics, the running maximum and the row sums kept by lanes;
+    until then `[v | 1]` and the accumulator at 384 lanes) are Mosaic's
+    to refuse (scoped VMEM), and interpret mode refuses none of them."""
     from elasticdl_tpu.ops import flash_attention as fa
 
     x = jax.ShapeDtypeStruct((batch, 8192, 20, 256), jnp.bfloat16, sharding=one_chip)
@@ -343,6 +345,40 @@ def test_flash_kernels_at_head_size_256_compile_for_the_v5e_at_the_cells_shape(
         assert name in text
     for name in fa.UNEQUAL.values():
         assert name not in text
+
+
+@pytest.mark.parametrize(
+    "length, d, tiles",
+    [
+        pytest.param(100, 128, (100, 100), id="a-whole-odd-length"),
+        pytest.param(72, 256, (72, 72), id="a-whole-odd-length-at-256"),
+        pytest.param(2048, 128, (1024, 512), id="tiles-1024x512"),
+        pytest.param(2048, 128, (512, 1024), id="tiles-512x1024"),
+    ],
+)
+def test_the_forward_by_lanes_compiles_for_the_v5e_off_the_policys_tiles(
+    one_chip, length, d, tiles
+):
+    """Where v fills its lanes the forward sums p lane by lane (PR 47):
+    a sub-block that is no whole number of 128 columns (a short whole
+    length) puts its row sum in lane 0 by an iota's test, and tiles that
+    are not square are never cut, so a sub-block is a whole tile, eight
+    groups of 128 columns at once. Both are Mosaic's to refuse."""
+    from elasticdl_tpu.ops import flash_attention as fa
+
+    x = jax.ShapeDtypeStruct((2, length, 4, d), jnp.bfloat16, sharding=one_chip)
+    forward = lambda q, k, v: fa._flash_fwd(q, k, v, True, *tiles, False)
+    assert fa.grid_steps_in(jax.make_jaxpr(forward)(x, x, x)) == {
+        "flash_grid_steps": 8 * len(fa._walk(fa.FWD_KERNEL, length, length, *tiles, True)[0]),
+        "flash_grid_steps_empty": 0,
+        "flash_fwd_lane_sums": 1,
+    }
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        text = jax.jit(forward).lower(x, x, x).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+    assert fa.FWD_KERNEL in text
 
 
 @pytest.mark.parametrize(

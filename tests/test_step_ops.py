@@ -607,26 +607,40 @@ class Windowed(nn.Module):
     """A global attention layer and one under a window of 20, in tiles
     of 16: four tiles each way at 64 positions."""
 
+    head_size: int = 16
+
     @nn.compact
     def __call__(self, x, training=False):
         from elasticdl_tpu.ops.flash_attention import flash_attention
 
+        d = self.head_size
         for window in (None, 20):
             q, k, v = (
-                nn.Dense(32)(x).reshape(*x.shape[:2], 2, 16) for _ in range(3)
+                nn.Dense(2 * d)(x).reshape(*x.shape[:2], 2, d) for _ in range(3)
             )
             x = x + nn.Dense(16)(
                 flash_attention(q, k, v, True, 16, 16, window=window).reshape(
-                    *x.shape[:2], 32
+                    *x.shape[:2], 2 * d
                 )
             )
         return nn.Dense(1, name="head")(x.mean(axis=1))
 
 
-def test_step_built_carries_the_flash_grids_steps(monkeypatch):
+@pytest.mark.parametrize(
+    "head_size, lane_sums",
+    [
+        pytest.param(16, 0, id="ones-in-the-spare-lanes"),
+        pytest.param(128, 2, id="row-sums-by-lanes"),
+    ],
+)
+def test_step_built_carries_the_flash_grids_steps(
+    monkeypatch, head_size, lane_sums
+):
     """`describe_step` sums the steps of every flash call in the traced
-    step, and those with no tile to compute; the worker's `step_built`
-    carries both. A step without the kernels says nothing of them
+    step, and those with no tile to compute, and counts the forwards
+    that keep their row sums by lanes (a head size that fills its lanes:
+    both layers' at 128, none at 16); the worker's `step_built` carries
+    all three. A step without the kernels says nothing of them
     (`TODAYS_FACTS` above)."""
     from elasticdl_tpu.utils import profiling
     from elasticdl_tpu.worker.elastic_allreduce_worker import (
@@ -639,7 +653,9 @@ def test_step_built_carries_the_flash_grids_steps(monkeypatch):
         elastic, "build_world_mesh",
         lambda mesh_axes_fn=None: Mesh(np.asarray(jax.devices()[:1]), ("data",)),
     )  # fmt: skip
-    trainer = elastic.ElasticDPTrainer(Windowed(), _loss, optax.sgd(1e-2))
+    trainer = elastic.ElasticDPTrainer(
+        Windowed(head_size), _loss, optax.sgd(1e-2)
+    )
     trainer.default_minibatch_size = 2
     batch = np.ones((2, 64, 16), np.float32), np.ones((2,), np.float32)
     try:
@@ -652,8 +668,9 @@ def test_step_built_carries_the_flash_grids_steps(monkeypatch):
         # the diagonal, nine of them under the window
         assert facts["flash_grid_steps"] == 4 * 3 * (10 + 9)
         assert facts["flash_grid_steps_empty"] == 0
+        assert facts["flash_fwd_lane_sums"] == lane_sums
         assert set(facts) == TODAYS_FACTS | {
-            "flash_grid_steps", "flash_grid_steps_empty"
+            "flash_grid_steps", "flash_grid_steps_empty", "flash_fwd_lane_sums"
         }  # fmt: skip
         worker = ElasticAllReduceWorker.__new__(ElasticAllReduceWorker)
         worker._step_reported, worker._worker_id = False, 0
@@ -667,6 +684,7 @@ def test_step_built_carries_the_flash_grids_steps(monkeypatch):
         assert emitted["kind"] == "step_built"
         assert emitted["flash_grid_steps"] == 228
         assert emitted["flash_grid_steps_empty"] == 0
+        assert emitted["flash_fwd_lane_sums"] == lane_sums
         assert emitted["attention"] == "pallas-interpret"
     finally:
         trainer.close()
