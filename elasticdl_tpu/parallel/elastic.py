@@ -60,9 +60,14 @@ from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from elasticdl_tpu.common.log_utils import default_logger as logger
+from elasticdl_tpu.nn.hbm_embedding import (
+    METRICS_COLLECTION,
+    a2a_overflow_total,
+)
 from elasticdl_tpu.nn.model_api import apply_model, init_variables, split_variables
 from elasticdl_tpu.ops import flash_attention
 from elasticdl_tpu.parallel import compile_plane, distributed, layout_solver
+from elasticdl_tpu.parallel.expert import MOE_STATE_COLLECTION
 from elasticdl_tpu.parallel.sharding import tp_degree_candidates
 from elasticdl_tpu.training.step import (
     TrainState,
@@ -159,15 +164,49 @@ def count_donated_inputs(lowered_text):
     )
 
 
+def _local_replica(x):
+    """The replica of ``x`` this process addresses (``x`` itself where
+    it is no jax array)."""
+    if hasattr(x, "addressable_shards"):
+        return x.addressable_shards[0].data
+    return x
+
+
 def host_copy(tree):
     """Fetch each leaf's process-addressable replica to host numpy."""
+    return jax.tree_util.tree_map(
+        lambda x: np.asarray(_local_replica(x)), tree
+    )
 
-    def fetch(x):
-        if hasattr(x, "addressable_shards"):
-            return np.asarray(x.addressable_shards[0].data)
-        return np.asarray(x)
 
-    return jax.tree_util.tree_map(fetch, tree)
+# The collections of the model's state that a sync point reads: the
+# expert layers' routing state (a bias and wrapping counters a layer),
+# what the model added to its loss, the embedding layers' overflow
+# counters. Each is a few hundred bytes, replicated.
+RECEIPT_COLLECTIONS = (
+    MOE_STATE_COLLECTION,
+    AUX_LOSS_COLLECTION,
+    METRICS_COLLECTION,
+)
+
+
+def receipt_state(state):
+    """The part of a model state (or of its spec tree) that a sync
+    point reads: :data:`RECEIPT_COLLECTIONS`, those of them the model
+    keeps."""
+    if not isinstance(state, dict):
+        return {}
+    return {c: state[c] for c in RECEIPT_COLLECTIONS if c in state}
+
+
+def kept_of(ts):
+    """What of the train state (or of its spec tree) a step hands back
+    a second time, beside the train state, as outputs of their own: its
+    version and :func:`receipt_state` of the model's state. The next
+    step donates the train state it is given (:func:`state_donation`)
+    and these it is not given, so the worker can read them one step
+    late, while that next step runs (``ElasticDPTrainer.settle``)."""
+    return {"version": ts.version, "state": receipt_state(ts.state)}
 
 
 def broadcast_from_device0(mesh, host_tree, source_process=0):
@@ -633,7 +672,10 @@ def make_elastic_train_step(
     remat=False,
 ):
     """Weighted lockstep step: ``(ts, features, labels, weights, epochs,
-    rng) -> (ts', loss, n_active, epoch_consensus)``.
+    rng) -> (ts', loss, n_active, epoch_consensus, kept)``: the new
+    train state, and the step's receipt, four small outputs that are
+    no part of it (``kept`` is :func:`kept_of` the new state, copies
+    the next step does not donate).
 
     Works over ANY mesh axis layout: ``axis`` defaults to the mesh's
     full axis-name tuple, the batch/weights/epochs shard over the
@@ -813,12 +855,13 @@ def make_elastic_train_step(
                 ),
                 version=ts.version + live.astype(jnp.int32),
             )
-        return new_ts, loss, n, epoch_seen
+        return new_ts, loss, n, epoch_seen, kept_of(new_ts)
 
     if state_specs is None:
-        ts_spec = P()
+        ts_spec = kept_spec = P()
     else:
         ts_spec = state_specs
+        kept_spec = kept_of(state_specs)
     # batch/weights/epochs shard dim 0 over the FLATTENED device order,
     # so each process's rows land on its own devices whatever the mesh
     # shape (same layout the trainer places them with)
@@ -827,7 +870,7 @@ def make_elastic_train_step(
         per_device,
         mesh=mesh,
         in_specs=(ts_spec, row_spec, row_spec, row_spec, row_spec, P()),
-        out_specs=(ts_spec, P(), P(), P()),
+        out_specs=(ts_spec, P(), P(), P(), kept_spec),
         check_vma=False,
     )
     # the state is donated where no peer can fail the collective, and
@@ -897,7 +940,7 @@ def make_pjit_train_step(
 
     Same call signature and external semantics as
     :func:`make_elastic_train_step` (``(ts, features, labels, weights,
-    epochs, rng) -> (ts', loss, n_active, epoch_consensus)``), but the
+    epochs, rng) -> (ts', loss, n_active, epoch_consensus, kept)``), but the
     body is GLOBAL-semantics math under ``jax.jit`` with
     ``NamedSharding`` out-shardings: XLA partitions the dense model per
     the spec tree and inserts the tensor-parallel collectives itself —
@@ -982,14 +1025,14 @@ def make_pjit_train_step(
             ),
             version=ts.version + live.astype(jnp.int32),
         )
-        return new_ts, loss, n, epoch_seen
+        return new_ts, loss, n, epoch_seen, kept_of(new_ts)
 
     # out-shardings PIN the layout: without them XLA could silently
     # re-replicate a sharded parameter on the way out and the "bigger
     # than one device" property would evaporate after the first step
     return jax.jit(
         step,
-        out_shardings=(ts_shardings, rep, rep, rep),
+        out_shardings=(ts_shardings, rep, rep, rep, kept_of(ts_shardings)),
         donate_argnums=state_donation(mesh),
     )
 
@@ -1183,7 +1226,22 @@ class ElasticDPTrainer:
         self._gather_fns = {}  # cached per-width info gathers
         self._host_step = 0
         self._last_local = None  # (features, labels) for weight-0 steps
-        self.epoch_consensus = None  # newest epoch any member has seen
+        # steps dispatched and not yet validated, oldest first: each
+        # one's receipt on the device (loss, n_active, epoch consensus,
+        # kept_of its state) and whether it carried data (see settle)
+        self._in_flight = []
+        self._in_flight_overflowed = False  # warn once per overflow
+        # the state the newest dispatched step was given, where that
+        # step did not donate it: what settle(lag=1) has validated
+        self._ts_behind = None
+        self._settled_losses = []  # validated, not yet handed out
+        # of the newest VALIDATED step: the newest epoch any member had
+        # seen, the devices that carried data, and the host copy of
+        # what it kept (kept_of): its version, its receipt_state
+        self.epoch_consensus = None
+        self.n_active = None
+        self._validated_version = None
+        self._validated_state = {}
         # in-memory replica plane (sharded jobs): see ShardMirror
         self.mirror_steps = 0  # 0 disables; worker sets from its flag
         self._mirror = None
@@ -1208,11 +1266,8 @@ class ElasticDPTrainer:
         self._spec_example = None  # host example batch (abstract args)
         # worker's fixed minibatch: lets speculation derive batch shapes
         self.default_minibatch_size = None
-        # step overlap: async H2D stager + deferred (collect-later)
-        # loss fetches drained at sync/log boundaries
+        # step overlap: async H2D stager
         self._feeder = None
-        self._pending_metrics = []  # device loss scalars of unsynced steps
-        self._pending_metrics_overflowed = False  # warn once per overflow
 
     @property
     def mesh(self):
@@ -1226,6 +1281,17 @@ class ElasticDPTrainer:
         return int(
             self._escapable(lambda: host_copy(self._ts.version))
         )
+
+    @property
+    def validated_version(self):
+        """The version of the newest VALIDATED step's state, from its
+        receipt (:meth:`settle`): what a task report carries, since a
+        sync point reports with the newest step still on the device and
+        :attr:`version` would wait for it. Before a world's first
+        validation, the placed state's own."""
+        if self._validated_version is None:
+            return self.version
+        return self._validated_version
 
     @property
     def has_state(self):
@@ -1250,40 +1316,41 @@ class ElasticDPTrainer:
             for leaf in jax.tree_util.tree_leaves(self._ts)
         )
 
+    @property
+    def steps_in_flight(self):
+        """Steps dispatched that no fetch has waited for yet."""
+        return len(self._in_flight)
+
     def routing_state(self):
-        """A host copy of the model's expert-routing state
+        """The host copy of the model's expert-routing state
         (``parallel/expert.MOE_STATE_COLLECTION``: a selection bias and
         wrapping assignment counters an expert layer, a few hundred
-        bytes), or None between worlds and for a model that keeps none.
-        Asked once a window, beside the loss drain, and accounted to
-        the same ``fetch`` phase: it waits for the window's last step."""
-        from elasticdl_tpu.parallel.expert import MOE_STATE_COLLECTION
-
-        return self._host_copy_of(MOE_STATE_COLLECTION)
-
-    def _host_copy_of(self, collection):
-        """A host copy of one collection of the model's state, charged
-        to the ``fetch`` phase; None between worlds and for a model
-        that keeps none."""
-        state = None if self._ts is None else self._ts.state
-        if not isinstance(state, dict) or collection not in state:
-            return None
-        with profiling.phases.measure("fetch"):
-            return host_copy(state[collection])
+        bytes) as the newest VALIDATED step left it, or None before a
+        world's first validation and for a model that keeps none. It
+        came with that step's receipt (:meth:`settle`): asking moves
+        nothing and waits for nothing."""
+        return self._validated_state.get(MOE_STATE_COLLECTION)
 
     def aux_losses(self):
-        """A host copy of what the model wrote to its ``aux_loss``
-        collection in the last step (``training/step.py``: every step
-        builder adds the collection to the loss), ``{leaf's own name:
-        value}``, leaves of one name summed; nothing between worlds and
-        for a model that writes none. Asked once a window beside
-        :meth:`routing_state`, under the same ``fetch`` phase."""
+        """What the model wrote to its ``aux_loss`` collection in the
+        newest VALIDATED step (``training/step.py``: every step builder
+        adds the collection to the loss), ``{leaf's own name: value}``,
+        leaves of one name summed; nothing before a world's first
+        validation and for a model that writes none. From that step's
+        receipt, as :meth:`routing_state`."""
         parts = {}
-        held = self._host_copy_of(AUX_LOSS_COLLECTION)
-        for path, leaf in jax.tree_util.tree_leaves_with_path(held or {}):
+        held = self._validated_state.get(AUX_LOSS_COLLECTION, {})
+        for path, leaf in jax.tree_util.tree_leaves_with_path(held):
             name = str(getattr(path[-1], "key", path[-1]))
             parts[name] = parts.get(name, 0.0) + float(np.sum(leaf))
         return parts
+
+    def embedding_overflow_total(self):
+        """The a2a capacity overflow the embedding layers had counted
+        by the newest VALIDATED step (``nn/hbm_embedding.py``
+        ``a2a_overflow_total``), None for a model that counts none:
+        from that step's receipt, as :meth:`routing_state`."""
+        return a2a_overflow_total(self._validated_state)
 
     def _most_on_a_device(self, stat):
         """The largest ``memory_stats()[stat]`` over the mesh's local
@@ -2132,31 +2199,91 @@ class ElasticDPTrainer:
             (id(features), id(labels)), should_abort=self.abort_check
         )
 
+    def settle(self, lag=0):
+        """Wait for every dispatched step but the newest ``lag`` and
+        take their receipts: the validation of those steps. Returns
+        the losses of the data steps among them (and of data steps a
+        ``sync=True`` step validated without handing out), host floats,
+        oldest first.
+
+        ``lag=1`` is the worker's sync point: it has just dispatched
+        step ``i`` and waits for step ``i - 1``, which is the moment
+        the device starts step ``i``, so everything the sync point does
+        with what it reads here runs while the device computes. What
+        it reads is the receipt alone (loss, ``n_active``, the epoch
+        consensus, :func:`kept_of` its state), small outputs that step
+        ``i`` was not given and whose host copies began at dispatch:
+        never the train state, which step ``i`` has donated or is
+        writing. ``lag=0`` waits for the newest step too and leaves the
+        device idle; a weight-0 step, whose ``n_active`` drives the
+        exit, and the pause paths do.
+
+        The newest validated step's ``epoch_consensus``, ``n_active``,
+        version (:attr:`validated_version`) and receipt state
+        (:meth:`routing_state`, :meth:`aux_losses`,
+        :meth:`embedding_overflow_total`) replace the last ones, and
+        its state becomes the checked (re-form fallback) state where
+        one is kept (:meth:`_keep_checked`). Raises what a failed
+        collective raises, with nothing taken: the caller's
+        failed-window path owns those steps."""
+        # the wait for the newest of them is this window's ``fetch``
+        with profiling.phases.measure("fetch"):
+            self._validate(lag)
+        out, self._settled_losses = self._settled_losses, []
+        return out
+
+    def _validate(self, lag):
+        """:meth:`settle`'s wait, charged to no phase; returns the
+        newest validated step's loss, or None where there was no step
+        to validate."""
+        n_taken = len(self._in_flight) - lag
+        if n_taken <= 0:
+            return None
+        taken = self._in_flight[:n_taken]
+
+        def _fetch():
+            # the copies began when each step was dispatched
+            losses = [
+                float(host_copy(receipt[0]))
+                for receipt, has_data in taken
+                if has_data
+            ]
+            return losses, host_copy(taken[-1][0])
+
+        losses, newest = self._escapable(_fetch)
+        del self._in_flight[:n_taken]
+        self._in_flight_overflowed = False
+        self._settled_losses.extend(losses)
+        loss, n, epoch_seen, kept = newest
+        self.n_active, self.epoch_consensus = int(n), int(epoch_seen)
+        self._validated_version = int(kept["version"])
+        self._validated_state = kept["state"]
+        # the fetch proves every dispatched collective up to that step
+        # completed; checkpoint its state as the re-form fallback
+        self._keep_checked(self._ts if lag == 0 else self._ts_behind)
+        return float(loss)
+
     def drain_metrics(self):
-        """Host floats of every deferred (unsynced) step loss, oldest
-        first — the collect-later half of dispatch-and-collect-later.
-        Call at log/eval/sync boundaries. On a wedged device or a
-        failed collective the pending scalars are dropped (their steps'
+        """:meth:`settle` for a caller that has validated already
+        (:meth:`validate`) or can do without: the losses of every step
+        in flight, oldest first. On a wedged device or a failed
+        collective the receipts in flight are dropped (their steps'
         accounting is handled by the failed-window path)."""
-        pending, self._pending_metrics = self._pending_metrics, []
-        self._pending_metrics_overflowed = False
-        if not pending or self._wedged:
-            return []
-        out = []
         try:
-            with profiling.phases.measure("fetch"):
-                for loss in pending:
-                    out.append(
-                        loss if isinstance(loss, float) else float(loss)
-                    )
+            if self._wedged:
+                # a fetch would block forever on the wedged stream
+                return []
+            return self.settle()
         except Exception:
             logger.warning(
-                "deferred loss fetch failed (broken collective?); "
-                "dropping %d pending metrics",
-                len(pending) - len(out),
+                "receipt fetch failed (broken collective?); dropping "
+                "the losses of %d steps in flight",
+                len(self._in_flight),
                 exc_info=True,
             )
-        return out
+            return []
+        finally:
+            self._in_flight = []
 
     def _leaf_is_paddable(self, names):
         return any(
@@ -3085,13 +3212,21 @@ class ElasticDPTrainer:
         at sync) exposes the newest epoch ANY member has seen — the
         skew-proof reform/pause trigger.
 
-        ``sync=False`` skips the device->host fetch and returns
-        (None, None, count): dispatch stays asynchronous, so the host
-        (task RPCs, input pipeline) runs ahead of the device instead of
-        stalling a round trip per step. Unsynced steps
-        are validated at the next ``sync=True`` call; a collective
-        failure then rolls the snapshot back to the last validated
-        state (bounded by the caller's sync cadence)."""
+        ``sync=False`` dispatches and returns (None, None, count):
+        the host (task RPCs, input pipeline) runs ahead of the device
+        instead of stalling a round trip per step, and the step's
+        receipt (its loss, ``n_active``, the epoch consensus and
+        :func:`kept_of` its state, whose host copies begin here) waits in
+        flight for :meth:`settle`, which validates it. The worker
+        settles one step behind what it has dispatched
+        (``settle(lag=1)`` every ``sync_every`` steps), so a collective
+        failure rolls the snapshot back to the last validated state,
+        at most the caller's sync cadence and one step.
+
+        ``sync=True`` is the step and ``settle()`` in one: it waits for
+        this step's own result, with the device left idle meanwhile,
+        and returns its loss; the losses of earlier unsynced steps stay
+        for :meth:`settle` / :meth:`drain_metrics`."""
         # pad + place the batch, the weights and the epochs (the wait
         # for a staged placement when one was taken)
         with profiling.phases.measure("batch_place"):
@@ -3148,7 +3283,8 @@ class ElasticDPTrainer:
 
         def _dispatch():
             # everything device-touching — eager PRNG ops, the jit
-            # call, the sync fetches — runs on the sacrificial thread
+            # call — runs on the sacrificial thread (as the receipts'
+            # fetches do, in _validate)
             with profiling.phases.measure("dispatch"):
                 rng = jax.random.fold_in(
                     jax.random.PRNGKey(self._seed), host_step
@@ -3164,7 +3300,7 @@ class ElasticDPTrainer:
                 fn = self._step_callable_for(args)
                 with self._mesh:
                     try:
-                        new_ts, loss, n, epoch_seen = fn(*args)
+                        new_ts, *receipt = fn(*args)
                     except (TypeError, ValueError):
                         if fn is self._step_fn:
                             raise
@@ -3180,44 +3316,36 @@ class ElasticDPTrainer:
                         if self._step_entry is not None:
                             self._step_entry.aot.clear()
                             self._step_entry.dispatch_memo.clear()
-                        new_ts, loss, n, epoch_seen = self._step_fn(
-                            *args
-                        )
-            if not sync:
-                # collect-later: the loss scalar stays on device (it is
-                # already a future); drain_metrics fetches at boundaries
-                return new_ts, loss, None, None
-            with profiling.phases.measure("fetch"):
-                return (
-                    new_ts,
-                    float(host_copy(loss)),
-                    int(host_copy(n)),
-                    int(host_copy(epoch_seen)),
-                )
+                        new_ts, *receipt = self._step_fn(*args)
+                # the receipt's way to the host begins now, so that the
+                # sync point that reads it one step late finds it there
+                # the moment its step has finished
+                for leaf in jax.tree_util.tree_leaves(receipt):
+                    _local_replica(leaf).copy_to_host_async()
+            return new_ts, receipt
 
-        new_ts, loss_v, n_v, epoch_seen_v = self._escapable(_dispatch)
-        self._ts = new_ts
+        behind = self._ts
+        self._ts, receipt = self._escapable(_dispatch)
+        self._ts_behind = behind if self._mesh.is_multi_process else None
+        if len(self._in_flight) >= 4096:
+            # the bound only exists as a leak backstop; a caller that
+            # never settles loses losses, which must not happen silently
+            del self._in_flight[0]
+            if not self._in_flight_overflowed:
+                self._in_flight_overflowed = True
+                logger.warning(
+                    "4096 steps in flight: the receipts (and losses) of "
+                    "the oldest are DROPPED until the next settle — "
+                    "settle more often to keep the loss record complete"
+                )
+        self._in_flight.append((receipt, has_data))
         if not sync:
-            if has_data:
-                if len(self._pending_metrics) < 4096:
-                    self._pending_metrics.append(loss_v)
-                elif not self._pending_metrics_overflowed:
-                    # the bound only exists as a leak backstop; a sync
-                    # cadence long enough to hit it loses losses, which
-                    # must not happen silently
-                    self._pending_metrics_overflowed = True
-                    logger.warning(
-                        "deferred-metric buffer full (4096): losses of "
-                        "further unsynced steps are DROPPED until the "
-                        "next drain — sync/drain more often to keep "
-                        "the loss record complete"
-                    )
             return None, None, count
-        # the fetch proves every dispatched collective up to here
-        # completed; checkpoint that state as the re-form fallback
-        self.epoch_consensus = epoch_seen_v
-        self._keep_checked(new_ts)
-        return loss_v, n_v, count
+        with profiling.phases.measure("fetch"):
+            loss_v = self._validate(0)
+        if has_data:
+            self._settled_losses.pop()  # this step's own: returned here
+        return loss_v, self.n_active, count
 
     def _keep_checked(self, ts):
         """Remember ``ts`` as the fetch-validated device state a failed
@@ -3262,9 +3390,13 @@ class ElasticDPTrainer:
     def validate(self):
         """Force-complete all dispatched work; True if it all succeeded.
 
-        On success the latest state becomes the checked (re-form
-        fallback) state, where one is kept (:meth:`_keep_checked`); on
-        failure the checked state is left at the last validated point.
+        The wait is for the receipts in flight (:meth:`settle` with
+        the losses kept for :meth:`drain_metrics`, and charged to
+        whatever phase the caller is in), the newest step's included.
+        On success the latest state becomes the checked
+        (re-form fallback) state, where one is kept
+        (:meth:`_keep_checked`); on failure the checked state is left
+        at the last validated point.
         """
         if self._ts is None:
             return True
@@ -3273,11 +3405,10 @@ class ElasticDPTrainer:
             # again would block forever — the state is unvalidatable
             return False
         try:
-            self._escapable(lambda: host_copy(self._ts.version))
+            self._validate(0)
         except Exception:
             logger.warning("validation failed: a dispatched step errored")
             return False
-        self._keep_checked(self._ts)
         return True
 
     def snapshot(self):
@@ -3392,5 +3523,10 @@ class ElasticDPTrainer:
         self._mesh = None
         self._step_fn = None
         self._step_entry = None
-        # pending deferred losses reference the departed world's buffers
-        self._pending_metrics = []
+        # receipts in flight reference the departed world's buffers;
+        # losses nobody took belong to no window of the next world
+        self._in_flight = []
+        self._settled_losses = []
+        self._ts_behind = None
+        self._validated_version = None
+        self._validated_state = {}

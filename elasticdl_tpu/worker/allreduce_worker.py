@@ -180,7 +180,9 @@ class AllReduceWorker:
         from elasticdl_tpu.worker.reporting import with_model_version
 
         return self._stub.report_task_result(
-            task_id, err_msg, with_model_version(self.trainer, exec_counters)
+            task_id,
+            err_msg,
+            with_model_version(lambda: self.trainer.version, exec_counters),
         )
 
     # -- steps --------------------------------------------------------------

@@ -584,8 +584,15 @@ class ElasticAllReduceWorker:
     def report_task_result(self, task_id, err_msg="", exec_counters=None):
         from elasticdl_tpu.worker.reporting import with_model_version
 
+        # the VALIDATED version, which came with a receipt: a sync
+        # point reports with the newest step still on the device, and
+        # reading that step's version would wait for it
         result = self._stub.report_task_result(
-            task_id, err_msg, with_model_version(self.trainer, exec_counters)
+            task_id,
+            err_msg,
+            with_model_version(
+                lambda: self.trainer.validated_version, exec_counters
+            ),
         )
         # piggyback the (rate-limited) telemetry snapshot — resize and
         # speculative-compile events reach the master's event log here
@@ -1022,19 +1029,28 @@ class ElasticAllReduceWorker:
         The same fields go to the span plane as one
         ``train/window`` span, so ``/trace`` and the flight recorder
         hold the last windows of a worker that died. Called at sync
-        points, where the deferred losses of the window have just been
-        drained, BEFORE the window's task reports go out, so the event
-        rides them to the master. The clocks restart here: what this
-        method does after closing the window is the next window's
-        ``report``."""
+        points, where the losses of the steps just validated have been
+        taken (``trainer.settle``), BEFORE their task reports go out,
+        so the event rides them to the master. A window is what one
+        validation covered: at an aligned sync the worker validates one
+        step behind what it has dispatched, so the window ends with the
+        step BEFORE the aligned one (a world's first window holds
+        ``sync_every - 1`` steps, and its last, closed where the world
+        is left, the one or more that were still in flight), and
+        ``in_flight_at_fetch`` says how many dispatched steps the
+        closing fetch did not wait for: 1 where the device had the
+        next step to run while the host reported, 0 where it was
+        drained (a weight-0 step, a world's last window). The clocks
+        restart here: what this method does after closing the window is
+        the next window's ``report``."""
         window = losses[self._losses_reported :]
         if not window:
             return
         from elasticdl_tpu.utils import profiling
 
         self._losses_reported = len(losses)
-        # with the loss drain, inside the window it closes: its wait
-        # for the window's last step is this window's ``fetch``
+        # what the window's last step left in its receipt, which came
+        # to the host with its loss: nothing here asks the device
         routing = self._window_routing()
         # the last step's loss apart, of a model that adds to it
         # through ``aux_loss``: each part under the name the model
@@ -1057,6 +1073,7 @@ class ElasticAllReduceWorker:
             fields = dict(
                 worker=self._worker_id,
                 steps=len(window),
+                in_flight_at_fetch=self.trainer.steps_in_flight,
                 first_loss=float(window[0]),
                 last_loss=float(window[-1]),
                 nonfinite=int(np.sum(~np.isfinite(window))),
@@ -1077,8 +1094,9 @@ class ElasticAllReduceWorker:
         expert layers (``moe_rows_here``, ``moe_rows_routed``,
         ``moe_rows_max_expert``, ``moe_rows_mean_expert``,
         ``expert_bias_abs_max``: parallel/expert.py
-        ``window_routing_counters``), from one small host copy of the
-        model's routing state; no fields for a model without."""
+        ``window_routing_counters``), from the routing state in the
+        receipt of this window's last step and in that of the window
+        before's; no fields for a model without."""
         routing = self.trainer.routing_state()
         if routing is None or "experts_held" not in self._model_facts:
             return {}
@@ -1199,27 +1217,35 @@ class ElasticAllReduceWorker:
             m in dead for m in members if m != self._worker_id
         )
 
-    def _flush_unreported(self, err_msg=""):
+    def _flush_unreported(self, err_msg="", keep=0):
         """Report record counts held back while their steps were
-        unvalidated. With an err_msg the consumed-but-unapplied records
-        count as failures (per-task failure counters), and a task that
-        drains on the failing flush fail-reports + requeues — the
-        reference's failed-minibatch accounting semantics."""
-        pending, self._unreported = self._unreported, []
+        unvalidated; the newest ``keep`` stay held (the steps still in
+        flight: the one an aligned sync has dispatched and not
+        validated). With an err_msg the
+        consumed-but-unapplied records count as failures (per-task
+        failure counters), and a task that drains on the failing flush
+        fail-reports + requeues — the reference's failed-minibatch
+        accounting semantics."""
+        settled = len(self._unreported) - keep
+        pending = self._unreported[:settled]
+        del self._unreported[:settled]
         for count in pending:
             self._task_data_service.report_record_done(count, err_msg)
 
     def _settle_and_leave(self, verdict, validate=True, losses=None):
-        """The leave epilogue every pause path shares: settle the sync
-        window (validated steps report done, a failed window
+        """The leave epilogue every pause path shares: settle every
+        step in flight, the one an aligned sync dispatched and did not
+        wait for included (validated steps report done, a failed window
         fail-reports + requeues), checkpoint the sharded plane, close
-        any open trace, and leave the world. A validated window's
-        deferred (collect-later) losses drain into ``losses`` — leave()
-        drops the pending scalars, so without this the pause paths
-        would silently lose up to sync_every-1 recorded steps."""
+        any open trace, and leave the world. The validated steps'
+        losses drain into ``losses`` and close the world's last
+        ``train_window`` — leave() drops the receipts in flight, so
+        without this the pause paths would silently lose up to
+        sync_every recorded steps."""
         ok = self.trainer.validate() if validate else False
         if ok and losses is not None:
             losses.extend(self.trainer.drain_metrics())
+            self._report_losses(losses)
         self._flush_unreported(
             "" if ok else "collective failed before validation"
         )
@@ -1318,41 +1344,46 @@ class ElasticAllReduceWorker:
             with phases.measure("input_wait"):
                 batch = self._next_batch()
             step_i += 1
-            # syncing (a device->host round trip) every step stalls the
-            # dispatch pipeline; data steps sync every sync_every steps,
-            # drain steps always (their n_active drives the exit).
-            # Records consumed by unsynced steps are reported only once
-            # their window validates.
+            # waiting for a step's result leaves the device with nothing
+            # to run until the host has dispatched again. So the loop
+            # only dispatches, and every sync_every steps it validates
+            # ONE STEP BEHIND: with step i dispatched it waits for step
+            # i - 1's receipt (trainer.settle), which arrives as the
+            # device starts step i, and everything the sync point does
+            # (the window's event, the task reports, staging, cadence,
+            # the next poll, placement and dispatch) runs under step
+            # i's device time. Records consumed by a step are reported
+            # only once it validated: step i's at the next sync point.
+            # A weight-0 step waits for its own result as well (its
+            # n_active drives the exit).
             # aligned_sync points land at the same step INDEX on every
             # rank (loop iterations are lockstep — one collective per
-            # iteration), so version reads there agree globally; a
+            # iteration) and read the same step i - 1 there, so the
+            # epoch consensus and version reads agree globally; a
             # drain-forced sync is local to the draining rank
             aligned_sync = step_i % self._sync_every == 0
             sync = batch is None or aligned_sync
+            count = n_active = None
             try:
+                features, labels = (None, None) if batch is None else batch
+                _, _, count = self.trainer.train_step(
+                    features,
+                    labels,
+                    self._minibatch_size,
+                    sync=False,
+                    epoch_hint=w["epoch"],
+                )
+                if batch is not None:
+                    self._unreported.append(count)
+                if aligned_sync:
+                    # the validated steps' losses, chronological
+                    losses.extend(self.trainer.settle(lag=1))
+                # what every rank read of step i - 1, whatever this
+                # rank goes on to read of its own weight-0 step
+                consensus = self.trainer.epoch_consensus
                 if batch is None:
-                    loss, n_active, count = self.trainer.train_step(
-                        None,
-                        None,
-                        self._minibatch_size,
-                        sync=True,
-                        epoch_hint=w["epoch"],
-                    )
-                    losses.extend(self.trainer.drain_metrics())
-                else:
-                    features, labels = batch
-                    loss, n_active, count = self.trainer.train_step(
-                        features,
-                        labels,
-                        self._minibatch_size,
-                        sync=sync,
-                        epoch_hint=w["epoch"],
-                    )
-                    if loss is not None:
-                        # collect-later losses of the unsynced window
-                        # land first, keeping the list chronological
-                        losses.extend(self.trainer.drain_metrics())
-                        losses.append(loss)
+                    losses.extend(self.trainer.settle())
+                    n_active = self.trainer.n_active
             except Exception:
                 logger.exception("collective step failed")
                 # the whole unvalidated window (including this batch)
@@ -1360,7 +1391,9 @@ class ElasticAllReduceWorker:
                 # records are re-read by whichever worker picks it up —
                 # retrying the batch here would double-charge the
                 # requeued task's accounting
-                if batch is not None:
+                if batch is not None and count is None:
+                    # the dispatch itself failed: its batch is not
+                    # among the held counts yet
                     leaf = batch[1]
                     self._unreported.append(int(np.asarray(leaf).shape[0]))
                 self._settle_and_leave("reform", validate=False)
@@ -1368,41 +1401,44 @@ class ElasticAllReduceWorker:
                     raise
                 return "reform"
             if batch is not None:
-                self._unreported.append(count)
                 self._telemetry.on_batch(count)
             if sync:
               # a peer death can surface here as WorldBroken from the
               # escapable waits inside the cadence fetches / the pause
               # refresh (trainer._await_ready): take the same reform
-              # path as a failed step — the just-synced window already
-              # validated and flushed, so no accounting is lost
+              # path as a failed step — the validated steps were
+              # flushed, and the one in flight fail-reports with it
               try:
                 self._report_losses(losses)
                 with phases.measure("report"):
-                    self._flush_unreported()
+                    # step i's records stay held: it has not validated
+                    self._flush_unreported(
+                        keep=self.trainer.steps_in_flight
+                    )
                 if batch is not None:
                     # step overlap: pull batch N+1 now — its H2D
                     # placement runs on the feeder thread while the
                     # cadence work below (checkpoint save, eval rounds,
                     # mirror refresh) runs here. Strictly AFTER the
-                    # flush: every consumed record is reported, so a
-                    # round boundary this peek crosses sees the same
-                    # settled ledger the unpeeked loop's next
+                    # flush: every validated record is reported, and a
+                    # round boundary this peek crosses settles the one
+                    # step in flight itself (_batches), so it sees the
+                    # same settled ledger the unpeeked loop's next
                     # _next_batch would — get_dataset never refuses
                     # over records this very iteration consumed.
                     with phases.measure("stage_next"):
                         self._peek_and_stage_next()
                 with phases.measure("cadence"):
                     self._alarm_on_embedding_overflow()
-                consensus = self.trainer.epoch_consensus
                 if (
                     aligned_sync
                     and consensus is not None
                     and consensus > world.epoch
                 ):
-                    # every member reads this SAME consensus value at
-                    # this SAME step index — the whole world pauses in
-                    # unison, no collective left hanging
+                    # every member reads this SAME consensus value (of
+                    # the step before) at this SAME step index, with
+                    # this index's step dispatched — the whole world
+                    # pauses in unison, no collective left hanging
                     logger.info(
                         "epoch bump %d -> %d; pausing at aligned sync",
                         world.epoch,
@@ -1510,18 +1546,9 @@ class ElasticAllReduceWorker:
     def _alarm_on_embedding_overflow(self):
         """Surface a2a capacity overflow (ids silently trained on zero
         rows) at sync points. The counter is a replicated scalar in the
-        model state, so the read costs one scalar fetch per sync."""
-        ts = self.trainer._ts
-        if ts is None:
-            return
-        from elasticdl_tpu.nn.hbm_embedding import a2a_overflow_total
-
-        try:
-            total = a2a_overflow_total(ts.state)
-        except Exception:
-            # mid-failure state; the step error path owns it
-            logger.debug("overflow counter fetch failed", exc_info=True)
-            return
+        model state that comes to the host in each step's receipt, so
+        the read costs nothing."""
+        total = self.trainer.embedding_overflow_total()
         if total and total > self._overflow_alarmed:
             logger.warning(
                 "embedding a2a capacity overflow: %d ids have read zero "

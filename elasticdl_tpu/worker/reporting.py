@@ -3,14 +3,15 @@
 from elasticdl_tpu.common.constants import TaskExecCounterKey
 
 
-def with_model_version(trainer, exec_counters):
-    """Piggyback the trainer's on-device version onto task-report
-    counters so the coordinating (ALLREDUCE) master — which applies no
-    gradients — can drive version-based triggers like the evaluation
-    cadence. Reading the version forces a device sync and can re-raise a
-    poisoned async dispatch on failure paths, so it is best-effort."""
+def with_model_version(read_version, exec_counters):
+    """Piggyback the trainer's model version (``read_version()``) onto
+    task-report counters so the coordinating (ALLREDUCE) master — which
+    applies no gradients — can drive version-based triggers like the
+    evaluation cadence. Reading the version may force a device sync and
+    can re-raise a poisoned async dispatch on failure paths, so it is
+    best-effort."""
     try:
-        version = trainer.version
+        version = read_version()
     except Exception:  # noqa: BLE001 - failure paths must still report
         version = -1
     if version >= 0:

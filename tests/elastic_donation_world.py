@@ -34,6 +34,7 @@ from elasticdl_tpu.parallel.distributed import WorldSpec  # noqa: E402
 from elasticdl_tpu.parallel.elastic import (  # noqa: E402
     ElasticDPTrainer,
     count_donated_inputs,
+    host_copy,
     make_pjit_train_step,
 )
 from model_zoo.transformer_lm import transformer_lm as tzoo  # noqa: E402
@@ -110,7 +111,22 @@ def main(pid, port_alone, port_pair):
     out["pair_input_deleted"] = any(_deleted(given))
     out["pair_checked_is_newest"] = trainer._checked_ts is trainer._ts
     out["pair_version"] = trainer.version
+    # the worker's aligned sync: two steps dispatched, the one before
+    # the newest validated. The rollback state is the VALIDATED step's
+    # (this mesh donates nothing, so it is alive), not the newest's
+    for i in range(2):
+        trainer.train_step(*_batch(20 + i + pid), ROWS, sync=False)
+    out["lagged_losses"] = len(trainer.settle(lag=1))
+    out["lagged_in_flight"] = trainer.steps_in_flight
+    out["lagged_checked_is_newest"] = trainer._checked_ts is trainer._ts
+    out["lagged_checked_version"] = int(
+        host_copy(trainer._checked_ts.version)
+    )
+    out["lagged_checked_deleted"] = any(_deleted(trainer._checked_ts))
+    out["lagged_validated_version"] = trainer.validated_version
+    out["lagged_newest_version"] = trainer.version
     out["pair_validated"] = trainer.validate()
+    out["settled_losses"] = len(trainer.drain_metrics())
     trainer.leave()
     trainer.close()
     print(json.dumps(out), flush=True)

@@ -455,12 +455,12 @@ def test_drain_metrics_empty_and_wedged():
     assert t.drain_metrics() == []
     features, labels = _batch(40)
     t.train_step(features, labels, BATCH, sync=False)
-    assert len(t._pending_metrics) == 1
+    assert t.steps_in_flight == 1
     # a wedged trainer must not fetch (the device stream would block
-    # forever); pending is dropped
+    # forever); the receipts in flight are dropped
     t._wedged = True
     assert t.drain_metrics() == []
-    assert t._pending_metrics == []
+    assert t.steps_in_flight == 0
     t._wedged = False
     t.close()
 

@@ -275,7 +275,7 @@ def test_layout_resolve_2x2_4x1_2x2_carries_state():
             w = put(np.ones((n_dev,), np.float32))
             e = put(np.zeros((n_dev,), np.int32))
             with mesh:
-                ts, loss, _n, _ = step(
+                ts, loss, _n, _, _ = step(
                     ts, g_f, g_l, w, e, jax.random.PRNGKey(7)
                 )
             losses.append(float(loss))

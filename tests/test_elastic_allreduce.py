@@ -237,7 +237,7 @@ def test_weighted_step_matches_plain_and_drain_is_noop():
     key = jax.random.PRNGKey(7)
 
     with mesh:
-        ts1, loss, n, _ = step(ts, g_feat, g_lab, ones, ep, key)
+        ts1, loss, n, _, _ = step(ts, g_feat, g_lab, ones, ep, key)
     assert int(n) == 8 and np.isfinite(float(loss))
     assert int(host_copy(ts1.version)) == 1
 
@@ -255,7 +255,7 @@ def test_weighted_step_matches_plain_and_drain_is_noop():
 
     # drain step: weight 0 everywhere is an exact no-op
     with mesh:
-        ts2, _, n0, _ = step(ts1, g_feat, g_lab, zeros, ep, key)
+        ts2, _, n0, _, _ = step(ts1, g_feat, g_lab, zeros, ep, key)
     assert int(n0) == 0
     assert int(host_copy(ts2.version)) == 1
     for a, b in zip(
@@ -315,7 +315,7 @@ def test_weighted_step_with_accumulation_matches_plain():
 
     key = jax.random.PRNGKey(7)
     with mesh:
-        ts1, loss, n, _ = step(
+        ts1, loss, n, _, _ = step(
             ts,
             put(features, P("data")),
             put(labels, P("data")),

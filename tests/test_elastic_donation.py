@@ -330,3 +330,23 @@ def test_a_mesh_that_spans_processes_keeps_its_input_state(
     # and the rollback state is kept as before
     assert account["pair_checked_is_newest"] is True
     assert account["pair_validated"] is True
+
+
+@pytest.mark.parametrize("rank", [0, 1])
+def test_a_lagged_validation_keeps_the_validated_state_not_the_newest(
+    two_process_world, rank
+):
+    """``settle(lag=1)`` with two steps dispatched on versions 5 -> 7:
+    one loss is handed out, one step stays in flight, and the state a
+    failed collective would roll back to is the validated step's (alive:
+    this mesh donates nothing), one version behind the newest."""
+    account = two_process_world[rank]
+    assert account["lagged_losses"] == account["lagged_in_flight"] == 1
+    assert account["lagged_checked_is_newest"] is False
+    assert account["lagged_checked_deleted"] is False
+    assert account["lagged_checked_version"] == 6
+    assert account["lagged_validated_version"] == 6  # the receipt's
+    assert account["lagged_newest_version"] == 7
+    # and the pause settles the step left in flight
+    assert account["pair_validated"] is True
+    assert account["settled_losses"] == 1

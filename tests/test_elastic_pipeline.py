@@ -105,7 +105,7 @@ def _run(mesh, model, specs, batches, weights, opt):
     losses = []
     with mesh:
         for features, labels in batches:
-            ts, loss, n, _ = step(
+            ts, loss, n, _, _ = step(
                 ts,
                 _put_rows(mesh, features, row_axes),
                 _put_rows(mesh, labels, row_axes),
@@ -215,7 +215,7 @@ def test_collective_pp_drain_is_exact_noop():
         np.array, jax.device_get(ts.params)
     )
     with mesh:
-        ts2, _, n, _ = step(
+        ts2, _, n, _, _ = step(
             ts,
             _put_rows(mesh, batches[0][0], row_axes),
             _put_rows(mesh, batches[0][1], row_axes),

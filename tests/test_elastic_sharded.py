@@ -95,7 +95,7 @@ def test_sharded_elastic_step_matches_dense_training():
     losses = []
     with mesh:
         for features, labels in batches:
-            ts, loss, n, _ = step(
+            ts, loss, n, _, _ = step(
                 ts, put_batch(features), put_batch(labels), ones, ep, key
             )
             assert int(n) == 8
@@ -152,7 +152,7 @@ def test_sharded_elastic_drain_is_exact_noop():
     )
     key = jax.random.PRNGKey(3)
     with mesh:
-        ts2, _, n, _ = step(
+        ts2, _, n, _, _ = step(
             ts,
             put_batch(batches[0][0]),
             put_batch(batches[0][1]),
@@ -197,7 +197,7 @@ def test_sharded_elastic_partial_weights_downweight_dead_devices():
     key = jax.random.PRNGKey(4)
     with mesh:
         for features, labels in batches:
-            ts, loss, n, _ = step(
+            ts, loss, n, _, _ = step(
                 ts, put_batch(features), put_batch(labels), weights, ep, key
             )
             assert int(n) == 4

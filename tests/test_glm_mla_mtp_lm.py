@@ -353,7 +353,7 @@ def test_the_modules_loss_reaches_the_steps_loss_through_aux_loss(
         mesh = Mesh(np.asarray(jax.devices()[:1]), ("data",))
         put = lambda x: jax.device_put(x, NamedSharding(mesh, P("data")))
         with mesh:
-            new, loss, _, _ = make_elastic_train_step(model, zoo.loss, opt, mesh)(
+            new, loss, _, _, kept = make_elastic_train_step(model, zoo.loss, opt, mesh)(
                 jax.device_put(ts, NamedSharding(mesh, P())),
                 {"tokens": put(tokens)}, put(tokens), put(np.ones(1, np.float32)),
                 put(np.zeros(1, np.int32)), key,
@@ -364,24 +364,30 @@ def test_the_modules_loss_reaches_the_steps_loss_through_aux_loss(
     np.testing.assert_allclose(
         float(new.state["aux_loss"]["mtp_loss"]), float(bare.mtp_loss), rtol=1e-5
     )
+    if builder != "training/step.py":
+        # and beside it, in the step's receipt: an output of its own
+        assert float(kept["state"]["aux_loss"]["mtp_loss"]) == float(
+            new.state["aux_loss"]["mtp_loss"]
+        )
     assert np.abs(np.asarray(new.params["mtp_0_proj"]["kernel"]) - before).max() > 0
 
 
 def test_the_trainer_reads_the_last_steps_parts(zoo, tokens):
     """What the worker's ``train_window`` event carries as ``mtp_loss``
-    (and, with the window's last loss, ``lm_loss``): the trainer's host
-    copy of the ``aux_loss`` collection, a leaf under its own name."""
-    from elasticdl_tpu.parallel.elastic import ElasticDPTrainer
-    from elasticdl_tpu.training.step import TrainState
+    (and, with the window's last loss, ``lm_loss``): the ``aux_loss``
+    collection of the last VALIDATED step's receipt, a leaf under its
+    own name; never a read of the live train state."""
+    from elasticdl_tpu.parallel.elastic import ElasticDPTrainer, receipt_state
 
     model, params, state, bare = _state_and_manual_loss(zoo, tokens)
-    state = {**state, "aux_loss": {"mtp_loss": bare.mtp_loss}}
+    state = {**state, "aux_loss": {"mtp_loss": np.asarray(bare.mtp_loss)}}
     trainer = ElasticDPTrainer.__new__(ElasticDPTrainer)
-    trainer._ts = TrainState.create(params, state, optax.sgd(0.1))
+    trainer._validated_state = receipt_state(state)
+    assert set(trainer._validated_state) == {"aux_loss", "moe_state"}
     parts = trainer.aux_losses()
     assert list(parts) == ["mtp_loss"]
     np.testing.assert_allclose(parts["mtp_loss"], float(bare.mtp_loss), rtol=1e-6)
-    trainer._ts = None
+    trainer._validated_state = {}
     assert trainer.aux_losses() == {}
 
 

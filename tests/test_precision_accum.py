@@ -248,7 +248,7 @@ def test_remat_step_matches_plain():
             model, loss_fn, opt, mesh, remat=remat
         )
         with mesh:
-            ts, loss, n, _ = estep(ts, g_feat, g_lab, ones, ep, key)
+            ts, loss, n, _, _ = estep(ts, g_feat, g_lab, ones, ep, key)
         outs.append((float(host_copy(loss)), host_copy(ts.params)))
     np.testing.assert_allclose(outs[1][0], outs[0][0], rtol=1e-6)
     for a, b in zip(

@@ -403,9 +403,32 @@ NUMBER_FIELDS = tuple(p + "_s" for p in STEP_PHASES) + (
 @pytest.mark.parametrize("field", NUMBER_FIELDS)
 def test_every_train_window_carries_the_field(job, field):
     windows, _ = job
-    assert len(windows) == STEPS // SYNC_EVERY
+    assert len(windows) == STEPS // SYNC_EVERY + 1
     for w in windows:
         assert w[field] >= 0, (field, w)
+
+
+def test_a_window_ends_one_step_behind_its_aligned_sync(job):
+    """The worker validates step ``i - 1`` with step ``i`` dispatched:
+    a world's first window holds ``sync_every - 1`` steps, every later
+    one ``sync_every``, and the job's end validates the one step left
+    in flight; no step is counted twice or lost."""
+    windows, _ = job
+    assert [w["steps"] for w in windows] == (
+        [SYNC_EVERY - 1]
+        + [SYNC_EVERY] * (STEPS // SYNC_EVERY - 1)
+        + [1]
+    )
+
+
+def test_in_flight_at_fetch_is_one_until_the_device_is_drained(job):
+    """Every closing fetch but the last left one dispatched step for
+    the device to run while the host reported; the last one is the
+    weight-0 step's, which waits for its own result."""
+    windows, _ = job
+    assert [w["in_flight_at_fetch"] for w in windows] == (
+        [1] * (len(windows) - 1) + [0]
+    )
 
 
 def test_every_train_window_names_its_slowest_phase(job):
